@@ -1,8 +1,10 @@
 # Container image for the TPU-native framework (ref reference Dockerfile:
-# the reference bundles Spark + PIO; here the runtime is Python + JAX).
-# For TPU hosts, swap the base image for one with libtpu and run with the
-# TPU device plugin; on CPU this image serves the event/query/admin planes
-# and runs tests.
+# the reference bundles Spark + PIO; here the runtime is Python 3.12 +
+# jax/jaxlib 0.9.0 + libtpu 0.0.34, which `pip install .` pulls in through
+# pyproject's jax[tpu]==0.9.0). On a TPU host run it with the chip's
+# devices passed through (/dev/vfio); train and deploy then need the chip
+# and fail without one. With JAX_PLATFORMS=cpu the image serves the
+# event/query/admin planes and runs the tests on the CPU.
 FROM python:3.12-slim
 
 RUN apt-get update \

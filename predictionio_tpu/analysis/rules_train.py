@@ -8,7 +8,7 @@ explicitly accounted (``pio_train_device_seconds_total``, the
 ``jax.block_until_ready`` / ``jax.device_get`` / one-arg ``np.asarray`` /
 ``.item()`` on a device value stalls the host for a device round-trip
 that *no instrument sees* — the profile under-reports device time and the
-roofline math in docs/PERF.md silently rots. Sanctioned forms:
+roofline math in PERF.md silently rots. Sanctioned forms:
 
 - ``obs.jaxprof.timed_block_until_ready(x, registry, where=…)``
 - ``obs.xray.device_fetch(x, where=…)`` / ``TrainProfile.device_barrier``

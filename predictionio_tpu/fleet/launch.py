@@ -117,12 +117,33 @@ def worker_argv(
     return out
 
 
+def _refuse_shared_chips(device_workers: int, args) -> None:
+    """A chip belongs to one process at a time, and every device-class
+    worker is a process that sees (and claims) every chip of its host: a
+    second one on the same box fails or hangs at backend start-up.
+    Refused here instead. With ``--hosts`` placement is per box and is
+    the operator's to size; cpu-class workers never want the chip."""
+    if (
+        device_workers > 1
+        and not getattr(args, "hosts", None)
+        and os.environ.get("JAX_PLATFORMS") != "cpu"
+    ):
+        raise ValueError(
+            f"{device_workers} device-class workers on this host would each "
+            "claim every chip, and a chip belongs to one process: use "
+            "--fleet 1 (with --fleet-max 1 under --autoscale; overflow "
+            "goes to --cpu-fallback-max workers), place workers with "
+            "--hosts, or run the fleet on the CPU with JAX_PLATFORMS=cpu"
+        )
+
+
 def run_fleet(args, cli_argv: list[str]) -> int:
     """Blocking fleet entry point for ``cmd_deploy``. ``cli_argv`` is the
     raw CLI argument vector (sys.argv[1:]) the workers are derived from."""
     n = int(args.fleet)
     if n < 1:
         raise ValueError("--fleet needs at least 1 replica")
+    _refuse_shared_chips(n, args)
     if getattr(args, "ssl_certfile", None) or getattr(args, "ssl_keyfile", None):
         # workers would inherit the TLS flags and serve HTTPS, but the
         # gateway probes/forwards plain HTTP on loopback — every replica
@@ -475,6 +496,7 @@ def build_autoscaler(
             f"--fleet-max ({config.max_replicas}) must be >= the --fleet "
             f"boot size ({n})"
         )
+    _refuse_shared_chips(config.max_replicas, args)
     if config.cpu_fallback_max < 0:
         raise ValueError("--cpu-fallback-max must be >= 0")
     if config.tick_interval_s <= 0:

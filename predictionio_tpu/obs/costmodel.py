@@ -12,7 +12,7 @@ intensity** (flops/byte), and projects it onto a device roofline
 "device cost per 1k queries" in USD.
 
 This runs entirely on the CPU backend — lowering + compiling never
-touches a device — so every sandbox-measured claim in docs/PERF.md gains
+touches a device — so every sandbox-measured claim gains
 an analytic device anchor *before* any hardware window opens (ROADMAP
 item 5: "no hardware window is wasted"). ALX (PAPERS.md) sized its TPU
 ALS from exactly this per-kernel flops/bytes accounting.
@@ -58,15 +58,6 @@ DEVICE_SPECS: dict[str, DeviceSpec] = {
 DEFAULT_DEVICE = "tpu-v4"
 
 
-def _first_cost_dict(compiled) -> dict[str, float]:
-    """``cost_analysis()`` returns a dict on some jax versions and a
-    one-element list of dicts on others; normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
 def _struct_bytes(tree) -> int:
     import jax
 
@@ -93,7 +84,7 @@ def _lower_cost(
     static_kwargs = static_kwargs or {}
     lowered = fn.lower(*args, **static_kwargs)
     compiled = lowered.compile()
-    ca = _first_cost_dict(compiled)
+    ca = compiled.cost_analysis()  # a dict in jax 0.9.0
     arg_bytes = _struct_bytes(args)
     # jitted-fn eval_shape respects static_argnames (the plain
     # jax.eval_shape would trace the static kwargs as abstract values)

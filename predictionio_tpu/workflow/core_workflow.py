@@ -88,7 +88,7 @@ def run_train(
     if not multi_host and "jax" in sys.modules:
         import jax
 
-        if getattr(jax.distributed, "is_initialized", lambda: False)():
+        if jax.distributed.is_initialized():
             multi_host = jax.process_count() > 1
     if multi_host:
         import jax
@@ -190,7 +190,13 @@ def run_train(
         wall = time.perf_counter() - t0
         instance.status = EngineInstanceStatus.COMPLETED
         instance.end_time = _dt.datetime.now(tz=UTC)
-        instance.spark_conf = {"train_wall_clock_sec": f"{wall:.3f}"}
+        # where the train's arrays sat, as the profile sampled it from the
+        # arrays themselves; None for a pure-host engine or under PIO_XRAY=0
+        devices = profile.devices if profile is not None else None
+        instance.spark_conf = {
+            "train_wall_clock_sec": f"{wall:.3f}",
+            "train_device": json.dumps(devices),
+        }
         instances.update(instance)
         _publish_to_registry(
             manifest,
@@ -205,11 +211,13 @@ def run_train(
             models=persistable,
         )
         logger.info(
-            "training completed: instance %s, %.2fs, %d model(s), %d byte blob",
+            "training completed: instance %s, %.2fs, %d model(s), %d byte "
+            "blob, device %s",
             instance_id,
             wall,
             len(models),
             len(blob),
+            json.dumps(devices),
         )
         return instance_id
     except Exception:

@@ -55,6 +55,7 @@ from predictionio_tpu.bandit import (
 from predictionio_tpu.controller.engine import Engine, EngineParams
 from predictionio_tpu.data.storage.base import EngineInstance
 from predictionio_tpu.data.storage.registry import Storage
+from predictionio_tpu.obs import xray
 from predictionio_tpu.obs.jaxprof import CompileWatcher
 from predictionio_tpu.obs.metrics import MetricsRegistry
 from predictionio_tpu.obs.profiler import (
@@ -893,6 +894,7 @@ class QueryServer:
         self._shadow_lock = threading.Lock()
         self._shadow_pending = 0
         self.start_time = _dt.datetime.now(tz=UTC)
+        self.serving_devices: dict[str, Any] | None = None  # set by warmup
         self.request_count = 0
         self.avg_serving_sec = 0.0
         self.last_serving_sec = 0.0
@@ -1831,6 +1833,7 @@ class QueryServer:
                 "engineFactory": self.manifest.engine_factory,
                 "engineInstanceId": self.instance_id,
                 "modelVersion": self._active.version,
+                "device": self.serving_devices,
                 "rollout": {
                     "mode": self._plan.mode,
                     "fraction": self._plan.fraction,
@@ -1958,9 +1961,8 @@ class QueryServer:
                 _, _, algorithms, serving = self.engine.make_components(
                     engine_params
                 )
-                # warm the NEW components before they take traffic (warmup
-                # failures are non-fatal by the same contract as deploy-time
-                # warmup: the first burst just pays its XLA compiles)
+                # warm the NEW components before they take traffic; a
+                # warmup failure fails this reload and the old lane stays
                 await loop.run_in_executor(
                     None, self._warmup_components, algorithms, models
                 )
@@ -2877,11 +2879,15 @@ class QueryServer:
         # the kernel variant its dispatch actually runs (exclusion /
         # composed-tower), not the generic one
         ann_lifecycle.bind_instruments(models, self.ann_instruments)
+        # a warmup failure is not swallowed: a program the device's
+        # compiler refuses would otherwise be paid for, or thrown, on the
+        # first request. At startup it fails the start; /reload and the
+        # registry lane loaders catch it, keep the old lane and report.
         for algo, model in zip(algorithms, models):
-            try:
-                algo.warmup_serving(model, self.config.max_batch_size)
-            except Exception:
-                logger.exception("serving warmup failed (continuing)")
+            algo.warmup_serving(model, self.config.max_batch_size)
+        # where the resident serving arrays sit, read from the arrays
+        # themselves (GET / reports it)
+        self.serving_devices = xray.live_devices()
         # baseline the compile watcher AFTER warmup: the compiles warmup
         # just paid for are intentional; only compiles past this point are
         # serving-time recompiles worth alarming on
@@ -2927,7 +2933,12 @@ class QueryServer:
                     await asyncio.sleep(1.0)
         else:
             raise last_error  # type: ignore[misc]
-        logger.info("engine server on %s:%d", self.config.ip, self.config.port)
+        logger.info(
+            "engine server on %s:%d, device %s",
+            self.config.ip,
+            self.config.port,
+            json.dumps(self.serving_devices),
+        )
 
     async def drain(self) -> None:
         """Graceful drain (the SIGTERM path): stop accepting, let the

@@ -15,10 +15,6 @@ os.environ["XLA_FLAGS"] = (
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from predictionio_tpu.utils.platform import ensure_cpu_if_requested
-
-ensure_cpu_if_requested()
-
 import jax  # noqa: E402
 
 
