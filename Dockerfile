@@ -20,7 +20,11 @@ COPY pio ./pio
 
 RUN pip install --no-cache-dir . flax optax
 
-ENV PIO_FS_BASEDIR=/var/lib/pio
+# the package is installed, not run from a checkout, so its default cache
+# path (<checkout>/.jax_cache) would land in site-packages: keep compiled
+# programs in the data volume instead
+ENV PIO_FS_BASEDIR=/var/lib/pio \
+    JAX_COMPILATION_CACHE_DIR=/var/lib/pio/jax_cache
 VOLUME /var/lib/pio
 
 # event server 7070, engine server 8000, admin 7071, dashboard 9000

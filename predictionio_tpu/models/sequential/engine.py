@@ -491,7 +491,9 @@ class AttentionAlgorithmParams(Params):
     lambda_: float = 0.1
     seed: int = 3
     # session window the attention encoder attends over; short by design
-    # (the pallas kernel's single-block path covers it on TPU)
+    # (the pallas kernel's single-block path covers it on TPU). On the
+    # chip a context of 1024 or more must be a multiple of 256
+    # (ops/attention.fused_attention refuses it otherwise)
     context: int = 8
     top_n: int = 10
 

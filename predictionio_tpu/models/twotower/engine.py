@@ -113,7 +113,9 @@ class TwoTowerAlgorithmParams(Params):
     seed: int = 0
     mesh: str = ""  # e.g. "data=-1,model=2"; empty = all devices on data
     # sequence encoder over each user's recent item history (consumes the
-    # pallas fused-attention kernel on TPU, ops/attention.py); 0 disables
+    # pallas fused-attention kernel on TPU, ops/attention.py); 0 disables.
+    # On the chip a length of 1024 or more must be a multiple of 256
+    # (fused_attention refuses it otherwise)
     history_len: int = 0
     n_heads: int = 2
     # sequence/context parallelism for the encoder: shard the history axis

@@ -58,7 +58,8 @@ class TwoTowerConfig:
     checkpoint_every: int = 1  # epochs between checkpoints
     resume: bool = True  # continue from the newest checkpoint if present
     # sequence encoder: 0 disables; > 0 = length of the per-user item
-    # history consumed by causal self-attention in the user tower
+    # history consumed by causal self-attention in the user tower (on the
+    # chip, 1024 or more must be a multiple of 256: fused_attention)
     history_len: int = 0
     n_heads: int = 2
     # sequence/context parallelism for the history encoder: when True and a

@@ -11,8 +11,8 @@ mode a first-class signal:
   above ``storm_threshold`` in one sampling interval raises the
   ``pio_jit_recompile_storm`` gauge and logs a warning naming the
   functions that recompiled.
-- :func:`install_jax_monitoring` taps ``jax.monitoring`` (when present)
-  for backend compile events and their durations —
+- :func:`install_jax_monitoring` taps ``jax.monitoring`` for backend
+  compiles and the seconds spent tracing, lowering and compiling —
   ``pio_xla_compile_events_total`` / ``pio_xla_compile_seconds_total``.
 - :func:`timed_block_until_ready` is the sanctioned way for algorithm
   code to host-sync: it accounts the stall into
@@ -52,40 +52,34 @@ def _looks_like_compile(event: str) -> bool:
     return "compil" in e and "/compilation_cache/" not in e
 
 
-def _on_event(event: str, *args: Any, **kwargs: Any) -> None:
-    global _mon_compile_events
-    if _looks_like_compile(str(event)):
-        with _mon_lock:
-            _mon_compile_events += 1
-
-
 def _on_duration(event: str, duration_secs: float, *a: Any, **kw: Any) -> None:
-    global _mon_compile_seconds
-    if _looks_like_compile(str(event)):
+    """jax 0.9.0 reports a compile as three durations (jaxpr trace, MLIR
+    lowering, backend compile) and as no plain event: the seconds are
+    the sum of all three, the count is one per backend compile (or its
+    load from the persistent cache)."""
+    global _mon_compile_events, _mon_compile_seconds
+    event = str(event)
+    if _looks_like_compile(event):
         with _mon_lock:
             _mon_compile_seconds += float(duration_secs)
+            if event.endswith("/backend_compile_duration"):
+                _mon_compile_events += 1
 
 
-def install_jax_monitoring() -> bool:
-    """Register compile-event listeners with ``jax.monitoring``.
-    Idempotent; returns False when jax (or the API) is unavailable.
-    The whole check-register-set sequence holds the lock (registration
-    is a plain list append, never re-enters this module) — a
-    check-then-act gap would let two concurrent watchers double-register
-    and permanently double-count every compile event."""
+def install_jax_monitoring() -> None:
+    """Register the compile-duration listener with ``jax.monitoring``.
+    Idempotent. The whole check-register-set sequence holds the lock
+    (registration is a plain list append, never re-enters this module) —
+    a check-then-act gap would let two concurrent watchers
+    double-register and permanently double-count every compile."""
     global _mon_installed
     with _mon_lock:
         if _mon_installed:
-            return True
-        try:
-            import jax.monitoring as monitoring
+            return
+        import jax.monitoring as monitoring
 
-            monitoring.register_event_listener(_on_event)
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            return False
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _mon_installed = True
-        return True
 
 
 def monitoring_totals() -> tuple[int, float]:

@@ -442,25 +442,29 @@ def fused_attention(
     path (``force_pallas`` runs the kernels in interpret mode, which is
     how the tests exercise them off-chip; on the chip the kernels are
     always compiled). Platform is read from ``jax.default_backend()`` so
-    the choice also works on tracers (e.g. inside shard_map)."""
+    the choice also works on tracers (e.g. inside shard_map).
+
+    Limit of the kernel path: a sequence whose score tile is past the
+    single-block budget (Lq * Lk >= 2**20, i.e. L >= 1024 square) must be
+    a multiple of 256 in both lengths, or the call raises ValueError."""
+    on_tpu = jax.default_backend() == "tpu"
+    if not (on_tpu or force_pallas):
+        return attention_reference(q, k, v, causal=causal)
     Lq, Lk = q.shape[2], k.shape[2]
     # single-block kernel holds the [Lq, Lk] f32 score tile in VMEM
     # (strict <: a 4MiB tile — L=1024 square — already takes the flash
     # path, which the interpret-mode routing test pins)
     single_block = Lq * Lk * 4 < 4 * 1024 * 1024
     if not single_block and (Lq % 256 or Lk % 256):
-        # refused on every platform, so a CPU test meets what the chip
-        # would: past the single-block budget the flash kernel needs
-        # divisible tiles, and a quiet switch to the jnp path there would
-        # hide which code ran
+        # past the single-block budget the flash kernel needs divisible
+        # tiles; a quiet switch to the jnp path here would hide which code
+        # ran on the chip
         raise ValueError(
             f"fused_attention: Lq={Lq}, Lk={Lk} is past the single-block "
-            "budget and not a multiple of 256; pad the sequence or call "
+            "kernel's budget and not a multiple of 256, which the flash "
+            "kernel needs; pad the sequence to a multiple of 256 or call "
             "attention_reference"
         )
-    on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
-        return attention_reference(q, k, v, causal=causal)
     interpret = not on_tpu
     if single_block:
         return _fused_attention_pallas(q, k, v, causal, interpret=interpret)

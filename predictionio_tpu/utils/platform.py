@@ -13,7 +13,11 @@ import os
 from pathlib import Path
 
 # <checkout>/.jax_cache, from this file's location: the directory is part
-# of the cache key, so it must not depend on the working directory
+# of the cache key, so it must not depend on the working directory. This
+# is the checkout only when the package runs from a source tree. Installed
+# with pip the same expression names site-packages/.jax_cache, which may be
+# read-only or shared: an installed image sets JAX_COMPILATION_CACHE_DIR
+# (the Dockerfile does)
 DEFAULT_COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
@@ -28,12 +32,13 @@ def configure_jax() -> None:
 
     Compile cache: ``JAX_COMPILATION_CACHE_DIR`` set from outside is read
     by JAX itself and left alone; unset, the cache goes to
-    ``DEFAULT_COMPILE_CACHE_DIR``. The serving bucket programs compile in
-    well under JAX's default one-second admission threshold, so the
-    threshold drops to zero unless the caller set one. A CPU run gets no
-    default cache: its compiles are quick, and jaxlib 0.9.0's XLA:CPU
-    loader logs a multi-kilobyte machine-feature warning for every
-    program it loads back.
+    ``DEFAULT_COMPILE_CACHE_DIR`` (a source checkout's ``.jax_cache``; an
+    installed package must be given the variable). The serving bucket
+    programs compile in well under JAX's default one-second admission
+    threshold, so the threshold drops to zero unless the caller set one. A
+    CPU run gets no default cache: its compiles are quick, and jaxlib
+    0.9.0's XLA:CPU loader logs a multi-kilobyte machine-feature warning
+    for every program it loads back.
     """
     env = os.environ
     if not env.get("JAX_PLATFORMS"):

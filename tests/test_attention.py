@@ -97,6 +97,18 @@ class TestFusedAttention:
         got2 = fused_attention(q, k, v, causal=causal, force_pallas=True)
         np.testing.assert_allclose(np.asarray(got2), np.asarray(expected), atol=2e-2)
 
+    def test_kernel_path_refuses_a_long_ragged_sequence(self):
+        """Past the single-block budget the flash kernel needs lengths
+        that divide by 256. The kernel path says so instead of quietly
+        running the jnp reference; off the chip, where the reference is
+        the documented path, the same call still answers."""
+        q, k, v = qkv(B=1, H=1, L=1100, D=8)
+        with pytest.raises(ValueError, match="multiple of 256"):
+            fused_attention(q, k, v, causal=True, force_pallas=True)
+        got = fused_attention(q, k, v, causal=True)
+        expected = attention_reference(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=1e-6)
+
 
 class TestUlyssesAttention:
     """All-to-all sequence parallelism (DeepSpeed-Ulysses scheme) must match

@@ -13,6 +13,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -67,6 +69,20 @@ def test_events_are_a_function_of_the_seed(tmp_path):
     assert set((vals * 2).tolist()) <= set(range(2, 11))
     first = (tmp_path / "a.jsonl").read_text().splitlines()[0]
     assert first.startswith('{"event":"rate","entityType":"user","entityId":"u')
+
+
+def test_the_widths_are_not_options():
+    """A smoke at a toy width must not be able to end in the same result
+    line as a real one: only scale (the ratings count) is settable, and
+    not below one rating for each entity."""
+    import chip_smoke
+
+    for flag in ("--users", "--items", "--rank"):
+        with pytest.raises(SystemExit):
+            chip_smoke.parse_args([flag, "8"])
+    assert chip_smoke.parse_args(["--ratings", "200000"]).ratings == 200_000
+    with pytest.raises(ValueError, match="full width"):
+        chip_smoke.synthesize_ratings(0, chip_smoke.N_USERS, chip_smoke.N_ITEMS, 100_000)
 
 
 def test_refuses_the_cpu_by_name():

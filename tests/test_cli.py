@@ -230,6 +230,11 @@ class TestEngineLifecycleCLI:
 
         code, out, _ = run(capsys, "train", "--engine-dir", engine_dir)
         assert code == 0 and "Engine instance ID" in out
+        # the train says where it ran, read from its arrays
+        (trained_on,) = [ln for ln in out.splitlines() if ln.startswith("Trained on: ")]
+        device = json.loads(trained_on[len("Trained on: "):])
+        assert device["platform"] == "cpu" and device["deviceKind"]
+        assert 1 <= device["deviceCount"] <= device["visibleDevices"]
 
         queries = tmp_path / "queries.json"
         queries.write_text('{"user": "u1", "num": 3}\n{"user": "u2", "num": 2}\n')
