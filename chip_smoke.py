@@ -18,10 +18,11 @@ chip and its children could not have it); ``kernels`` and ``check`` are
 this file run again as a child. Nothing is retried, probed or skipped: the
 first step that fails ends the run with a non-zero exit code. A train or a
 server that did not run on ``REQUIRED_PLATFORM`` is a failure, whatever
-else succeeded. The last line of a successful run is
-``{"ok": true, "device": {...}, "shape": {...}}``: the device as JAX
-reported it and the shape that ran. The widths are constants of this file;
-only the ratings count (scale) can be set from outside.
+else succeeded. The last line of a successful run is exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
+the device as JAX reported it; the line before it, ``SHAPE {...}``, names
+the shape that ran. The widths are constants of this file; only the ratings
+count (scale) can be set from outside.
 
 ``python chip_smoke.py`` needs no arguments; see PERF.md for what a run
 established.
@@ -355,13 +356,21 @@ def main(argv: list[str] | None = None) -> int:
         say(f"all steps passed in {time.perf_counter() - t_start:.1f} s")
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
-    print(json.dumps({
-        "ok": True,
-        "device": {"platform": found["platform"], "kind": found["kind"], "count": found["count"]},
-        "shape": {"users": N_USERS, "items": N_ITEMS, "rank": RANK,
-                  "iterations": ITERATIONS, "ratings": args.ratings},
-    }))
+    # the shape that ran goes on a line of its own: the last line holds the
+    # keys "ok" and "device" and no others (the chip check's contract)
+    print("SHAPE " + json.dumps({"users": N_USERS, "items": N_ITEMS, "rank": RANK,
+                                 "iterations": ITERATIONS, "ratings": args.ratings}))
+    print(json.dumps(result_line(found)), flush=True)
     return 0
+
+
+def result_line(found: dict) -> dict:
+    """The run's last line of standard output, for a run that passed."""
+    return {
+        "ok": True,
+        "device": {"platform": str(found["platform"]), "kind": str(found["kind"]),
+                   "count": int(found["count"])},
+    }
 
 
 def parse_args(argv: list[str] | None) -> argparse.Namespace:

@@ -85,6 +85,20 @@ def test_the_widths_are_not_options():
         chip_smoke.synthesize_ratings(0, chip_smoke.N_USERS, chip_smoke.N_ITEMS, 100_000)
 
 
+def test_the_result_line_has_the_contract_keys_and_no_others():
+    """The chip check reads the last line of standard output and refuses
+    anything but {"ok", "device": {"platform", "kind", "count"}}; what else
+    a run has to say (its shape) goes on the lines before."""
+    import json
+
+    import chip_smoke
+
+    found = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "extra": "dropped"}
+    line = json.loads(json.dumps(chip_smoke.result_line(found)))
+    assert line == {"ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert type(line["device"]["count"]) is int
+
+
 def test_refuses_the_cpu_by_name():
     """Run where JAX is held to the CPU it exits non-zero, says which
     platform it found, and prints no result line."""
