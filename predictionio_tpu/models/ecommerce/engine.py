@@ -40,6 +40,7 @@ from predictionio_tpu.controller import (
     Params,
     SanityCheck,
 )
+from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.workflow.context import WorkflowContext
@@ -551,7 +552,7 @@ class ECommAlgorithm(JaxAlgorithm):
         if rows:
             weights = self._weights(ctx, model)
             f = model.user_factors.shape[1]
-            b = topk.next_pow2(len(rows))
+            b = topk.batch_bucket(len(rows))
             pool = topk.scratch()
             vec_buf = pool.zeros("ecomm.vecs", (b, f), np.float32)
             np.take(
@@ -572,10 +573,11 @@ class ECommAlgorithm(JaxAlgorithm):
                 results[i] = self.predict_with_context(ctx, model, queries[i])
             if handle is not None:
                 scores, idx = topk.fetch_topk(handle)
-                for row, i in enumerate(rows):
-                    results[i] = self._result_rows(
-                        model, scores[row], idx[row], min(queries[i].num, kk)
-                    )
+                with annotate("pio:fetch.unpack"):
+                    for row, i in enumerate(rows):
+                        results[i] = self._result_rows(
+                            model, scores[row], idx[row], min(queries[i].num, kk)
+                        )
             return results  # type: ignore[return-value]
 
         return finalize

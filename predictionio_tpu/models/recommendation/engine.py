@@ -450,6 +450,7 @@ class ALSAlgorithm(JaxAlgorithm):
         with batch n+1's dispatch (ops.als.ServingIndex.serve_batch_async).
         User indices are assembled into a reusable staging buffer
         (ops.topk.scratch) and only the packed [B,2,k] result is fetched."""
+        from predictionio_tpu.obs.jaxprof import annotate
         from predictionio_tpu.ops import topk
         from predictionio_tpu.ops.als import next_pow2
 
@@ -479,7 +480,7 @@ class ALSAlgorithm(JaxAlgorithm):
             # ServingIndex.warmup_buckets
             k = min(max(queries[i].num for i in batch_pos), n_items)
             kk = min(next_pow2(k), n_items)
-            bucket = next_pow2(len(batch_pos))
+            bucket = topk.batch_bucket(len(batch_pos))
             # pad rows serve user 0, dropped on unpack
             idxs = topk.scratch().zeros("rec.uidx", (bucket,), np.int32)
             idxs[: len(batch_pos)] = batch_idx
@@ -490,15 +491,16 @@ class ALSAlgorithm(JaxAlgorithm):
                 results[i] = self.predict(model, queries[i])
             if handle is not None:
                 scores, idx = topk.fetch_topk(handle)
-                for row, i in enumerate(batch_pos):
-                    num = min(queries[i].num, n_items)
-                    results[i] = PredictedResult(
-                        tuple(
-                            ItemScore(model.item_vocab[int(it)], float(s))
-                            for s, it in zip(scores[row, :num], idx[row, :num])
-                            if np.isfinite(s)
+                with annotate("pio:fetch.unpack"):
+                    for row, i in enumerate(batch_pos):
+                        num = min(queries[i].num, n_items)
+                        results[i] = PredictedResult(
+                            tuple(
+                                ItemScore(model.item_vocab[int(it)], float(s))
+                                for s, it in zip(scores[row, :num], idx[row, :num])
+                                if np.isfinite(s)
+                            )
                         )
-                    )
             return results  # type: ignore[return-value]
 
         return finalize

@@ -32,6 +32,7 @@ from predictionio_tpu.controller import (
     Params,
     SanityCheck,
 )
+from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.workflow.context import WorkflowContext
@@ -256,7 +257,7 @@ class ALSAlgorithm(JaxAlgorithm):
         handle = None
         kk = 0
         if rows:
-            b = topk.next_pow2(len(rows))
+            b = topk.batch_bucket(len(rows))
             qcap = topk.next_pow2(max_q)
             pool = topk.scratch()
             qidx_buf = pool.zeros("recuser.qidx", (b, qcap), np.int32)
@@ -275,17 +276,18 @@ class ALSAlgorithm(JaxAlgorithm):
         def finalize() -> list[PredictedResult]:
             if handle is not None:
                 scores, idx = topk.fetch_topk(handle)
-                for row, i in enumerate(rows):
-                    num = min(queries[i].num, kk)
-                    results[i] = PredictedResult(
-                        tuple(
-                            SimilarUserScore(
-                                model.followed_vocab[int(u)], float(s)
+                with annotate("pio:fetch.unpack"):
+                    for row, i in enumerate(rows):
+                        num = min(queries[i].num, kk)
+                        results[i] = PredictedResult(
+                            tuple(
+                                SimilarUserScore(
+                                    model.followed_vocab[int(u)], float(s)
+                                )
+                                for s, u in zip(scores[row, :num], idx[row, :num])
+                                if np.isfinite(s)
                             )
-                            for s, u in zip(scores[row, :num], idx[row, :num])
-                            if np.isfinite(s)
                         )
-                    )
             return results  # type: ignore[return-value]
 
         return finalize

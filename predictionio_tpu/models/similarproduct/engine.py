@@ -38,6 +38,7 @@ from predictionio_tpu.controller import (
     Params,
     SanityCheck,
 )
+from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.ops.cooccurrence import cooccurrence_top_n, score_by_cooccurrence
@@ -442,7 +443,7 @@ class _ALSBase(JaxAlgorithm):
         if rows:
             # pow2 buckets on batch/query-width/k keep the compile universe
             # at ~log^3 programs (same discipline as ops/als warmup_buckets)
-            b = topk.next_pow2(len(rows))
+            b = topk.batch_bucket(len(rows))
             qcap = topk.next_pow2(max_q)
             pool = topk.scratch()
             qidx_buf = pool.zeros("similar.qidx", (b, qcap), np.int32)
@@ -500,19 +501,20 @@ class _ALSBase(JaxAlgorithm):
                         ann.record_recall(idx, exact_idx, rows=len(rows))
                 else:
                     scores, idx = topk.fetch_topk(handle)
-                for row, i in enumerate(rows):
-                    num = min(queries[i].num, kk)
-                    results[i] = PredictedResult(
-                        tuple(
-                            ItemScore(
-                                model.item_vocab[int(it)],
-                                float(s),
-                                model.properties_of(int(it)),
+                with annotate("pio:fetch.unpack"):
+                    for row, i in enumerate(rows):
+                        num = min(queries[i].num, kk)
+                        results[i] = PredictedResult(
+                            tuple(
+                                ItemScore(
+                                    model.item_vocab[int(it)],
+                                    float(s),
+                                    model.properties_of(int(it)),
+                                )
+                                for s, it in zip(scores[row, :num], idx[row, :num])
+                                if np.isfinite(s)
                             )
-                            for s, it in zip(scores[row, :num], idx[row, :num])
-                            if np.isfinite(s)
                         )
-                    )
             return results  # type: ignore[return-value]
 
         return finalize

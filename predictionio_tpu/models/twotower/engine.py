@@ -342,6 +342,7 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         than the probe pool); sampled batches ALSO run exact as a shadow
         to measure the live recall proxy."""
         from predictionio_tpu.ann.lifecycle import ATTR as _ANN_ATTR
+        from predictionio_tpu.obs.jaxprof import annotate
         from predictionio_tpu.ops import topk
 
         n = len(model.item_vocab)
@@ -362,7 +363,7 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         exact_handle = None
         kk = 0
         if rows:
-            b = topk.next_pow2(len(rows))
+            b = topk.batch_bucket(len(rows))
             pool = topk.scratch()
             uidx_buf = pool.zeros("twotower.uidx", (b,), np.int32)
             uidx_buf[: len(rows)] = uidxs  # pad rows serve user 0, dropped
@@ -397,15 +398,16 @@ class TwoTowerAlgorithm(JaxAlgorithm):
                         ann.record_recall(idx, exact_idx, rows=len(rows))
                 else:
                     scores, idx = fetch_topk(handle)
-                for row, i in enumerate(rows):
-                    num = min(queries[i].num, kk)
-                    results[i] = PredictedResult(
-                        tuple(
-                            ItemScore(model.item_vocab[int(it)], float(s))
-                            for s, it in zip(scores[row, :num], idx[row, :num])
-                            if np.isfinite(s)
+                with annotate("pio:fetch.unpack"):
+                    for row, i in enumerate(rows):
+                        num = min(queries[i].num, kk)
+                        results[i] = PredictedResult(
+                            tuple(
+                                ItemScore(model.item_vocab[int(it)], float(s))
+                                for s, it in zip(scores[row, :num], idx[row, :num])
+                                if np.isfinite(s)
+                            )
                         )
-                    )
             return results  # type: ignore[return-value]
 
         return finalize

@@ -17,6 +17,10 @@ mode a first-class signal:
 - :func:`timed_block_until_ready` is the sanctioned way for algorithm
   code to host-sync: it accounts the stall into
   ``pio_device_stall_seconds_total`` instead of losing it.
+- :func:`annotate` is the ONE way the program writes a host span onto the
+  profiler's clock (``pio:<layer>.<what>``, docs/observability.md "Spans
+  on the profiler's clock"); :class:`GcWatcher` counts the interpreter's
+  collection pauses and puts the full ones on that clock too.
 
 jax itself is imported lazily — constructing a watcher costs nothing on
 processes (event server, ``pio top``) that never touch a device.
@@ -24,6 +28,8 @@ processes (event server, ``pio top``) that never touch a device.
 
 from __future__ import annotations
 
+import functools
+import gc
 import logging
 import sys
 import threading
@@ -42,6 +48,8 @@ _mon_lock = threading.Lock()
 _mon_installed = False
 _mon_compile_events = 0
 _mon_compile_seconds = 0.0
+_mon_cache_hits = 0
+_mon_cache_misses = 0
 
 
 def _looks_like_compile(event: str) -> bool:
@@ -66,8 +74,23 @@ def _on_duration(event: str, duration_secs: float, *a: Any, **kw: Any) -> None:
                 _mon_compile_events += 1
 
 
+def _on_event(event: str, *a: Any, **kw: Any) -> None:
+    """The persistent compile cache's own verdicts (jax 0.9.0
+    ``_src/compiler.py``, ``_src/compilation_cache.py``): a hit loaded an
+    executable, a miss went on to compile one. Neither fires when the
+    cache is off or a program is refused admission to it."""
+    global _mon_cache_hits, _mon_cache_misses
+    if event == "/jax/compilation_cache/cache_hits":
+        with _mon_lock:
+            _mon_cache_hits += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        with _mon_lock:
+            _mon_cache_misses += 1
+
+
 def install_jax_monitoring() -> None:
-    """Register the compile-duration listener with ``jax.monitoring``.
+    """Register the compile-duration and cache-verdict listeners with
+    ``jax.monitoring``.
     Idempotent. The whole check-register-set sequence holds the lock
     (registration is a plain list append, never re-enters this module) —
     a check-then-act gap would let two concurrent watchers
@@ -79,12 +102,107 @@ def install_jax_monitoring() -> None:
         import jax.monitoring as monitoring
 
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         _mon_installed = True
 
 
 def monitoring_totals() -> tuple[int, float]:
     with _mon_lock:
         return _mon_compile_events, _mon_compile_seconds
+
+
+def compile_cache_totals() -> tuple[int, int]:
+    """``(hits, misses)`` of the persistent compile cache so far."""
+    with _mon_lock:
+        return _mon_cache_hits, _mon_cache_misses
+
+
+# ---------------------------------------------------------------------------
+# host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=1)
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def annotate(name: str, **stats: Any):
+    """A host span on the profiler's clock: ``with annotate("pio:dispatch",
+    batch=7): ...``. The span is recorded only while a profiler session is
+    open (``POST /profile/capture``, ``PIO_PROFILE_DIR``, a benchmark's
+    traced slice) and lands in that session's xplane file beside the
+    device's ``XLA Ops``; with no session it costs under a microsecond and
+    writes nothing. It wraps synchronous code on one thread, never an
+    ``await``: the event loop interleaves coroutines on one thread and
+    would break the nesting. A wait is a counter, not a span."""
+    return _trace_annotation()(name, **stats)
+
+
+class GcWatcher:
+    """The interpreter's collection pauses, counted where they happen:
+    a ``gc.callbacks`` hook that takes two clock readings a collection and
+    adds to plain attributes (collections are serialised by the interpreter,
+    and the hook runs for every one of them, the young ones included: 30 a
+    second under the saturated benchmark cell, PERF.md), mirrored into
+    ``pio_gc_pause_seconds_total{generation}`` and
+    ``pio_gc_collections_total{generation}`` at scrape. A full collection
+    (generation 2) also opens a ``pio:gc`` span on the collecting thread.
+    ``install`` / ``remove`` bracket the owner's life."""
+
+    GENERATIONS = 3
+
+    def __init__(self, registry: MetricsRegistry):
+        self.pause_s = [0.0] * self.GENERATIONS
+        self.collections = [0] * self.GENERATIONS
+        self._t0 = 0.0
+        self._span: Any = None
+        self._m_pause = registry.counter(
+            "pio_gc_pause_seconds_total",
+            "seconds the interpreter stood still collecting garbage, "
+            "by generation",
+            labelnames=("generation",),
+        )
+        self._m_collections = registry.counter(
+            "pio_gc_collections_total",
+            "garbage collections run, by generation",
+            labelnames=("generation",),
+        )
+        self.collect()  # every generation scrapes as an explicit 0
+
+    def install(self) -> None:
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict[str, int]) -> None:
+        if phase == "start":
+            if info["generation"] == 2:
+                self._span = annotate("pio:gc", generation=2)
+                self._span.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        elapsed = time.perf_counter() - self._t0
+        generation = info["generation"]
+        self.pause_s[generation] += elapsed
+        self.collections[generation] += 1
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def collect(self) -> None:
+        """Registry collector: mirror the plain tallies at scrape."""
+        for generation in range(self.GENERATIONS):
+            label = str(generation)
+            self._m_pause.set_total(self.pause_s[generation], generation=label)
+            self._m_collections.set_total(
+                self.collections[generation], generation=label
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +264,14 @@ class CompileWatcher:
         self._xla_seconds = registry.counter(
             "pio_xla_compile_seconds_total",
             "cumulative seconds spent in XLA compilation (jax.monitoring)",
+        )
+        self._cache_hits = registry.counter(
+            "pio_compile_cache_hits_total",
+            "programs loaded from the persistent compile cache",
+        )
+        self._cache_misses = registry.counter(
+            "pio_compile_cache_misses_total",
+            "programs the persistent compile cache did not hold (compiled)",
         )
         install_jax_monitoring()
 
@@ -217,6 +343,9 @@ class CompileWatcher:
         events, seconds = monitoring_totals()
         self._xla_events.set_total(events)
         self._xla_seconds.set_total(seconds)
+        hits, misses = compile_cache_totals()
+        self._cache_hits.set_total(hits)
+        self._cache_misses.set_total(misses)
         return new_misses
 
     def total_misses(self) -> float:
@@ -267,6 +396,9 @@ def timed_block_until_ready(
 
 __all__ = [
     "CompileWatcher",
+    "GcWatcher",
+    "annotate",
+    "compile_cache_totals",
     "install_jax_monitoring",
     "monitoring_totals",
     "timed_block_until_ready",

@@ -39,6 +39,11 @@ _current_trace: contextvars.ContextVar[str | None] = contextvars.ContextVar(
     "pio_trace_id", default=None
 )
 
+# the span open in this context: the parent of any span started under it
+_current_span: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "pio_span_id", default=None
+)
+
 _trace_logger = logging.getLogger("pio.trace")
 
 
@@ -48,6 +53,13 @@ def mint_trace_id() -> str:
 
 def current_trace_id() -> str | None:
     return _current_trace.get()
+
+
+def current_span_id() -> str | None:
+    """The id of the span open in this context (``Tracer.span``), for work
+    that leaves the context (the micro-batcher's queue) to carry along as
+    its spans' parent."""
+    return _current_span.get()
 
 
 def set_trace_id(trace_id: str | None) -> contextvars.Token:
@@ -84,14 +96,20 @@ class Span:
     duration_s: float = 0.0
     status: str = "ok"
     tags: dict[str, Any] = dataclasses.field(default_factory=dict)
+    # the span that caused this one (None at a request's root), and the
+    # start on the monotonic clock: what orders a request's spans
+    parent_id: str | None = None
+    start_mono_ns: int = dataclasses.field(default_factory=time.monotonic_ns)
 
     def to_json_dict(self) -> dict[str, Any]:
         return {
             "traceId": self.trace_id,
             "spanId": self.span_id,
+            "parentId": self.parent_id,
             "name": self.name,
             "kind": self.kind,
             "startTime": round(self.start_time, 6),
+            "startMonoNs": self.start_mono_ns,
             "durationMs": round(self.duration_s * 1000.0, 3),
             "status": self.status,
             "tags": self.tags,
@@ -122,13 +140,17 @@ class Tracer:
     ) -> Iterator[Span]:
         """Time a block as one span. The span is yielded so callers can
         attach tags mid-flight; an escaping exception marks the status
-        with the exception type and re-raises."""
+        with the exception type and re-raises. The span open in the
+        calling context is its parent, and it is the parent of spans
+        started inside the block."""
         sp = Span(
             trace_id=trace_id or current_trace_id() or mint_trace_id(),
             name=name,
             kind=kind,
             tags=dict(tags),
+            parent_id=_current_span.get(),
         )
+        token = _current_span.set(sp.span_id)
         t0 = time.perf_counter()
         try:
             yield sp
@@ -137,6 +159,7 @@ class Tracer:
             raise
         finally:
             sp.duration_s = time.perf_counter() - t0
+            _current_span.reset(token)
             self.record(sp)
 
     def record_span(
@@ -146,10 +169,12 @@ class Tracer:
         duration_s: float,
         trace_id: str | None = None,
         status: str = "ok",
+        parent_id: str | None = None,
         **tags: Any,
     ) -> Span:
-        """Record an already-timed span (the micro-batcher measures queue
-        /dispatch/fetch itself and reports per-query afterwards)."""
+        """Record an already-timed span that ends now (the micro-batcher
+        measures queue/dispatch/fetch itself and reports per-query
+        afterwards, under the ingress span it was handed as parent)."""
         sp = Span(
             trace_id=trace_id or current_trace_id() or mint_trace_id(),
             name=name,
@@ -158,6 +183,8 @@ class Tracer:
             duration_s=duration_s,
             status=status,
             tags=dict(tags),
+            parent_id=parent_id,
+            start_mono_ns=time.monotonic_ns() - int(duration_s * 1e9),
         )
         self.record(sp)
         return sp
@@ -179,11 +206,13 @@ class Tracer:
         return [s.to_json_dict() for s in spans]
 
     def find(self, trace_id: str) -> list[dict[str, Any]]:
-        """All ring-resident spans of one trace, oldest first."""
+        """All ring-resident spans of one trace, oldest first by their
+        monotonic start (the ring holds them in the order they ENDED: an
+        ingress span after the batch span it caused)."""
         with self._lock:
-            return [
-                s.to_json_dict() for s in self._ring if s.trace_id == trace_id
-            ]
+            spans = [s for s in self._ring if s.trace_id == trace_id]
+        spans.sort(key=lambda s: s.start_mono_ns)
+        return [s.to_json_dict() for s in spans]
 
     def clear(self) -> None:
         with self._lock:

@@ -283,30 +283,32 @@ def _normal_equations_blocked(
         A, b, n = carry
         br, c, v, ww = inputs
         ww = ww.astype(acc_dtype)  # int8 wire format -> f32 math
-        vecs = gathered[c]  # [CB, D, f] gather (bf16 rows when opted in)
-        if implicit:
-            ow = ww * (alpha * v)  # (conf - 1), 0 in pad slots
-            bw = ww * (1.0 + alpha * v)
-        else:
-            ow = ww
-            bw = ww * v
-        # weights stay f32 on every mode (the f32*bf16 product promotes, so
-        # ONLY the gathered rows are rounded — the documented contract; the
-        # multiply precision was never the bottleneck, the gather bytes are)
-        # and the einsums accumulate in acc_dtype
-        A_blk = jnp.einsum(
-            "bdf,bdg->bfg",
-            ow[..., None] * vecs,
-            vecs,
-            preferred_element_type=acc_dtype,
-        ).astype(acc_dtype)
-        b_blk = jnp.einsum(
-            "bd,bdf->bf", bw, vecs, preferred_element_type=acc_dtype
-        ).astype(acc_dtype)
-        n_blk = ww.sum(axis=-1)
-        A = A.at[br].add(A_blk, indices_are_sorted=True)
-        b = b.at[br].add(b_blk, indices_are_sorted=True)
-        n = n.at[br].add(n_blk, indices_are_sorted=True)
+        with jax.named_scope("gather"):
+            vecs = gathered[c]  # [CB, D, f] gather (bf16 rows when opted in)
+        with jax.named_scope("gram"):
+            if implicit:
+                ow = ww * (alpha * v)  # (conf - 1), 0 in pad slots
+                bw = ww * (1.0 + alpha * v)
+            else:
+                ow = ww
+                bw = ww * v
+            # weights stay f32 on every mode (the f32*bf16 product promotes,
+            # so ONLY the gathered rows are rounded — the documented
+            # contract; the multiply precision was never the bottleneck, the
+            # gather bytes are) and the einsums accumulate in acc_dtype
+            A_blk = jnp.einsum(
+                "bdf,bdg->bfg",
+                ow[..., None] * vecs,
+                vecs,
+                preferred_element_type=acc_dtype,
+            ).astype(acc_dtype)
+            b_blk = jnp.einsum(
+                "bd,bdf->bf", bw, vecs, preferred_element_type=acc_dtype
+            ).astype(acc_dtype)
+            n_blk = ww.sum(axis=-1)
+            A = A.at[br].add(A_blk, indices_are_sorted=True)
+            b = b.at[br].add(b_blk, indices_are_sorted=True)
+            n = n.at[br].add(n_blk, indices_are_sorted=True)
         return (A, b, n), None
 
     (A, b, n), _ = lax.scan(step, (A0, b0, n0), (br_ch, c_ch, v_ch, w_ch))
@@ -354,19 +356,21 @@ def _solve_blocked(
         block_rows, cols, vals, w, opposite, n_entities, block_chunk, implicit, alpha,
         gather_dtype,
     )
-    eye = jnp.eye(f, dtype=A.dtype)
-    if implicit:
-        # shared dense term accumulates at the (>= f32) accumulator dtype
-        # even if ``opposite`` arrived bf16 from a caller
-        gram = jnp.einsum(
-            "df,dg->fg", opposite, opposite, preferred_element_type=A.dtype
-        )
-        A = A + gram[None, :, :]
-    if degree_scaled_reg:
-        A = A + (reg * jnp.maximum(counts, 1.0))[:, None, None] * eye[None, :, :]
-    else:
-        A = A + reg * eye[None, :, :]
-    return _batched_spd_solve(A, b, solver)
+    with jax.named_scope("gram"):
+        eye = jnp.eye(f, dtype=A.dtype)
+        if implicit:
+            # shared dense term accumulates at the (>= f32) accumulator
+            # dtype even if ``opposite`` arrived bf16 from a caller
+            gram = jnp.einsum(
+                "df,dg->fg", opposite, opposite, preferred_element_type=A.dtype
+            )
+            A = A + gram[None, :, :]
+        if degree_scaled_reg:
+            A = A + (reg * jnp.maximum(counts, 1.0))[:, None, None] * eye[None, :, :]
+        else:
+            A = A + reg * eye[None, :, :]
+    with jax.named_scope("solve"):
+        return _batched_spd_solve(A, b, solver)
 
 
 def _solve_side(
@@ -522,29 +526,30 @@ def _device_pack(
     two padded block-table sets, and all the host pack time past the one
     counting sort.
     """
-    nnz = cols_u.shape[0]
-    items_u = cols_u.astype(jnp.int32)
-    if val_table is not None:
-        # dictionary-coded wire: one tiny-table gather decodes exactly
-        ratings_u = val_table[vals_u.astype(jnp.int32)]
-    else:
-        ratings_u = vals_u.astype(jnp.float32)
-    # user column from the grouped order: +1 at each entity's start position,
-    # then an inclusive cumsum. O(n) in two passes — the searchsorted
-    # formulation (binary search = ~17 gather passes over the prefix array)
-    # measured 2.7s for 19.6M rows on a v5e; this is 0.03s
-    start_u = jnp.cumsum(deg_u) - deg_u
-    users_u = jnp.cumsum(
-        jnp.zeros((nnz,), jnp.int32).at[start_u[1:]].add(1)
-    )
-    u_tables = _expand_blocks_traced(deg_u, items_u, ratings_u, d, nb_u, n_users)
-    _, users_by_item, ratings_by_item = lax.sort(
-        (items_u, users_u, ratings_u), num_keys=1, is_stable=True
-    )
-    i_tables = _expand_blocks_traced(
-        deg_i, users_by_item, ratings_by_item, d, nb_i, n_items
-    )
-    return (*u_tables, *i_tables)
+    with jax.named_scope("pack"):
+        nnz = cols_u.shape[0]
+        items_u = cols_u.astype(jnp.int32)
+        if val_table is not None:
+            # dictionary-coded wire: one tiny-table gather decodes exactly
+            ratings_u = val_table[vals_u.astype(jnp.int32)]
+        else:
+            ratings_u = vals_u.astype(jnp.float32)
+        # user column from the grouped order: +1 at each entity's start position,
+        # then an inclusive cumsum. O(n) in two passes — the searchsorted
+        # formulation (binary search = ~17 gather passes over the prefix array)
+        # measured 2.7s for 19.6M rows on a v5e; this is 0.03s
+        start_u = jnp.cumsum(deg_u) - deg_u
+        users_u = jnp.cumsum(
+            jnp.zeros((nnz,), jnp.int32).at[start_u[1:]].add(1)
+        )
+        u_tables = _expand_blocks_traced(deg_u, items_u, ratings_u, d, nb_u, n_users)
+        _, users_by_item, ratings_by_item = lax.sort(
+            (items_u, users_u, ratings_u), num_keys=1, is_stable=True
+        )
+        i_tables = _expand_blocks_traced(
+            deg_i, users_by_item, ratings_by_item, d, nb_i, n_items
+        )
+        return (*u_tables, *i_tables)
 
 
 def _compress_ratings_wire(
@@ -658,77 +663,90 @@ def als_train(
     import time
 
     from predictionio_tpu.obs import xray
+    from predictionio_tpu.obs.jaxprof import annotate
 
     prof = xray.current_profile()
+    # each stage is also a host span on the profiler's clock
+    # (obs/jaxprof.annotate): on the plain path a span closes when the host's
+    # call returns, which is what the host did
     with xray.phase(xray.PHASE_HOST_ETL):
-        user_idx = np.asarray(user_idx, np.int32)
-        item_idx = np.asarray(item_idx, np.int32)
-        ratings = np.asarray(ratings, np.float32)
-        valid = (user_idx >= 0) & (item_idx >= 0)
-        user_idx, item_idx, ratings = (
-            user_idx[valid], item_idx[valid], ratings[valid]
-        )
-        if user_idx.shape[0]:
-            for name, idx, bound in (
-                ("user", user_idx, n_users),
-                ("item", item_idx, n_items),
-            ):
-                mx = int(idx.max())
-                if mx >= bound:
-                    raise ValueError(
-                        f"{name} index {mx} out of range for n_{name}s={bound}"
-                    )
-        d = max(8, min(config.block_d, config.chunk))
-        block_chunk = max(8, config.chunk // d)
-        use_device_pack = config.pack != "host" and user_idx.shape[0] > 0
+        # the pack span opens here, before the pack_s clock does: checking
+        # and filtering the ratings is host work on them too, and at 20 M
+        # ratings the device waits 0.3 s for it
+        with annotate("pio:als.pack"):
+            user_idx = np.asarray(user_idx, np.int32)
+            item_idx = np.asarray(item_idx, np.int32)
+            ratings = np.asarray(ratings, np.float32)
+            valid = (user_idx >= 0) & (item_idx >= 0)
+            user_idx, item_idx, ratings = (
+                user_idx[valid], item_idx[valid], ratings[valid]
+            )
+            if user_idx.shape[0]:
+                for name, idx, bound in (
+                    ("user", user_idx, n_users),
+                    ("item", item_idx, n_items),
+                ):
+                    mx = int(idx.max())
+                    if mx >= bound:
+                        raise ValueError(
+                            f"{name} index {mx} out of range for n_{name}s={bound}"
+                        )
+            d = max(8, min(config.block_d, config.chunk))
+            block_chunk = max(8, config.chunk // d)
+            use_device_pack = config.pack != "host" and user_idx.shape[0] > 0
 
-        t0 = time.perf_counter()
-        if use_device_pack:
-            cols_u, vals_u, deg_u = _host_group_by(
-                user_idx, item_idx, ratings, n_users
-            )
-            deg_i = np.bincount(item_idx, minlength=n_items).astype(np.int32)
-            nb_u = _pad_blocks(int((-(-deg_u // d)).sum()), block_chunk)
-            nb_i = _pad_blocks(int((-(-deg_i // d)).sum()), block_chunk)
-            # wire compression, all LOSSLESS: opposite ids as int16 when the
-            # vocab fits; ratings in their smallest exact form (uint8
-            # dictionary codes / f16 / f32 — see _compress_ratings_wire).
-            if n_items <= np.iinfo(np.int16).max:
-                cols_u = cols_u.astype(np.int16)
-            vals_u, val_table = _compress_ratings_wire(vals_u)
-            t_pack = time.perf_counter()
-            wire = [jax.device_put(a) for a in (cols_u, vals_u, deg_u, deg_i)]
-            table_dev = (
-                jax.device_put(val_table) if val_table is not None else None
-            )
-            if timings is not None:
-                fetch_barrier(*wire)
-            t_upload = time.perf_counter()
-            dev = list(
-                _device_pack(
-                    *wire, val_table=table_dev,
-                    d=d, nb_u=nb_u, nb_i=nb_i, n_users=n_users, n_items=n_items,
+            t0 = time.perf_counter()
+            if use_device_pack:
+                cols_u, vals_u, deg_u = _host_group_by(
+                    user_idx, item_idx, ratings, n_users
                 )
-            )
-            if timings is not None:
-                # device-side table build (sort + gather expansion) attributed
-                # to its own bucket: device_s means SOLVER iterations only, on
-                # both pack paths, or per-iteration figures aren't comparable
-                fetch_barrier(dev[0], dev[4])
+                deg_i = np.bincount(item_idx, minlength=n_items).astype(np.int32)
+                nb_u = _pad_blocks(int((-(-deg_u // d)).sum()), block_chunk)
+                nb_i = _pad_blocks(int((-(-deg_i // d)).sum()), block_chunk)
+                # wire compression, all LOSSLESS: opposite ids as int16 when
+                # the vocab fits; ratings in their smallest exact form (uint8
+                # dictionary codes / f16 / f32 — see _compress_ratings_wire).
+                if n_items <= np.iinfo(np.int16).max:
+                    cols_u = cols_u.astype(np.int16)
+                vals_u, val_table = _compress_ratings_wire(vals_u)
+            else:
+                u_blocks = _block_coo(
+                    user_idx, item_idx, ratings, d, block_chunk, n_users
+                )
+                i_blocks = _block_coo(
+                    item_idx, user_idx, ratings, d, block_chunk, n_items
+                )
+            t_pack = time.perf_counter()
+        if use_device_pack:
+            with annotate("pio:als.upload"):
+                wire = [jax.device_put(a) for a in (cols_u, vals_u, deg_u, deg_i)]
+                table_dev = (
+                    jax.device_put(val_table) if val_table is not None else None
+                )
+                if timings is not None:
+                    fetch_barrier(*wire)
+            t_upload = time.perf_counter()
+            with annotate("pio:als.build"):
+                dev = list(
+                    _device_pack(
+                        *wire, val_table=table_dev,
+                        d=d, nb_u=nb_u, nb_i=nb_i, n_users=n_users, n_items=n_items,
+                    )
+                )
+                if timings is not None:
+                    # device-side table build (sort + gather expansion)
+                    # attributed to its own bucket: device_s means SOLVER
+                    # iterations only, on both pack paths, or per-iteration
+                    # figures aren't comparable
+                    fetch_barrier(dev[0], dev[4])
             t_build = time.perf_counter()
         else:
-            u_blocks = _block_coo(
-                user_idx, item_idx, ratings, d, block_chunk, n_users
-            )
-            i_blocks = _block_coo(
-                item_idx, user_idx, ratings, d, block_chunk, n_items
-            )
-            t_pack = time.perf_counter()
-            # block tables cross host->device ONCE; the per-iteration
-            # launches reuse the same device buffers
-            dev = [jax.device_put(a) for a in (*u_blocks, *i_blocks)]
-            if timings is not None:
-                fetch_barrier(*dev)
+            with annotate("pio:als.upload"):
+                # block tables cross host->device ONCE; the per-iteration
+                # launches reuse the same device buffers
+                dev = [jax.device_put(a) for a in (*u_blocks, *i_blocks)]
+                if timings is not None:
+                    fetch_barrier(*dev)
             t_upload = time.perf_counter()
             t_build = t_upload  # tables arrive pre-built on the host path
         user_f, item_f = _als_init(
@@ -737,14 +755,16 @@ def als_train(
     import contextlib
 
     nnz = int(user_idx.shape[0])
-    for _ in range(config.iterations):
+    for iteration in range(config.iterations):
         with contextlib.ExitStack() as stack:
             rec = (
                 stack.enter_context(prof.step(nnz=nnz))
                 if prof is not None
                 else None
             )
-            with xray.phase(xray.PHASE_SWEEP):
+            with xray.phase(xray.PHASE_SWEEP), annotate(
+                "pio:als.sweep", iteration=iteration
+            ):
                 user_f, item_f = _als_step(
                     user_f,
                     item_f,
@@ -769,18 +789,20 @@ def als_train(
             with prof.phase(xray.PHASE_HOST_ETL):
                 prof.add_rows(nnz)
                 prof.sample_memory()
-    if timings is not None:
-        fetch_barrier(user_f, item_f)
-        timings["pack_s"] = t_pack - t0
-        timings["upload_s"] = t_upload - t_pack
-        timings["build_s"] = t_build - t_upload
-        timings["device_s"] = time.perf_counter() - t_build
-        # block-table shapes, for the HBM bytes-moved model
-        # (solver_hbm_bytes_per_iter): nb = blocks per side, d = block width
-        timings["nb_u"] = int(dev[0].shape[0])
-        timings["nb_i"] = int(dev[4].shape[0])
-        timings["d"] = d
-    return user_f[:n_users], item_f[:n_items]
+    with annotate("pio:als.fetch"):
+        if timings is not None:
+            fetch_barrier(user_f, item_f)
+            timings["pack_s"] = t_pack - t0
+            timings["upload_s"] = t_upload - t_pack
+            timings["build_s"] = t_build - t_upload
+            timings["device_s"] = time.perf_counter() - t_build
+            # block-table shapes, for the HBM bytes-moved model
+            # (solver_hbm_bytes_per_iter): nb = blocks per side, d = block
+            # width
+            timings["nb_u"] = int(dev[0].shape[0])
+            timings["nb_i"] = int(dev[4].shape[0])
+            timings["d"] = d
+        return user_f[:n_users], item_f[:n_items]
 
 
 def solver_hbm_bytes_per_iter(
@@ -858,17 +880,28 @@ def _unpack(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _serve_by_index(uidx, user_factors, item_factors, mask, k: int):
-    scores = item_factors @ user_factors[uidx]  # [n_items]
-    scores = jnp.where(mask, scores, -jnp.inf)
-    return _pack(*lax.top_k(scores, k))
+    with jax.named_scope("gather"):
+        user_vec = user_factors[uidx]
+    with jax.named_scope("score"):
+        scores = item_factors @ user_vec  # [n_items]
+        scores = jnp.where(mask, scores, -jnp.inf)
+    with jax.named_scope("topk"):
+        return _pack(*lax.top_k(scores, k))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _serve_by_index_batch(uidxs, user_factors, item_factors, mask, k: int):
-    scores = user_factors[uidxs] @ item_factors.T  # [B, n_items] on the MXU
-    scores = jnp.where(mask[None, :], scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return jnp.stack([lax.bitcast_convert_type(s, jnp.int32), i], axis=1)
+    # the scopes name each HLO operation's op_name
+    # (jit(_serve_by_index_batch)/score/dot_general), so a trace can follow
+    # the product and the selection from build to build
+    with jax.named_scope("gather"):
+        user_vecs = user_factors[uidxs]
+    with jax.named_scope("score"):
+        scores = user_vecs @ item_factors.T  # [B, n_items] on the MXU
+        scores = jnp.where(mask[None, :], scores, -jnp.inf)
+    with jax.named_scope("topk"):
+        s, i = lax.top_k(scores, k)
+        return jnp.stack([lax.bitcast_convert_type(s, jnp.int32), i], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

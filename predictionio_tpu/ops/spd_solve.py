@@ -47,11 +47,12 @@ def _cg_body(A, b, iters: int, precision=None):
     dinv = 1.0 / jnp.sum(A * eye, axis=-1)  # diagonal without jnp.diagonal
 
     def mv(x):
-        return jax.lax.dot_general(
-            A, x[..., None], (((2,), (1,)), ((0,), (0,))),
-            precision=precision,
-            preferred_element_type=jnp.float32,
-        )[..., 0]
+        with jax.named_scope("matvec"):
+            return jax.lax.dot_general(
+                A, x[..., None], (((2,), (1,)), ((0,), (0,))),
+                precision=precision,
+                preferred_element_type=jnp.float32,
+            )[..., 0]
 
     def step(_, st):
         x, r, p, rz = st

@@ -28,6 +28,10 @@ Design (mirrors ops/als):
     COPIES: ``jnp.asarray`` on the CPU backend aliases host numpy memory,
     and an aliased buffer overwritten for batch N+1 while batch N's
     kernel is still in flight serves batch N the wrong queries.
+  - ``batch_bucket`` is the ONE place a serving batch is rounded up to its
+    power-of-two bucket, and it counts what the rounding costs: the rows
+    launched against the rows that are queries
+    (``pio_serve_rows_total{kind}``, ``pio_serve_batches_total{bucket}``).
   - ``host_top_k`` is the sanctioned HOST ending for score vectors that
     are host-born in the first place (popularity counts, cooccurrence
     maps). It lives here so the ``serving-host-roundtrip`` lint rule can
@@ -45,9 +49,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops.als import next_pow2, upload
 
 __all__ = [
+    "batch_bucket",
+    "bucket_counts",
     "dot_top_k_async",
     "gather_sum_top_k_async",
     "fused_top_k_async",
@@ -70,6 +77,37 @@ __all__ = [
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
+
+
+# what bucketing launched, process-wide ({bucket: [batches, real rows]}): the
+# engines that bucket know no server, so the server's registry mirrors these
+# at scrape (QueryServer._collect_buckets)
+_bucket_lock = threading.Lock()
+_bucket_tally: dict[int, list[int]] = {}
+
+
+def batch_bucket(n: int) -> int:
+    """The power-of-two bucket a batch of ``n`` real rows is launched as
+    (``next_pow2``: what ``warmup_pow2_buckets`` and
+    ``ServingIndex.warmup_buckets`` compiled), counted: ``n`` rows are
+    queries, ``bucket - n`` are padding the device scores all the same."""
+    bucket = next_pow2(n)
+    with _bucket_lock:
+        tally = _bucket_tally.setdefault(bucket, [0, 0])
+        tally[0] += 1
+        tally[1] += n
+    return bucket
+
+
+def bucket_counts() -> tuple[int, int, dict[int, int]]:
+    """``(real rows, bucket rows, {bucket: batches})`` launched so far."""
+    with _bucket_lock:
+        tallies = {bucket: tuple(t) for bucket, t in _bucket_tally.items()}
+    return (
+        sum(real for _, real in tallies.values()),
+        sum(bucket * batches for bucket, (batches, _) in tallies.items()),
+        {bucket: batches for bucket, (batches, _) in tallies.items()},
+    )
 
 
 def pack_batch(scores: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -196,8 +234,9 @@ def fetch_topk(handle) -> tuple[np.ndarray, np.ndarray]:
     Returns ([B,k] float32 scores, [B,k] int32 indices)."""
     from predictionio_tpu.ops.als import ServingIndex
 
-    # pio-lint: disable=serving-host-roundtrip -- the ONE sanctioned fetch: O(batch*k) packed result, accounted by the request waterfall
-    packed = np.asarray(handle)
+    with annotate("pio:fetch.block"):  # the host blocked on the device
+        # pio-lint: disable=serving-host-roundtrip -- the ONE sanctioned fetch: O(batch*k) packed result, accounted by the request waterfall
+        packed = np.asarray(handle)
     if packed.ndim == 2:  # single-query [2,k]
         packed = packed[None]
     # ops/als owns the wire format; this is the one decode of it

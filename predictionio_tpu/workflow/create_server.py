@@ -34,6 +34,7 @@ import datetime as _dt
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -56,7 +57,7 @@ from predictionio_tpu.controller.engine import Engine, EngineParams
 from predictionio_tpu.data.storage.base import EngineInstance
 from predictionio_tpu.data.storage.registry import Storage
 from predictionio_tpu.obs import xray
-from predictionio_tpu.obs.jaxprof import CompileWatcher
+from predictionio_tpu.obs.jaxprof import CompileWatcher, GcWatcher, annotate
 from predictionio_tpu.obs.metrics import MetricsRegistry
 from predictionio_tpu.obs.profiler import (
     ProfileBusyError,
@@ -67,6 +68,7 @@ from predictionio_tpu.obs.sampler import HostSampler
 from predictionio_tpu.obs.tracing import (
     TRACE_HEADER,
     Tracer,
+    current_span_id,
     current_trace_id,
     get_tracer,
     mint_trace_id,
@@ -365,6 +367,8 @@ class _QItem:
     # a quiesced stable lane); the batcher inserts the encoded body under
     # (answered version, key) once the batch resolves
     cache_key: bytes | None = None
+    # the ingress span's id: the parent of this query's `query.batch` span
+    parent_span_id: str | None = None
 
 
 class _MicroBatcher:
@@ -413,6 +417,9 @@ class _MicroBatcher:
         self._cancelled_tasks: list[asyncio.Task] = []
         self.batches_dispatched = 0
         self.queries_dispatched = 0
+        # running number of collected batches: the `batch` stat of a batch's
+        # pio: spans and the `batch` tag of its riders' query.batch spans
+        self._batch_seq = 0
         self.watchdog_trips = 0  # batches failed for blowing their deadline
         self.shed_count = 0  # requests rejected by admission control
 
@@ -458,6 +465,7 @@ class _MicroBatcher:
                 t_submit if t_submit is not None else time.perf_counter(),
                 phases if phases is not None else {},
                 cache_key,
+                current_span_id(),
             )
         )
         if self._task is None or self._task.done():
@@ -470,7 +478,7 @@ class _MicroBatcher:
             if not item.fut.done():
                 item.fut.set_exception(exc)
 
-    def _dispatch_combined(self, items: list[_QItem]):
+    def _dispatch_combined(self, items: list[_QItem], batch_no: int = 0):
         """Idle fast path: dispatch AND finalize in ONE executor hop.
 
         The dispatch->fetch pipeline exists to overlap batch n's transport
@@ -484,7 +492,7 @@ class _MicroBatcher:
         entirely. Arrivals during the combined call simply form the next
         batch — exactly what adaptive batching does while a dispatch is
         busy."""
-        fin = self._server._dispatch_query_batch(items)
+        fin = self._server._dispatch_query_batch(items, batch_no)
         results = fin()
 
         def resolved():
@@ -534,58 +542,60 @@ class _MicroBatcher:
                         batch.append(self._queue.get_nowait())
                     except asyncio.QueueEmpty:
                         break
+                collected_t = time.perf_counter()
                 await self._inflight.acquire()  # bound batches in flight
             except asyncio.CancelledError:
                 # shutdown while holding a collected-but-undispatched batch:
                 # its clients must get a response, not an eternal await
                 self._fail_batch(batch, ShuttingDownError())
                 raise
-            # requests that expired while queued are failed here, not
-            # dispatched: device work for an answer nobody is waiting on
-            # would only deepen an overload
             collect_t = time.perf_counter()
-            live = []
-            for item in batch:
-                if item.fut.done():  # client gone / cancelled
-                    # its probe slot (if it held one) can never be recorded
-                    self._server.dispatch_breaker.release_probe()
+            # a collected batch sat this long waiting for one of the
+            # in-flight slots (it does not grow while it waits): the part of
+            # queue_wait that a batcher filling its buckets would move
+            self._server._m_slot_wait.inc(collect_t - collected_t)
+            self._batch_seq += 1
+            batch_no = self._batch_seq
+            with annotate("pio:loop.collect", batch=batch_no):
+                # requests that expired while queued are failed here, not
+                # dispatched: device work for an answer nobody is waiting on
+                # would only deepen an overload
+                live = []
+                for item in batch:
+                    if item.fut.done():  # client gone / cancelled
+                        # its probe slot (if it held one) can never be recorded
+                        self._server.dispatch_breaker.release_probe()
+                        continue
+                    if item.deadline.expired:
+                        item.fut.set_exception(
+                            DeadlineExceeded("query expired in admission queue")
+                        )
+                    else:
+                        live.append(item)
+                        queue_wait_s = collect_t - item.t_submit
+                        item.phases["t_collect"] = collect_t
+                        self._server.waterfall.observe(
+                            PHASE_QUEUE_WAIT, queue_wait_s, item.trace_id
+                        )
+                if not live:
+                    self._inflight.release()
                     continue
-                if item.deadline.expired:
-                    item.fut.set_exception(
-                        DeadlineExceeded("query expired in admission queue")
-                    )
-                else:
-                    live.append(item)
-                    queue_wait_s = collect_t - item.t_submit
-                    item.phases["t_collect"] = collect_t
-                    self._server._m_queue_wait.observe(queue_wait_s)
-                    self._server.waterfall.observe(
-                        PHASE_QUEUE_WAIT, queue_wait_s, item.trace_id
-                    )
-            if not live:
-                self._inflight.release()
-                continue
-            batch = live
-            batch_deadline = Deadline.min_of([it.deadline for it in batch])
-            # idle fast path: a batch of ONE with nothing queued behind it
-            # and no finalize in flight has nothing to pipeline against —
-            # run dispatch AND finalize in one executor hop (see
-            # _dispatch_combined); the dispatch watchdog below still bounds
-            # the whole combined call. Any larger batch means the server is
-            # under load, where occupying the dispatch thread through the
-            # fetch would serialize the pipeline it exists to overlap.
-            combined = (
-                len(batch) == 1
-                and self._queue.empty()
-                and not self._finish_tasks
-            )
-            # dispatch under a watchdog. NOT wait_for(): cancelling an
-            # executor future whose fn is already running blocks until the
-            # fn returns — the exact hang the watchdog exists to escape.
-            # asyncio.wait() times out without cancelling; the stuck call
-            # is then abandoned and its pool replaced.
-            dispatch_t0 = time.perf_counter()
-            try:
+                batch = live
+                batch_deadline = Deadline.min_of([it.deadline for it in batch])
+                # idle fast path: a batch of ONE with nothing queued behind
+                # it and no finalize in flight has nothing to pipeline
+                # against — run dispatch AND finalize in one executor hop
+                # (see _dispatch_combined); the dispatch watchdog below still
+                # bounds the whole combined call. Any larger batch means the
+                # server is under load, where occupying the dispatch thread
+                # through the fetch would serialize the pipeline it exists to
+                # overlap.
+                combined = (
+                    len(batch) == 1
+                    and self._queue.empty()
+                    and not self._finish_tasks
+                )
+                dispatch_t0 = time.perf_counter()
                 # the batch list itself is the handoff — the dispatch
                 # thread reads payload/trace_id straight off the queued
                 # items (no per-batch tuple-list materialization)
@@ -595,8 +605,15 @@ class _MicroBatcher:
                     if combined
                     else self._server._dispatch_query_batch,
                     batch,
+                    batch_no,
                 )
                 exec_fut.add_done_callback(_swallow_result)
+            # dispatch under a watchdog. NOT wait_for(): cancelling an
+            # executor future whose fn is already running blocks until the
+            # fn returns — the exact hang the watchdog exists to escape.
+            # asyncio.wait() times out without cancelling; the stuck call
+            # is then abandoned and its pool replaced.
+            try:
                 done, pending = await asyncio.wait(
                     [exec_fut], timeout=batch_deadline.remaining()
                 )
@@ -640,7 +657,6 @@ class _MicroBatcher:
                     - t.get("device_s", 0.0)
                     - t.get("serve_s", 0.0),
                 )
-            self._server._m_dispatch.observe(dispatch_s)
             # batch-scoped waterfall phases: every rider waits out the whole
             # batch, so each query is accounted the batch's duration
             assembly_s = max(0.0, dispatch_t0 - collect_t)
@@ -662,6 +678,7 @@ class _MicroBatcher:
                     batch_deadline,
                     dispatch_s,
                     dispatch_t0 + dispatch_s,
+                    batch_no,
                 )
             )
             self._finish_tasks.add(task)
@@ -674,6 +691,7 @@ class _MicroBatcher:
         deadline: Deadline,
         dispatch_s: float = 0.0,
         dispatch_end: float = 0.0,
+        batch_no: int = 0,
     ) -> None:
         loop = asyncio.get_running_loop()
         fetch_t0 = time.perf_counter()
@@ -687,7 +705,6 @@ class _MicroBatcher:
             # read as zero-stall
             results = finalize()
             fetch_s = time.perf_counter() - fetch_t0
-            self._server._m_fetch.observe(fetch_s)
             device_s = (getattr(finalize, "timings", None) or {}).get(
                 "device_s", 0.0
             )
@@ -724,7 +741,6 @@ class _MicroBatcher:
                 )
                 return
             fetch_s = time.perf_counter() - fetch_t0
-            self._server._m_fetch.observe(fetch_s)
             # the fetch phase is where the host blocks on the device
             # transport: account it as stall time (see obs/jaxprof.py)
             self._server._m_stall.inc(fetch_s, where="micro-batch-fetch")
@@ -743,54 +759,57 @@ class _MicroBatcher:
             finally:
                 self._inflight.release()
         done_t = time.perf_counter()
-        # waterfall decomposition of the dispatch-end -> results-distributed
-        # window: device compute and serve are measured inside finalize (it
-        # publishes them via its `timings` attribute); everything else in
-        # the window — executor hop, transport readback, result unpack — is
-        # the fetch residual
-        timings = getattr(finalize, "timings", None) or {}
-        device_s = max(0.0, timings.get("device_s", 0.0))
-        serve_s = max(0.0, timings.get("serve_s", 0.0))
-        window_s = (done_t - dispatch_end) if dispatch_end else fetch_s
-        fetch_resid_s = max(0.0, window_s - device_s - serve_s)
-        wf = self._server.waterfall
-        for item, (out, version) in zip(batch, results):
-            wf.observe(PHASE_DEVICE_COMPUTE, device_s, item.trace_id)
-            wf.observe(PHASE_FETCH, fetch_resid_s, item.trace_id)
-            wf.observe(PHASE_SERVE, serve_s, item.trace_id)
-            if item.cache_key is not None and not isinstance(out, BaseException):
-                self._server._cache_store(version, item.cache_key, out)
-            item.phases["t_done"] = done_t
-            queue_s = max(
-                0.0, item.phases.get("t_collect", item.t_submit) - item.t_submit
-            )
-            # one `batch` span per query, carrying the full phase waterfall
-            # AND the model version that answered — the hop between the
-            # ingress span and any storage spans the engine's serving
-            # components recorded
-            self._server.tracer.record_span(
-                "query.batch",
-                kind="batch",
-                duration_s=done_t - item.t_submit,
-                trace_id=item.trace_id,
-                status=type(out).__name__ if isinstance(out, BaseException) else "ok",
-                batch_size=len(batch),
-                version=version,
-                queue_ms=round(queue_s * 1000, 3),
-                dispatch_ms=round(dispatch_s * 1000, 3),
-                fetch_ms=round(fetch_s * 1000, 3),
-                **phase_tags_ms(
-                    device_compute=device_s,
-                    serve=serve_s,
-                    fetch_residual=fetch_resid_s,
-                ),
-            )
-            if item.fut.done():  # client gone / cancelled
-                continue
-            if isinstance(out, BaseException):
-                item.fut.set_exception(out)
-            else:
-                item.fut.set_result(out)
+        with annotate("pio:loop.finish", batch=batch_no):
+            # waterfall decomposition of the dispatch-end -> results-distributed
+            # window: device compute and serve are measured inside finalize (it
+            # publishes them via its `timings` attribute); everything else in
+            # the window — executor hop, transport readback, result unpack — is
+            # the fetch residual
+            timings = getattr(finalize, "timings", None) or {}
+            device_s = max(0.0, timings.get("device_s", 0.0))
+            serve_s = max(0.0, timings.get("serve_s", 0.0))
+            window_s = (done_t - dispatch_end) if dispatch_end else fetch_s
+            fetch_resid_s = max(0.0, window_s - device_s - serve_s)
+            wf = self._server.waterfall
+            for item, (out, version) in zip(batch, results):
+                wf.observe(PHASE_DEVICE_COMPUTE, device_s, item.trace_id)
+                wf.observe(PHASE_FETCH, fetch_resid_s, item.trace_id)
+                wf.observe(PHASE_SERVE, serve_s, item.trace_id)
+                if item.cache_key is not None and not isinstance(out, BaseException):
+                    self._server._cache_store(version, item.cache_key, out)
+                item.phases["t_done"] = done_t
+                queue_s = max(
+                    0.0, item.phases.get("t_collect", item.t_submit) - item.t_submit
+                )
+                # one `batch` span per query, carrying the full phase waterfall
+                # AND the model version that answered — the hop between the
+                # ingress span and any storage spans the engine's serving
+                # components recorded
+                self._server.tracer.record_span(
+                    "query.batch",
+                    kind="batch",
+                    duration_s=done_t - item.t_submit,
+                    trace_id=item.trace_id,
+                    status=type(out).__name__ if isinstance(out, BaseException) else "ok",
+                    parent_id=item.parent_span_id,
+                    batch=batch_no,
+                    batch_size=len(batch),
+                    version=version,
+                    queue_ms=round(queue_s * 1000, 3),
+                    dispatch_ms=round(dispatch_s * 1000, 3),
+                    fetch_ms=round(fetch_s * 1000, 3),
+                    **phase_tags_ms(
+                        device_compute=device_s,
+                        serve=serve_s,
+                        fetch_residual=fetch_resid_s,
+                    ),
+                )
+                if item.fut.done():  # client gone / cancelled
+                    continue
+                if isinstance(out, BaseException):
+                    item.fut.set_exception(out)
+                else:
+                    item.fut.set_result(out)
 
     def close(self) -> None:
         self._closed = True  # new submits fail fast from here on
@@ -915,18 +934,29 @@ class QueryServer:
             "HTTP request wall time, by route",
             labelnames=("endpoint",),
         )
-        self._m_queue_wait = m.histogram(
-            "pio_queue_wait_seconds",
-            "time queries spend in the micro-batch admission queue",
+        self._m_slot_wait = m.counter(
+            "pio_batch_slot_wait_seconds_total",
+            "seconds collected micro-batches sat waiting for an in-flight "
+            "slot before dispatch (once a batch; inside the queue_wait phase)",
         )
-        self._m_dispatch = m.histogram(
-            "pio_dispatch_seconds",
-            "micro-batch dispatch phase (decode + device enqueue) wall time",
+        # what power-of-two bucketing launched (ops/topk.batch_bucket keeps
+        # the tallies, the engines know no server): mirrored at scrape
+        self._m_serve_rows = m.counter(
+            "pio_serve_rows_total",
+            "rows of launched serving buckets: kind=real are queries, "
+            "kind=bucket is what the device scored (the rest is padding)",
+            labelnames=("kind",),
         )
-        self._m_fetch = m.histogram(
-            "pio_fetch_seconds",
-            "micro-batch fetch phase (device->host transport + serve) wall time",
+        self._m_serve_batches = m.counter(
+            "pio_serve_batches_total",
+            "serving batches launched, by power-of-two bucket",
+            labelnames=("bucket",),
         )
+        m.register_collector(self._collect_buckets)
+        # the interpreter's collection pauses (hook installed by start(),
+        # removed by stop())
+        self.gc_watcher = GcWatcher(m)
+        m.register_collector(self.gc_watcher.collect)
         self._m_stall = m.counter(
             "pio_device_stall_seconds_total",
             "cumulative seconds spent blocked on device->host synchronization",
@@ -1391,7 +1421,13 @@ class QueryServer:
         # the sort_keys canonical path
         return web.json_response(body, dumps=_fast_dumps)
 
-    def _dispatch_query_batch(self, items: list[_QItem]):
+    def _dispatch_query_batch(self, items: list[_QItem], batch_no: int = 0):
+        """One micro-batch's dispatch phase as a ``pio:dispatch`` span;
+        ``batch_no`` is the batcher's running number of the batch."""
+        with annotate("pio:dispatch", batch=batch_no, n=len(items)):
+            return self._route_and_dispatch(items)
+
+    def _route_and_dispatch(self, items: list[_QItem]):
         """Dispatch-phase of one micro-batch (runs on the dispatch thread):
         decode and supplement each query, then *dispatch* every algorithm's
         device work via ``predict_batch_dispatch`` without blocking on
@@ -1446,61 +1482,62 @@ class QueryServer:
         stable_idx: list[int] = []
         cand_idx: list[int] = []
         inst = self._rollout_instruments
-        for i, payload in enumerate(payloads):
-            token = set_trace_id(trace_ids[i])
-            try:
+        with annotate("pio:dispatch.decode"):
+            for i, payload in enumerate(payloads):
+                token = set_trace_id(trace_ids[i])
                 try:
-                    q = self.engine.decode_query(payload)
-                    queries[i] = q
-                except Exception as exc:
-                    # client error (bad payload) — no lane touched it, so
-                    # no per-version accounting
-                    outs[i] = exc
-                    continue
-                lane = stable
-                if canary and (
-                    choose_lane(
-                        plan,
-                        routing_key(payload, self.config.sticky_key_field),
-                    )
-                    == LANE_CANDIDATE
-                ):
-                    # a failing candidate supplement degrades this query to
-                    # the stable answer, not to an error; the failure is
-                    # paired with a request so the error-RATE gate compares
-                    # like with like
                     try:
-                        supplemented[i] = cand.serving.supplement(q)
-                        lane = cand
-                    except Exception:
-                        logger.exception("candidate supplement failed")
-                        if gen == self._rollout_gen:
-                            inst.requests.inc(
-                                version=cand.version, lane=LANE_CANDIDATE
-                            )
-                        self._record_candidate_failure(cand.version, gen)
-                if lane is stable:
-                    try:
-                        supplemented[i] = stable.serving.supplement(q)
+                        q = self.engine.decode_query(payload)
+                        queries[i] = q
                     except Exception as exc:
-                        # symmetric accounting: a stable supplement failure
-                        # is a stable-lane error, not silence — otherwise a
-                        # flaky shared dependency reads as candidate-only
-                        # and rolls back a candidate no worse than stable
-                        inst.requests.inc(
-                            version=stable.version, lane=LANE_STABLE
-                        )
-                        inst.errors.inc(
-                            version=stable.version, lane=LANE_STABLE
-                        )
+                        # client error (bad payload) — no lane touched it, so
+                        # no per-version accounting
                         outs[i] = exc
                         continue
-                    stable_idx.append(i)
-                else:
-                    versions[i] = cand.version
-                    cand_idx.append(i)
-            finally:
-                reset_trace_id(token)
+                    lane = stable
+                    if canary and (
+                        choose_lane(
+                            plan,
+                            routing_key(payload, self.config.sticky_key_field),
+                        )
+                        == LANE_CANDIDATE
+                    ):
+                        # a failing candidate supplement degrades this query to
+                        # the stable answer, not to an error; the failure is
+                        # paired with a request so the error-RATE gate compares
+                        # like with like
+                        try:
+                            supplemented[i] = cand.serving.supplement(q)
+                            lane = cand
+                        except Exception:
+                            logger.exception("candidate supplement failed")
+                            if gen == self._rollout_gen:
+                                inst.requests.inc(
+                                    version=cand.version, lane=LANE_CANDIDATE
+                                )
+                            self._record_candidate_failure(cand.version, gen)
+                    if lane is stable:
+                        try:
+                            supplemented[i] = stable.serving.supplement(q)
+                        except Exception as exc:
+                            # symmetric accounting: a stable supplement failure
+                            # is a stable-lane error, not silence — otherwise a
+                            # flaky shared dependency reads as candidate-only
+                            # and rolls back a candidate no worse than stable
+                            inst.requests.inc(
+                                version=stable.version, lane=LANE_STABLE
+                            )
+                            inst.errors.inc(
+                                version=stable.version, lane=LANE_STABLE
+                            )
+                            outs[i] = exc
+                            continue
+                        stable_idx.append(i)
+                    else:
+                        versions[i] = cand.version
+                        cand_idx.append(i)
+                finally:
+                    reset_trace_id(token)
         dispatched: list[tuple[Lane, str, list[int], list[Any], list[Any]]] = []
         for lane, lane_name, idxs in (
             (stable, LANE_STABLE, stable_idx),
@@ -1513,7 +1550,9 @@ class QueryServer:
             for algo, model in zip(lane.algorithms, lane.models):
                 fin = None
                 try:
-                    fin = algo.predict_batch_dispatch(model, sup)
+                    # staging, upload and the kernel's launch
+                    with annotate("pio:dispatch.enqueue", n=len(sup)):
+                        fin = algo.predict_batch_dispatch(model, sup)
                 except Exception:
                     logger.exception(
                         "predict_batch_dispatch failed; deferring to fetch"
@@ -1538,60 +1577,61 @@ class QueryServer:
                 inst.predict_seconds.observe(
                     lane_predict_s, version=lane.version
                 )
-                for row, i in enumerate(idxs):
-                    token = set_trace_id(trace_ids[i])
-                    t_serve = time.perf_counter()
-                    # candidate accounting is generation-scoped end to end:
-                    # a stale batch must not add errorless requests to the
-                    # denominator of the NEW candidate's error-rate gate
-                    # (its errors are already dropped by the gen guard)
-                    if lane_name != LANE_CANDIDATE or gen == self._rollout_gen:
-                        inst.requests.inc(version=lane.version, lane=lane_name)
-                    try:
-                        outs[i] = self._serve_one(
-                            lane,
-                            queries[i],
-                            [preds[row] for preds in preds_per_algo],
-                            sniffed,
-                        )
-                        if lane_name == LANE_CANDIDATE and gen == self._rollout_gen:
-                            # same generation guard as the failure paths: a
-                            # stale batch's successes must not reset the
-                            # consecutive-failure count a failing successor
-                            # candidate is accumulating
-                            self.candidate_breaker.record_success()
-                        if bandit is not None:
-                            # an answered query is a pull the moment it is
-                            # served; the trace id becomes matchable for
-                            # feedback credit
-                            bandit.record_impression(
-                                trace_ids[i],
-                                ARM_CANDIDATE
-                                if lane_name == LANE_CANDIDATE
-                                else ARM_STABLE,
-                                lane.version,
+                with annotate("pio:serve"):
+                    for row, i in enumerate(idxs):
+                        token = set_trace_id(trace_ids[i])
+                        t_serve = time.perf_counter()
+                        # candidate accounting is generation-scoped end to end:
+                        # a stale batch must not add errorless requests to the
+                        # denominator of the NEW candidate's error-rate gate
+                        # (its errors are already dropped by the gen guard)
+                        if lane_name != LANE_CANDIDATE or gen == self._rollout_gen:
+                            inst.requests.inc(version=lane.version, lane=lane_name)
+                        try:
+                            outs[i] = self._serve_one(
+                                lane,
+                                queries[i],
+                                [preds[row] for preds in preds_per_algo],
+                                sniffed,
                             )
-                    except Exception as exc:
-                        if lane_name == LANE_CANDIDATE:
-                            self._record_candidate_failure(lane.version, gen)
-                            outs[i], versions[i] = self._stable_retry(
-                                stable, queries[i], sniffed
-                            )
-                            if bandit is not None and not isinstance(
-                                outs[i], BaseException
-                            ):
-                                # re-answered on stable: that's a stable pull
+                            if lane_name == LANE_CANDIDATE and gen == self._rollout_gen:
+                                # same generation guard as the failure paths: a
+                                # stale batch's successes must not reset the
+                                # consecutive-failure count a failing successor
+                                # candidate is accumulating
+                                self.candidate_breaker.record_success()
+                            if bandit is not None:
+                                # an answered query is a pull the moment it is
+                                # served; the trace id becomes matchable for
+                                # feedback credit
                                 bandit.record_impression(
-                                    trace_ids[i], ARM_STABLE, stable.version
+                                    trace_ids[i],
+                                    ARM_CANDIDATE
+                                    if lane_name == LANE_CANDIDATE
+                                    else ARM_STABLE,
+                                    lane.version,
                                 )
-                        else:
-                            inst.errors.inc(
-                                version=lane.version, lane=lane_name
-                            )
-                            outs[i] = exc
-                    finally:
-                        timings["serve_s"] += time.perf_counter() - t_serve
-                        reset_trace_id(token)
+                        except Exception as exc:
+                            if lane_name == LANE_CANDIDATE:
+                                self._record_candidate_failure(lane.version, gen)
+                                outs[i], versions[i] = self._stable_retry(
+                                    stable, queries[i], sniffed
+                                )
+                                if bandit is not None and not isinstance(
+                                    outs[i], BaseException
+                                ):
+                                    # re-answered on stable: that's a stable pull
+                                    bandit.record_impression(
+                                        trace_ids[i], ARM_STABLE, stable.version
+                                    )
+                            else:
+                                inst.errors.inc(
+                                    version=lane.version, lane=lane_name
+                                )
+                                outs[i] = exc
+                        finally:
+                            timings["serve_s"] += time.perf_counter() - t_serve
+                            reset_trace_id(token)
             if shadow:
                 pairs = [
                     (queries[i], outs[i])
@@ -2040,6 +2080,16 @@ class QueryServer:
         n = cache.clear() if version is None else cache.flush_version(version)
         if n:
             logger.info("result cache: flushed %d entries (%s)", n, why)
+
+    def _collect_buckets(self) -> None:
+        """Registry collector: mirror ops/topk's bucket tallies (a process
+        that never imported it has launched no bucket: explicit zeros)."""
+        topk = sys.modules.get("predictionio_tpu.ops.topk")
+        real, bucket, batches = topk.bucket_counts() if topk else (0, 0, {})
+        self._m_serve_rows.set_total(real, kind="real")
+        self._m_serve_rows.set_total(bucket, kind="bucket")
+        for size, count in batches.items():
+            self._m_serve_batches.set_total(count, bucket=str(size))
 
     def _collect_cache(self) -> None:
         """Scrape-time mirror of the cache's monotonic stats into the
@@ -2898,6 +2948,7 @@ class QueryServer:
 
     async def start(self) -> None:
         await asyncio.get_running_loop().run_in_executor(None, self._warmup)
+        self.gc_watcher.install()  # removed by stop()
         retries = max(1, self.config.bind_retries)
         last_error: Exception | None = None
         for attempt in range(retries):
@@ -2997,6 +3048,7 @@ class QueryServer:
         self._drain_task = asyncio.ensure_future(_go())
 
     async def stop(self) -> None:
+        self.gc_watcher.remove()
         self._batcher.close()
         await self._batcher.wait_closed()
         self._sniffer_pool.shutdown(wait=False, cancel_futures=True)

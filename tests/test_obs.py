@@ -231,6 +231,26 @@ class TestTracing:
         assert names == ["s4", "s3", "s2"]
         assert tracer.spans_recorded == 5
 
+    def test_span_parent_and_find_oldest_first(self):
+        """A span started inside another has it as parent, and ``find``
+        orders a request's spans by their monotonic start: the ring holds
+        them in the order they ENDED (the child first)."""
+        tracer = Tracer()
+        with tracer.span("outer", trace_id="abcd") as outer:
+            with tracer.span("inner", trace_id="abcd") as inner:
+                pass
+            late = tracer.record_span(
+                "timed", "batch", 0.0, trace_id="abcd", parent_id=outer.span_id
+            )
+        assert outer.parent_id is None
+        assert inner.parent_id == late.parent_id == outer.span_id
+        assert [s["name"] for s in tracer.recent()] == ["outer", "timed", "inner"]
+        found = tracer.find("abcd")
+        assert [s["name"] for s in found] == ["outer", "inner", "timed"]
+        starts = [s["startMonoNs"] for s in found]
+        assert starts == sorted(starts) and all(isinstance(t, int) for t in starts)
+        assert found[1]["parentId"] == found[0]["spanId"] and found[0]["parentId"] is None
+
     def test_contextvar_isolation(self):
         assert current_trace_id() is None
         token = set_trace_id("aaaa")
@@ -464,6 +484,26 @@ class TestQueryServerObs:
                 await client.close()
 
         asyncio.run(outer())
+
+    def test_batch_span_has_the_ingress_span_as_parent(self):
+        """The ingress span's id rides the queued item across the
+        micro-batcher: a request's spans order and say which caused which."""
+        tid = mint_trace_id()
+
+        async def body(client, server):
+            resp = await client.post(
+                "/queries.json", json={"qid": 1}, headers={TRACE_HEADER: tid}
+            )
+            assert resp.status == 200
+            ingress, batch = get_tracer().find(tid)
+            assert (ingress["kind"], batch["kind"]) == ("ingress", "batch")
+            assert ingress["parentId"] is None
+            assert batch["parentId"] == ingress["spanId"]
+            assert batch["startMonoNs"] >= ingress["startMonoNs"]
+            # the batcher's running number of the batch, as on its pio: spans
+            assert batch["tags"]["batch"] == server._batcher._batch_seq == 1
+
+        _run_query_server(body)
 
     def test_shed_and_deadline_counters_move_under_chaos(self):
         """Acceptance: a chaos run shows shed/deadline counters moving."""
