@@ -1005,7 +1005,10 @@ class ServingIndex:
         while b <= next_pow2(max_batch):
             handles.append(
                 _serve_by_index_batch(
-                    jnp.zeros((b,), jnp.int32),
+                    # through upload(), as serve_batch_async stages its
+                    # indices: its copy is a program of its own for every
+                    # bucket, and a bucket's first batch would load it
+                    upload(np.zeros((b,), np.int32)),
                     self.user_factors,
                     self.item_factors,
                     self._full_mask,

@@ -29,6 +29,7 @@ one device->host top-k readback. Serving latency histogram kept in-process
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 import datetime as _dt
 import json
@@ -183,7 +184,11 @@ class ServerConfig:
     # per-request on an actor and carries a literal ``TODO: Parallelize``,
     # CreateServer.scala:488-491). max_batch_size <= 1 disables coalescing;
     # batch_window_ms > 0 adds a flush timer (rarely needed: batches form
-    # adaptively while the previous batch is in flight on the worker thread).
+    # adaptively while the device is busy). At most two batches are ahead of
+    # the device at a time (a slot is a batch it has not answered yet: one
+    # computing, one queued behind it to hide the host's launch); a batch is
+    # closed when it gets its slot, so arrivals join it until then. Not a
+    # setting: see _MicroBatcher.
     max_batch_size: int = 128
     batch_window_ms: float = 0.0
     # -- resilience (see docs/resilience.md) --------------------------------
@@ -371,25 +376,68 @@ class _QItem:
     parent_span_id: str | None = None
 
 
+class _Slot:
+    """One of the batcher's places ahead of the chip, held by a dispatched
+    batch until the device has answered it. It goes back exactly once,
+    whichever gets there first: ``device_answered`` from the fetch thread,
+    the moment ``finalize`` has the packed results on the host and before
+    it serves them, or ``release`` on the event loop (a failed dispatch, and
+    the end of the batch's ``_finish`` task however it ends: result,
+    exception, watchdog trip, cancellation)."""
+
+    __slots__ = ("_slots", "_loop", "_held")
+
+    def __init__(self, slots: asyncio.Semaphore, loop: asyncio.AbstractEventLoop):
+        self._slots = slots
+        self._loop = loop
+        self._held = True
+
+    def release(self, _finished: asyncio.Task | None = None) -> None:
+        if self._held:
+            self._held = False
+            self._slots.release()
+
+    def device_answered(self) -> None:
+        try:
+            self._loop.call_soon_threadsafe(self.release)
+        except RuntimeError:
+            pass  # the loop closed under a finalize that outlived shutdown
+
+
 class _MicroBatcher:
     """Coalesces concurrent /queries.json requests into batched predicts.
 
-    Requests enqueue (payload, future) pairs; a single dispatcher pulls
-    everything pending (up to ``max_batch``) and hands the batch to a
-    dedicated worker thread, which runs the full decode -> supplement ->
-    predict_batch -> serve pipeline off the event loop. Batching is
-    *adaptive*: while the worker is busy with batch n, new arrivals
-    accumulate and become batch n+1 — a solo request dispatches immediately
-    (no timer penalty), a concurrent burst converges to one device call per
-    batch. An optional flush window can be configured but is 0 by default.
+    Requests enqueue (payload, future) pairs; a single dispatcher hands
+    batches to a dedicated dispatch thread (decode -> supplement -> launch)
+    and their finalizes (block on the device -> unpack -> serve -> encode)
+    to fetch threads, all off the event loop. Batching is *adaptive*: while
+    the device is busy, new arrivals accumulate and become the next batch —
+    a solo request dispatches immediately (no timer penalty), a concurrent
+    burst converges to one device call per batch. An optional flush window
+    can be configured but is 0 by default.
+
+    A *slot* is a batch the device has not answered yet, and there are
+    ``SLOTS`` = 2 of them: one batch computing and one queued behind it is
+    what hides the host's decode, upload and launch of the next; one chip
+    runs batches one after another, so every further batch ahead of it only
+    waits in the device's queue, and every query in it waits one kernel
+    longer. The dispatcher waits for the first pending query, then for a
+    slot, and only then closes the batch: nothing is collected and held, so
+    what arrives while it waits rides the batch that gets the slot. The
+    slot goes back when ``finalize`` has the device's results on the host,
+    not when the host has finished serving them (see ``_Slot``), so batch
+    n's serve overlaps the dispatch of n + 1 and n + 2; ``FETCH_THREADS``
+    leaves room for those overlapping serve stages.
     """
+
+    SLOTS = 2
+    FETCH_THREADS = 4
 
     def __init__(
         self,
         server: "QueryServer",
         max_batch: int,
         window_s: float,
-        max_inflight: int = 4,
         high_water: int = 0,
         shed_retry_after_s: float = 1.0,
     ):
@@ -400,19 +448,21 @@ class _MicroBatcher:
         self.window_s = max(0.0, window_s)
         self.high_water = max(0, high_water)
         self.shed_retry_after_s = shed_retry_after_s
-        self._queue: asyncio.Queue = asyncio.Queue()
+        # pending queries, oldest first: they stay here until a batch is
+        # closed, so queue_depth, shedding and close() see every one of them
+        self._queue: collections.deque[_QItem] = collections.deque()
+        self._arrived = asyncio.Event()
         self._task: asyncio.Task | None = None
         self._closed = False
-        self._max_fetch_workers = max(1, max_inflight)
         # dispatch runs on one thread (decode + device enqueue, fast);
-        # fetches block on the transport and overlap on their own threads
+        # finalizes block on the device and then serve, on their own threads
         self._dispatch_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="pio-dispatch"
         )
         self._fetch_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=self._max_fetch_workers, thread_name_prefix="pio-fetch"
+            max_workers=self.FETCH_THREADS, thread_name_prefix="pio-fetch"
         )
-        self._inflight = asyncio.Semaphore(max(1, max_inflight))
+        self._slots = asyncio.Semaphore(self.SLOTS)
         self._finish_tasks: set[asyncio.Task] = set()
         self._cancelled_tasks: list[asyncio.Task] = []
         self.batches_dispatched = 0
@@ -425,7 +475,7 @@ class _MicroBatcher:
 
     @property
     def queue_depth(self) -> int:
-        return self._queue.qsize()
+        return len(self._queue)
 
     async def submit(
         self,
@@ -445,18 +495,18 @@ class _MicroBatcher:
         at its own last measured boundary so adjacent phases tile."""
         if self._closed:
             raise ShuttingDownError()
-        if self.high_water and self._queue.qsize() >= self.high_water:
+        if self.high_water and len(self._queue) >= self.high_water:
             self.shed_count += 1
             self._server._m_shed.inc()
             raise LoadShedError(
                 f"admission queue over high water "
-                f"({self._queue.qsize()}/{self.high_water})",
+                f"({len(self._queue)}/{self.high_water})",
                 self.shed_retry_after_s,
             )
         if deadline is None:
             deadline = Deadline.never()
         fut = asyncio.get_running_loop().create_future()
-        self._queue.put_nowait(
+        self._queue.append(
             _QItem(
                 payload,
                 fut,
@@ -468,6 +518,7 @@ class _MicroBatcher:
                 current_span_id(),
             )
         )
+        self._arrived.set()
         if self._task is None or self._task.done():
             self._task = asyncio.ensure_future(self._run())
         return await fut
@@ -525,35 +576,28 @@ class _MicroBatcher:
 
         old = self._fetch_pool
         self._fetch_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=self._max_fetch_workers, thread_name_prefix="pio-fetch"
+            max_workers=self.FETCH_THREADS, thread_name_prefix="pio-fetch"
         )
         old.shutdown(wait=False)
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
-            item = await self._queue.get()
-            batch = [item]
-            try:
-                if self.window_s > 0:
-                    await asyncio.sleep(self.window_s)
-                while len(batch) < self.max_batch:
-                    try:
-                        batch.append(self._queue.get_nowait())
-                    except asyncio.QueueEmpty:
-                        break
-                collected_t = time.perf_counter()
-                await self._inflight.acquire()  # bound batches in flight
-            except asyncio.CancelledError:
-                # shutdown while holding a collected-but-undispatched batch:
-                # its clients must get a response, not an eternal await
-                self._fail_batch(batch, ShuttingDownError())
-                raise
+            # slot first, batch closed last: wait for a pending query, then
+            # for a slot, and only then drain the queue into the batch. A
+            # cancellation (close()) at any of these awaits holds nothing:
+            # the queries are still queued and close() answers them
+            if not self._queue:
+                self._arrived.clear()
+                await self._arrived.wait()
+            if self.window_s > 0:
+                await asyncio.sleep(self.window_s)
+            queued_before = len(self._queue)
+            wait_t0 = time.perf_counter()
+            await self._slots.acquire()
+            slot = _Slot(self._slots, loop)
             collect_t = time.perf_counter()
-            # a collected batch sat this long waiting for one of the
-            # in-flight slots (it does not grow while it waits): the part of
-            # queue_wait that a batcher filling its buckets would move
-            self._server._m_slot_wait.inc(collect_t - collected_t)
+            self._server._m_slot_wait.inc(collect_t - wait_t0)
             self._batch_seq += 1
             batch_no = self._batch_seq
             with annotate("pio:loop.collect", batch=batch_no):
@@ -561,7 +605,9 @@ class _MicroBatcher:
                 # dispatched: device work for an answer nobody is waiting on
                 # would only deepen an overload
                 live = []
-                for item in batch:
+                joined = 0
+                for arrival in range(min(len(self._queue), self.max_batch)):
+                    item = self._queue.popleft()
                     if item.fut.done():  # client gone / cancelled
                         # its probe slot (if it held one) can never be recorded
                         self._server.dispatch_breaker.release_probe()
@@ -572,13 +618,15 @@ class _MicroBatcher:
                         )
                     else:
                         live.append(item)
+                        if arrival >= queued_before:
+                            joined += 1  # arrived during the wait for this slot
                         queue_wait_s = collect_t - item.t_submit
                         item.phases["t_collect"] = collect_t
                         self._server.waterfall.observe(
                             PHASE_QUEUE_WAIT, queue_wait_s, item.trace_id
                         )
                 if not live:
-                    self._inflight.release()
+                    slot.release()
                     continue
                 batch = live
                 batch_deadline = Deadline.min_of([it.deadline for it in batch])
@@ -592,7 +640,7 @@ class _MicroBatcher:
                 # overlap.
                 combined = (
                     len(batch) == 1
-                    and self._queue.empty()
+                    and not self._queue
                     and not self._finish_tasks
                 )
                 dispatch_t0 = time.perf_counter()
@@ -618,7 +666,7 @@ class _MicroBatcher:
                     [exec_fut], timeout=batch_deadline.remaining()
                 )
             except asyncio.CancelledError:
-                self._inflight.release()
+                slot.release()
                 # shutdown mid-dispatch: this batch's clients must get a
                 # response too (close()'s drain only covers queued items)
                 self._fail_batch(batch, ShuttingDownError())
@@ -626,7 +674,7 @@ class _MicroBatcher:
             if pending:
                 # watchdog trip: fail THIS batch, walk away from the stuck
                 # dispatch thread, keep serving everyone else
-                self._inflight.release()
+                slot.release()
                 self.watchdog_trips += 1
                 self._server._m_watchdog.inc()
                 self._replace_dispatch_pool()
@@ -640,7 +688,7 @@ class _MicroBatcher:
             try:
                 finalize = exec_fut.result()
             except BaseException as exc:
-                self._inflight.release()
+                slot.release()
                 self._server.dispatch_breaker.record_failure()
                 for item in batch:
                     if not item.fut.done():
@@ -669,6 +717,7 @@ class _MicroBatcher:
                 )
             self.batches_dispatched += 1
             self.queries_dispatched += len(batch)
+            self._server._m_joined_in_slot_wait.inc(joined)
             # finish asynchronously: the collect loop immediately forms and
             # dispatches the next batch while this one's fetch is in flight
             task = asyncio.ensure_future(
@@ -676,6 +725,7 @@ class _MicroBatcher:
                     batch,
                     finalize,
                     batch_deadline,
+                    slot.device_answered,
                     dispatch_s,
                     dispatch_t0 + dispatch_s,
                     batch_no,
@@ -683,12 +733,14 @@ class _MicroBatcher:
             )
             self._finish_tasks.add(task)
             task.add_done_callback(self._finish_tasks.discard)
+            task.add_done_callback(slot.release)  # if finalize has not already
 
     async def _finish(
         self,
         batch: list[_QItem],
         finalize,
         deadline: Deadline,
+        device_answered,
         dispatch_s: float = 0.0,
         dispatch_end: float = 0.0,
         batch_no: int = 0,
@@ -711,16 +763,19 @@ class _MicroBatcher:
             if device_s > 0.0:
                 self._server._m_stall.inc(device_s, where="micro-batch-fetch")
             self._server.dispatch_breaker.record_success()
-            self._inflight.release()
         else:
-            exec_fut = loop.run_in_executor(self._fetch_pool, finalize)
+            # finalize hands the slot back itself, from the fetch thread, the
+            # moment the device's results are on the host: the next batch is
+            # closed and dispatched while this one is still being served
+            exec_fut = loop.run_in_executor(
+                self._fetch_pool, finalize, device_answered
+            )
             exec_fut.add_done_callback(_swallow_result)
             try:
                 done, pending = await asyncio.wait(
                     [exec_fut], timeout=deadline.remaining()
                 )
             except asyncio.CancelledError:
-                self._inflight.release()
                 # shutdown: resolve the batch's futures (handlers awaiting
                 # them would otherwise hang for aiohttp's whole shutdown
                 # timeout)
@@ -730,7 +785,6 @@ class _MicroBatcher:
                 # fetch watchdog: same walk-away as dispatch (see _run);
                 # other finalizes in flight on the old pool still run to
                 # completion
-                self._inflight.release()
                 self.watchdog_trips += 1
                 self._server._m_watchdog.inc()
                 self._replace_fetch_pool()
@@ -756,8 +810,6 @@ class _MicroBatcher:
                 self._server.dispatch_breaker.record_failure()
             else:
                 self._server.dispatch_breaker.record_success()
-            finally:
-                self._inflight.release()
         done_t = time.perf_counter()
         with annotate("pio:loop.finish", batch=batch_no):
             # waterfall decomposition of the dispatch-end -> results-distributed
@@ -824,11 +876,8 @@ class _MicroBatcher:
         # have handlers awaiting their futures (collected/dispatched batches
         # are resolved by the _run/_finish cancellation paths)
         exc = ShuttingDownError()
-        while True:
-            try:
-                item = self._queue.get_nowait()
-            except asyncio.QueueEmpty:
-                break
+        while self._queue:
+            item = self._queue.popleft()
             if not item.fut.done():
                 item.fut.set_exception(exc)
         self._dispatch_pool.shutdown(wait=False, cancel_futures=True)
@@ -936,8 +985,14 @@ class QueryServer:
         )
         self._m_slot_wait = m.counter(
             "pio_batch_slot_wait_seconds_total",
-            "seconds collected micro-batches sat waiting for an in-flight "
-            "slot before dispatch (once a batch; inside the queue_wait phase)",
+            "seconds the micro-batcher waited for a slot with at least one "
+            "query pending (once a batch; the batch stays open meanwhile; "
+            "inside the queue_wait phase)",
+        )
+        self._m_joined_in_slot_wait = m.counter(
+            "pio_batch_joined_in_slot_wait_total",
+            "queries of dispatched micro-batches that arrived after the "
+            "batcher began waiting for that batch's slot",
         )
         # what power-of-two bucketing launched (ops/topk.batch_bucket keeps
         # the tallies, the engines know no server): mirrored at scrape
@@ -1432,8 +1487,10 @@ class QueryServer:
         decode and supplement each query, then *dispatch* every algorithm's
         device work via ``predict_batch_dispatch`` without blocking on
         results. Returns a finalize callable (run on a fetch thread) that
-        blocks on the transport, serves, and encodes — so the dispatcher can
-        start batch n+1 while batch n's results are in flight.
+        blocks on the device, calls its ``device_answered`` argument (the
+        batcher's slot going back), then serves and encodes — so the
+        dispatcher can start batch n+1 while batch n's results are in flight
+        and batch n+2 while they are being served.
 
         ``items`` is the batcher's queued-item list itself (payload +
         ingress trace id read in place — zero per-batch re-packing); the
@@ -1566,17 +1623,26 @@ class QueryServer:
         # the batcher derives (see _finish)
         timings: dict[str, float] = {"device_s": 0.0, "serve_s": 0.0}
 
-        def finalize() -> list[tuple[Any, str]]:
+        def finalize(device_answered=None) -> list[tuple[Any, str]]:
             sniffed: list[tuple[Any, Any]] = []
             inst = self._rollout_instruments
-            for lane, lane_name, idxs, sup, finalizers in dispatched:
+            fetched: list[list[list[Any]]] = []
+            for lane, _, _, sup, finalizers in dispatched:
                 t0 = time.perf_counter()
-                preds_per_algo = self._lane_predictions(lane, sup, finalizers)
+                fetched.append(self._lane_predictions(lane, sup, finalizers))
                 lane_predict_s = time.perf_counter() - t0
                 timings["device_s"] += lane_predict_s
                 inst.predict_seconds.observe(
                     lane_predict_s, version=lane.version
                 )
+            # every lane's packed results are on the host: the device has
+            # answered this batch, and the batcher's slot goes back before
+            # the per-query serve and encode below
+            if device_answered is not None:
+                device_answered()
+            for (lane, lane_name, idxs, _, _), preds_per_algo in zip(
+                dispatched, fetched
+            ):
                 with annotate("pio:serve"):
                     for row, i in enumerate(idxs):
                         token = set_trace_id(trace_ids[i])

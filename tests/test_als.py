@@ -188,6 +188,35 @@ class TestServingIndex:
         dense = vf @ uf[1]
         assert list(items) == list(np.argsort(-dense)[:5])
 
+    def test_a_warmed_buckets_first_batch_compiles_nothing(self):
+        # the staging copy in upload() is a program of its own for every
+        # bucket shape: warmup_buckets has to take the serving path's upload,
+        # or a bucket's first batch compiles (or loads) it at serve time
+        from jax import monitoring
+
+        from predictionio_tpu.ops.als import ServingIndex
+
+        rng = np.random.default_rng(0)
+        # shapes no other test of this process serves: nothing is compiled yet
+        idx = ServingIndex(
+            rng.normal(size=(7, 6)).astype(np.float32),
+            rng.normal(size=(41, 6)).astype(np.float32),
+        )
+        compiled = []
+
+        def listener(event, duration_secs, **kw):
+            if event.endswith("/backend_compile_duration"):
+                compiled.append(event)
+
+        monitoring.register_event_duration_secs_listener(listener)
+        idx.warmup_buckets(3, 13)  # buckets 1, 2, 4, 8, 16; k bucket 4
+        warmed = len(compiled)
+        assert warmed > 0
+        for n in (1, 2, 3, 5, 9, 13):
+            staged = np.zeros((1 << (n - 1).bit_length(),), np.int32)
+            np.asarray(idx.serve_batch_async(staged, 4))
+        assert len(compiled) == warmed
+
 
 class TestShardedALS:
     """ALX-style mesh-parallel ALS (ops/als_sharded.py) on the virtual

@@ -123,6 +123,14 @@ def metrics_of(server) -> dict[str, float]:
     return out
 
 
+async def until(condition, timeout=5.0):
+    """Poll ``condition`` on the running loop until it holds."""
+    end = asyncio.get_running_loop().time() + timeout
+    while not condition():
+        assert asyncio.get_running_loop().time() < end, "the condition never held"
+        await asyncio.sleep(0.002)
+
+
 async def submit_all(server, users):
     """Submit the users' queries in one go: they queue before the collect
     loop wakes, so they form ONE batch of ``len(users)``."""
@@ -234,6 +242,7 @@ def test_counter_families_scrape_as_zero_from_the_start_and_histograms_are_gone(
     text = server.metrics.render_prometheus()
     scraped = metrics_of(server)
     assert scraped["pio_batch_slot_wait_seconds_total"] == 0.0
+    assert scraped["pio_batch_joined_in_slot_wait_total"] == 0.0
     for generation in "012":
         assert f'pio_gc_pause_seconds_total{{generation="{generation}"}}' in scraped
         assert f'pio_gc_collections_total{{generation="{generation}"}}' in scraped
@@ -245,33 +254,42 @@ def test_counter_families_scrape_as_zero_from_the_start_and_histograms_are_gone(
         assert name not in text
 
 
-def test_slot_wait_grows_when_a_collected_batch_waits_for_the_only_slot(monkeypatch):
+def test_slot_wait_grows_while_both_slots_are_taken_and_arrivals_join_the_batch(monkeypatch):
     from predictionio_tpu.ops import topk
-    from predictionio_tpu.workflow.create_server import _MicroBatcher
 
     server = make_server()
-    server._batcher = _MicroBatcher(server, max_batch=8, window_s=0.0, max_inflight=1)
+    batcher = server._batcher
+    device = threading.Event()  # set: the device answers every batch
     real_fetch = topk.fetch_topk
 
-    def slow_fetch(handle):
-        threading.Event().wait(0.08)  # the first batch holds the slot this long
+    def gated_fetch(handle):
+        device.wait(10)
         return real_fetch(handle)
 
-    monkeypatch.setattr(topk, "fetch_topk", slow_fetch)
+    monkeypatch.setattr(topk, "fetch_topk", gated_fetch)
 
     async def body():
         first = asyncio.ensure_future(submit_all(server, [1, 2]))
-        await asyncio.sleep(0.02)  # dispatched; its fetch is in flight
-        second = await submit_all(server, [3])
-        await first
-        server._batcher.close()
-        await server._batcher.wait_closed()
-        return second
+        await until(lambda: batcher.batches_dispatched == 1)
+        second = asyncio.ensure_future(submit_all(server, [3]))
+        await until(lambda: batcher.batches_dispatched == 2)  # both slots taken
+        third = asyncio.ensure_future(submit_all(server, [4]))
+        await until(lambda: batcher.queue_depth == 1)  # pending, not collected
+        await asyncio.sleep(0.05)
+        fourth = asyncio.ensure_future(submit_all(server, [5]))  # joins the open batch
+        await until(lambda: batcher.queue_depth == 2)
+        device.set()
+        answers = await asyncio.gather(first, second, third, fourth)
+        batcher.close()
+        await batcher.wait_closed()
+        return answers
 
-    assert len(asyncio.run(body())[0]["itemScores"]) == 3
-    waited = metrics_of(server)["pio_batch_slot_wait_seconds_total"]
-    assert 0.03 < waited < 0.5, waited
-    assert server._batcher.batches_dispatched == 2
+    answers = asyncio.run(body())
+    assert all(len(a["itemScores"]) == 3 for group in answers for a in group)
+    scraped = metrics_of(server)
+    assert 0.04 < scraped["pio_batch_slot_wait_seconds_total"] < 5.0
+    assert scraped["pio_batch_joined_in_slot_wait_total"] == 1.0
+    assert (batcher.batches_dispatched, batcher.queries_dispatched) == (3, 5)
 
 
 def test_bucket_rows_count_queries_against_what_the_device_scored():
