@@ -15,6 +15,9 @@ covers host-only (pure Python/NumPy) algorithms, the analog of LAlgorithm.
 
 from __future__ import annotations
 
+import importlib
+import os
+import uuid
 from typing import Any, Generic
 
 import jax
@@ -56,12 +59,24 @@ class JaxAlgorithm(BaseAlgorithm[PD, M, Q, P], Generic[PD, M, Q, P]):
     """
 
     def make_persistent_model(self, ctx: WorkflowContext, model: M) -> Any:
+        """A :class:`PersistentModel` keeps its own storage: it is saved
+        under a fresh id and only its manifest goes into the model
+        repository's blob. Every other model is pulled to the host."""
+        if isinstance(model, PersistentModel):
+            model_id = uuid.uuid4().hex
+            if model.save(model_id, self.params, persistent_model_dir()):
+                cls = type(model)
+                return PersistentModelManifest(f"{cls.__module__}.{cls.__qualname__}", model_id)
         return model_to_host(model)
 
     def prepare_model(self, ctx: WorkflowContext, persisted: Any) -> M:
         """Default re-layout: leave arrays on host; algorithms that want
         device-resident serving override and device_put with their preferred
-        shardings."""
+        shardings. A manifest is resolved to the model its class loads."""
+        if isinstance(persisted, PersistentModelManifest):
+            module, _, name = persisted.class_path.rpartition(".")
+            cls = getattr(importlib.import_module(module), name)
+            return cls.load(persisted.model_id, self.params, persistent_model_dir())
         return persisted
 
 
@@ -89,10 +104,20 @@ class PersistentModel:
 
 class PersistentModelManifest:
     """Marker stored in the model repo instead of bytes
-    (ref workflow/PersistentModelManifest.scala)."""
+    (ref workflow/PersistentModelManifest.scala): the class that loads the
+    model and the id it was saved under."""
 
-    def __init__(self, class_path: str):
+    def __init__(self, class_path: str, model_id: str = ""):
         self.class_path = class_path
+        self.model_id = model_id
 
     def to_json_dict(self) -> dict[str, str]:
-        return {"class_path": self.class_path}
+        return {"class_path": self.class_path, "model_id": self.model_id}
+
+
+def persistent_model_dir() -> str:
+    """Where :class:`PersistentModel`s keep their files:
+    ``$PIO_FS_BASEDIR/models`` (``~/.pio_store/models`` by default), one
+    directory a saved model."""
+    base = os.environ.get("PIO_FS_BASEDIR", os.path.join(os.path.expanduser("~"), ".pio_store"))
+    return os.path.join(base, "models")

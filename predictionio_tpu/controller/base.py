@@ -143,6 +143,13 @@ class BaseAlgorithm(Generic[PD, M, Q, P]):
         compiles. Called by the query server at start and after /reload.
         Default: nothing to warm."""
 
+    def register_metrics(self, registry: Any) -> None:
+        """Declare this algorithm's own instruments in ``registry`` (an
+        ``obs.metrics.MetricsRegistry``): the query server calls it with its
+        registry for every algorithm it is about to serve, at start and
+        after /reload, so an engine's counters are the engine's and the
+        server knows none of them. Default: the algorithm has none."""
+
     # -- persistence hooks (ref makePersistentModel, BaseAlgorithm.scala:95)
     def make_persistent_model(self, ctx: WorkflowContext, model: Any) -> Any:
         """Return the object to persist for this model. Default: the model

@@ -9,21 +9,30 @@ Reference parity (behavioral):
     ``(creation_time_us, event_id)`` total order, bounded pages), so the
     session order the trainer sees is the ingest order, not scan luck.
 
-TPU design: the optional attention scorer is the serving consumer of
-``ops/attention.fused_attention`` (the pallas kernel benched in BENCH_r03):
-session items gather their input embeddings, one causal single-head
-attention pass over the short context window produces the session vector,
-and scoring+masking+selection is the shared fused
-``ops/topk.dot_top_k_async`` program over the resident output table — only
-the packed (k scores, k indices) result ever crosses the wire. When an ANN
-index is pinned to the lane the session vector handle feeds
-``ann.search_async`` zero-copy, same as the two-tower engine.
+TPU design: the optional attention scorer is a serving consumer of
+``ops/attention.fused_attention``: session items gather their input
+embeddings, one causal single-head attention pass over the short context
+window produces the session vector, and scoring+masking+selection is the
+shared fused ``ops/topk.dot_top_k_async`` program over the resident output
+table — only the packed (k scores, k indices) result ever crosses the
+wire. When an ANN index is pinned to the lane the session vector handle
+feeds ``ann.search_async`` zero-copy, same as the two-tower engine.
+
+The ``olmoe`` scorer puts a real backbone in the same place: the session's
+items are the tokens of OLMoE-1B-7B (``olmoe.py``: 16 heads of 128 with
+q/k norms and RoPE, 64 sparse experts with 8 a token), one causal prefill
+a query, the final-normed hidden state at the session's last position
+scored against ``lm_head`` through the same ``ops/topk.dot_top_k_async``.
+Its weights are drawn from a seed, not fitted: fitting the backbone is not
+this engine's work yet (ROADMAP R7).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
+import time
 from typing import Any, Iterator, Sequence
 
 import numpy as np
@@ -36,11 +45,14 @@ from predictionio_tpu.controller import (
     JaxAlgorithm,
     LocalAlgorithm,
     Params,
+    PersistentModel,
     SanityCheck,
 )
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.store.event_store import resolve_app
 from predictionio_tpu.e2.markov_chain import MarkovChainModel, train_markov_chain
+from predictionio_tpu.models.sequential.metrics import OlmoeInstruments
+from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops import topk
 from predictionio_tpu.workflow.context import WorkflowContext
 
@@ -493,7 +505,9 @@ class AttentionAlgorithmParams(Params):
     # session window the attention encoder attends over; short by design
     # (the pallas kernel's single-block path covers it on TPU). On the
     # chip a context of 1024 or more must be a multiple of 256
-    # (ops/attention.fused_attention refuses it otherwise)
+    # (ops/attention.fused_attention refuses it otherwise). This is the
+    # `attention` scorer's window only: `olmoe` takes a session's last
+    # `max_position_embeddings` items, in length buckets
     context: int = 8
     top_n: int = 10
 
@@ -558,7 +572,9 @@ class AttentionAlgorithm(JaxAlgorithm):
         attention pass; the last position's output is the session
         vector. Left-pad slots repeat the window's oldest item — a
         documented smoothing bias that keeps the program shape static
-        (fused_attention has no key mask by design)."""
+        (fused_attention has no key mask by design). True of this
+        scorer only: `olmoe` pads on the RIGHT, where causal attention
+        keeps the padding out of every real position, and is exact."""
         import jax.numpy as jnp
 
         from predictionio_tpu.ops.attention import fused_attention
@@ -691,6 +707,322 @@ class AttentionAlgorithm(JaxAlgorithm):
 
 
 # ---------------------------------------------------------------------------
+# OLMoE algorithm (one prefill through sparse experts -> fused top-k)
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoeAlgorithmParams(Params):
+    """The published ``config.json`` of allenai/OLMoE-1B-7B-0125-Instruct,
+    key for key (a variant file carries them verbatim), and the seed the
+    weights are drawn from. The keys the program has one answer for
+    (``attention_bias`` false, ``clip_qkv`` null, ``hidden_act`` silu,
+    ``norm_topk_prob`` false, ``rope_scaling`` null, untied embeddings, as
+    many key-value heads as heads) are refused at any other value rather
+    than ignored."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 1024
+    num_hidden_layers: int = 16
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    num_experts: int = 64
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = False
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    rope_scaling: dict | None = None
+    attention_bias: bool = False
+    clip_qkv: float | None = None
+    tie_word_embeddings: bool = False
+    vocab_size: int = 50304
+    max_position_embeddings: int = 4096
+    model_type: str = "olmoe"
+    seed: int = 3
+
+    def config(self):
+        from predictionio_tpu.models.sequential.olmoe import OlmoeConfig
+
+        one_answer = {
+            "model_type": "olmoe", "hidden_act": "silu", "norm_topk_prob": False,
+            "rope_scaling": None, "attention_bias": False, "clip_qkv": None,
+            "tie_word_embeddings": False, "num_key_value_heads": self.num_attention_heads,
+        }
+        for key, value in one_answer.items():
+            if getattr(self, key) != value:
+                raise ValueError(
+                    f"olmoe: {key}={getattr(self, key)!r} is not implemented (only {value!r})"
+                )
+        return OlmoeConfig(
+            hidden_size=self.hidden_size,
+            intermediate_size=self.intermediate_size,
+            num_hidden_layers=self.num_hidden_layers,
+            num_attention_heads=self.num_attention_heads,
+            num_experts=self.num_experts,
+            num_experts_per_tok=self.num_experts_per_tok,
+            vocab_size=self.vocab_size,
+            max_position_embeddings=self.max_position_embeddings,
+            rms_norm_eps=self.rms_norm_eps,
+            rope_theta=self.rope_theta,
+        )
+
+
+class OlmoeModel(PersistentModel, SanityCheck):
+    """The backbone's weight tree on the device, the item vocabulary (item
+    ``i`` is token ``i``) and every user's session tail: the last
+    ``max_position_embeddings`` items, all users' in ONE int32 array with
+    offsets (``tails[offsets[u]:offsets[u + 1]]``), not Python lists.
+
+    It keeps its own storage (``save`` / ``load``): one raw file an array,
+    read back array by array onto the device, so 7 GB of weights never
+    pass through ``workflow/model_io``'s one pickled blob."""
+
+    def __init__(self, config, item_vocab, users, tails, offsets, weights):
+        self.config = config
+        self.item_vocab = list(item_vocab)
+        self.users = list(users)
+        self.tails = np.asarray(tails, np.int32)
+        self.offsets = np.asarray(offsets, np.int64)
+        self.weights = weights  # {name: device array}, layers stacked
+        self._item_index: dict[str, int] | None = None
+        self._user_index: dict[str, int] | None = None
+        self._head = None
+
+    def sanity_check(self) -> None:
+        if not self.item_vocab:
+            raise ValueError("empty item vocab")
+        if len(self.item_vocab) > self.config.vocab_size:
+            raise ValueError(
+                f"{len(self.item_vocab)} items do not fit a vocabulary of "
+                f"{self.config.vocab_size}"
+            )
+
+    def item_index(self) -> dict[str, int]:
+        if self._item_index is None:
+            self._item_index = {v: i for i, v in enumerate(self.item_vocab)}
+        return self._item_index
+
+    def user_index(self) -> dict[str, int]:
+        if self._user_index is None:
+            self._user_index = {u: i for i, u in enumerate(self.users)}
+        return self._user_index
+
+    def head(self):
+        """``lm_head`` as ``ops/topk`` scores against it: float32 on the
+        device (the bf16 values, exactly), one conversion a model."""
+        if self._head is None:
+            import jax.numpy as jnp
+
+            self._head = self.weights["lm_head"].astype(jnp.float32)
+        return self._head
+
+    def session_tokens(self, query: Query) -> np.ndarray:
+        """The query's session as token ids, oldest first, at most
+        ``max_position_embeddings`` of them: explicit ``recentItems`` win
+        (unknown items dropped), a bare ``user`` gets their stored tail."""
+        top = self.config.max_position_embeddings
+        if query.recent_items:
+            index = self.item_index()
+            known = [index[i] for i in query.recent_items if i in index]
+            return np.asarray(known[-top:], np.int32)
+        u = self.user_index().get(query.user) if query.user is not None else None
+        if u is None:
+            return np.empty(0, np.int32)
+        return self.tails[self.offsets[u] : self.offsets[u + 1]]
+
+    # -------------------------------------------------------- persistence
+    def save(self, instance_id: str, params: Any, base_dir: str) -> bool:
+        from predictionio_tpu.models.sequential import olmoe
+
+        header = {
+            "config": dataclasses.asdict(self.config),
+            "item_vocab": self.item_vocab,
+            "users": self.users,
+        }
+        arrays = {**self.weights, "tails": self.tails, "offsets": self.offsets}
+        olmoe.save_arrays(os.path.join(base_dir, instance_id), header, arrays)
+        return True
+
+    @classmethod
+    def load(cls, instance_id: str, params: Any, base_dir: str) -> "OlmoeModel":
+        import jax
+
+        from predictionio_tpu.models.sequential import olmoe
+
+        directory = os.path.join(base_dir, instance_id)
+        header = olmoe.load_header(directory)
+        arrays = {}
+        for name, spec in header["arrays"].items():
+            host = olmoe.load_array(directory, name, spec)
+            # a weight goes to the device and leaves the host at once
+            arrays[name] = host if name in ("tails", "offsets") else jax.device_put(host)
+        return cls(
+            olmoe.OlmoeConfig(**header["config"]),
+            header["item_vocab"],
+            header["users"],
+            arrays.pop("tails"),
+            arrays.pop("offsets"),
+            arrays,
+        )
+
+
+def session_tails(sequences: Sequence[np.ndarray], keep: int):
+    """``(tails, offsets)``: every sequence's last ``keep`` items, laid end
+    to end in one int32 array."""
+    lengths = np.fromiter((min(len(s), keep) for s in sequences), np.int64, len(sequences))
+    offsets = np.concatenate([[0], np.cumsum(lengths)])
+    tails = np.empty(int(offsets[-1]), np.int32)
+    for seq, start, n in zip(sequences, offsets, lengths):
+        tails[start : start + n] = seq[len(seq) - n :]
+    return tails, offsets
+
+
+class OlmoeAlgorithm(JaxAlgorithm):
+    """Next-item scoring by one prefill through OLMoE-1B-7B.
+
+    Train: builds the item vocabulary (item ``i`` is token ``i``) and every
+    user's session tail from the ordered events, and DRAWS the weights from
+    ``seed`` in bfloat16. Fitting the backbone is not this PR (ROADMAP R7
+    trains Moonlight): the scores are those of a random network, and what
+    is exact is that they are THIS network's, which the reference holds.
+
+    Serve: ``predict_batch_dispatch`` does not treat a batch as B equal
+    rows. It groups the batch it is handed by length bucket (64, 128, ...,
+    ``max_position_embeddings``), right-pads a group's sessions into one
+    ``[rows, bucket]`` block of ``olmoe.TOKEN_BUDGET`` tokens (a larger
+    group takes several programs, a longer session one row), launches
+    ``olmoe.session_vectors`` and ``topk.dot_top_k_async`` (session items
+    masked) for every program, and returns ONE finalize that answers in the
+    queries' order. The set of program shapes is closed
+    (``OlmoeConfig.program_shapes``) and ``warmup_serving`` compiles all of
+    it. What it launched is counted in ``instruments``, the algorithm's own
+    until a query server hands over its registry."""
+
+    params_class = OlmoeAlgorithmParams
+    params: OlmoeAlgorithmParams
+
+    def __init__(self, params: OlmoeAlgorithmParams | None = None):
+        super().__init__(params)
+        self.instruments = OlmoeInstruments()
+
+    def register_metrics(self, registry) -> None:
+        self.instruments = OlmoeInstruments(registry)
+
+    def train(self, ctx: WorkflowContext, td: TrainingData) -> OlmoeModel:
+        from predictionio_tpu.models.sequential import olmoe
+
+        config = self.params.config()
+        tails, offsets = session_tails(td.sequences, config.max_position_embeddings)
+        model = OlmoeModel(
+            config, td.item_vocab, td.users, tails, offsets,
+            olmoe.init_weights(config, self.params.seed),
+        )
+        model.sanity_check()
+        return model
+
+    # ------------------------------------------------------------- serving
+    @staticmethod
+    def _plan(model: OlmoeModel, queries: Sequence[Query]):
+        """Look-up and bucketing: ``(sessions, programs)``, a program being
+        ``(bucket, rows, [query index, ...])``. Queries with no session are
+        in no program."""
+        from predictionio_tpu.models.sequential import olmoe
+
+        buckets = model.config.buckets()
+        sessions = [model.session_tokens(q) for q in queries]
+        groups: dict[int, list[int]] = {}
+        for i, session in enumerate(sessions):
+            if len(session):
+                groups.setdefault(olmoe.bucket_of(len(session), buckets), []).append(i)
+        programs = []
+        for bucket in sorted(groups):
+            members, rows = groups[bucket], olmoe.program_rows(bucket)
+            for start in range(0, len(members), rows):
+                programs.append((bucket, rows, members[start : start + rows]))
+        return sessions, programs
+
+    @staticmethod
+    def _stage(model: OlmoeModel, sessions, program):
+        """One program's host arrays: tokens right-padded with token 0 (any
+        token would do: no real position sees it), each row's last real
+        position (-1 for a padding row), and the candidate mask without the
+        session's items, the vocabulary's unused rows and the padding rows."""
+        bucket, rows, members = program
+        tokens = np.zeros((rows, bucket), np.int32)
+        last = np.full(rows, -1, np.int32)
+        mask = np.zeros((rows, model.config.vocab_size), bool)
+        mask[: len(members), : len(model.item_vocab)] = True
+        for row, i in enumerate(members):
+            session = sessions[i]
+            tokens[row, : len(session)] = session
+            last[row] = len(session) - 1
+            mask[row, session] = False
+        return tokens, last, mask
+
+    def predict_batch_dispatch(self, model: OlmoeModel, queries: Sequence[Query]):
+        from predictionio_tpu.models.sequential import olmoe
+
+        config = model.config
+        t0 = time.perf_counter()
+        sessions, programs = self._plan(model, queries)
+        with annotate("pio:seq.stage", batch=len(queries), programs=len(programs)):
+            staged = [self._stage(model, sessions, program) for program in programs]
+        self.instruments.on_stage(time.perf_counter() - t0)
+        n = len(model.item_vocab)
+        kk = min(topk.next_pow2(max(1, max(q.num for q in queries))), n)
+        launched = []
+        for (bucket, rows, members), (tokens, last, mask) in zip(programs, staged):
+            real = int(last[: len(members)].sum()) + len(members)
+            with annotate("pio:seq.launch", bucket=bucket, rows=rows, tokens=real):
+                vectors, busiest = olmoe.session_vectors(
+                    model.weights, topk.upload(tokens, np.int32), topk.upload(last, np.int32),
+                    config=config,
+                )
+                handle = topk.dot_top_k_async(model.head(), vectors, mask, kk)
+            self.instruments.on_launch(bucket, rows, real)
+            launched.append((handle, busiest, real))
+
+        def finalize() -> list[PredictedResult]:
+            out: list[PredictedResult] = [PredictedResult(())] * len(queries)
+            for (bucket, rows, members), (handle, busiest, real) in zip(programs, launched):
+                scores, idx = topk.fetch_topk(handle)
+                # one integer a program rides back with its answer
+                even = real * config.num_experts_per_tok / config.num_experts
+                self.instruments.on_expert_load(int(busiest), config.num_hidden_layers * even)
+                for row, i in enumerate(members):
+                    picks = [
+                        ItemScore(model.item_vocab[int(item)], float(score))
+                        for score, item in zip(scores[row], idx[row])
+                        if np.isfinite(score)
+                    ]
+                    out[i] = PredictedResult(tuple(picks[: queries[i].num]))
+            return out
+
+        return finalize
+
+    def predict_batch(
+        self, model: OlmoeModel, queries: Sequence[Query]
+    ) -> list[PredictedResult]:
+        return self.predict_batch_dispatch(model, queries)()
+
+    def predict(self, model: OlmoeModel, query: Query) -> PredictedResult:
+        return self.predict_batch(model, [query])[0]
+
+    def warmup_serving(self, model: OlmoeModel, max_batch: int) -> None:
+        """Compile every program shape there is, by the path serving takes:
+        for each ``(rows, bucket)`` a batch of ``rows`` sessions of
+        ``bucket`` items (the staging copies, ``session_vectors`` and the
+        top-k over ``rows``). ``max_batch`` bounds nothing here: a batch of
+        any size is cut into these shapes."""
+        n = len(model.item_vocab)
+        num = min(10, n)
+        for rows, bucket in model.config.program_shapes():
+            items = tuple(model.item_vocab[i % n] for i in range(bucket))
+            self.predict_batch(model, [Query(recent_items=items, num=num)] * rows)
+
+
+# ---------------------------------------------------------------------------
 # Serving / factory
 # ---------------------------------------------------------------------------
 
@@ -704,7 +1036,11 @@ def engine_factory() -> Engine:
     return Engine(
         DataSource,
         Preparator,
-        {"markov": MarkovAlgorithm, "attention": AttentionAlgorithm},
+        {
+            "markov": MarkovAlgorithm,
+            "attention": AttentionAlgorithm,
+            "olmoe": OlmoeAlgorithm,
+        },
         Serving,
         query_class=Query,
     )

@@ -1,6 +1,8 @@
-"""The ``pio_seq_*`` metric family (docs/observability.md).
+"""The ``pio_seq_*`` metric families (docs/observability.md): the stream
+trainer's (``SeqInstruments``) and the ``olmoe`` scorer's
+(``OlmoeInstruments``, with ``pio_moe_*``).
 
-Registered eagerly (AnnInstruments discipline): the family exists at zero
+``SeqInstruments`` is registered eagerly (AnnInstruments discipline): the family exists at zero
 from process start so scrapers and the docs metrics-contract test see it
 before the first session folds in. The stream pipeline binds it to the
 :class:`~predictionio_tpu.stream.trainers.SequentialStreamTrainer` via its
@@ -53,3 +55,65 @@ class SeqInstruments:
         self.pairs.set(float(pairs))
         self.sessions.set(float(sessions))
         self.snapshots.inc()
+
+
+class OlmoeInstruments:
+    """What the ``olmoe`` scorer launched, counted where it happens
+    (``engine.OlmoeAlgorithm``). An algorithm starts with a registry of its
+    own; a query server that serves it hands over its registry through
+    ``register_metrics``, so two deployments in one process count apart."""
+
+    def __init__(self, registry: MetricsRegistry | None = None):
+        self.registry = registry or MetricsRegistry()
+        r = self.registry
+        self.tokens = r.counter(
+            "pio_seq_tokens_total",
+            "tokens of launched session programs: kind=real are session "
+            "items, kind=padded is what the device computed (buckets)",
+            labelnames=("kind",),
+        )
+        self.programs = r.counter(
+            "pio_seq_programs_total",
+            "session programs launched, by length bucket",
+            labelnames=("bucket",),
+        )
+        self.rows = r.counter(
+            "pio_seq_rows_total",
+            "rows (sessions and padding rows) of launched session programs, "
+            "by length bucket",
+            labelnames=("bucket",),
+        )
+        self.stage_seconds = r.counter(
+            "pio_seq_stage_seconds_total",
+            "seconds the dispatch thread spent looking sessions up, "
+            "bucketing and padding them (once a batch)",
+        )
+        self.batches = r.counter(
+            "pio_seq_batches_total", "batches the session scorer staged"
+        )
+        self.expert_tokens_max = r.counter(
+            "pio_moe_expert_tokens_max_total",
+            "copies of REAL tokens (no padding) the busiest expert got, "
+            "summed over layers and programs",
+        )
+        self.expert_tokens_mean = r.counter(
+            "pio_moe_expert_tokens_mean_total",
+            "copies of real tokens an even split would give each expert, "
+            "summed over layers and programs",
+        )
+
+    def on_stage(self, seconds: float) -> None:
+        self.stage_seconds.inc(seconds)
+        self.batches.inc()
+
+    def on_launch(self, bucket: int, rows: int, real_tokens: int) -> None:
+        self.tokens.inc(float(real_tokens), kind="real")
+        self.tokens.inc(float(rows * bucket), kind="padded")
+        self.programs.inc(bucket=str(bucket))
+        self.rows.inc(float(rows), bucket=str(bucket))
+
+    def on_expert_load(self, busiest: int, even: float) -> None:
+        """One program's layers, summed: the copies of real tokens its
+        busiest expert got in each, and what an even split gives each."""
+        self.expert_tokens_max.inc(float(busiest))
+        self.expert_tokens_mean.inc(even)

@@ -1,0 +1,128 @@
+"""Sparse experts: router, grouping of tokens by expert, grouped products.
+
+A mixture-of-experts feed-forward as OLMoE has it: the router scores every
+token against every expert in float32, a token goes to its ``k`` best and
+their softmax weights are NOT renormalised; each expert is a gated MLP
+``down(silu(gate(x)) * up(x))``. One chip holds every expert, so the work
+is three GROUPED products: the tokens' copies are sorted by expert, and
+rows ``offsets[e]:offsets[e+1]`` of the sorted block meet expert ``e``'s
+matrix. No token is dropped and there is no capacity factor: a group is as
+long as the router made it, whatever the imbalance.
+
+Scopes (``jax.named_scope``; the benchmark's per-layer metrics read them):
+``router`` (the caller wraps :func:`route` in it), and inside ``experts``:
+``sort`` (argsort by expert, gather of the copies, group sizes), ``gmm``
+(the three grouped products and the gate) and ``combine`` (un-sort and the
+weighted sum over a token's ``k`` experts).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+__all__ = ["route", "expert_load", "grouped_matmul", "expert_ffn"]
+
+# (rows, contraction, columns) of the grouped product's tiles, from the chip
+# (PERF.md, PR 26): 256 rows by 1,024 columns with the contraction WHOLE (so
+# that an output tile is written once) was the best of twelve at [16 k and
+# 131 k, 2048] x [64, 2048, 1024] and at [.., 1024] x [64, 1024, 2048]
+TILING = (256, 2048, 1024)
+
+
+def route(x, router_w, k: int):
+    """``(weights [T, k] float32, experts [T, k] int32)`` of tokens ``x``
+    [T, hidden]: softmax over ALL experts in float32 (the product at
+    ``highest`` too: a choice between the k-th and the (k+1)-th expert must
+    not hang on a bf16 rounding), then the top k, not renormalised."""
+    logits = jnp.dot(
+        x.astype(jnp.float32),
+        router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    return lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+def expert_load(experts, n_experts: int, counted=None):
+    """How many token copies each expert got, [n_experts] int32, over the
+    tokens ``counted`` [T] marks (all of them by default). A comparison and
+    a sum, no scatter."""
+    hits = experts[..., None] == jnp.arange(n_experts, dtype=experts.dtype)
+    if counted is not None:
+        hits = hits & counted[:, None, None]
+    return jnp.sum(hits, axis=(0, 1), dtype=jnp.int32)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
+    """``lhs[offsets[g]:offsets[g+1]] @ rhs[g]`` for every group ``g``:
+    ``lhs`` [M, K] sorted by group, ``rhs`` [G, K, N], ``group_sizes`` [G]
+    int32 summing to M (a multiple of 8); float32 accumulation, rounded to
+    ``out_dtype``. An empty group costs nothing.
+
+    On the chip the megablox Pallas kernel that jax ships (of the two
+    candidates timed there it won: ``lax.ragged_dot`` took 1.3 times as
+    long, PERF.md PR 26). Off the chip the kernel runs only interpreted,
+    its grid through host callbacks: a served program of a test's size takes
+    a fifth of a second, and the CPU rehearsal of the benchmark's cell
+    answers 5 queries a second where it has to answer 32 in its 6 s
+    (sandbox, PR 26). So there XLA's own ``ragged_dot`` stands in, as
+    ``ops/attention.fused_attention`` takes its jnp path off the chip; the
+    tests run the kernel interpreted against it, alone and through a whole
+    program."""
+    if jax.default_backend() != "tpu":
+        out = lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
+        return out.astype(out_dtype)
+    return grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype)
+
+
+def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
+    """The kernel itself (``interpret`` is how a test runs it off the chip)."""
+    rows, contraction, columns = TILING
+    # a contraction tile past the operand's own is masked on every step: it
+    # took 1.5 times as long at [.., 1024] x [64, 1024, 2048]
+    tiling = (math.gcd(rows, lhs.shape[0]), min(contraction, lhs.shape[1]), columns)
+    return gmm(
+        lhs, rhs, group_sizes, preferred_element_type=out_dtype, tiling=tiling, interpret=interpret
+    )
+
+
+def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=0):
+    """``sum_j weights[t, j] * ffn_{experts[t, j]}(x[t])`` for tokens ``x``
+    [T, hidden]: ``gate`` and ``up`` [G, hidden, width], ``down``
+    [G, width, hidden], expert ``e``'s matrices at ``first_group + e``.
+
+    ``G`` may be more than the router's ``n_experts``: every layer's experts
+    stacked, ``first_group`` (traced or not) the layer's first. The kernel
+    then reads the layer's matrices where they lie; a slice taken in front
+    of it is a copy of all of them (0.8 GB a layer at OLMoE's widths, 2 ms).
+
+    Returns ``y`` [T, hidden] float32."""
+    tokens, k = experts.shape
+    groups = gate.shape[0]
+    n_experts = groups if n_experts is None else n_experts
+    with jax.named_scope("sort"):
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)  # copies, sorted by expert
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros(groups, jnp.int32),
+            expert_load(experts, n_experts),
+            (jnp.asarray(first_group, jnp.int32),),
+        )
+        xs = x.astype(gate.dtype)[order // k]  # [T*k, hidden]
+    with jax.named_scope("gmm"):
+        # gate and up leave the kernel in the operands' type: `down` takes
+        # them in it anyway, and float32 would double what the gate moves
+        g = grouped_matmul(xs, gate, sizes, gate.dtype).astype(jnp.float32)
+        u = grouped_matmul(xs, up, sizes, gate.dtype).astype(jnp.float32)
+        out = grouped_matmul((jax.nn.silu(g) * u).astype(down.dtype), down, sizes, jnp.float32)
+    with jax.named_scope("combine"):
+        # where each copy went: the inverse of the sort, by one scatter of
+        # T*k integers, then a gather of rows (no scatter-add of rows)
+        place = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+        out = out[place].reshape(tokens, k, -1)
+        y = jnp.sum(out * weights[..., None], axis=1)
+    return y

@@ -2995,6 +2995,10 @@ class QueryServer:
         # the kernel variant its dispatch actually runs (exclusion /
         # composed-tower), not the generic one
         ann_lifecycle.bind_instruments(models, self.ann_instruments)
+        # an algorithm that keeps instruments of its own declares them in
+        # this server's registry before it takes traffic
+        for algo in algorithms:
+            algo.register_metrics(self.metrics)
         # a warmup failure is not swallowed: a program the device's
         # compiler refuses would otherwise be paid for, or thrown, on the
         # first request. At startup it fails the start; /reload and the

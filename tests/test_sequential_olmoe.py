@@ -1,0 +1,539 @@
+"""The sequential engine's ``olmoe`` scorer against its plain reference, at a
+tiny size on the CPU: 2 layers, hidden 64, 4 heads of 16, 8 experts of width
+32 with 2 a token, vocabulary 128, sessions of 3 to 70 items, so that two
+length buckets and a split of one bucket into two programs occur.
+
+The weights are the algorithm's own (drawn in bfloat16 from its seed). Where
+a test compares values it upcasts the SAME weights to float32 for both sides:
+the program's operands follow its weights' type, so on the CPU both sides
+compute in float32 and differ only by the order of float32 sums.
+"""
+
+import asyncio
+import dataclasses
+import json
+import socket
+import threading
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.controller import PersistentModelManifest
+from predictionio_tpu.models.sequential import (
+    OlmoeAlgorithm,
+    OlmoeAlgorithmParams,
+    OlmoeModel,
+    Query,
+    TrainingData,
+    engine_factory,
+    olmoe,
+    olmoe_reference as reference,
+)
+from predictionio_tpu.ops import moe
+
+TINY = dict(
+    hidden_size=64, intermediate_size=32, num_hidden_layers=2, num_attention_heads=4,
+    num_key_value_heads=4, num_experts=8, num_experts_per_tok=2, vocab_size=128,
+    max_position_embeddings=128,
+)
+N_ITEMS = 120  # 8 rows of the vocabulary are no item
+# float32 against float32 on the CPU: both sides multiply in float32 and
+# differ by the order of their sums (blocked attention, grouped products, a
+# scan over experts), a few units of 2^-23 a sum, through two layers. Logits
+# are of unit order; the worst seen over the seeds below is 2e-6. 1e-4 is
+# fifty times that and a hundred times under what ONE bf16 product does
+# (2^-9 = 2e-3), so a lower precision anywhere fails.
+ATOL = 1e-4
+MEMORY_STORAGE = {
+    "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
+    "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+    "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+    "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM",
+}
+
+
+@pytest.fixture(autouse=True)
+def small_programs(monkeypatch):
+    """The token budget is a constant the chip set (2,048): here a program
+    holds 256 tokens, so bucket 64 comes 4 rows high and bucket 128 comes 2,
+    and a dozen sessions split."""
+    monkeypatch.setattr(olmoe, "TOKEN_BUDGET", 256)
+
+
+def training_data(seed=0, n_users=14) -> TrainingData:
+    rng = np.random.default_rng(seed)
+    # a few distinct lengths (the reference compiles once for each), the
+    # ends of both buckets among them
+    lengths = rng.choice([3, 17, 40, 64, 65, 70], n_users)
+    lengths[:3] = (3, 64, 70)
+    return TrainingData(
+        [f"u{i}" for i in range(n_users)],
+        [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths],
+        [f"i{i}" for i in range(N_ITEMS)],
+    )
+
+
+@pytest.fixture(scope="module")
+def trained():
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(**TINY, seed=5))
+    model = algorithm.train(None, training_data())
+    # the same bf16 draws, upcast: both sides then compute in float32
+    model.weights = jax.tree.map(lambda a: a.astype(jnp.float32), model.weights)
+    return algorithm, model
+
+
+def reference_weights(model: OlmoeModel) -> dict:
+    w = model.weights
+    layers = [olmoe.layer_of(w, i) for i in range(model.config.num_hidden_layers)]
+    return {**{k: w[k] for k in olmoe.TOP_ARRAYS}, "layers": layers}
+
+
+_reference_logits: dict = {}  # the `trained` model's, by session: two tests ask for the same
+_reference_jit: dict = {}  # one jitted reference a model: one compile a session length
+
+
+def reference_answer(model: OlmoeModel, session: np.ndarray, num: int):
+    """top-``num`` of the reference's logits at the session's last position,
+    its items and the vocabulary's unused rows left out."""
+    config = dataclasses.asdict(model.config)
+    key = session.tobytes()
+    if id(model) not in _reference_jit:
+        weights = reference_weights(model)
+        _reference_jit[id(model)] = jax.jit(
+            lambda tokens: reference.next_item_logits(weights, config, tokens)
+        )
+    if key not in _reference_logits:
+        _reference_logits[key] = np.asarray(_reference_jit[id(model)](jnp.asarray(session)))
+    logits = _reference_logits[key]
+    allowed = np.ones(len(logits), bool)
+    allowed[N_ITEMS:] = False
+    allowed[session] = False
+    order = np.argsort(-np.where(allowed, logits, -np.inf), kind="stable")[:num]
+    return logits, order
+
+
+# ---------------------------------------------------------------- ops/moe
+
+
+@pytest.mark.parametrize("tokens,k,seed", [(64, 2, 0), (192, 2, 1), (128, 3, 2), (64, 8, 3)])
+def test_grouped_experts_equal_the_dense_form(tokens, k, seed):
+    rng = np.random.default_rng(seed)
+    hidden, width, n_experts = 64, 32, 8
+    x = jnp.asarray(rng.normal(size=(tokens, hidden)), jnp.float32)
+    layer = {
+        "router": jnp.asarray(rng.normal(size=(hidden, n_experts)) / 8, jnp.float32),
+        "gate": jnp.asarray(rng.normal(size=(n_experts, hidden, width)) / 8, jnp.float32),
+        "up": jnp.asarray(rng.normal(size=(n_experts, hidden, width)) / 8, jnp.float32),
+        "down": jnp.asarray(rng.normal(size=(n_experts, width, hidden)) / 6, jnp.float32),
+    }
+    weights, experts = moe.route(x, layer["router"], k)
+    got = moe.expert_ffn(x, weights, experts, layer["gate"], layer["up"], layer["down"])
+    want = reference.moe(x, layer, {"num_experts_per_tok": k})
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # every token got its k experts, whatever the imbalance: nothing dropped
+    group_sizes = moe.expert_load(experts, n_experts)
+    assert int(group_sizes.sum()) == tokens * k
+    assert np.array_equal(
+        np.asarray(group_sizes), np.bincount(np.asarray(experts).ravel(), minlength=n_experts)
+    )
+    # counted over some of the tokens only (a program's real positions)
+    counted = np.arange(tokens) % 3 == 0
+    assert np.array_equal(
+        np.asarray(moe.expert_load(experts, n_experts, jnp.asarray(counted))),
+        np.bincount(np.asarray(experts)[counted].ravel(), minlength=n_experts),
+    )
+    # the weights are the softmax's own, not renormalised
+    probs = np.asarray(reference.router_probs(x, layer))
+    np.testing.assert_allclose(
+        np.asarray(weights), -np.sort(-probs, axis=1)[:, :k], atol=1e-6
+    )
+    assert k == n_experts or float(np.asarray(weights).sum(axis=1).max()) < 1.0
+
+
+@pytest.mark.parametrize("first_group", [0, 8])
+def test_the_chips_kernel_interpreted_equals_xlas_ragged_dot(first_group):
+    # the megablox kernel as the chip runs it (tiles of 256 rows cut to the
+    # block's height), over two layers' stacked groups of which one is live
+    rng = np.random.default_rng(6)
+    lhs = jnp.asarray(rng.normal(size=(384, 64)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(16, 64, 32)), jnp.float32)
+    sizes = np.zeros(16, np.int32)
+    sizes[first_group : first_group + 8] = rng.multinomial(384, [1 / 8] * 8)
+    sizes = jnp.asarray(sizes)
+    got = moe.grouped_matmul_kernel(lhs, rhs, sizes, jnp.float32, interpret=True)
+    want = moe.grouped_matmul(lhs, rhs, sizes, jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_one_expert_taking_every_token_drops_none():
+    # the router sends all tokens to experts 0 and 1: groups of 64, 64, 0, ...
+    hidden, width, n_experts, tokens = 64, 32, 8, 64
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(np.abs(rng.normal(size=(tokens, hidden))), jnp.float32)
+    router = np.zeros((hidden, n_experts), np.float32)
+    router[:, 0], router[:, 1] = 1.0, 0.5
+    layer = {
+        "router": jnp.asarray(router),
+        "gate": jnp.asarray(rng.normal(size=(n_experts, hidden, width)) / 8, jnp.float32),
+        "up": jnp.asarray(rng.normal(size=(n_experts, hidden, width)) / 8, jnp.float32),
+        "down": jnp.asarray(rng.normal(size=(n_experts, width, hidden)) / 6, jnp.float32),
+    }
+    weights, experts = moe.route(x, layer["router"], 2)
+    got = moe.expert_ffn(x, weights, experts, layer["gate"], layer["up"], layer["down"])
+    assert np.asarray(moe.expert_load(experts, n_experts)).tolist() == [tokens, tokens, 0, 0, 0, 0, 0, 0]
+    want = reference.moe(x, layer, {"num_experts_per_tok": 2})
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+# ------------------------------------------------------- the whole model
+
+
+@pytest.mark.parametrize("length,kernel", [(64, False), (128, False), (64, True)])
+def test_full_logits_equal_the_references(trained, length, kernel, monkeypatch):
+    _, model = trained
+    if kernel:
+        # the grouped product the chip serves with, interpreted, through a
+        # whole program: every layer's experts stacked, the layer's first group
+        traced = []
+
+        def interpreted(*args):
+            traced.append(args[0].shape)
+            return moe.grouped_matmul_kernel(*args, interpret=True)
+
+        monkeypatch.setattr(moe, "grouped_matmul", interpreted)
+        olmoe.all_logits.clear_cache()
+    rng = np.random.default_rng(length)
+    tokens = rng.integers(0, N_ITEMS, (3, length)).astype(np.int32)
+    got = np.asarray(olmoe.all_logits(model.weights, jnp.asarray(tokens), config=model.config))
+    config = dataclasses.asdict(model.config)
+    for row in range(3):
+        want = np.asarray(reference.forward(reference_weights(model), config, tokens[row]))
+        assert 0.5 < want.std() < 2.0  # logits of unit order, as the scaling promises
+        np.testing.assert_allclose(got[row], want, atol=ATOL)
+    if kernel:
+        assert len(traced) == 3  # the scan's one layer: gate, up, down
+        olmoe.all_logits.clear_cache()
+
+
+def test_the_busiest_experts_count_leaves_the_padding_out(trained):
+    _, model = trained
+    session = np.random.default_rng(13).integers(0, N_ITEMS, 37).astype(np.int32)
+    counts = []
+    for rows, bucket, pad in ((1, 64, 0), (2, 64, 5), (2, 128, 0)):
+        tokens = np.full((rows, bucket), pad, np.int32)
+        tokens[0, :37] = session
+        last = np.full(rows, -1, np.int32)
+        last[0] = 36
+        _, busiest = olmoe.session_vectors(
+            model.weights, jnp.asarray(tokens), jnp.asarray(last), config=model.config
+        )
+        counts.append(int(busiest))
+    # 37 real tokens with 2 experts each over 2 layers, whatever is padded around them
+    assert counts[0] == counts[1] == counts[2]
+    assert 2 * 37 * 2 / 8 <= counts[0] <= 2 * 37
+
+
+def test_two_deployments_in_one_process_count_apart(trained):
+    from predictionio_tpu.obs.metrics import MetricsRegistry
+
+    _, model = trained
+    one, other = (OlmoeAlgorithm(OlmoeAlgorithmParams(**TINY, seed=1)) for _ in range(2))
+    served = MetricsRegistry()
+    one.register_metrics(served)  # as a query server does before traffic
+    one.predict(model, Query(user="u0", num=3))
+    assert served.get("pio_seq_tokens_total").value(kind="real") == len(
+        model.session_tokens(Query(user="u0"))
+    )
+    assert served.get("pio_seq_batches_total").value() == 1
+    assert other.instruments.tokens.value(kind="real") == 0
+    assert other.instruments.registry is not served
+
+
+def test_right_padding_changes_no_real_positions_output(trained):
+    _, model = trained
+    rng = np.random.default_rng(9)
+    session = rng.integers(0, N_ITEMS, 37).astype(np.int32)
+    outs = []
+    for bucket, pad in ((64, 0), (64, 77), (128, 5)):
+        tokens = np.full((2, bucket), pad, np.int32)
+        tokens[0, :37] = session
+        logits = olmoe.all_logits(model.weights, jnp.asarray(tokens), config=model.config)
+        outs.append(np.asarray(logits)[0, :37])
+    # the same bucket with other padding: the same program, bit for bit
+    assert np.array_equal(outs[0], outs[1])
+    # a longer bucket is another program: float32 sums in another order
+    np.testing.assert_allclose(outs[0], outs[2], atol=ATOL)
+
+
+def test_a_batch_of_mixed_lengths_is_answered_in_order_as_the_reference_does(trained):
+    algorithm, model = trained
+    td = training_data()
+    rng = np.random.default_rng(11)
+    queries = [Query(user=u, num=5) for u in td.users]
+    # explicit recentItems win over the stored tail; unknown items are dropped
+    recent = tuple(f"i{i}" for i in rng.integers(0, N_ITEMS, 9)) + ("no-such-item",)
+    queries.insert(4, Query(user="u0", recent_items=recent, num=7))
+    queries.insert(9, Query(user="nobody", num=5))  # no session: no items
+    sessions, programs = algorithm._plan(model, queries)
+    assert {bucket for bucket, _, _ in programs} == {64, 128}
+    # more sessions in bucket 64 than one program holds: split
+    assert sum(1 for bucket, _, _ in programs if bucket == 64) >= 2
+    assert sorted(i for _, _, members in programs for i in members) == [
+        i for i in range(len(queries)) if i != 9
+    ]
+    answers = algorithm.predict_batch_dispatch(model, queries)()
+    assert len(answers) == len(queries) and answers[9].item_scores == ()
+    for query, session, answer in zip(queries, sessions, answers):
+        if not len(session):
+            continue
+        logits, order = reference_answer(model, session, query.num)
+        ids = [int(s.item[1:]) for s in answer.item_scores]
+        assert len(ids) == query.num and not set(ids) & set(session.tolist())
+        np.testing.assert_allclose([s.score for s in answer.item_scores], logits[ids], atol=ATOL)
+        for place, (got, want) in enumerate(zip(ids, order)):
+            # the reference's item, but where it scores the two within the tolerance
+            assert got == want or abs(logits[got] - logits[want]) <= 2 * ATOL, place
+    assert np.array_equal(sessions[4], [int(i[1:]) for i in recent[:-1]])
+
+
+def test_one_query_equals_its_row_of_a_batch(trained):
+    algorithm, model = trained
+    queries = [Query(user=f"u{i}", num=4) for i in (2, 1, 0, 5)]
+    batch = algorithm.predict_batch(model, queries)
+    alone = algorithm.predict(model, queries[1])
+    assert [s.item for s in alone.item_scores] == [s.item for s in batch[1].item_scores]
+    np.testing.assert_allclose(
+        [s.score for s in alone.item_scores], [s.score for s in batch[1].item_scores], atol=ATOL
+    )
+
+
+def test_program_shapes_are_a_small_closed_set(monkeypatch):
+    monkeypatch.undo()  # the constants the chip set
+    config = OlmoeAlgorithmParams().config()
+    shapes = config.program_shapes()
+    assert config.buckets() == (64, 128, 256, 512, 1024, 2048, 4096)
+    assert len(shapes) == len(set(shapes)) <= 16
+    # one height a bucket: the budget's rows, one row where a session is longer
+    assert shapes == ((32, 64), (16, 128), (8, 256), (4, 512), (2, 1024), (1, 2048), (1, 4096))
+
+
+def test_warmup_serving_leaves_nothing_to_compile():
+    from jax import monitoring
+
+    # a width no other test of this process compiles
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(**{**TINY, "intermediate_size": 16}, seed=2))
+    td = training_data(seed=3, n_users=16)
+    model = algorithm.train(None, td)
+    compiled = []
+
+    def listener(event, duration_secs, **kw):
+        if event.endswith("/backend_compile_duration"):
+            compiled.append(event)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    algorithm.warmup_serving(model, 64)
+    warmed = len(compiled)
+    assert warmed >= len(model.config.program_shapes())
+    answers = algorithm.predict_batch(model, [Query(user=u, num=10) for u in td.users])
+    assert all(len(a.item_scores) == 10 for a in answers)
+    assert len(compiled) == warmed
+
+
+def test_unimplemented_config_values_are_refused_not_ignored():
+    for key, value in (
+        ("norm_topk_prob", True), ("attention_bias", True), ("clip_qkv", 8.0),
+        ("num_key_value_heads", 2), ("tie_word_embeddings", True), ("hidden_act", "gelu"),
+    ):
+        with pytest.raises(ValueError, match=key):
+            OlmoeAlgorithmParams(**{**TINY, key: value}).config()
+
+
+def test_the_variant_file_carries_the_published_config_verbatim():
+    from pathlib import Path
+
+    import predictionio_tpu.models.sequential as package
+
+    variant = json.loads((Path(package.__file__).parent / "variants" / "olmoe-1b-7b.json").read_text())
+    params = engine_factory().engine_params_from_variant(variant).algorithms[0][1]
+    published = {
+        "attention_bias": False, "clip_qkv": None, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 1024, "max_position_embeddings": 4096, "model_type": "olmoe",
+        "norm_topk_prob": False, "num_attention_heads": 16, "num_experts": 64,
+        "num_experts_per_tok": 8, "num_hidden_layers": 16, "num_key_value_heads": 16,
+        "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 10000,
+        "tie_word_embeddings": False, "vocab_size": 50304,
+    }
+    raw = variant["algorithms"][0]["params"]
+    assert {k: raw[k] for k in published} == published
+    assert {k: getattr(params, k) for k in published} == published
+    assert dataclasses.asdict(OlmoeAlgorithmParams(seed=params.seed)) == dataclasses.asdict(params)
+
+
+# ---------------------------------------------------------- persistence
+
+
+def test_save_then_load_is_equal_bit_for_bit(tmp_path):
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(**TINY, seed=7))
+    model = algorithm.train(None, training_data(seed=1))
+    assert model.weights["gate"].dtype == jnp.bfloat16  # drawn in bf16, stacked by layer
+    assert model.weights["gate"].shape == (2, 8, 64, 32)
+    assert model.save("m1", algorithm.params, str(tmp_path))
+    files = sorted(p.name for p in (tmp_path / "m1").iterdir())
+    assert files == sorted([f"{name}.bin" for name in [*model.weights, "tails", "offsets"]] + ["olmoe.json"])
+    # raw arrays: a file is exactly its array's bytes
+    assert (tmp_path / "m1" / "gate.bin").stat().st_size == 2 * 8 * 64 * 32 * 2
+    loaded = OlmoeModel.load("m1", algorithm.params, str(tmp_path))
+    assert loaded.config == model.config
+    assert loaded.item_vocab == model.item_vocab and loaded.users == model.users
+    assert np.array_equal(loaded.tails, model.tails) and np.array_equal(loaded.offsets, model.offsets)
+    assert loaded.weights.keys() == model.weights.keys()
+    for name, array in model.weights.items():
+        assert loaded.weights[name].dtype == array.dtype, name
+        assert np.array_equal(
+            np.asarray(loaded.weights[name]).view(np.uint8), np.asarray(array).view(np.uint8)
+        ), name
+    queries = [Query(user=f"u{i}", num=5) for i in range(6)]
+    assert algorithm.predict_batch(loaded, queries) == algorithm.predict_batch(model, queries)
+    # a truncated file is an error that names it, not a wrong model
+    with open(tmp_path / "m1" / "wo.bin", "r+b") as f:
+        f.truncate(100)
+    with pytest.raises(ValueError, match="wo.bin"):
+        OlmoeModel.load("m1", algorithm.params, str(tmp_path))
+
+
+def test_the_model_repository_holds_a_manifest_not_the_weights(tmp_path, monkeypatch):
+    from predictionio_tpu.workflow import model_io
+
+    monkeypatch.setenv("PIO_FS_BASEDIR", str(tmp_path))
+    engine = engine_factory()
+    params = engine.engine_params_from_variant(
+        {
+            "datasource": {"params": {"appName": "seq"}},
+            "algorithms": [{"name": "olmoe", "params": {**TINY, "seed": 7}}],
+        }
+    )
+    _, _, (algorithm,), _ = engine.make_components(params)
+    model = algorithm.train(None, training_data(seed=1))
+    (persisted,) = engine.make_serializable_models(None, params, [model])
+    assert isinstance(persisted, PersistentModelManifest)
+    assert persisted.class_path == "predictionio_tpu.models.sequential.engine.OlmoeModel"
+    blob = model_io.serialize_models([persisted])
+    assert len(blob) < 1024  # what train → deploy passes through the pickled blob
+    assert (tmp_path / "models" / persisted.model_id / "olmoe.json").is_file()
+    (deployed,) = engine.prepare_deploy(None, params, model_io.deserialize_models(blob))
+    assert isinstance(deployed, OlmoeModel)
+    queries = [Query(user=f"u{i}", num=5) for i in range(4)]
+    assert algorithm.predict_batch(deployed, queries) == algorithm.predict_batch(model, queries)
+
+
+def test_session_tails_keep_the_last_items_in_one_array():
+    from predictionio_tpu.models.sequential.engine import session_tails
+
+    sequences = [np.arange(5, dtype=np.int32), np.arange(200, dtype=np.int32), np.empty(0, np.int32)]
+    tails, offsets = session_tails(sequences, keep=128)
+    assert tails.dtype == np.int32 and offsets.tolist() == [0, 5, 133, 133]
+    assert tails[:5].tolist() == [0, 1, 2, 3, 4] and tails[5:].tolist() == list(range(72, 200))
+
+
+# --------------------------------------------------------------- server
+
+
+def test_query_server_answers_mixed_lengths_over_http_and_counts_them(trained):
+    from predictionio_tpu.data.storage.registry import Storage
+    from predictionio_tpu.workflow.create_server import QueryServer, ServerConfig
+    from predictionio_tpu.workflow.engine_loader import EngineManifest
+
+    _, model = trained
+    engine = engine_factory()
+    params = engine.engine_params_from_variant(
+        {
+            "datasource": {"params": {"appName": "seq"}},
+            "algorithms": [{"name": "olmoe", "params": {**TINY, "seed": 5}}],
+        }
+    )
+    with socket.socket() as sock:
+        sock.bind(("127.0.0.1", 0))
+        port = sock.getsockname()[1]
+    server = QueryServer(
+        engine=engine, engine_params=params, models=[model],
+        manifest=EngineManifest(
+            engine_id="seq", version="1", variant="engine.json",
+            engine_factory="predictionio_tpu.models.sequential.engine_factory",
+        ),
+        instance_id="seq", storage=Storage(env=MEMORY_STORAGE),
+        config=ServerConfig(ip="127.0.0.1", port=port, max_batch_size=32),
+    )
+    loop = asyncio.new_event_loop()
+    started = threading.Event()
+
+    def serve():
+        asyncio.set_event_loop(loop)
+        loop.run_until_complete(server.start())
+        started.set()
+        loop.run_forever()
+
+    thread = threading.Thread(target=serve, daemon=True)
+    thread.start()
+    assert started.wait(120)
+
+    def post(body: dict) -> dict:
+        request = urllib.request.Request(
+            f"http://127.0.0.1:{port}/queries.json", json.dumps(body).encode(),
+            {"Content-Type": "application/json"},
+        )
+        with urllib.request.urlopen(request, timeout=30) as resp:
+            return json.loads(resp.read())
+
+    def scrape() -> dict:
+        with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics", timeout=30) as resp:
+            lines = resp.read().decode().splitlines()
+        return {k: float(v) for k, _, v in (l.rpartition(" ") for l in lines if l and l[0] != "#")}
+
+    try:
+        before = scrape()
+        td = training_data()
+        replies = {}
+
+        def ask(user):
+            replies[user] = post({"user": user, "num": 6})
+
+        threads = [threading.Thread(target=ask, args=(u,)) for u in td.users]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for user, session in zip(td.users, td.sequences):
+            logits, order = reference_answer(model, session, 6)
+            rows = replies[user]["itemScores"]
+            ids = [int(r["item"][1:]) for r in rows]
+            assert len(ids) == 6 and not set(ids) & set(session.tolist())
+            np.testing.assert_allclose([r["score"] for r in rows], logits[ids], atol=ATOL)
+            assert all(
+                g == w or abs(logits[g] - logits[w]) <= 2 * ATOL for g, w in zip(ids, order)
+            )
+        with_items = post({"recentItems": ["i3", "i4", "i5"], "num": 3})["itemScores"]
+        assert len(with_items) == 3 and not {"i3", "i4", "i5"} & {r["item"] for r in with_items}
+        after = scrape()
+
+        def grown(key):
+            return after.get(key, 0.0) - before.get(key, 0.0)
+
+        real = sum(len(s) for s in td.sequences) + 3
+        assert grown('pio_seq_tokens_total{kind="real"}') == real
+        padded = grown('pio_seq_tokens_total{kind="padded"}')
+        by_bucket = {b: grown(f'pio_seq_rows_total{{bucket="{b}"}}') for b in (64, 128)}
+        assert padded == 64 * by_bucket[64] + 128 * by_bucket[128] > real
+        programs = sum(grown(f'pio_seq_programs_total{{bucket="{b}"}}') for b in (64, 128))
+        assert programs >= 2 and grown("pio_seq_batches_total") >= 1
+        assert grown("pio_seq_stage_seconds_total") > 0
+        # per layer and program the busiest expert has at least the mean
+        mean = grown("pio_moe_expert_tokens_mean_total")
+        assert mean == 2 * real * 2 / 8  # real tokens' copies: the padding is not counted
+        assert mean <= grown("pio_moe_expert_tokens_max_total") <= 8 * mean
+    finally:
+        asyncio.run_coroutine_threadsafe(server.stop(), loop).result(timeout=30)
+        loop.call_soon_threadsafe(loop.stop)
+        thread.join(timeout=30)
