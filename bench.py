@@ -406,28 +406,6 @@ def phase_als(ck: _Checkpoint) -> None:
             ),
         )
 
-        # the VMEM-fused CG solver (one HBM read of the [n, f, f] systems
-        # vs f+4 — the dominant term of the traffic model)
-        t_fused: dict = {}
-        cfg_fused = ALSConfig(
-            rank=rank, iterations=iterations, reg=0.05, chunk=65536,
-            solver="cg_fused",
-        )
-        uf_f, vf_f = als_train(
-            users_tr, items_tr, vals_tr, n_users, n_items, cfg_fused,
-            timings=t_fused,
-        )
-        ck.save(
-            als_cgfused_device_s=round(t_fused["device_s"], 3),
-            als_cgfused_heldout_rmse=round(
-                _heldout_rmse(
-                    np.asarray(uf_f), np.asarray(vf_f),
-                    users, items, vals, test_mask,
-                ),
-                4,
-            ),
-        )
-
     # held-out quality gate (the wall-clock above is already checkpointed
     # if the readback faults)
     uf_host, vf_host = np.asarray(uf), np.asarray(vf)

@@ -396,7 +396,7 @@ def child_kernels() -> int:
     import jax.numpy as jnp
 
     from predictionio_tpu.ops.attention import attention_reference, fused_attention
-    from predictionio_tpu.ops.spd_solve import _cg_body, batched_spd_solve_auto
+    from predictionio_tpu.ops.spd_solve import _cg_lanes, batched_spd_solve_auto
 
     dev = jax.devices()[0]
     found = {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
@@ -440,24 +440,24 @@ def child_kernels() -> int:
             if not err < 2e-2:
                 raise SystemExit(f"chip_smoke: attention kernel differs from its reference at {shape}")
 
-    # fused CG through the entry ops/als calls, at the smoke's rank over
-    # the user table's systems and at the template's default rank over the
-    # item table's; systems shaped like regularized ALS normal equations
+    # the CG's tile kernel through the jitted entry, at the smoke's rank
+    # over the user table's systems and at the template's default rank over
+    # the item table's, against the same body as plain XLA; systems shaped
+    # like regularized ALS normal equations
     for n, f in ((N_USERS + 1, RANK), (N_ITEMS + 1, 10)):
         g = jnp.asarray(rng.normal(size=(n, f, 2 * f)), jnp.float32)
         A = jnp.einsum("nfd,ngd->nfg", g, g, precision="highest") / (2 * f) + 0.5 * jnp.eye(f)
         b = jnp.asarray(rng.normal(size=(n, f)), jnp.float32)
-        kern = jax.jit(batched_spd_solve_auto)
-        holds_compiled_kernel(kern, A, b)
-        x, first_s = first_call(kern, A, b)
-        ref = jax.jit(lambda A, b: _cg_body(A, b, A.shape[-1] + 4))(A, b)
+        holds_compiled_kernel(batched_spd_solve_auto, A, b)
+        x, first_s = first_call(batched_spd_solve_auto, A, b)
+        ref = jax.jit(lambda A, b: _cg_lanes(jnp.transpose(A, (2, 1, 0)), b.T).T)(A, b)
         resid = float(jnp.max(jnp.abs(jnp.einsum("nfg,ng->nf", A, x, precision="highest") - b)))
         err = float(jnp.max(jnp.abs(x - ref)))
-        print(f"kernel cg_fused n={n} f={f}: max|kernel - _cg_body| {err:.2e}, max residual "
+        print(f"kernel cg n={n} f={f}: max|kernel - plain body| {err:.2e}, max residual "
               f"{resid:.2e}; first call {first_s:.2f} s")
-        # same algorithm, f32 products on both sides: float rounding only
+        # same body, f32 multiply-and-add on both sides: float rounding only
         if not (err < 1e-4 and resid < 1e-4):
-            raise SystemExit(f"chip_smoke: cg_fused differs from _cg_body at f={f}")
+            raise SystemExit(f"chip_smoke: the CG tile kernel differs from its plain body at f={f}")
     print("KERNELS " + json.dumps(found))
     return 0
 

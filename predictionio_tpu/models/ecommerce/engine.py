@@ -169,7 +169,7 @@ class ECommAlgorithmParams(Params):
     lambda_: float = 0.01
     alpha: float = 1.0
     seed: int | None = 3
-    # "cg" | "cg_fused" | "cholesky" (see ops/als.ALSConfig.solver)
+    # "cg" | "cholesky"; "cg_fused" is read as "cg" (see ops/als.ALSConfig.solver)
     solver: str = "cg"
     # adjust-score variant: enable the per-request weightedItems constraint
     # lookup (off by default — it costs one event-store query per predict)

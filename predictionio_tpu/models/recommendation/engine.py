@@ -279,9 +279,9 @@ class ALSAlgorithmParams(Params):
     # solver's Gram accumulation (halves the gather-bound loop's row bytes;
     # accumulators and solves stay f32 — see ops/als.ALSConfig.gather_dtype)
     gather_dtype: str = "f32"
-    # "cg" | "cg_fused" | "cholesky": per-entity SPD solver; "cg_fused"
-    # keeps the normal-equation systems VMEM-resident (one HBM read
-    # instead of f+4 — see ops/als.ALSConfig.solver)
+    # "cg" | "cholesky": per-entity SPD solver. "cg" holds the systems
+    # batch-last and, on a TPU, solves a tile of them to the end in VMEM;
+    # "cg_fused" is read as "cg" (see ops/als.ALSConfig.solver)
     solver: str = "cg"
 
 

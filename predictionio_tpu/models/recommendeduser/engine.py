@@ -129,7 +129,7 @@ class ALSAlgorithmParams(Params):
     lambda_: float = 0.01
     alpha: float = 1.0
     seed: int | None = 3
-    # "cg" | "cg_fused" | "cholesky" (see ops/als.ALSConfig.solver)
+    # "cg" | "cholesky"; "cg_fused" is read as "cg" (see ops/als.ALSConfig.solver)
     solver: str = "cg"
 
 

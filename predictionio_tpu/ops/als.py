@@ -62,14 +62,18 @@ class ALSConfig:
     seed: int = 3
     chunk: int = 16384  # COO entries per scan step (blocked: block_d * blocks)
     block_d: int = 128  # entity-block width for the MXU Gram path
-    # "cg" | "cg_fused" | "cholesky": batched f-by-f SPD solver.
-    # Jacobi-preconditioned CG run for f+4 iterations is exact-termination
-    # on an f-dim Krylov space (it IS a direct method for these sizes,
-    # modulo fp rounding) and maps to batched matvecs. "cg_fused" is the
-    # identical algorithm as a VMEM-resident pallas kernel: one HBM read of
-    # the [n, f, f] systems instead of f+4 (the dominant term of
-    # solver_hbm_bytes_per_iter's traffic model, PERF.md); off-TPU it runs
-    # plain cg. Which is faster on the chip is not measured (ROADMAP S2).
+    # "cg" | "cholesky": batched f-by-f SPD solver. Jacobi-preconditioned
+    # CG run for f+4 iterations is exact-termination on an f-dim Krylov
+    # space (it IS a direct method for these sizes, modulo fp rounding). It
+    # holds its systems batch-last ([f, f, n]: n on the 128 lanes), so a
+    # matvec reads them at their own bytes, and on a TPU it solves a tile
+    # of lanes to the end in VMEM (ops/spd_solve.py). Measured on a v5e in
+    # rec-als-ml20m.train (PERF.md section 6, PR 27), solve seconds an
+    # iteration: 0.180 over padded [n, f, f] as it was, 0.034 batch-last in
+    # plain XLA, 0.007 as the tile kernel. "cg_fused" named an MXU kernel
+    # over [64, f, f] blocks that took 0.256 s an iteration and is gone; the
+    # spelling is still read and runs the one CG, until ROADMAP D3 removes
+    # it. "cholesky" is the tests' reference.
     solver: str = "cg"
     # "auto" | "degree" | "constant" — see module docstring (ALS-WR)
     reg_scaling: str = "auto"
@@ -318,22 +322,15 @@ def _normal_equations_blocked(
 def _batched_spd_solve(A: jnp.ndarray, b: jnp.ndarray, solver: str) -> jnp.ndarray:
     """Solve B independent f-by-f SPD systems. ``cg`` = Jacobi-preconditioned
     conjugate gradient for f+4 iterations (exact termination on the f-dim
-    space; batched matvecs ride the MXU — see ALSConfig.solver); ``cg_fused``
-    = the same algorithm as a VMEM-resident pallas kernel (one HBM read of
-    A instead of f+4 — ops/spd_solve.py); ``cholesky`` = LAPACK-style
-    factorization (reference semantics, slower on TPU)."""
-    if solver == "cg_fused":
-        from predictionio_tpu.ops.spd_solve import batched_spd_solve_auto
-
-        return batched_spd_solve_auto(A, b)
+    space), over the systems turned batch-last so that a matvec reads them
+    at their own bytes (ops/spd_solve.py; see ALSConfig.solver, which also
+    says what became of ``cg_fused``); ``cholesky`` = LAPACK-style
+    factorization (the tests' reference, slower on TPU)."""
     if solver == "cholesky":
         return jax.scipy.linalg.cho_solve((jnp.linalg.cholesky(A), True), b)
-    # stock cg = the SAME body the fused kernel runs (ops/spd_solve.py);
-    # one shared implementation keeps the fused/stock parity contract
-    # from silently drifting
     from predictionio_tpu.ops.spd_solve import _cg_body
 
-    return _cg_body(A, b, A.shape[-1] + 4)
+    return _cg_body(A, b)
 
 
 def _solve_blocked(
@@ -647,10 +644,12 @@ def als_train(
     it: ``pack_s`` (host group-by / block packing), ``upload_s`` (H2D
     transfer of the wire arrays, barrier-confirmed), ``build_s``
     (device-side block-table construction — 0 on the host pack path),
-    ``device_s`` (solver iterations only, barrier-confirmed). The
-    instrumentation barriers make the decomposition sum to the call's wall
-    clock; the un-instrumented path keeps the fully-async dispatch
-    pipeline.
+    ``device_s`` (solver iterations only, barrier-confirmed). The four
+    clocks run back to back from the start of the pack, so they sum to the
+    call's wall clock LESS what comes before it: checking and filtering the
+    ratings (0.33 s at 19.6 M ratings on a v5e's host, PERF.md section 5)
+    is in none of them, only under the ``pio:als.pack`` span. The
+    un-instrumented path keeps the fully-async dispatch pipeline.
 
     With an active train profile (obs/xray): the host pack/upload/build
     accounts as ``host_etl``, each iteration becomes one profiled

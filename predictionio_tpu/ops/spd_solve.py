@@ -1,20 +1,32 @@
-"""Batched small-SPD solve with a VMEM-resident fused-CG pallas kernel.
+"""Batched small-SPD solve: Jacobi-CG over systems held batch-last, a tile of
+lanes at a time in VMEM on the chip.
 
 The ALS half-solve ends with ~n_entities independent [f, f] SPD systems
-(f = rank, 16-64). The stock path (``ops/als.py:_batched_spd_solve``)
-runs Jacobi-preconditioned CG for f+4 iterations as whole-array jnp ops:
-every iteration re-reads the entire [n, f, f] A tensor from HBM — at
-ML-20M that is 36 passes over ~680 MB per side, ~70% of the iteration's
-mandatory memory traffic (the traffic model is
-``ops/als.solver_hbm_bytes_per_iter``; PERF.md states it).
+(f = rank, 10-64). ``_cg_body`` is the ONE conjugate gradient of the
+repository: ``ops/als._batched_spd_solve`` (every ALS half-step, sharded or
+not) and the stream layer's fold-in (``batched_spd_solve_auto``) run it.
 
-This kernel runs the IDENTICAL algorithm — same preconditioner, same
-f+4 exact-termination iteration count, same update order, so results
-match to float rounding — but tiles A into VMEM once and keeps every CG
-vector on-chip: HBM traffic drops to one read of A + the vectors, and
-the per-iteration matvecs become MXU ``dot_general``s over the resident
-tile. One pallas grid cell handles ``bs`` systems (``_systems_per_cell``:
-64 at f = 32).
+The layout is the point. A TPU array keeps its last axis on 128 lanes and
+its second-to-last on 8 sublanes, so ``[n, f, f]`` float32 at f = 32 takes
+four times its bytes (about twenty times at f = 10), and CG re-reads the
+systems f + 5 times: at ML-20M that was 37 passes over 2.3 GB on the user
+side, the heaviest operation of a whole train (PERF.md section 6, PR 27).
+So the solve turns its operands ONCE at the door: the batch goes last
+(``[f, f, n]``, ``[f, n]``), n lies on the lanes and pads by at most 127
+systems, f lies on sublanes and a major axis and pads not at all at 32 or
+64. The matvec is then a float32 multiply-and-add over the MAJOR axis,
+``sum_j A[j, i, :] * p[j, :]``: no lane reduction and no MXU pass, exact as
+XLA's default for the old batched ``dot_general`` was.
+
+On a TPU the same body runs as a Pallas kernel over ``[f, f, T]`` tiles of
+lanes (``_cg_tiles``): systems are independent along the lanes, so a tile
+is solved to the end in VMEM and the systems leave HBM once, not f + 5
+times; the matvec stays on the VPU. Off the chip the body runs as plain
+XLA. Measured on a v5e in ``rec-als-ml20m.train`` (rank 32, both sides;
+PERF.md section 6, PR 27), seconds of ``solve`` an iteration: 0.180 as it
+was (``[n, f, f]`` padded to 128 lanes), 0.034 batch-last in plain XLA,
+0.007 as this kernel. The MXU kernel over ``[64, f, f]`` blocks that lived
+here before (``cg_fused``) took 0.256 and is gone.
 
 Reference analog: the per-entity normal-equation solves inside MLlib
 ALS (``CholeskySolver`` in the reference's Spark stack); redesigned
@@ -23,110 +35,111 @@ TPU-first rather than translated.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
-def _cg_body(A, b, iters: int, precision=None):
-    """THE Jacobi-CG used everywhere: ops/als.py's stock ``cg`` branch
-    and the pallas kernel both run this body, so the fused kernel's
-    'identical algorithm' parity contract cannot silently drift. The
-    iterations are a ``lax.fori_loop`` in both: unrolled inside the kernel,
-    the f+4 = 36 iterations at f = 32 took Mosaic 169 s to compile against
-    1.8 s for the loop (libtpu 0.0.34, ahead-of-time compile for a v5e).
+def _cg_lanes(At, bt):
+    """Jacobi-CG over systems held batch-last: ``At[j, i, :]`` is entry
+    (i, j) of each system, ``bt`` is ``[f, n]``; returns x as ``[f, n]``.
+    Same preconditioner, same f + 4 steps (exact termination on an
+    f-dimensional Krylov space), same update order as ever; every vector
+    is ``[f, n]`` and every reduction runs over f, never over the lanes."""
+    f = At.shape[0]
+    eye = jnp.eye(f, dtype=At.dtype)
+    dinv = 1.0 / jnp.sum(At * eye[:, :, None], axis=0)  # [f, n]
 
-    ``precision`` is the matvec's: XLA computes this batched f32 matvec
-    exactly at its default (1e-6 from a float64 solve on a v5e), while
-    Mosaic's default is one bf16 pass (2e-2), so the kernel asks for
-    ``HIGHEST`` to keep the two paths within float rounding."""
-    f = A.shape[-1]
-    eye = jnp.eye(f, dtype=A.dtype)
-    dinv = 1.0 / jnp.sum(A * eye, axis=-1)  # diagonal without jnp.diagonal
-
-    def mv(x):
+    def mv(p):
         with jax.named_scope("matvec"):
-            return jax.lax.dot_general(
-                A, x[..., None], (((2,), (1,)), ((0,), (0,))),
-                precision=precision,
-                preferred_element_type=jnp.float32,
-            )[..., 0]
+            return jnp.sum(At * p[:, None, :], axis=0)
 
     def step(_, st):
         x, r, p, rz = st
         Ap = mv(p)
-        alpha = rz / jnp.maximum(jnp.sum(p * Ap, -1), 1e-30)
-        x = x + alpha[:, None] * p
-        r = r - alpha[:, None] * Ap
+        alpha = rz / jnp.maximum(jnp.sum(p * Ap, 0), 1e-30)
+        x = x + alpha[None, :] * p
+        r = r - alpha[None, :] * Ap
         z = r * dinv
-        rz2 = jnp.sum(r * z, -1)
-        p = z + (rz2 / jnp.maximum(rz, 1e-30))[:, None] * p
+        rz2 = jnp.sum(r * z, 0)
+        p = z + (rz2 / jnp.maximum(rz, 1e-30))[None, :] * p
         return x, r, p, rz2
 
-    x = b * dinv
-    r = b - mv(x)
+    x = bt * dinv
+    r = bt - mv(x)
     z = r * dinv
-    return jax.lax.fori_loop(0, iters, step, (x, r, z, jnp.sum(r * z, -1)))[0]
+    return jax.lax.fori_loop(0, f + 4, step, (x, r, z, jnp.sum(r * z, 0)))[0]
 
 
-def _kernel(a_ref, b_ref, x_ref, *, iters: int):
-    x_ref[...] = _cg_body(
-        a_ref[...], b_ref[...], iters, precision=jax.lax.Precision.HIGHEST
-    )
+def _tile_row_bytes(f: int) -> int:
+    """Bytes of one lane of a float32 ``[f, f, T]`` tile as VMEM holds it
+    (rows padded to the 8 sublanes)."""
+    return f * -(-f // 8) * 8 * 4
 
 
-def _systems_per_cell(f: int) -> int:
-    """Systems per grid cell: the [bs, f, f] f32 tile, as VMEM pads it
-    (rows to 8, lanes to 128), held to 1 MiB and to a multiple of the
-    8-row sublane tile. At 2 MiB (128 systems at f = 32) the kernel's stack
-    passes the 16 MiB scoped-VMEM limit with the matvec at HIGHEST."""
-    padded = -(-f // 8) * 8 * -(-f // 128) * 128 * 4
-    return max(8, min(128, (1 << 20) // padded // 8 * 8))
+def _lanes_per_tile(f: int) -> int:
+    """Lanes (systems) a grid cell: the tile held to 2 MiB and to whole
+    128-lane rows, one row at least: 512 at f = 32, 128 from f = 48 up. At
+    4 MiB (f = 32, T = 1,024) the kernel's stack passes the 16 MiB of scoped
+    VMEM; a taller single row (f over 64) raises that limit with it
+    (``_cg_tiles``). 0 above f = 128, where one row passes 8 MiB: no kernel
+    there (ahead-of-time compiles for a v5e, PR 27)."""
+    if _tile_row_bytes(f) * 128 > 8 << 20:
+        return 0
+    return max(128, min(512, (2 << 20) // _tile_row_bytes(f) // 128 * 128))
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
-def batched_spd_solve_fused(
-    A: jnp.ndarray,  # [n, f, f] SPD (regularized normal equations)
-    b: jnp.ndarray,  # [n, f]
-    bs: int | None = None,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Solve n independent SPD systems; one HBM read of A total.
+def _kernel(a_ref, b_ref, x_ref):
+    x_ref[...] = _cg_lanes(a_ref[...], b_ref[...])
 
-    ``bs`` systems per grid cell (default: what fits, ``_systems_per_cell``).
-    Pads n up to a multiple of ``bs`` with identity systems (solution 0)
-    — the pad rows are sliced off before returning.
-    """
+
+def _cg_tiles(At, bt, interpret: bool = False):
+    """``_cg_lanes`` a tile of lanes at a time, each tile held in VMEM from
+    its first matvec to its last. The last tile may hang over n: its
+    surplus lanes hold whatever the read brought, are solved like the rest
+    (no lane ever meets another) and are dropped by the write."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    n, f = A.shape[0], A.shape[-1]
-    iters = f + 4
-    bs = bs or _systems_per_cell(f)
-    pad = (-n) % bs
-    if pad:
-        eye = jnp.broadcast_to(jnp.eye(f, dtype=A.dtype), (pad, f, f))
-        A = jnp.concatenate([A, eye])
-        b = jnp.concatenate([b, jnp.zeros((pad, f), b.dtype)])
-    n_pad = A.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_kernel, iters=iters),
-        grid=(n_pad // bs,),
+    f, n = bt.shape
+    tile = _lanes_per_tile(f)
+    return pl.pallas_call(
+        _kernel,
+        grid=(pl.cdiv(n, tile),),
         in_specs=[
-            pl.BlockSpec((bs, f, f), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bs, f), lambda i: (i, 0)),
+            pl.BlockSpec((f, f, tile), lambda i: (0, 0, i)),
+            pl.BlockSpec((f, tile), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((bs, f), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, f), jnp.float32),
+        out_specs=pl.BlockSpec((f, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((f, n), bt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # XLA may fuse each operand's producer (the regularisation's
+            # add, b's turn) into the kernel's reads. As a bare custom call
+            # the kernel changed what XLA kept in VMEM across `_als_step`:
+            # the item side's scatter-add of b went to HBM (55 ms an
+            # iteration) and `gram` took 44 ms more, against the 27 ms the
+            # kernel saves (v5e, PERF.md section 6, PR 27)
+            allow_input_fusion=[True, True],
+            # two buffers of the tile, the matvec's product and the vectors
+            vmem_limit_bytes=max(16 << 20, 8 * _tile_row_bytes(f) * tile),
+        ),
         interpret=interpret,
-    )(A.astype(jnp.float32), b.astype(jnp.float32))
-    return out[:n]
+    )(At, bt)
 
 
+def _cg_body(A, b):
+    """Solve ``A [n, f, f] x = b [n, f]`` by Jacobi-CG; returns ``[n, f]``.
+    One transposition in and one out buy every matvec over the systems at
+    their own bytes (module docstring); on the chip the body runs tile by
+    tile in VMEM, elsewhere (and above rank 128) as plain XLA."""
+    At, bt = jnp.transpose(A, (2, 1, 0)), b.T
+    if jax.default_backend() == "tpu" and _lanes_per_tile(A.shape[-1]):
+        return _cg_tiles(At, bt).T
+    return _cg_lanes(At, bt).T
+
+
+@jax.jit
 def batched_spd_solve_auto(A: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Fused kernel on TPU; the identical-algorithm jnp path elsewhere
-    (same platform contract as ops/attention.fused_attention)."""
-    if jax.default_backend() == "tpu":
-        return batched_spd_solve_fused(A, b)
-    return _cg_body(A, b, A.shape[-1] + 4)
+    """The jitted entry for callers outside a program of their own (the
+    stream layer's fold-in of a handful of systems): the one CG, on every
+    platform."""
+    return _cg_body(A, b)
