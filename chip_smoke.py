@@ -8,7 +8,7 @@ Runs, through the CLI a user would type and one child process at a time,
 on the recommendation template at the repository's headline shape
 (138,000 users x 27,000 items, rank 32, 10 iterations; the ML-20M shape
 BASELINE.md names). Ratings are scale, not width: they are synthesized from
-``--seed`` with bench.synthesize_ratings' distribution and cut to what
+``--seed`` with the distribution ``synthesize_ratings`` states and cut to what
 ``pio import`` loads in about a minute; every user and item is rated at
 least once, so the factor tables, the [138k, 32, 32] normal-equation
 workspace and the serving index keep their full width.
@@ -48,7 +48,7 @@ import numpy as np
 REPO = os.path.dirname(os.path.abspath(__file__))
 REQUIRED_PLATFORM = "tpu"
 
-# the headline shape (BASELINE.md; bench.py's "ml20m"): widths are never cut
+# the headline shape (BASELINE.md: ML-20M's): widths are never cut
 N_USERS, N_ITEMS, RANK, ITERATIONS = 138_000, 27_000, 32, 10
 FULL_RATINGS = 20_000_000
 # the cut: `pio import` loads about a million events a minute into SQLite
@@ -70,10 +70,9 @@ SCORE_TOLERANCE_FACTOR = 2.0**-16
 
 
 def synthesize_ratings(seed: int, n_users: int, n_items: int, n_ratings: int):
-    """bench.synthesize_ratings' distribution (uniform users, zipf(1.3)
-    items, rank-8 structure + 3.0 + N(0, 0.3), clipped to [1, 5] and
-    quantized to half stars), with the first ratings re-pointed so that
-    every user and every item occurs at least once."""
+    """Uniform users, zipf(1.3) items, a rank-8 structure + 3.0 + N(0, 0.3)
+    clipped to [1, 5] and quantized to half stars, with the first ratings
+    re-pointed so that every user and every item occurs at least once."""
     if n_ratings < max(n_users, n_items):
         raise ValueError("fewer ratings than entities: the tables would not be full width")
     rng = np.random.default_rng(seed)
@@ -423,7 +422,7 @@ def child_kernels() -> int:
     rng = np.random.default_rng(0)
     # (B, H, L, D): the single-block kernel at the two-tower history
     # encoder's and the sequential scorer's shapes, the flash kernel at the
-    # bench's long-sequence shape. The kernels multiply in bf16 with f32
+    # long-sequence shape (4 x 8 x 2048 x 64). The kernels multiply in bf16 with f32
     # accumulation (2e-2, the repo's own test tolerance); the reference
     # runs in f32 so that only the kernel's rounding is in the difference.
     for shape in ((8, 2, 8, 32), (8, 1, 8, 16), (4, 8, 2048, 64)):

@@ -212,7 +212,7 @@ class AnnSearcher:
         (packed [B,2,k], counts [B]) device-handle pair."""
         import jax.numpy as jnp
 
-        from predictionio_tpu.ops.als import upload
+        from predictionio_tpu.ops.topk import upload
 
         search, search_excl, search_masked, search_q8 = _KERNELS
         nprobe = min(nprobe or self.nprobe, self.index.clusters)
@@ -280,12 +280,12 @@ class AnnSearcher:
         """The one sanctioned fetch of an ANN search: the packed [B,2,k]
         top-k plus the [B] candidate counts — O(batch*k), never
         O(batch*corpus). Returns (scores, item indices, counts)."""
-        from predictionio_tpu.ops.als import ServingIndex
+        from predictionio_tpu.ops.topk import unpack_batch
 
         packed, counts = handle
         # pio-lint: disable=serving-host-roundtrip -- k-only packed fetch + [B] counts, the ANN wire contract
         packed_np, counts_np = np.asarray(packed), np.asarray(counts)
-        scores, idx = ServingIndex.unpack_batch(packed_np)
+        scores, idx = unpack_batch(packed_np)
         return scores, idx, counts_np
 
     def warmup(self, max_batch: int, k: int) -> None:

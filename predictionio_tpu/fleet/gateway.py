@@ -37,9 +37,9 @@ part, not a new runtime. Responsibilities:
   on the ingress trace id: ``gateway.route`` (replica chosen,
   healthy-replica count, panic/retry attribution, final status) and one
   ``gateway.proxy`` per forward attempt (upstream wall time per
-  replica) — the gateway hop ``bench.py`` prices is attributable per
-  request. ``GET /traces/recent`` fan-in merges the gateway's own span
-  ring with each replica's (fetched live from healthy replicas, served
+  replica) — the gateway hop is attributable per request.
+  ``GET /traces/recent`` fan-in merges the gateway's own span ring with
+  each replica's (fetched live from healthy replicas, served
   from the per-tick cache for dead ones — a SIGKILLed worker's last
   spans survive it); ``?trace_id=`` assembles one gateway→replica
   waterfall, which is where a federated p99 exemplar resolves.

@@ -586,10 +586,10 @@ class ECommAlgorithm(JaxAlgorithm):
         n = len(model.item_vocab)
         f = model.user_factors.shape[1]
         kk = min(topk.next_pow2(10), n)
-        # with adjust_score the serving path routes to the WEIGHTED kernel
-        # only while a weightedItems constraint is actually set (a live
-        # event-store lookup — unknowable here), so warm BOTH variants:
-        # whichever one serves, its programs are compiled
+        # with adjust_score the serving path passes weights (and runs the
+        # program specialised on them) only while a weightedItems constraint
+        # is actually set (a live event-store lookup — unknowable here), so
+        # warm BOTH: whichever one serves, its programs are compiled
         variants: list[np.ndarray | None] = [None]
         if self.params.adjust_score:
             variants.append(np.ones(n, np.float32))

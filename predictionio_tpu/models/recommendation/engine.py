@@ -317,9 +317,9 @@ class ALSModel(SanityCheck):
 
     def serving_index(self):
         """Both factor tables resident on device; index-addressed top-k
-        with one upload + one fetch per query (ops.als.ServingIndex)."""
+        with one upload + one fetch per batch (ops.topk.ServingIndex)."""
         if self._serving_index is None:
-            from predictionio_tpu.ops.als import ServingIndex
+            from predictionio_tpu.ops.topk import ServingIndex
 
             self._serving_index = ServingIndex(self.user_factors, self.item_factors)
         return self._serving_index
@@ -411,9 +411,8 @@ class ALSAlgorithm(JaxAlgorithm):
                 iidx = model.item_index(item)
                 if iidx is not None:
                     mask[iidx] = False
-        scores, idx = model.serving_index().serve(
-            uidx, min(query.num, len(model.item_vocab)), mask=mask
-        )
+        # a batch of one at the k bucket the deploy warmed, the first num kept
+        scores, idx = model.serving_index().serve(uidx, query.num, mask=mask)
         return PredictedResult(
             tuple(
                 ItemScore(model.item_vocab[int(i)], float(s))
@@ -423,21 +422,20 @@ class ALSAlgorithm(JaxAlgorithm):
         )
 
     def warmup_serving(self, model: ALSModel, max_batch: int) -> None:
-        """Pre-compile the single-query program plus every pow2 batch bucket
-        for the default result size, so the first request burst after deploy
-        or /reload pays no XLA compiles."""
-        index = model.serving_index()
-        k = min(DEFAULT_QUERY_NUM, len(model.item_vocab))
-        index.warmup(k)
-        index.warmup_buckets(k, max_batch)
+        """Pre-compile every pow2 batch bucket (bucket 1 is the single
+        query's) for the default result size, so the first request burst
+        after deploy or /reload pays no XLA compiles."""
+        model.serving_index().warmup_buckets(
+            min(DEFAULT_QUERY_NUM, len(model.item_vocab)), max_batch
+        )
 
     def predict_batch(
         self, model: ALSModel, queries: Sequence[Query]
     ) -> list[PredictedResult]:
         """Serving micro-batch: all mask-free known-user queries become ONE
         batched top-k kernel ([B] indices -> [B,2,k] packed result); unknown
-        users answer empty and blacklist queries (per-query device mask) fall
-        back to the single-query path. This is what lets the query server
+        users answer empty and blacklist queries (per-query device mask) go
+        one by one, as batches of one. This is what lets the query server
         sustain batched-kernel throughput end-to-end instead of one device
         round-trip per request."""
         return self.predict_batch_dispatch(model, queries)()
@@ -447,12 +445,11 @@ class ALSAlgorithm(JaxAlgorithm):
     ):
         """Pipelined serving: dispatch the batched top-k kernel now, fetch in
         the returned finalize — the query server overlaps batch n's transport
-        with batch n+1's dispatch (ops.als.ServingIndex.serve_batch_async).
+        with batch n+1's dispatch (ops.topk.ServingIndex.serve_batch_async).
         User indices are assembled into a reusable staging buffer
         (ops.topk.scratch) and only the packed [B,2,k] result is fetched."""
         from predictionio_tpu.obs.jaxprof import annotate
         from predictionio_tpu.ops import topk
-        from predictionio_tpu.ops.als import next_pow2
 
         results: list[PredictedResult | None] = [None] * len(queries)
         batch_pos: list[int] = []
@@ -463,7 +460,7 @@ class ALSAlgorithm(JaxAlgorithm):
             if uidx is None:
                 results[i] = PredictedResult(())
             elif q.black_list:
-                # per-query device mask: single-query path, but deferred to
+                # per-query device mask: a batch of one, deferred to
                 # finalize — a blocking predict here would stall the shared
                 # dispatch thread for a full device round-trip
                 masked_pos.append(i)
@@ -479,7 +476,7 @@ class ALSAlgorithm(JaxAlgorithm):
             # ~log2(max_batch) programs, pre-warmed via
             # ServingIndex.warmup_buckets
             k = min(max(queries[i].num for i in batch_pos), n_items)
-            kk = min(next_pow2(k), n_items)
+            kk = min(topk.next_pow2(k), n_items)
             bucket = topk.batch_bucket(len(batch_pos))
             # pad rows serve user 0, dropped on unpack
             idxs = topk.scratch().zeros("rec.uidx", (bucket,), np.int32)

@@ -442,7 +442,7 @@ class _ALSBase(JaxAlgorithm):
         kk = 0
         if rows:
             # pow2 buckets on batch/query-width/k keep the compile universe
-            # at ~log^3 programs (same discipline as ops/als warmup_buckets)
+            # at ~log^3 programs (same discipline as ServingIndex.warmup_buckets)
             b = topk.batch_bucket(len(rows))
             qcap = topk.next_pow2(max_q)
             pool = topk.scratch()

@@ -187,10 +187,9 @@ class TwoTowerModelState(SanityCheck):
             import functools
 
             import jax
-            import jax.numpy as jnp
 
             from predictionio_tpu.models.twotower.model import TwoTower as _TT
-            from predictionio_tpu.ops.topk import pack_batch
+            from predictionio_tpu.ops.topk import select_top_k
 
             mdl = self.model()
 
@@ -201,22 +200,21 @@ class TwoTowerModelState(SanityCheck):
                 u = mdl.apply(
                     {"params": params}, uidx, hist, method=_TT.embed_users
                 )
-                scores = u @ items.T  # [B, n_items] on the MXU
-                s, i = jax.lax.top_k(scores, k)
-                return pack_batch(s, i)
+                with jax.named_scope("score"):
+                    scores = u @ items.T  # [B, n_items] on the MXU
+                return select_top_k(scores, k)
 
             self._serve_fn = _serve
-        from predictionio_tpu.ops.als import upload
+        from predictionio_tpu.ops.topk import upload
 
         # upload() COPIES: uidx/hist live in reusable scratch buffers the
         # dispatcher overwrites for the next batch while this one is in
         # flight (jnp.asarray would alias them on the CPU backend)
-        hist_d = upload(hist) if hist is not None else None
         return self._serve_fn(
             self.device_params(),
             self.device_items(),
             upload(uidx),
-            hist_d,
+            upload(hist),
             k,
         )
 
@@ -240,10 +238,9 @@ class TwoTowerModelState(SanityCheck):
                 )
 
             self._embed_fn = _embed
-        from predictionio_tpu.ops.als import upload
+        from predictionio_tpu.ops.topk import upload
 
-        hist_d = upload(hist) if hist is not None else None
-        return self._embed_fn(self.device_params(), upload(uidx), hist_d)
+        return self._embed_fn(self.device_params(), upload(uidx), upload(hist))
 
     def __getstate__(self):
         return {

@@ -5,7 +5,7 @@ with static shapes — which means XLA can *price* it without running it:
 ``jitted.lower(...).compile().cost_analysis()`` returns the compiler's
 own flops and bytes-accessed accounting for the optimized HLO. This
 module lowers one representative shape per registered bucket family
-(ops/topk dot/gather/fused, ann search, twotower towers, als
+(ops/topk's three programs, ann search, twotower towers, als
 sweep/solve), reads that accounting into per-kernel **arithmetic
 intensity** (flops/byte), and projects it onto a device roofline
 (``max(flops/peak_flops, bytes/peak_bw)``) to get a per-model
@@ -17,10 +17,8 @@ an analytic device anchor *before* any hardware window opens (ROADMAP
 item 5: "no hardware window is wasted"). ALX (PAPERS.md) sized its TPU
 ALS from exactly this per-kernel flops/bytes accounting.
 
-Consumers: ``pio doctor --roofline`` (JSON report), ``bench.py``'s
-``roofline_*`` BENCH fields (gated by ``--compare``), and the PERF doc.
-Imports jax lazily — the module is importable (and listable) from
-stdlib-light CLI paths.
+Consumer: ``pio doctor --roofline`` (JSON report). Imports jax lazily —
+the module is importable (and listable) from stdlib-light CLI paths.
 """
 
 from __future__ import annotations
@@ -46,16 +44,16 @@ class DeviceSpec:
         return dataclasses.asdict(self)
 
 
-# the devices this repo's claims are priced against; cpu-host is the
-# sandbox floor (one modern server socket, DDR bandwidth) so the CPU
-# numbers the CI measures can be read against the same model
+# the devices a report can be priced against (tpu-v5e, the chip this
+# repository is measured on, carries benchmark/peaks.json's figures);
+# cpu-host is the sandbox floor (one modern server socket, DDR bandwidth)
 DEVICE_SPECS: dict[str, DeviceSpec] = {
     "tpu-v4": DeviceSpec("tpu-v4", 275e12, 1.2e12, 3.22),
     "tpu-v5e": DeviceSpec("tpu-v5e", 197e12, 0.82e12, 1.20),
     "tpu-v5p": DeviceSpec("tpu-v5p", 459e12, 2.77e12, 4.20),
     "cpu-host": DeviceSpec("cpu-host", 1.0e12, 0.1e12, 0.40),
 }
-DEFAULT_DEVICE = "tpu-v4"
+DEFAULT_DEVICE = "tpu-v5e"
 
 
 def _struct_bytes(tree) -> int:
@@ -118,35 +116,31 @@ def _lower_cost(
 def topk_costs(
     *, n: int = 4096, f: int = 32, b: int = 32, q: int = 8, k: int = 10
 ) -> tuple[list[dict[str, Any]], int]:
-    """The fused score->mask->top-k serving bucket (ops/topk)."""
+    """The score->mask->top-k serving programs (ops/topk.PROGRAMS), each
+    with the operands its engines pass: the by-index front its all-true
+    [n] mask, the other two a [B, n] mask and no weights."""
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops import topk as T
+    from predictionio_tpu.ops import topk
 
     S = jax.ShapeDtypeStruct
     table = S((n, f), jnp.float32)
-    vecs = S((b, f), jnp.float32)
     mask = S((b, n), jnp.bool_)
-    weights = S((n,), jnp.float32)
-    qidx = S((b, q), jnp.int32)
-    qweight = S((b, q), jnp.float32)
-    scores = S((b, n), jnp.float32)
-    recipes = [
-        ("dot_top_k", T._dot_top_k, (table, vecs, mask)),
-        ("dot_top_k_unmasked", T._dot_top_k_unmasked, (table, vecs)),
-        ("dot_top_k_weighted", T._dot_top_k_weighted, (table, vecs, mask, weights)),
-        ("gather_sum_top_k", T._gather_sum_top_k, (table, qidx, qweight, mask)),
-        (
-            "gather_sum_top_k_weighted",
-            T._gather_sum_top_k_weighted,
-            (table, qidx, qweight, mask, weights),
+    operands = {
+        "_serve_by_index_batch": (
+            S((b,), jnp.int32), table, table, S((n,), jnp.bool_),
         ),
-        ("mask_top_k", T._mask_top_k, (scores, mask)),
-    ]
+        "_dot_top_k": (table, S((b, f), jnp.float32), mask, None),
+        "_gather_sum_top_k": (
+            table, S((b, q), jnp.int32), S((b, q), jnp.float32), mask, None,
+        ),
+    }
     return [
-        _lower_cost("topk", name, fn, args, {"k": k})
-        for name, fn, args in recipes
+        _lower_cost(
+            "topk", fn.__name__.lstrip("_"), fn, operands[fn.__name__], {"k": k}
+        )
+        for fn in topk.PROGRAMS
     ], b
 
 
@@ -334,28 +328,6 @@ def analyze(
     return report
 
 
-def bench_fields(
-    families: list[str] | None = None,
-    device: str | DeviceSpec = DEFAULT_DEVICE,
-) -> dict[str, Any]:
-    """Flatten :func:`analyze` into the ``roofline_*`` BENCH JSON fields
-    (shared by ``bench.py`` and the contract tests): per family, total
-    gigaflops/megabytes, arithmetic intensity, and the per-1k-queries
-    price; plus the device the projection priced against."""
-    report = analyze(families=families, device=device)
-    fields: dict[str, Any] = {"roofline_device": report["device"]["name"]}
-    for fam, entry in report["families"].items():
-        fields[f"roofline_{fam}_gflops"] = round(entry["totalFlops"] / 1e9, 6)
-        fields[f"roofline_{fam}_mbytes"] = round(entry["totalBytes"] / 1e6, 6)
-        fields[f"roofline_{fam}_ai"] = round(entry["arithmeticIntensity"], 4)
-        fields[f"roofline_{fam}_cost_per_1k_usd"] = round(
-            entry["costPer1kQueriesUsd"], 10
-        )
-    for fam, err in report["errors"].items():
-        fields[f"roofline_{fam}_error"] = err[:200]
-    return fields
-
-
 __all__ = [
     "DEFAULT_DEVICE",
     "DEVICE_SPECS",
@@ -364,7 +336,6 @@ __all__ = [
     "analyze",
     "als_costs",
     "ann_costs",
-    "bench_fields",
     "roofline_time_s",
     "topk_costs",
     "twotower_costs",

@@ -293,7 +293,7 @@ class CompileWatcher:
     def watch_package(self) -> int:
         """Scan loaded ``<package_prefix>`` modules for module-level jitted
         functions (the framework keeps its serving kernels there — e.g.
-        ``ops/als.py``'s top-k programs). Returns how many are watched."""
+        ``ops/topk.py``'s three programs). Returns how many are watched."""
         for mod_name, module in list(sys.modules.items()):
             if module is None or not mod_name.startswith(self.package_prefix):
                 continue

@@ -6,12 +6,7 @@ similar-product, NaiveBayes in classification) with XLA-compiled JAX on
 sharded arrays.
 """
 
-from predictionio_tpu.ops.als import (
-    ALSConfig,
-    ServingIndex,
-    als_train,
-    predict_scores,
-    top_k_items,
-)
+from predictionio_tpu.ops.als import ALSConfig, als_train
+from predictionio_tpu.ops.topk import ServingIndex
 
-__all__ = ["ALSConfig", "ServingIndex", "als_train", "predict_scores", "top_k_items"]
+__all__ = ["ALSConfig", "ServingIndex", "als_train"]

@@ -795,284 +795,9 @@ def als_train(
             timings["upload_s"] = t_upload - t_pack
             timings["build_s"] = t_build - t_upload
             timings["device_s"] = time.perf_counter() - t_build
-            # block-table shapes, for the HBM bytes-moved model
-            # (solver_hbm_bytes_per_iter): nb = blocks per side, d = block
-            # width
+            # block-table shapes, for a caller's model of the bytes an
+            # iteration moves: nb = blocks per side, d = block width
             timings["nb_u"] = int(dev[0].shape[0])
             timings["nb_i"] = int(dev[4].shape[0])
             timings["d"] = d
         return user_f[:n_users], item_f[:n_items]
-
-
-def solver_hbm_bytes_per_iter(
-    nb_u: int,
-    nb_i: int,
-    d: int,
-    f: int,
-    n_users: int,
-    n_items: int,
-    *,
-    gather_dtype: str = "f32",
-    solver: str = "cg",
-    implicit: bool = False,
-) -> int:
-    """Mandatory HBM traffic of one ALS iteration (both half-solves), in
-    bytes — the roofline denominator for ``als_hbm_util`` (bytes/iter ÷
-    measured iter time ÷ HBM bandwidth). This models the traffic the
-    formulation REQUIRES; the measured iteration can only be slower, so
-    util > 1 means the timing probe is broken, and util well below ~0.5
-    means the implementation (not the memory system) is the bottleneck.
-
-    Per half-solve with NB [d]-wide blocks over n_ent(+1 dummy) entities:
-
-    - block-stream reads: cols int32 + vals f32 + w int8 + the factor-row
-      gather (f x 4 bytes, or f x 2 under ``gather_dtype="bf16"``) —
-      NB*d*(9 + f*gb);
-    - Gram scatter-adds (read+modify+write of the [f,f]+[f]+[1] block
-      results): 2*NB*(f^2+f+1)*4;
-    - A-matrix assembly/regularization pass: 2*n_ent*f^2*4;
-    - cg solve: (f+4) batched matvecs re-reading A from HBM —
-      (f+4)*n_ent*f^2*4 — plus ~8 [f]-vector reads/writes per cg step;
-      cholesky is modeled as ~2 passes over A;
-    - implicit mode adds one shared-gram read of the opposite factors.
-    """
-    gb = 2 if gather_dtype == "bf16" else 4
-    total = 0
-    for nb, n_ent, n_opp in (
-        (nb_u, n_users + 1, n_items + 1),
-        (nb_i, n_items + 1, n_users + 1),
-    ):
-        stream = nb * d * (9 + f * gb)
-        gram_scatter = 2 * nb * (f * f + f + 1) * 4
-        assemble = 2 * n_ent * f * f * 4
-        if solver == "cg":
-            solve = (f + 4) * n_ent * (f * f + 8 * f) * 4
-        else:
-            solve = 2 * n_ent * f * f * 4
-        shared = n_opp * f * 4 if implicit else 0
-        total += stream + gram_scatter + assemble + solve + shared
-    return int(total)
-
-
-# ---------------------------------------------------------------------------
-# Serving-side scoring
-# ---------------------------------------------------------------------------
-#
-# The hot path (BASELINE's <10ms p50 target) is engineered for minimum
-# host<->device round trips, because every transfer is a dispatch:
-#   - factor tables stay resident on device (``ServingIndex``),
-#   - the query uploads ONE int32 scalar (the user index); the factor gather
-#     happens on device,
-#   - scores and indices come back in ONE packed int32 fetch. The scores ride
-#     as a bitcast (float32 bits are preserved exactly in an int32 lane);
-#     packing the *indices* as float32 would be wrong — small indices bitcast
-#     to denormal floats, which XLA flushes to zero.
-
-
-def _pack(scores, idx):
-    return jnp.stack([lax.bitcast_convert_type(scores, jnp.int32), idx])
-
-
-def _unpack(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    return packed[0].view(np.float32), packed[1]
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _serve_by_index(uidx, user_factors, item_factors, mask, k: int):
-    with jax.named_scope("gather"):
-        user_vec = user_factors[uidx]
-    with jax.named_scope("score"):
-        scores = item_factors @ user_vec  # [n_items]
-        scores = jnp.where(mask, scores, -jnp.inf)
-    with jax.named_scope("topk"):
-        return _pack(*lax.top_k(scores, k))
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _serve_by_index_batch(uidxs, user_factors, item_factors, mask, k: int):
-    # the scopes name each HLO operation's op_name
-    # (jit(_serve_by_index_batch)/score/dot_general), so a trace can follow
-    # the product and the selection from build to build
-    with jax.named_scope("gather"):
-        user_vecs = user_factors[uidxs]
-    with jax.named_scope("score"):
-        scores = user_vecs @ item_factors.T  # [B, n_items] on the MXU
-        scores = jnp.where(mask[None, :], scores, -jnp.inf)
-    with jax.named_scope("topk"):
-        s, i = lax.top_k(scores, k)
-        return jnp.stack([lax.bitcast_convert_type(s, jnp.int32), i], axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _topk_scores_packed(user_vec, item_factors, mask, k: int):
-    scores = item_factors @ user_vec
-    scores = jnp.where(mask, scores, -jnp.inf)
-    return _pack(*lax.top_k(scores, k))
-
-
-def predict_scores(user_vec: jax.Array, item_factors: jax.Array) -> jax.Array:
-    return item_factors @ user_vec
-
-
-def top_k_items(
-    user_vec: jax.Array,
-    item_factors: jax.Array,
-    k: int,
-    mask: jax.Array | None = None,
-) -> tuple[np.ndarray, np.ndarray]:
-    """One-shot top-k for an explicit user vector; single packed fetch.
-    ``mask`` False = excluded item. Prefer ``ServingIndex`` on the serving
-    path — it also keeps the user table resident."""
-    if mask is None:
-        mask = jnp.ones((item_factors.shape[0],), bool)
-    # pio-lint: disable=train-unaccounted-sync -- serving-path fetch, accounted by the request waterfall
-    packed = np.asarray(_topk_scores_packed(user_vec, item_factors, mask, k))
-    return _unpack(packed)
-
-
-def next_pow2(n: int) -> int:
-    """Bucket-rounding rule shared by the batched predict path and
-    ``ServingIndex.warmup_buckets`` — they must agree or warmed shapes won't
-    match served shapes and serve-time compiles come back."""
-    return 1 << max(0, n - 1).bit_length()
-
-
-def upload(x, dtype=None):
-    """Host->device upload that GUARANTEES the device buffer is decoupled
-    from the host array.
-
-    On the CPU backend ``jnp.asarray(host_numpy)`` is ZERO-COPY: the jax
-    array aliases the numpy memory. Every async serving dispatch that
-    stages its batch in a reused ``ops.topk.ScratchBuffers`` slot then
-    races the in-flight kernel against the next batch's assembly — the
-    observed failure (offline double-buffer pipeline, CPU backend) was
-    batch N's first rows answering with batch N+1's users, a torn read of
-    the overwritten staging buffer. ``copy=True`` restores the contract
-    the scratch pools are built on: the host buffer is reusable the
-    moment the dispatch call returns. Device arrays pass through
-    untouched (immutable, nothing to decouple); on non-CPU backends the
-    H2D transfer is a copy regardless."""
-    if isinstance(x, jax.Array):
-        return x
-    # pio-lint: disable=train-unaccounted-sync,serving-host-roundtrip -- host staging array (device handles returned above), never a device round-trip
-    arr = np.asarray(x) if dtype is None else np.asarray(x, dtype)
-    return jnp.asarray(arr, copy=True)
-
-
-class ServingIndex:
-    """Device-resident factor tables with index-addressed top-k serve.
-
-    The TPU replacement for the reference's in-JVM model broadcast
-    (``CreateServer.scala:196-200`` deserializes the kryo model into the
-    server heap; here the model lives in HBM and every query is one compiled
-    kernel). Per-query cost: one int32 upload + one [2,k] int32 fetch
-    (row 0 = float32 score bits, row 1 = item indices).
-    """
-
-    def __init__(self, user_factors, item_factors):
-        self.user_factors = jnp.asarray(user_factors)
-        self.item_factors = jnp.asarray(item_factors)
-        self._full_mask = jnp.ones((self.item_factors.shape[0],), bool)
-
-    @property
-    def n_users(self) -> int:
-        return self.user_factors.shape[0]
-
-    @property
-    def n_items(self) -> int:
-        return self.item_factors.shape[0]
-
-    def warmup(self, k: int) -> None:
-        # pio-lint: disable=train-unaccounted-sync -- deploy-time warmup, deliberately synchronous
-        jax.block_until_ready(
-            _serve_by_index(
-                jnp.int32(0), self.user_factors, self.item_factors, self._full_mask, k
-            )
-        )
-
-    def warmup_buckets(self, k: int, max_batch: int) -> None:
-        """Pre-compile every power-of-two batch bucket up to ``max_batch``
-        for top-``k`` (k rounded up to its own bucket). The batched predict
-        path buckets ragged batch sizes to powers of two; compiling them all
-        at deploy time keeps the first ragged burst from paying a compile."""
-        kk = min(next_pow2(k), self.n_items)
-        b = 1
-        handles = []
-        # the dispatch path buckets len(batch) <= max_batch up to
-        # next_pow2(max_batch), so that is the range to warm (warming only
-        # to max_batch would leave e.g. bucket 128 cold for max_batch=100)
-        while b <= next_pow2(max_batch):
-            handles.append(
-                _serve_by_index_batch(
-                    # through upload(), as serve_batch_async stages its
-                    # indices: its copy is a program of its own for every
-                    # bucket, and a bucket's first batch would load it
-                    upload(np.zeros((b,), np.int32)),
-                    self.user_factors,
-                    self.item_factors,
-                    self._full_mask,
-                    kk,
-                )
-            )
-            b *= 2
-        # pio-lint: disable=train-unaccounted-sync -- deploy-time warmup, deliberately synchronous
-        jax.block_until_ready(handles)
-
-    def serve(
-        self, user_index: int, k: int, mask: jax.Array | np.ndarray | None = None
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Top-k (scores, item indices) for one user index."""
-        m = self._full_mask if mask is None else jnp.asarray(mask)
-        # pio-lint: disable=train-unaccounted-sync -- serving-path fetch, accounted by the request waterfall
-        packed = np.asarray(
-            _serve_by_index(
-                jnp.int32(user_index), self.user_factors, self.item_factors, m, k
-            )
-        )
-        return _unpack(packed)
-
-    def serve_batch(
-        self,
-        user_indices: np.ndarray,
-        k: int,
-        mask: jax.Array | np.ndarray | None = None,
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Micro-batched serve: [B] indices -> ([B,k] scores, [B,k] items).
-        This is the throughput path an async query server batches into."""
-        return self.unpack_batch(
-            # pio-lint: disable=train-unaccounted-sync -- serving-path fetch, accounted by the request waterfall
-            np.asarray(self.serve_batch_async(user_indices, k, mask))
-        )
-
-    def serve_batch_async(
-        self,
-        user_indices: np.ndarray | jax.Array,
-        k: int,
-        mask: jax.Array | np.ndarray | None = None,
-    ) -> jax.Array:
-        """Non-blocking batched serve: dispatches the kernel and returns the
-        packed [B,2,k] int32 device array WITHOUT fetching it. An async query
-        server dispatches batch n+1 while fetching batch n's result, so
-        device work and transport overlap; decode with ``unpack_batch``."""
-        m = self._full_mask if mask is None else upload(mask)
-        if isinstance(user_indices, jax.Array):
-            # already on device: a np.asarray round-trip would block on a
-            # D2H fetch and defeat the non-blocking contract
-            idxs = user_indices.astype(jnp.int32)
-        else:
-            # upload() COPIES: callers stage indices in reusable scratch
-            # buffers and overwrite them for the next batch while this
-            # batch's kernel is still in flight
-            idxs = upload(user_indices, np.int32)
-        return _serve_by_index_batch(
-            idxs, self.user_factors, self.item_factors, m, k
-        )
-
-    @staticmethod
-    def unpack_batch(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """Decode a fetched [B,2,k] packed result into ([B,k] float32 scores,
-        [B,k] int32 item indices)."""
-        return (
-            np.ascontiguousarray(packed[:, 0, :]).view(np.float32),
-            packed[:, 1, :],
-        )

@@ -1,41 +1,48 @@
-"""Shared fused score->mask->top-k serving kernels.
+"""The serving ending: a score matrix becomes k ids and scores on the wire.
 
-Every serving engine used to run its own ending: the recommendation
-template already kept score+select fused on device (``ops/als.ServingIndex``,
-the ALX recipe — batched matmul feeding ``lax.top_k``, one packed [B,2,k]
-int32 fetch), while twotower / similarproduct / ecommerce / recommendeduser
-fetched the FULL score vector to host and argsorted there. That is
-O(batch * corpus) floats from device to host per batch; through this
-module it becomes O(batch * k) for everyone.
+Every engine's device answer ends here, so a change to the ending (a
+narrower table, a selection by blocks) is made once and measured on all of
+them.
 
-Design (mirrors ops/als):
-  - score + mask + select compile into ONE jitted program per
-    (batch-bucket, k-bucket) shape; the resident factor table never moves.
-  - results come back as a single packed int32 fetch: row 0 carries the
-    float32 score bits via ``bitcast_convert_type`` (packing indices as
-    floats would flush small indices to denormal zero), row 1 the indices.
-  - per-batch host buffers (query vectors, gathered indices, masks) are
-    DONATED to the kernel (``donate_argnums``): XLA may reuse their device
-    allocation for the output instead of holding both live. The resident
-    table argument is never donated. Donation is a no-op on the CPU
-    backend; the warning it would log is filtered below.
-  - ``ScratchBuffers`` gives the dispatch path preallocated, reusable host
-    staging buffers (thread-local: the micro-batcher's dispatch thread and
-    the shadow/stable-retry threads each get their own pool), so batch
-    assembly writes queries straight into a recycled numpy buffer instead
-    of allocating per window. Reuse is only sound because every staging
-    upload goes through ``ops.als.upload`` (re-exported here), which
-    COPIES: ``jnp.asarray`` on the CPU backend aliases host numpy memory,
-    and an aliased buffer overwritten for batch N+1 while batch N's
-    kernel is still in flight serves batch N the wrong queries.
-  - ``batch_bucket`` is the ONE place a serving batch is rounded up to its
-    power-of-two bucket, and it counts what the rounding costs: the rows
-    launched against the rows that are queries
-    (``pio_serve_rows_total{kind}``, ``pio_serve_batches_total{bucket}``).
-  - ``host_top_k`` is the sanctioned HOST ending for score vectors that
-    are host-born in the first place (popularity counts, cooccurrence
-    maps). It lives here so the ``serving-host-roundtrip`` lint rule can
-    hold engines to "no argsort outside the fused helper".
+  - ONE body, :func:`select_top_k`: mask -> (per-item weights) ->
+    ``lax.top_k`` -> pack, under the ``named_scope``s ``score`` and ``topk``.
+    It is a plain function, so an engine that composes a program of its own
+    (the two-tower's tower -> scores) ends in it too.
+  - THREE jitted fronts that make the scores and end in the body
+    (``PROGRAMS``): ``_serve_by_index_batch`` (gather user rows by index
+    from a resident table, then the product: ``ServingIndex``),
+    ``_dot_top_k`` (query vectors given: ``dot_top_k_async``) and
+    ``_gather_sum_top_k`` (gather, weight and sum the query rows:
+    ``gather_sum_top_k_async``). An operand a caller leaves out (mask,
+    weights) is ``None`` in the SAME jitted function: an absent operand is
+    an empty pytree and jit specialises on it. Each compiles once per
+    (batch bucket, k bucket); the resident tables never move and are never
+    donated, the per-batch uploads are (``donate_argnums``; a no-op on the
+    CPU backend, whose warning is filtered below).
+  - The wire format: one packed [B, 2, k] int32 result, row 0 the float32
+    score bits (``bitcast_convert_type``: exact in an int32 lane), row 1
+    the indices (:func:`pack_batch`). Packing the INDICES as floats would
+    be wrong: small indices are denormal floats and XLA flushes them to
+    zero. :func:`unpack_batch` is the one decode.
+  - :func:`fetch_topk` is the one device->host fetch of the serving path:
+    O(batch * k), never O(batch * corpus). The ``serving-host-roundtrip``
+    lint rule holds engines to it.
+  - :func:`upload` is the one host->device staging call and it COPIES.
+    ``ScratchBuffers`` gives the dispatch path reusable host staging buffers
+    (thread-local: the micro-batcher's dispatch thread and the
+    shadow/stable-retry threads each get their own pool); reuse is only
+    sound because of that copy: ``jnp.asarray`` on the CPU backend aliases
+    host numpy memory, and an aliased buffer overwritten for batch N+1
+    while batch N's kernel is still in flight serves batch N the wrong
+    queries.
+  - :func:`batch_bucket` is the ONE place a serving batch is rounded up to
+    its power-of-two bucket (:func:`next_pow2`, which the warmups share),
+    and it counts what the rounding costs: the rows launched against the
+    rows that are queries (``pio_serve_rows_total{kind}``,
+    ``pio_serve_batches_total{bucket}``).
+  - :func:`host_top_k` is the HOST ending for score vectors that are
+    host-born in the first place (popularity counts, cooccurrence maps):
+    nothing device-resident to fuse with.
 """
 
 from __future__ import annotations
@@ -50,22 +57,24 @@ import numpy as np
 from jax import lax
 
 from predictionio_tpu.obs.jaxprof import annotate
-from predictionio_tpu.ops.als import next_pow2, upload
 
 __all__ = [
+    "PROGRAMS",
+    "ScratchBuffers",
+    "ServingIndex",
     "batch_bucket",
     "bucket_counts",
     "dot_top_k_async",
-    "gather_sum_top_k_async",
-    "fused_top_k_async",
     "fetch_topk",
+    "gather_sum_top_k_async",
     "host_top_k",
-    "warmup_pow2_buckets",
+    "next_pow2",
     "pack_batch",
     "scratch",
+    "select_top_k",
+    "unpack_batch",
     "upload",
-    "ScratchBuffers",
-    "next_pow2",
+    "warmup_pow2_buckets",
 ]
 
 # donation is unsupported on the CPU backend; jax warns once per compiled
@@ -77,6 +86,13 @@ __all__ = [
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
+
+
+def next_pow2(n: int) -> int:
+    """The bucket-rounding rule the dispatch paths and the warmups share:
+    they must agree or warmed shapes won't match served shapes and
+    serve-time compiles come back."""
+    return 1 << max(0, n - 1).bit_length()
 
 
 # what bucketing launched, process-wide ({bucket: [batches, real rows]}): the
@@ -110,137 +126,227 @@ def bucket_counts() -> tuple[int, int, dict[int, int]]:
     )
 
 
+def upload(x, dtype=None):
+    """Host->device upload that GUARANTEES the device buffer is decoupled
+    from the host array.
+
+    On the CPU backend ``jnp.asarray(host_numpy)`` is ZERO-COPY: the jax
+    array aliases the numpy memory. Every async serving dispatch that
+    stages its batch in a reused ``ScratchBuffers`` slot then races the
+    in-flight kernel against the next batch's assembly — the observed
+    failure (offline double-buffer pipeline, CPU backend) was batch N's
+    first rows answering with batch N+1's users, a torn read of the
+    overwritten staging buffer. ``copy=True`` restores the contract the
+    scratch pools are built on: the host buffer is reusable the moment the
+    dispatch call returns. Device arrays pass through untouched (immutable,
+    nothing to decouple), and so does ``None`` (an operand the caller left
+    out); on non-CPU backends the H2D transfer is a copy regardless."""
+    if x is None or isinstance(x, jax.Array):
+        return x
+    # pio-lint: disable=train-unaccounted-sync,serving-host-roundtrip -- host staging array (device handles returned above), never a device round-trip
+    arr = np.asarray(x) if dtype is None else np.asarray(x, dtype)
+    return jnp.asarray(arr, copy=True)
+
+
+# ---------------------------------------------------------------------------
+# the wire format
+# ---------------------------------------------------------------------------
+
+
 def pack_batch(scores: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """[B,k] scores + [B,k] indices -> packed [B,2,k] int32 (score bits in
-    row 0 — same wire idiom as ops/als). Public so engines composing their
-    own device program (e.g. the two-tower forward) can end it on the
-    same one-fetch wire format ``fetch_topk`` decodes."""
+    row 0). Public for the programs that select over something other than
+    a [B, n] score matrix (``ann/search``'s gathered candidates) and still
+    end on the wire format ``fetch_topk`` decodes."""
     return jnp.stack([lax.bitcast_convert_type(scores, jnp.int32), idx], axis=1)
 
 
-_pack_batch = pack_batch  # internal alias
+def unpack_batch(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """The one decode of a fetched [B,2,k] packed result: ([B,k] float32
+    scores, [B,k] int32 indices)."""
+    return (
+        np.ascontiguousarray(packed[:, 0, :]).view(np.float32),
+        packed[:, 1, :],
+    )
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k",), donate_argnums=(1, 2)
-)
-def _dot_top_k(table, vecs, mask, k: int):
-    """scores = vecs @ table.T, masked, top-k. table [n,f] resident;
-    vecs [B,f] and mask [B,n] are per-batch uploads (donated)."""
-    scores = vecs @ table.T  # [B, n] on the MXU
-    scores = jnp.where(mask, scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return _pack_batch(s, i)
+def fetch_topk(handle) -> tuple[np.ndarray, np.ndarray]:
+    """The ONE sanctioned device->host fetch on the serving path: a packed
+    [B,2,k] int32 result — O(batch*k), never O(batch*corpus).
+    Returns ([B,k] float32 scores, [B,k] int32 indices)."""
+    with annotate("pio:fetch.block"):  # the host blocked on the device
+        # pio-lint: disable=serving-host-roundtrip -- the ONE sanctioned fetch: O(batch*k) packed result, accounted by the request waterfall
+        packed = np.asarray(handle)
+    return unpack_batch(packed)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k",), donate_argnums=(1,)
-)
-def _dot_top_k_unmasked(table, vecs, k: int):
-    scores = vecs @ table.T
-    s, i = lax.top_k(scores, k)
-    return _pack_batch(s, i)
+# ---------------------------------------------------------------------------
+# the body and its three fronts
+# ---------------------------------------------------------------------------
+
+
+def select_top_k(scores, k: int, mask=None, weights=None):
+    """The one ending, traced inside a jitted program: ``scores`` [B, n]
+    times ``weights`` ([n] per-item multiplier, or None), entries whose
+    ``mask`` ([n] or [B, n] bool, or None) is False sent to -inf, the k
+    best of each row packed as [B, 2, k]. The scopes name each HLO
+    operation's op_name (``jit(_serve_by_index_batch)/topk/...``), so a
+    trace can follow the product and the selection from build to build; a
+    front puts its own product under ``score`` as well."""
+    with jax.named_scope("score"):
+        if weights is not None:
+            scores = scores * weights[None, :]
+        if mask is not None:
+            scores = jnp.where(
+                mask if mask.ndim == 2 else mask[None, :], scores, -jnp.inf
+            )
+    with jax.named_scope("topk"):
+        return pack_batch(*lax.top_k(scores, k))
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _serve_by_index_batch(uidxs, user_factors, item_factors, mask, k: int):
+    """Both tables resident; uidxs [B] int32 is the whole upload."""
+    with jax.named_scope("gather"):
+        user_vecs = user_factors[uidxs]
+    with jax.named_scope("score"):
+        scores = user_vecs @ item_factors.T  # [B, n_items] on the MXU
+    return select_top_k(scores, k, mask)
 
 
 @functools.partial(
     jax.jit, static_argnames=("k",), donate_argnums=(1, 2, 3)
 )
-def _dot_top_k_weighted(table, vecs, mask, weights, k: int):
-    """The adjust-score variant: a per-item weight vector multiplies the
-    scores before selection (weights ride up per call, donated)."""
-    scores = (vecs @ table.T) * weights[None, :]
-    scores = jnp.where(mask, scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return pack_batch(s, i)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k",), donate_argnums=(1, 2, 3)
-)
-def _gather_sum_top_k(table, qidx, qweight, mask, k: int):
-    """The summed-similarity pattern (similarproduct / recommendeduser):
-    gather the query rows, matmul against the whole table, sum over the
-    query axis, mask, select. table [n,f]; qidx [B,Q] int32 (pad rows point
-    at row 0 and are zero-weighted); qweight [B,Q] float32; mask [B,n]."""
-    q = table[qidx] * qweight[..., None]  # [B, Q, f]
-    scores = jnp.einsum("nf,bqf->bn", table, q)
-    scores = jnp.where(mask, scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return pack_batch(s, i)
+def _dot_top_k(table, vecs, mask, weights, k: int):
+    """table [n,f] resident; vecs [B,f], mask and weights are per-batch
+    uploads (donated)."""
+    with jax.named_scope("score"):
+        scores = vecs @ table.T  # [B, n] on the MXU
+    return select_top_k(scores, k, mask, weights)
 
 
 @functools.partial(
     jax.jit, static_argnames=("k",), donate_argnums=(1, 2, 3, 4)
 )
-def _gather_sum_top_k_weighted(table, qidx, qweight, mask, weights, k: int):
-    q = table[qidx] * qweight[..., None]
-    scores = jnp.einsum("nf,bqf->bn", table, q) * weights[None, :]
-    scores = jnp.where(mask, scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return pack_batch(s, i)
+def _gather_sum_top_k(table, qidx, qweight, mask, weights, k: int):
+    """The summed-similarity pattern (similarproduct / recommendeduser):
+    gather the query rows, matmul against the whole table, sum over the
+    query axis. table [n,f]; qidx [B,Q] int32 (pad rows point at row 0 and
+    are zero-weighted); qweight [B,Q] float32."""
+    with jax.named_scope("gather"):
+        q = table[qidx] * qweight[..., None]  # [B, Q, f]
+    with jax.named_scope("score"):
+        scores = jnp.einsum("nf,bqf->bn", table, q)
+    return select_top_k(scores, k, mask, weights)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k",), donate_argnums=(0, 1)
-)
-def _mask_top_k(scores, mask, k: int):
-    scores = jnp.where(mask, scores, -jnp.inf)
-    s, i = lax.top_k(scores, k)
-    return _pack_batch(s, i)
+# every jitted program of this module (obs/costmodel prices exactly these)
+PROGRAMS = (_serve_by_index_batch, _dot_top_k, _gather_sum_top_k)
 
 
 def dot_top_k_async(table, vecs, mask, k: int, weights=None):
     """Dispatch (no fetch) the fused matmul+mask+top-k: ``table`` [n,f]
-    device-resident, ``vecs`` [B,f], ``mask`` [B,n] bool or None,
+    device-resident, ``vecs`` [B,f], ``mask`` [n] or [B,n] bool or None,
     ``weights`` an optional [n] per-item score multiplier. Returns the
     packed [B,2,k] device handle; decode with :func:`fetch_topk`."""
-    vecs_d = upload(vecs, np.float32)
-    if weights is not None:
-        m = (
-            upload(mask)
-            if mask is not None
-            else jnp.ones((vecs_d.shape[0], table.shape[0]), bool)
-        )
-        return _dot_top_k_weighted(
-            table, vecs_d, m, upload(weights, np.float32), k
-        )
-    if mask is None:
-        return _dot_top_k_unmasked(table, vecs_d, k)
-    return _dot_top_k(table, vecs_d, upload(mask), k)
+    return _dot_top_k(
+        table,
+        upload(vecs, np.float32),
+        upload(mask),
+        upload(weights, np.float32),
+        k,
+    )
 
 
 def gather_sum_top_k_async(table, qidx, qweight, mask, k: int, weights=None):
-    """Dispatch the gather->sum->mask->top-k kernel; see
-    :func:`_gather_sum_top_k` for shapes. Returns the packed handle."""
-    qidx_d = upload(qidx, np.int32)
-    qw_d = upload(qweight, np.float32)
-    mask_d = upload(mask)
-    if weights is not None:
-        return _gather_sum_top_k_weighted(
-            table, qidx_d, qw_d, mask_d, upload(weights, np.float32), k
+    """Dispatch the gather->sum->mask->top-k program; see
+    :func:`_gather_sum_top_k` for shapes, :func:`dot_top_k_async` for
+    ``mask`` and ``weights``. Returns the packed handle."""
+    return _gather_sum_top_k(
+        table,
+        upload(qidx, np.int32),
+        upload(qweight, np.float32),
+        upload(mask),
+        upload(weights, np.float32),
+        k,
+    )
+
+
+class ServingIndex:
+    """Device-resident factor tables with index-addressed top-k serve.
+
+    The TPU replacement for the reference's in-JVM model broadcast
+    (``CreateServer.scala:196-200`` deserializes the kryo model into the
+    server heap; here the model lives in HBM and every batch is one compiled
+    program). Per-batch cost: one [B] int32 upload + one [B,2,k] int32 fetch.
+    """
+
+    def __init__(self, user_factors, item_factors):
+        self.user_factors = jnp.asarray(user_factors)
+        self.item_factors = jnp.asarray(item_factors)
+        self._full_mask = jnp.ones((self.item_factors.shape[0],), bool)
+
+    @property
+    def n_users(self) -> int:
+        return self.user_factors.shape[0]
+
+    @property
+    def n_items(self) -> int:
+        return self.item_factors.shape[0]
+
+    def warmup_buckets(self, k: int, max_batch: int) -> None:
+        """Pre-compile every power-of-two batch bucket up to
+        ``next_pow2(max_batch)`` (the range the dispatch path buckets
+        len(batch) <= max_batch into) for top-``k`` (k rounded up to its own
+        bucket), so neither a single query nor the first ragged burst pays
+        a compile."""
+        kk = min(next_pow2(k), self.n_items)
+        # through serve_batch_async, as the serving path stages its
+        # indices: upload()'s copy is a program of its own for every bucket,
+        # and a bucket's first batch would load it
+        warmup_pow2_buckets(
+            max_batch,
+            lambda b: self.serve_batch_async(np.zeros((b,), np.int32), kk),
         )
-    return _gather_sum_top_k(table, qidx_d, qw_d, mask_d, k)
 
+    def serve(
+        self, user_index: int, k: int, mask: jax.Array | np.ndarray | None = None
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Top-k (scores, item indices) for one user index: row 0 of batch
+        bucket 1 at k's bucket (the shapes ``warmup_buckets`` compiled),
+        the first ``k`` kept. ``mask`` [n_items] False = excluded item."""
+        kk = min(next_pow2(k), self.n_items)
+        # the fetch below ends before the caller has its mask back: no
+        # staging copy (a program of its own, and not a warmed one)
+        m = None if mask is None else jnp.asarray(mask)
+        scores, idx = fetch_topk(
+            self.serve_batch_async(np.array([user_index], np.int32), kk, m)
+        )
+        return scores[0, :k], idx[0, :k]
 
-def fused_top_k_async(scores, mask, k: int):
-    """Mask + top-k over an already-computed device score matrix [B,n]
-    (both donated — the scores buffer is consumed by the selection)."""
-    return _mask_top_k(scores, upload(mask), k)
-
-
-def fetch_topk(handle) -> tuple[np.ndarray, np.ndarray]:
-    """The ONE sanctioned device->host fetch on the serving path: a packed
-    [B,2,k] (or [2,k]) int32 result — O(batch*k), never O(batch*corpus).
-    Returns ([B,k] float32 scores, [B,k] int32 indices)."""
-    from predictionio_tpu.ops.als import ServingIndex
-
-    with annotate("pio:fetch.block"):  # the host blocked on the device
-        # pio-lint: disable=serving-host-roundtrip -- the ONE sanctioned fetch: O(batch*k) packed result, accounted by the request waterfall
-        packed = np.asarray(handle)
-    if packed.ndim == 2:  # single-query [2,k]
-        packed = packed[None]
-    # ops/als owns the wire format; this is the one decode of it
-    return ServingIndex.unpack_batch(packed)
+    def serve_batch_async(
+        self,
+        user_indices: np.ndarray | jax.Array,
+        k: int,
+        mask: jax.Array | np.ndarray | None = None,
+    ) -> jax.Array:
+        """Non-blocking batched serve: dispatches the program and returns
+        the packed [B,2,k] int32 device array WITHOUT fetching it. An async
+        query server dispatches batch n+1 while fetching batch n's result, so
+        device work and transport overlap; decode with ``fetch_topk``."""
+        m = self._full_mask if mask is None else upload(mask)
+        if isinstance(user_indices, jax.Array):
+            # already on device: a np.asarray round-trip would block on a
+            # D2H fetch and defeat the non-blocking contract
+            idxs = user_indices.astype(jnp.int32)
+        else:
+            # upload() COPIES: callers stage indices in reusable scratch
+            # buffers and overwrite them for the next batch while this
+            # batch's kernel is still in flight
+            idxs = upload(user_indices, np.int32)
+        return _serve_by_index_batch(
+            idxs, self.user_factors, self.item_factors, m, k
+        )
 
 
 def warmup_pow2_buckets(max_batch: int, dispatch) -> None:
@@ -249,8 +355,6 @@ def warmup_pow2_buckets(max_batch: int, dispatch) -> None:
     and blocking on every returned handle, so the first burst after
     deploy/reload pays no XLA compiles on the common shapes. ``dispatch``
     is the engine's per-bucket kernel call (dot / gather-sum / tower)."""
-    import jax
-
     handles = []
     b = 1
     top = next_pow2(max_batch)

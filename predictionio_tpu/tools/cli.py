@@ -2920,7 +2920,7 @@ def build_parser() -> argparse.ArgumentParser:
         "--device",
         default=None,
         help="device spec the roofline prices against "
-        "(tpu-v4/tpu-v5e/tpu-v5p/cpu-host; default tpu-v4)",
+        "(tpu-v4/tpu-v5e/tpu-v5p/cpu-host; default tpu-v5e)",
     )
     x.set_defaults(fn=cmd_doctor)
 

@@ -2,7 +2,7 @@
 # Tier-1 lint gate: run the TPU-aware static analyzer over the package and
 # examples. Exits nonzero on any unsuppressed error-severity finding.
 # Usage: scripts/run_lint.sh [extra lint args...]
-#        scripts/run_lint.sh --ci   # CI entry point: lint + perf gate + chaos
+#        scripts/run_lint.sh --ci   # CI entry point: lint + CPU smokes + chaos
 set -euo pipefail
 
 repo_root="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
@@ -30,27 +30,6 @@ PYEOF
   python -m predictionio_tpu.analysis.cli --report-suppressions \
     > /tmp/pio_lint_suppressions.txt
   echo "suppression inventory: $(tail -n 1 /tmp/pio_lint_suppressions.txt)"
-
-  # --- perf-regression gate (docs/observability.md, ROADMAP item 5) -------
-  # 1. the gate must PASS an unchanged run ...
-  baseline="tests/fixtures/bench_baseline.json"
-  python bench.py --compare "$baseline" --current "$baseline" \
-    > /tmp/pio_compare_same.json
-  # 2. ... and TRIP on an injected slowdown (latencies doubled, qps halved)
-  python - "$baseline" > /tmp/pio_bench_regressed.json <<'PYEOF'
-import json, sys
-d = json.load(open(sys.argv[1]))
-for k, v in list(d.items()):
-    if isinstance(v, (int, float)) and (k.endswith("_ms") or k.endswith("_qps")):
-        d[k] = v * 2.0 if k.endswith("_ms") else v / 2.0
-print(json.dumps(d))
-PYEOF
-  if python bench.py --compare "$baseline" --current /tmp/pio_bench_regressed.json \
-      > /tmp/pio_compare_regressed.json; then
-    echo "perf-regression gate FAILED to trip on an injected slowdown" >&2
-    exit 1
-  fi
-  echo "perf-regression gate: passes unchanged run, trips injected slowdown"
 
   # --- capacity-planner self-check (pio doctor; docs/observability.md) ----
   # the planner must PASS a plan that fits ...
@@ -88,80 +67,6 @@ print(
     f"peak/dev {pj['memory']['peakBytesPerDevice']} B"
 )
 PYEOF
-
-  # 3. a CPU-only bench smoke: the serving_local phase drives the real
-  #    QueryServer over loopback and records the full phase waterfall —
-  #    proving the evidence chain end to end on every CI run.
-  #    --no-compare: the smoke's own gate (next step) runs with a
-  #    noise-tolerant threshold, not the strict full-round default
-  env JAX_PLATFORMS=cpu PIO_BENCH_SCALE=ml100k \
-    python bench.py --cpu-only --no-compare --only serving_local \
-    > /tmp/pio_bench_smoke.json
-  echo "bench smoke: $(tail -c 300 /tmp/pio_bench_smoke.json)"
-
-  # 4. the device-bound-serving gate (ISSUE 8): the smoke's fetch-phase
-  #    p50 (and the other p50/qps fields it shares with the fixture) must
-  #    stay under the checked-in pre-fused-top-k baseline — the O(batch*k)
-  #    fetch contract is held by measurement on every CI run. p95s are
-  #    excluded (shared-CI-host tail noise) and the tolerance is wide: the
-  #    full-fetch regression this guards is a step change, not jitter.
-  python - "$baseline" > /tmp/pio_smoke_baseline.json <<'PYEOF'
-import json, sys
-d = json.load(open(sys.argv[1]))
-keep = {
-    k: v for k, v in d.items()
-    if k.endswith("_p50_ms") or k.endswith("_qps")
-}
-print(json.dumps(keep))
-PYEOF
-  if ! python bench.py --compare /tmp/pio_smoke_baseline.json \
-      --current /tmp/pio_bench_smoke.json --compare-tolerance 1.0 \
-      > /tmp/pio_compare_smoke.json; then
-    echo "serving smoke regressed vs checked-in baseline:" >&2
-    tail -c 600 /tmp/pio_compare_smoke.json >&2
-    exit 1
-  fi
-  echo "serving smoke vs baseline: $(tail -c 240 /tmp/pio_compare_smoke.json)"
-
-  # --- batchpredict smoke (ISSUE 14, docs/batch_predict.md): the offline
-  #     mega-batch pipeline on the same CPU backend must beat the serving
-  #     smoke's online qps by >= 3x (the full-round gate in bench.py is
-  #     5x; the CI floor is looser for shared-host noise), its
-  #     read->assemble->dispatch->fetch->write timeline must tile the run
-  #     wall clock, and `pio top --batchpredict` must render the progress
-  #     line from the run's status file.
-  env JAX_PLATFORMS=cpu PIO_BENCH_SCALE=ml100k \
-    python bench.py --cpu-only --no-compare --only batchpredict \
-    > /tmp/pio_bench_bp.json
-  bp_status=$(python - <<'PYEOF'
-import json
-def last_json(path):
-    for line in reversed(open(path).read().strip().splitlines()):
-        if line.startswith("{"):
-            return json.loads(line)
-    raise SystemExit(f"no JSON line in {path}")
-bp = last_json("/tmp/pio_bench_bp.json")
-sv = last_json("/tmp/pio_bench_smoke.json")
-off, on = bp["batchpredict_offline_qps"], sv["serving_local_e2e_qps"]
-assert bp["batchpredict_errors"] == 0, bp["batchpredict_errors"]
-assert bp["batchpredict_tiling_gate_ok"], bp["batchpredict_tiling_ratio"]
-assert off >= 3.0 * on, f"offline {off} q/s < 3x online {on} q/s"
-import sys
-print(
-    f"batchpredict smoke: offline {off:.0f} q/s vs online {on:.0f} q/s "
-    f"({off / on:.1f}x), phases tile ({bp['batchpredict_tiling_ratio']:.3f})",
-    file=sys.stderr,
-)
-print(bp["batchpredict_status_file"])
-PYEOF
-  )
-  # plain grep (not -q): -q exits at first match and SIGPIPEs the still-
-  # writing renderer, which pipefail then reports as a stage failure
-  if ! ./pio top --batchpredict "$bp_status" --once | grep "batchpredict" >/dev/null; then
-    echo "pio top --batchpredict did not render the progress line" >&2
-    exit 1
-  fi
-  echo "pio top --batchpredict renders from the run's status file"
 
   # --- evalgrid smoke (ISSUE 15, docs/evaluation.md): 2 params x 2 folds
   #     on a tiny corpus with a REAL SIGKILL mid-grid — the resumed run

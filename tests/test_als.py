@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-from predictionio_tpu.ops.als import ALSConfig, als_train, top_k_items
+from predictionio_tpu.ops.als import ALSConfig, als_train
 
 
 def synthetic_ratings(n_users=30, n_items=20, rank=4, density=0.5, seed=0):
@@ -109,113 +109,6 @@ class TestImplicitALS:
         g0 = scores[0, :10].mean() - scores[0, 10:].mean()
         g1 = scores[1, 10:].mean() - scores[1, :10].mean()
         assert g0 > 0 and g1 > 0
-
-
-class TestTopK:
-    def test_top_k_and_mask(self):
-        import jax.numpy as jnp
-
-        vf = jnp.asarray(np.diag(np.arange(1.0, 6.0)))  # 5 items, rank 5
-        user = jnp.ones(5)
-        scores, idx = top_k_items(user, vf, 3)
-        assert list(idx) == [4, 3, 2]
-        mask = np.ones(5, bool)
-        mask[4] = False  # blacklist best item
-        scores, idx = top_k_items(user, vf, 3, jnp.asarray(mask))
-        assert list(idx) == [3, 2, 1]
-
-
-class TestServingIndex:
-    def _index(self):
-        from predictionio_tpu.ops.als import ServingIndex
-
-        uf = np.eye(4, 5, dtype=np.float32)  # user u scores item via vf
-        vf = np.diag(np.arange(1.0, 6.0)).astype(np.float32)[:, :5]
-        return ServingIndex(uf, vf)
-
-    def test_serve_matches_dense_scores(self):
-        idx = self._index()
-        scores, items = idx.serve(2, 3)
-        dense = np.asarray(idx.item_factors) @ np.asarray(idx.user_factors)[2]
-        order = np.argsort(-dense)[:3]
-        assert list(items) == list(order)
-        np.testing.assert_allclose(scores, dense[order], rtol=1e-6)
-
-    def test_serve_mask_blacklist(self):
-        idx = self._index()
-        mask = np.ones(5, bool)
-        _, items = idx.serve(2, 1)
-        mask[int(items[0])] = False
-        _, items2 = idx.serve(2, 1, mask)
-        assert int(items2[0]) != int(items[0])
-
-    def test_serve_batch_consistent_with_single(self):
-        idx = self._index()
-        bs, bi = idx.serve_batch(np.array([0, 1, 2, 3]), 2)
-        for u in range(4):
-            s, i = idx.serve(u, 2)
-            np.testing.assert_array_equal(bi[u], i)
-            np.testing.assert_allclose(bs[u], s, rtol=1e-6)
-
-    def test_small_indices_survive_packing(self):
-        # regression: packing indices as bitcast *float32* made small indices
-        # denormal floats, which XLA flush-to-zero turned into index 0. The
-        # packed row must be int32 (scores ride as the bitcast instead).
-        from predictionio_tpu.ops.als import ServingIndex
-
-        rng = np.random.default_rng(0)
-        uf = rng.normal(size=(5, 8)).astype(np.float32)
-        vf = rng.normal(size=(50, 8)).astype(np.float32)
-        idx = ServingIndex(uf, vf)
-        scores, items = idx.serve(1, 4)
-        dense = vf @ uf[1]
-        expect = np.argsort(-dense)[:4]
-        assert list(items) == list(expect)
-        np.testing.assert_allclose(scores, dense[expect], rtol=1e-5)
-        _, bi = idx.serve_batch(np.array([1, 3]), 4)
-        assert list(bi[0]) == list(expect)
-
-    def test_index_bitcast_exact_for_large_indices(self):
-        # indices > 2^24 would lose precision as float casts; the packed
-        # path bitcasts, so spot-check determinism on a bigger table
-        from predictionio_tpu.ops.als import ServingIndex
-
-        rng = np.random.default_rng(0)
-        vf = rng.normal(size=(50_000, 8)).astype(np.float32)
-        uf = rng.normal(size=(4, 8)).astype(np.float32)
-        idx = ServingIndex(uf, vf)
-        _, items = idx.serve(1, 5)
-        dense = vf @ uf[1]
-        assert list(items) == list(np.argsort(-dense)[:5])
-
-    def test_a_warmed_buckets_first_batch_compiles_nothing(self):
-        # the staging copy in upload() is a program of its own for every
-        # bucket shape: warmup_buckets has to take the serving path's upload,
-        # or a bucket's first batch compiles (or loads) it at serve time
-        from jax import monitoring
-
-        from predictionio_tpu.ops.als import ServingIndex
-
-        rng = np.random.default_rng(0)
-        # shapes no other test of this process serves: nothing is compiled yet
-        idx = ServingIndex(
-            rng.normal(size=(7, 6)).astype(np.float32),
-            rng.normal(size=(41, 6)).astype(np.float32),
-        )
-        compiled = []
-
-        def listener(event, duration_secs, **kw):
-            if event.endswith("/backend_compile_duration"):
-                compiled.append(event)
-
-        monitoring.register_event_duration_secs_listener(listener)
-        idx.warmup_buckets(3, 13)  # buckets 1, 2, 4, 8, 16; k bucket 4
-        warmed = len(compiled)
-        assert warmed > 0
-        for n in (1, 2, 3, 5, 9, 13):
-            staged = np.zeros((1 << (n - 1).bit_length(),), np.int32)
-            np.asarray(idx.serve_batch_async(staged, 4))
-        assert len(compiled) == warmed
 
 
 class TestShardedALS:
@@ -498,25 +391,6 @@ class TestDevicePack:
         }
         assert all(val >= 0 for val in t.values())
         assert t["nb_u"] > 0 and t["nb_i"] > 0 and t["d"] >= 8
-
-    def test_hbm_bytes_model(self):
-        """Mandatory-traffic model for the roofline metric: bf16 gathers
-        shrink only the stream term; cg re-reads A (f+4) times vs
-        cholesky's ~2; host- and device-pack paths report identical block
-        shapes for identical data."""
-        from predictionio_tpu.ops.als import solver_hbm_bytes_per_iter
-
-        args = dict(nb_u=100, nb_i=80, d=128, f=32, n_users=1000, n_items=800)
-        f32 = solver_hbm_bytes_per_iter(**args)
-        bf16 = solver_hbm_bytes_per_iter(**args, gather_dtype="bf16")
-        stream_delta = (100 + 80) * 128 * 32 * 2  # half the gather bytes
-        assert f32 - bf16 == stream_delta
-        chol = solver_hbm_bytes_per_iter(**args, solver="cholesky")
-        assert chol < f32
-        # the dominant terms are positive and scale with the table size
-        assert solver_hbm_bytes_per_iter(
-            nb_u=200, nb_i=80, d=128, f=32, n_users=1000, n_items=800
-        ) > f32
 
     def test_ratings_wire_compression_forms(self):
         """Smallest lossless wire form: uint8 dictionary for <=256 distinct

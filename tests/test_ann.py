@@ -919,27 +919,3 @@ class TestTopAnnLine:
         )
         payload = json.loads(outs[0])
         assert payload["ann"]["indexes"]["v1"]["items"] == 500
-
-
-class TestBenchContractAnn:
-    def test_compare_directions_for_ann_fields(self):
-        import bench
-
-        assert bench._compare_direction("serving_ann_p50_ms") == 1
-        assert bench._compare_direction("serving_ann_candidates_frac") == 1
-        assert bench._compare_direction("serving_ann_recall_at_10") == -1
-        # informational fields must NOT gate
-        assert bench._compare_direction("serving_ann_build_s") == 0
-
-    def test_recall_decay_trips_the_gate(self):
-        import bench
-
-        prior = {"serving_ann_recall_at_10": 0.99, "serving_ann_p50_ms": 5.0}
-        good = bench.compare_bench(
-            {"serving_ann_recall_at_10": 0.98, "serving_ann_p50_ms": 5.1}, [prior]
-        )
-        assert good["compare_ok"]
-        bad = bench.compare_bench(
-            {"serving_ann_recall_at_10": 0.60, "serving_ann_p50_ms": 5.0}, [prior]
-        )
-        assert not bad["compare_ok"]

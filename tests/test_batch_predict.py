@@ -637,7 +637,7 @@ class TestUploadDecoupling:
     """`jnp.asarray(host_numpy)` on the CPU backend is zero-copy: the jax
     array ALIASES the numpy buffer. The scratch-pool reuse every async
     dispatch path depends on ("the buffer is reusable as soon as dispatch
-    returns") is only sound because ops.als.upload copies — without it,
+    returns") is only sound because ops.topk.upload copies — without it,
     the offline double-buffer pipeline intermittently served batch N's
     first rows with batch N+1's users (a torn read of the overwritten
     staging buffer)."""
@@ -666,18 +666,15 @@ class TestUploadDecoupling:
         import numpy as np
 
         from predictionio_tpu.ops import topk
-        from predictionio_tpu.ops.als import ServingIndex
 
         rng = np.random.default_rng(0)
-        index = ServingIndex(
+        index = topk.ServingIndex(
             rng.normal(size=(12, 6)).astype(np.float32),
             rng.normal(size=(8, 6)).astype(np.float32),
         )
-        expect = ServingIndex.unpack_batch(
-            np.asarray(
-                index.serve_batch_async(np.arange(8, dtype=np.int32), 4)
-            )
-        )[1]
+        _, expect = topk.fetch_topk(
+            index.serve_batch_async(np.arange(8, dtype=np.int32), 4)
+        )
         buf = np.arange(8, dtype=np.int32)
         handle = index.serve_batch_async(buf, 4)
         buf[:] = 0  # overwrite the staging buffer mid-flight

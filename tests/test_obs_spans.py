@@ -362,18 +362,21 @@ def _compiled_text(program: str) -> str:
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops import als, topk
 
     S = jax.ShapeDtypeStruct
+    table, mask = S((30, 8), jnp.float32), S((4, 30), jnp.bool_)
     if program == "_serve_by_index_batch":
-        lowered = als._serve_by_index_batch.lower(
-            S((4,), jnp.int32), S((40, 8), jnp.float32), S((30, 8), jnp.float32),
-            S((30,), jnp.bool_), k=4,
+        lowered = topk._serve_by_index_batch.lower(
+            S((4,), jnp.int32), S((40, 8), jnp.float32), table, S((30,), jnp.bool_), k=4,
         )
-    elif program == "_serve_by_index":
-        lowered = als._serve_by_index.lower(
-            S((), jnp.int32), S((40, 8), jnp.float32), S((30, 8), jnp.float32),
-            S((30,), jnp.bool_), k=4,
+    elif program == "_dot_top_k":
+        lowered = topk._dot_top_k.lower(
+            table, S((4, 8), jnp.float32), mask, S((30,), jnp.float32), k=4
+        )
+    elif program == "_gather_sum_top_k":
+        lowered = topk._gather_sum_top_k.lower(
+            table, S((4, 2), jnp.int32), S((4, 2), jnp.float32), mask, None, k=4
         )
     elif program == "_als_step":
         tables = [S((16,), jnp.int32), S((16, 8), jnp.int32), S((16, 8), jnp.float32), S((16, 8), jnp.int8)]
@@ -393,7 +396,8 @@ def _compiled_text(program: str) -> str:
     "program, scopes",
     [
         ("_serve_by_index_batch", ["gather", "score", "topk"]),
-        ("_serve_by_index", ["gather", "score", "topk"]),
+        ("_dot_top_k", ["score", "topk"]),
+        ("_gather_sum_top_k", ["gather", "score", "topk"]),
         ("_als_step", ["gather", "gram", "solve", "solve/while/body/closed_call/matvec"]),
         ("_device_pack", ["pack"]),
     ],

@@ -461,23 +461,6 @@ class TestCostModel:
             assert kernel["bytesAccessed"] > 0
             assert kernel["bound"] in ("compute", "memory")
 
-    def test_bench_fields_flat_and_finite(self, roofline_report):
-        import math
-
-        from predictionio_tpu.obs import costmodel
-
-        # rebuild fields from the cached report's shape contract
-        fields = {"roofline_device": roofline_report["device"]["name"]}
-        assert fields["roofline_device"] == "tpu-v4"
-        live = costmodel.bench_fields(["topk"])
-        for key in (
-            "roofline_topk_gflops",
-            "roofline_topk_mbytes",
-            "roofline_topk_ai",
-            "roofline_topk_cost_per_1k_usd",
-        ):
-            assert math.isfinite(live[key]) and live[key] > 0, key
-
     def test_roofline_bound_classification(self):
         from predictionio_tpu.obs.costmodel import (
             DEVICE_SPECS,
