@@ -86,19 +86,17 @@ class ALSConfig:
     # f32, so only the gathered operand is rounded (8-bit mantissa).
     gather_dtype: str = "f32"
     # "auto" | "device" | "host": how the COO list becomes MXU block tables.
-    # "device" (= "auto"): host does ONE O(n) stable group-by-user (native
-    # C++ counting sort, numpy fallback), uploads the minimal wire form
-    # (opposite-entity column + ratings + two tiny degree histograms; the
-    # grouped-by order makes the user column itself redundant), and the
-    # device rebuilds everything else — user column via scatter+cumsum
-    # over the degree prefix (see _device_pack; the searchsorted
-    # formulation measured 90x slower), the item-side ordering via one
-    # stable device sort (~0.13s for 20M triples on v5e), and both block
-    # tables via gather-expansion (no scatters). It cuts the all-host
-    # pack's time and its padded upload's bytes (neither measured on
-    # today's machine).
+    # "device" (= "auto"): the three columns go up as they are before the
+    # host has read them (235 MB in 26 ms at ML-20M's shape on a v5e, and
+    # ``device_put`` returns in 1 ms), the host meanwhile checks every id and
+    # counts the degrees (one native pass a column: the degrees size the
+    # block tables, which are static shapes), and the device does the rest
+    # (see _device_pack): a stable sort by user, a stable sort by item of
+    # that, and both block tables by gather-expansion (no scatters). Measured
+    # in rec-als-ml20m.train (PERF.md section 6, PR 29): the chip waited
+    # 1.69 s a train for the host's group-by and wire codings before.
     # "host" keeps the original numpy block packing (exact reference for
-    # tests; also the fallback for empty inputs).
+    # tests; also what an empty input takes).
     pack: str = "auto"
 
     def __post_init__(self):
@@ -477,6 +475,15 @@ def _expand_blocks_traced(deg, cols_sorted, vals_sorted, d: int, nb: int, dummy_
     ``_block_coo`` computes: entity e owns ``ceil(deg[e]/d)`` consecutive
     blocks; pad slots carry weight 0; pad blocks point at ``dummy_row``.
     """
+    # the slot indices below depend on ``deg`` alone, so XLA would compute
+    # them ahead of the sort that makes the streams and then start on the
+    # gathers the moment the sort ends, with one stream still where the sort
+    # left it. Tied to the streams they are computed after the sort, and the
+    # streams' move into the faster memory hides behind them: 0.19 s against
+    # 0.48 for the item side's 21 M ratings on a v5e (PERF.md section 6, PR 29)
+    deg, cols_sorted, vals_sorted = lax.optimization_barrier(
+        (deg, cols_sorted, vals_sorted)
+    )
     n_entities = deg.shape[0]
     nblk = (deg + (d - 1)) // d
     bb_incl = jnp.cumsum(nblk)  # inclusive block prefix
@@ -503,11 +510,11 @@ def _expand_blocks_traced(deg, cols_sorted, vals_sorted, d: int, nb: int, dummy_
     jax.jit, static_argnames=("d", "nb_u", "nb_i", "n_users", "n_items")
 )
 def _device_pack(
-    cols_u,  # [nnz] opposite (item) ids grouped by user; int16 or int32 wire
-    vals_u,  # [nnz] ratings grouped by user; uint8 codes / float16 / float32
+    users,  # [nnz] int32, in the caller's order
+    items,  # [nnz] int32
+    ratings,  # [nnz] float32
     deg_u,  # [n_users] int32 per-user rating count
     deg_i,  # [n_items] int32 per-item rating count
-    val_table=None,  # [<=256] f32 dictionary for uint8-coded ratings
     *,
     d: int,
     nb_u: int,
@@ -515,29 +522,18 @@ def _device_pack(
     n_users: int,
     n_items: int,
 ):
-    """Build BOTH sides' block tables on device from the minimal wire form.
+    """Build BOTH sides' block tables on device from the raw columns.
 
-    The user column is implicit in the grouped order (reconstructed via
-    searchsorted over the degree prefix sum); the item-side ordering comes
-    from one stable device sort. Saves ~2/3 of the H2D bytes vs uploading
-    two padded block-table sets, and all the host pack time past the one
-    counting sort.
+    A stable sort by user groups the ratings as ``_host_group_by`` does (the
+    order inside a user is the input's, as the counting sort's is), and a
+    stable sort by item of THAT stream gives the item side's order; each
+    side's tables are then one gather-expansion. The host hands over the
+    columns untouched and the two degree histograms (``nb_u`` and ``nb_i``
+    follow from them and are static shapes).
     """
     with jax.named_scope("pack"):
-        nnz = cols_u.shape[0]
-        items_u = cols_u.astype(jnp.int32)
-        if val_table is not None:
-            # dictionary-coded wire: one tiny-table gather decodes exactly
-            ratings_u = val_table[vals_u.astype(jnp.int32)]
-        else:
-            ratings_u = vals_u.astype(jnp.float32)
-        # user column from the grouped order: +1 at each entity's start position,
-        # then an inclusive cumsum. O(n) in two passes — the searchsorted
-        # formulation (binary search = ~17 gather passes over the prefix array)
-        # measured 2.7s for 19.6M rows on a v5e; this is 0.03s
-        start_u = jnp.cumsum(deg_u) - deg_u
-        users_u = jnp.cumsum(
-            jnp.zeros((nnz,), jnp.int32).at[start_u[1:]].add(1)
+        users_u, items_u, ratings_u = lax.sort(
+            (users, items, ratings), num_keys=1, is_stable=True
         )
         u_tables = _expand_blocks_traced(deg_u, items_u, ratings_u, d, nb_u, n_users)
         _, users_by_item, ratings_by_item = lax.sort(
@@ -553,7 +549,8 @@ def _compress_ratings_wire(
     vals: "np.ndarray",
 ) -> tuple["np.ndarray", "np.ndarray | None"]:
     """Smallest LOSSLESS wire form of the ratings column; returns
-    ``(wire_vals, table)``.
+    ``(wire_vals, table)``. The mesh-sharded trainer's wire
+    (ops/als_sharded.py); ``als_train`` sends float32 as it is.
 
     - ≤256 distinct values (every real star-rating dataset: ML uses 0.5
       steps over [0.5, 5]) -> uint8 dictionary codes + a tiny f32 value
@@ -583,7 +580,9 @@ def _host_group_by(
     rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, n_entities: int
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Stable group-by-entity: native C++ counting sort (O(n), one pass each
-    for histogram and scatter) with a numpy stable-argsort fallback.
+    for histogram and scatter) with a numpy stable-argsort fallback. The
+    mesh-sharded trainer's host pack (ops/als_sharded.py), and the order
+    ``_device_pack``'s sort by user is held to.
 
     Ids must lie in [0, n_entities): an oversized id would give the degree
     histogram the wrong length and every downstream block table a silently
@@ -628,6 +627,34 @@ def fetch_barrier(*arrays) -> float:
     return float(np.asarray(_barrier_checksum(*arrays)))
 
 
+def _checked_degrees(
+    user_idx: np.ndarray, item_idx: np.ndarray, n_users: int, n_items: int
+) -> tuple[np.ndarray, np.ndarray] | None:
+    """``(deg_u, deg_i)``, every id checked on the way: one native pass a
+    column (``utils/native.degrees``). None when a rating carries a negative
+    id (the caller drops those and asks again); an id past its vocabulary
+    raises. Without the native library, or to say WHICH id was out of range,
+    numpy does the same in six passes."""
+    from predictionio_tpu.utils import native
+
+    columns = (("user", user_idx, n_users), ("item", item_idx, n_items))
+    degrees = [native.degrees(idx, bound) for _, idx, bound in columns]
+    if all(deg is not None for deg in degrees):
+        return degrees[0], degrees[1]
+    if min(int(user_idx.min()), int(item_idx.min())) < 0:
+        return None
+    for name, idx, bound in columns:
+        mx = int(idx.max())
+        if mx >= bound:
+            raise ValueError(
+                f"{name} index {mx} out of range for n_{name}s={bound}"
+            )
+    return tuple(
+        np.bincount(idx, minlength=bound).astype(np.int32)
+        for _, idx, bound in columns
+    )
+
+
 def als_train(
     user_idx: np.ndarray,
     item_idx: np.ndarray,
@@ -641,15 +668,19 @@ def als_train(
     item_factors [n_items, f]).
 
     Pass a ``timings`` dict to get a wall-clock decomposition written into
-    it: ``pack_s`` (host group-by / block packing), ``upload_s`` (H2D
-    transfer of the wire arrays, barrier-confirmed), ``build_s``
-    (device-side block-table construction — 0 on the host pack path),
-    ``device_s`` (solver iterations only, barrier-confirmed). The four
-    clocks run back to back from the start of the pack, so they sum to the
-    call's wall clock LESS what comes before it: checking and filtering the
-    ratings (0.33 s at 19.6 M ratings on a v5e's host, PERF.md section 5)
-    is in none of them, only under the ``pio:als.pack`` span. The
-    un-instrumented path keeps the fully-async dispatch pipeline.
+    it: ``pack_s`` (the host's part: handing the raw columns to the
+    transfer, then checking the ids and counting the degrees beside it; the
+    whole numpy block packing on the host pack path), ``upload_s`` (what is
+    left of the transfer once the host is done, barrier-confirmed),
+    ``build_s`` (device-side block-table construction; 0 on the host pack
+    path), ``device_s`` (solver iterations only, barrier-confirmed), and
+    ``wire_bytes`` (what crossed to the device before the first sweep). The
+    four clocks run back to back from the call's first line, so they sum to
+    the call's wall clock. The un-instrumented path keeps the fully-async
+    dispatch pipeline: no barrier between upload, build and the first sweep.
+    The columns are handed to the transfer as they are (``jax.device_put``
+    of the caller's arrays): do not write into them before the factors are
+    ready.
 
     With an active train profile (obs/xray): the host pack/upload/build
     accounts as ``host_etl``, each iteration becomes one profiled
@@ -669,85 +700,66 @@ def als_train(
     # (obs/jaxprof.annotate): on the plain path a span closes when the host's
     # call returns, which is what the host did
     with xray.phase(xray.PHASE_HOST_ETL):
-        # the pack span opens here, before the pack_s clock does: checking
-        # and filtering the ratings is host work on them too, and at 20 M
-        # ratings the device waits 0.3 s for it
         with annotate("pio:als.pack"):
+            t0 = time.perf_counter()
             user_idx = np.asarray(user_idx, np.int32)
             item_idx = np.asarray(item_idx, np.int32)
             ratings = np.asarray(ratings, np.float32)
-            valid = (user_idx >= 0) & (item_idx >= 0)
-            user_idx, item_idx, ratings = (
-                user_idx[valid], item_idx[valid], ratings[valid]
-            )
-            if user_idx.shape[0]:
-                for name, idx, bound in (
-                    ("user", user_idx, n_users),
-                    ("item", item_idx, n_items),
-                ):
-                    mx = int(idx.max())
-                    if mx >= bound:
-                        raise ValueError(
-                            f"{name} index {mx} out of range for n_{name}s={bound}"
-                        )
             d = max(8, min(config.block_d, config.chunk))
             block_chunk = max(8, config.chunk // d)
-            use_device_pack = config.pack != "host" and user_idx.shape[0] > 0
-
-            t0 = time.perf_counter()
+            columns = (user_idx, item_idx, ratings)
+            wire, degrees = [], None
+            while degrees is None and columns[0].shape[0]:
+                if config.pack != "host":
+                    # the columns leave as they are, before the host has read
+                    # them: device_put returns at once and the transfer runs
+                    # beside the host's one pass over the ids
+                    wire = [jax.device_put(a) for a in columns]
+                degrees = _checked_degrees(columns[0], columns[1], n_users, n_items)
+                if degrees is None:
+                    # a rating with a negative id is dropped, as ever; what is
+                    # left takes the same road (what went up is let go)
+                    valid = (columns[0] >= 0) & (columns[1] >= 0)
+                    columns, wire = tuple(a[valid] for a in columns), []
+            user_idx, item_idx, ratings = columns
+            use_device_pack = bool(wire)
             if use_device_pack:
-                cols_u, vals_u, deg_u = _host_group_by(
-                    user_idx, item_idx, ratings, n_users
+                nb_u, nb_i = (
+                    _pad_blocks(int((-(-deg // d)).sum()), block_chunk)
+                    for deg in degrees
                 )
-                deg_i = np.bincount(item_idx, minlength=n_items).astype(np.int32)
-                nb_u = _pad_blocks(int((-(-deg_u // d)).sum()), block_chunk)
-                nb_i = _pad_blocks(int((-(-deg_i // d)).sum()), block_chunk)
-                # wire compression, all LOSSLESS: opposite ids as int16 when
-                # the vocab fits; ratings in their smallest exact form (uint8
-                # dictionary codes / f16 / f32 — see _compress_ratings_wire).
-                if n_items <= np.iinfo(np.int16).max:
-                    cols_u = cols_u.astype(np.int16)
-                vals_u, val_table = _compress_ratings_wire(vals_u)
+                host_made = degrees
             else:
-                u_blocks = _block_coo(
-                    user_idx, item_idx, ratings, d, block_chunk, n_users
+                host_made = (
+                    *_block_coo(user_idx, item_idx, ratings, d, block_chunk, n_users),
+                    *_block_coo(item_idx, user_idx, ratings, d, block_chunk, n_items),
                 )
-                i_blocks = _block_coo(
-                    item_idx, user_idx, ratings, d, block_chunk, n_items
-                )
+            wire_bytes = sum(a.nbytes for a in (*wire, *host_made))
             t_pack = time.perf_counter()
-        if use_device_pack:
-            with annotate("pio:als.upload"):
-                wire = [jax.device_put(a) for a in (cols_u, vals_u, deg_u, deg_i)]
-                table_dev = (
-                    jax.device_put(val_table) if val_table is not None else None
-                )
-                if timings is not None:
-                    fetch_barrier(*wire)
+        with annotate("pio:als.upload", bytes=wire_bytes):
+            # what the host made crosses host->device ONCE (the degree
+            # histograms behind the columns, or the host's block tables); the
+            # per-iteration launches reuse the same device buffers
+            dev = [*wire, *(jax.device_put(a) for a in host_made)]
+            del wire  # the raw columns go when _device_pack has read them
+            if timings is not None:
+                fetch_barrier(*dev)
             t_upload = time.perf_counter()
+        if use_device_pack:
             with annotate("pio:als.build"):
                 dev = list(
                     _device_pack(
-                        *wire, val_table=table_dev,
-                        d=d, nb_u=nb_u, nb_i=nb_i, n_users=n_users, n_items=n_items,
+                        *dev, d=d, nb_u=nb_u, nb_i=nb_i, n_users=n_users, n_items=n_items
                     )
                 )
                 if timings is not None:
-                    # device-side table build (sort + gather expansion)
+                    # device-side table build (two sorts + gather expansion)
                     # attributed to its own bucket: device_s means SOLVER
                     # iterations only, on both pack paths, or per-iteration
                     # figures aren't comparable
                     fetch_barrier(dev[0], dev[4])
-            t_build = time.perf_counter()
-        else:
-            with annotate("pio:als.upload"):
-                # block tables cross host->device ONCE; the per-iteration
-                # launches reuse the same device buffers
-                dev = [jax.device_put(a) for a in (*u_blocks, *i_blocks)]
-                if timings is not None:
-                    fetch_barrier(*dev)
-            t_upload = time.perf_counter()
-            t_build = t_upload  # tables arrive pre-built on the host path
+        # tables arrive pre-built on the host path: its build_s is 0
+        t_build = time.perf_counter() if use_device_pack else t_upload
         user_f, item_f = _als_init(
             n_users=n_users, n_items=n_items, rank=config.rank, seed=config.seed
         )
@@ -795,6 +807,7 @@ def als_train(
             timings["upload_s"] = t_upload - t_pack
             timings["build_s"] = t_build - t_upload
             timings["device_s"] = time.perf_counter() - t_build
+            timings["wire_bytes"] = wire_bytes
             # block-table shapes, for a caller's model of the bytes an
             # iteration moves: nb = blocks per side, d = block width
             timings["nb_u"] = int(dev[0].shape[0])

@@ -137,6 +137,13 @@ def get_library() -> ctypes.CDLL | None:
             ctypes.POINTER(ctypes.c_float),
             ctypes.POINTER(ctypes.c_int32),
         ]
+        lib.pio_degrees.restype = ctypes.c_int32
+        lib.pio_degrees.argtypes = [
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.c_int64,
+            ctypes.c_int32,
+            ctypes.POINTER(ctypes.c_int32),
+        ]
         lib.pio_cooccur_topn.restype = ctypes.c_int32
         lib.pio_cooccur_topn.argtypes = [
             ctypes.POINTER(ctypes.c_int32),
@@ -212,6 +219,25 @@ def coo_group(
     if rc != 0:
         return None
     return cols_out, vals_out, deg
+
+
+def degrees(ids: np.ndarray, n_entities: int) -> np.ndarray | None:
+    """Per-entity counts (int32 ``[n_entities]``) of an id column, every id
+    checked against ``[0, n_entities)`` in the same pass. Returns None when
+    the native library is unavailable or an id is out of range (callers find
+    out which with numpy, and count with ``np.bincount``)."""
+    lib = get_library()
+    if lib is None:
+        return None
+    ids = np.ascontiguousarray(ids, np.int32)
+    deg = np.zeros(n_entities, np.int32)
+    rc = lib.pio_degrees(
+        ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ids.shape[0],
+        n_entities,
+        deg.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+    )
+    return deg if rc == 0 else None
 
 
 def scan_jsonl_columnar(

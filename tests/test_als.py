@@ -143,11 +143,10 @@ class TestShardedALS:
         assert rmse_multi < 0.15
         assert rmse_multi < max(5 * abs(rmse_single), 0.15)
 
-    def test_dictionary_wire_sharded_parity(self):
+    def test_dictionary_wire_sharded_parity(self, monkeypatch):
         """Star-rating data rides the uint8 dictionary wire on the sharded
         path too; factors must match the f32-wire run exactly (the decode
         gather reproduces identical f32 values)."""
-        from predictionio_tpu.ops import als as als_mod
         from predictionio_tpu.ops.als import ALSConfig
         from predictionio_tpu.ops.als_sharded import als_train_sharded
 
@@ -158,18 +157,10 @@ class TestShardedALS:
         cfg = ALSConfig(rank=8, iterations=4, reg=0.05, chunk=512)
         uf_dict, vf_dict = als_train_sharded(u, i, r, n_u, n_i, cfg)
         # force the f32 wire by disabling the compressor
-        orig = als_mod._compress_ratings_wire
-        try:
-            als_mod._compress_ratings_wire = lambda v: (v, None)
-            import predictionio_tpu.ops.als_sharded as sh
+        import predictionio_tpu.ops.als_sharded as sh
 
-            sh._compress_ratings_wire = als_mod._compress_ratings_wire
-            uf_f32, vf_f32 = als_train_sharded(u, i, r, n_u, n_i, cfg)
-        finally:
-            als_mod._compress_ratings_wire = orig
-            import predictionio_tpu.ops.als_sharded as sh
-
-            sh._compress_ratings_wire = orig
+        monkeypatch.setattr(sh, "_compress_ratings_wire", lambda v: (v, None))
+        uf_f32, vf_f32 = als_train_sharded(u, i, r, n_u, n_i, cfg)
         np.testing.assert_allclose(uf_dict, uf_f32, rtol=0, atol=1e-5)
         np.testing.assert_allclose(vf_dict, vf_f32, rtol=0, atol=1e-5)
 
@@ -292,51 +283,120 @@ class TestShardedALS:
                 assert np.array_equal(g, w_arr), (trial, name)
 
 
+def _raw_columns(case: str):
+    """``(users, items, ratings, n_users, n_items)`` for one shape of input
+    ``_device_pack`` has to group as the host reference does."""
+    rng = np.random.default_rng(11)
+    n_users, n_items, nnz = 120, 80, 6000
+    if case == "n_items_over_int16":
+        n_items = 40_000
+    u = rng.integers(0, n_users, nnz).astype(np.int32)
+    i = rng.integers(0, n_items, nnz).astype(np.int32)
+    if case == "already_grouped":
+        u = np.sort(u)
+    elif case == "one_user_only":
+        u[:] = 7
+    elif case == "degree_0_and_over_d":
+        # user 3 and item 5 rate nothing; user 9 and item 2 span several blocks
+        u[u == 3], i[i == 5] = 4, 6
+        u[:300], i[300:700] = 9, 2
+    elif case == "n_items_over_int16":
+        i[:50] = rng.integers(32_768, n_items, 50)
+    elif case == "duplicate_pairs":
+        u[:40], i[:40] = 17, 23
+    else:
+        assert case == "shuffled"
+    # half-star ratings, distinct enough that a slot swapped inside a block shows
+    v = (rng.integers(2, 11, nnz) / 2.0).astype(np.float32)
+    return u, i, v, n_users, n_items
+
+
 class TestDevicePack:
-    """The device-side block-building pipeline (round-4 perf work): host does
-    one O(n) group-by, the device reconstructs the user column, sorts the
-    item side, and gather-expands both block tables. Must agree with the
-    all-host ``_block_coo`` reference layout."""
+    """The device-side block-building pipeline: the host hands over the raw
+    columns and the two degree histograms; the device groups by user with a
+    stable sort, sorts that stream by item, and gather-expands both block
+    tables. Must agree with the all-host ``_block_coo`` reference layout."""
 
     def _coo(self, n_users=120, n_items=80, nnz=6000, seed=3):
         rng = np.random.default_rng(seed)
         u = rng.integers(0, n_users, nnz).astype(np.int32)
         i = rng.integers(0, n_items, nnz).astype(np.int32)
-        # half-star ratings: exactly f16-representable, so the lossless wire
-        # compression path (f16 + int16) is exercised
         v = (rng.integers(2, 11, nnz) / 2.0).astype(np.float32)
         return u, i, v
 
-    def test_u_side_tables_bit_identical_to_host_pack(self):
-        from predictionio_tpu.ops.als import (
-            _block_coo,
-            _device_pack,
-            _host_group_by,
-            _pad_blocks,
-        )
+    @pytest.mark.parametrize(
+        "case",
+        [
+            "shuffled",
+            "already_grouped",
+            "one_user_only",
+            "degree_0_and_over_d",
+            "n_items_over_int16",
+            "duplicate_pairs",
+        ],
+    )
+    def test_tables_from_raw_columns_equal_the_host_reference(self, case):
+        """All eight tables, ``array_equal``. The user side is ``_block_coo``
+        of the columns as they are; the item side is ``_block_coo`` of the
+        user-grouped stream, which is the order the item sort starts from."""
+        from predictionio_tpu.ops.als import _block_coo, _device_pack, _pad_blocks
 
-        u, i, v = self._coo()
-        n_users, n_items, d, bc = 120, 80, 16, 64
-        cols_u, vals_u, deg_u = _host_group_by(u, i, v, n_users)
+        u, i, v, n_users, n_items = _raw_columns(case)
+        d, bc = 16, 64
+        deg_u = np.bincount(u, minlength=n_users).astype(np.int32)
         deg_i = np.bincount(i, minlength=n_items).astype(np.int32)
-        nb_u = _pad_blocks(int((-(-deg_u // d)).sum()), bc)
-        nb_i = _pad_blocks(int((-(-deg_i // d)).sum()), bc)
         tables = _device_pack(
-            cols_u.astype(np.int16),
-            vals_u.astype(np.float16),
-            deg_u,
-            deg_i,
+            u, i, v, deg_u, deg_i,
             d=d,
-            nb_u=nb_u,
-            nb_i=nb_i,
+            nb_u=_pad_blocks(int((-(-deg_u // d)).sum()), bc),
+            nb_i=_pad_blocks(int((-(-deg_i // d)).sum()), bc),
             n_users=n_users,
             n_items=n_items,
         )
-        host = _block_coo(u, i, v, d, bc, n_users)
-        for dev_t, host_t, name in zip(tables[:4], host, ("br", "cols", "vals", "w")):
-            np.testing.assert_array_equal(
-                np.asarray(dev_t), host_t, err_msg=f"u-side {name}"
-            )
+        grouped = np.argsort(u, kind="stable")
+        host = (
+            *_block_coo(u, i, v, d, bc, n_users),
+            *_block_coo(i[grouped], u[grouped], v[grouped], d, bc, n_items),
+        )
+        names = [f"{side}-side {t}" for side in "ui" for t in ("br", "cols", "vals", "w")]
+        for dev_t, host_t, name in zip(tables, host, names, strict=True):
+            np.testing.assert_array_equal(np.asarray(dev_t), host_t, err_msg=name)
+        if case == "degree_0_and_over_d":
+            assert deg_u[3] == 0 and deg_i[5] == 0 and deg_u[9] > d and deg_i[2] > d
+
+    @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("case", ["negative_ids", "no_negative_ids", "empty", "all_negative"])
+    def test_train_returns_what_the_host_pack_returns(self, case, implicit):
+        """Ratings with a negative id are dropped on both pack paths, an
+        input with none is not copied, and an empty one (or one that is
+        empty once they are dropped) trains on the host tables."""
+        u, i, v = self._coo(nnz=4000)
+        if case == "negative_ids":
+            u, i = u.copy(), i.copy()
+            u[::7], i[3::11] = -1, -5
+        elif case == "all_negative":
+            u = np.full_like(u, -1)
+        elif case == "empty":
+            u, i, v = u[:0], i[:0], v[:0]
+
+        def train(pack, *columns):
+            cfg = ALSConfig(rank=4, iterations=3, reg=0.05, implicit=implicit, pack=pack)
+            return [np.asarray(f) for f in als_train(*columns, 120, 80, cfg)]
+
+        dev, host = train("device", u, i, v), train("host", u, i, v)
+        if case in ("empty", "all_negative"):
+            # no rating is left: both take the host's (empty) tables
+            for got, want in zip(dev, host):
+                np.testing.assert_array_equal(got, want)
+            return
+        # the item side sums in another order (see the parity test below)
+        for got, want in zip(dev, host):
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)
+        if case == "negative_ids":
+            keep = (u >= 0) & (i >= 0)
+            assert 0 < keep.sum() < len(u)
+            for got, want in zip(dev, train("device", u[keep], i[keep], v[keep])):
+                np.testing.assert_array_equal(got, want)
 
     def test_host_group_by_native_matches_numpy(self):
         from predictionio_tpu.ops.als import _host_group_by
@@ -356,6 +416,35 @@ class TestDevicePack:
         bad = u.copy()
         bad[0] = 10_000
         assert native.coo_group(bad, i, v, 120) is None
+
+    @pytest.mark.parametrize("native_library", [True, False], ids=["native", "numpy"])
+    def test_checked_degrees_counts_and_refuses(self, native_library, monkeypatch):
+        """One pass a column checks and counts; numpy does the same without
+        the library, and says which id it was when one is out of range."""
+        from predictionio_tpu.ops.als import _checked_degrees
+        from predictionio_tpu.utils import native
+
+        if not native_library:
+            monkeypatch.setattr(native, "degrees", lambda ids, n: None)
+        elif native.get_library() is None:
+            pytest.skip("native library unavailable")
+        u, i, _ = self._coo(seed=9)
+        deg_u, deg_i = _checked_degrees(u, i, 120, 80)
+        assert deg_u.dtype == np.int32 and deg_i.dtype == np.int32
+        np.testing.assert_array_equal(deg_u, np.bincount(u, minlength=120))
+        np.testing.assert_array_equal(deg_i, np.bincount(i, minlength=80))
+        negative = i.copy()
+        negative[5] = -1
+        assert _checked_degrees(u, negative, 120, 80) is None
+        # a negative id wins over one past the vocabulary: that rating may be
+        # the one the caller is about to drop
+        too_large = u.copy()
+        too_large[5] = 120
+        assert _checked_degrees(too_large, negative, 120, 80) is None
+        with pytest.raises(ValueError, match="user index 120 out of range for n_users=120"):
+            _checked_degrees(too_large, i, 120, 80)
+        with pytest.raises(ValueError, match="item index 99 out of range for n_items=80"):
+            _checked_degrees(u, np.where(i == 0, 99, i).astype(np.int32), 120, 80)
 
     @pytest.mark.parametrize("implicit", [False, True])
     def test_end_to_end_quality_parity_with_host_pack(self, implicit):
@@ -382,15 +471,69 @@ class TestDevicePack:
         assert np.asarray(uf).shape == (10, 4)
         assert np.all(np.isfinite(np.asarray(uf)))
 
-    def test_timings_decomposition_present(self):
+    @pytest.mark.parametrize("pack", ["device", "host"])
+    def test_timings_decomposition_present(self, pack):
         u, i, v = self._coo(nnz=2000)
         t: dict = {}
-        als_train(u, i, v, 120, 80, ALSConfig(rank=4, iterations=2), timings=t)
+        als_train(u, i, v, 120, 80, ALSConfig(rank=4, iterations=2, pack=pack), timings=t)
         assert set(t) == {
-            "pack_s", "upload_s", "build_s", "device_s", "nb_u", "nb_i", "d",
+            "pack_s", "upload_s", "build_s", "device_s", "wire_bytes",
+            "nb_u", "nb_i", "d",
         }
         assert all(val >= 0 for val in t.values())
         assert t["nb_u"] > 0 and t["nb_i"] > 0 and t["d"] >= 8
+        if pack == "device":
+            # the three columns as they are, and the two degree histograms
+            assert t["wire_bytes"] == 3 * 4 * 2000 + 4 * (120 + 80)
+        else:
+            assert t["build_s"] == 0 and t["wire_bytes"] > 3 * 4 * 2000
+
+    def test_timings_tile_the_call_and_the_spans_are_written_in_order(self, tmp_path):
+        """What ``benchmark/readers`` stand on (``timings_sum`` and
+        ``idle_under_span``): the four clocks run back to back from the
+        call's first line, and under an open profiler session each stage is
+        one host span, in the clocks' order, ``pio:als.upload`` with the
+        bytes that crossed."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData
+
+        u, i, v = self._coo(nnz=2000)
+        cfg = ALSConfig(rank=4, iterations=3)
+        als_train(u, i, v, 120, 80, cfg, timings={})  # every program compiled
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            t: dict = {}
+            t0 = time.perf_counter()
+            als_train(u, i, v, 120, 80, cfg, timings=t)
+            wall = time.perf_counter() - t0
+        finally:
+            jax.profiler.stop_trace()
+        stages = t["pack_s"] + t["upload_s"] + t["build_s"] + t["device_s"]
+        # nothing but imports and one look-up precedes the pack's clock
+        assert stages <= wall and wall - stages < 0.05, (t, wall)
+
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        spans = sorted(
+            (event.start_ns, event.name, dict(event.stats), event.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for event in line.events
+            if event.name.startswith("pio:als.")
+        )
+        assert [name for _, name, _, _ in spans] == [
+            "pio:als.pack", "pio:als.upload", "pio:als.build",
+            "pio:als.sweep", "pio:als.sweep", "pio:als.sweep", "pio:als.fetch",
+        ]
+        by_name = {name: (stats, duration) for _, name, stats, duration in spans}
+        assert by_name["pio:als.upload"][0]["bytes"] == t["wire_bytes"]
+        # a span is its clock: the pack's closes where upload_s starts
+        for name, key in (("pio:als.pack", "pack_s"), ("pio:als.upload", "upload_s")):
+            assert abs(by_name[name][1] / 1e9 - t[key]) < 0.005, (name, by_name[name], t)
 
     def test_ratings_wire_compression_forms(self):
         """Smallest lossless wire form: uint8 dictionary for <=256 distinct
@@ -425,9 +568,9 @@ class TestDevicePack:
         if table is not None:
             np.testing.assert_array_equal(table[wire], tricky)
 
-    def test_dictionary_wire_trains_identically(self):
-        """Star-rating data (dictionary wire) must produce bit-identical
-        factors to the host-pack path, which never compresses."""
+    def test_star_ratings_train_as_on_the_host_pack_path(self):
+        """Star-rating data goes up as float32 and trains to the host-pack
+        path's factors."""
         rng = np.random.default_rng(5)
         u = rng.integers(0, 120, 4000).astype(np.int32)
         i = rng.integers(0, 80, 4000).astype(np.int32)

@@ -386,7 +386,8 @@ def _compiled_text(program: str) -> str:
         )
     else:
         lowered = als._device_pack.lower(
-            S((64,), jnp.int16), S((64,), jnp.float32), S((40,), jnp.int32), S((30,), jnp.int32),
+            S((64,), jnp.int32), S((64,), jnp.int32), S((64,), jnp.float32),
+            S((40,), jnp.int32), S((30,), jnp.int32),
             d=8, nb_u=48, nb_i=40, n_users=40, n_items=30,
         )
     return lowered.compile().as_text()
