@@ -23,8 +23,12 @@ items are the tokens of OLMoE-1B-7B (``olmoe.py``: 16 heads of 128 with
 q/k norms and RoPE, 64 sparse experts with 8 a token), one causal prefill
 a query, the final-normed hidden state at the session's last position
 scored against ``lm_head`` through the same ``ops/topk.dot_top_k_async``.
-Its weights are drawn from a seed, not fitted: fitting the backbone is not
-this engine's work yet (ROADMAP R7).
+The ``kimi_linear`` scorer is a second backbone behind the same staging and
+launch (``BackboneAlgorithm``): Kimi-Linear-48B-A3B's block
+(``kimi_linear.py``: layers of four kinds, a chunked gated-delta-rule scan
+beside latent attention, a shared expert and the chip's share of 256
+sigmoid-routed experts). Both backbones' weights are drawn from a seed, not
+fitted: fitting a backbone is not this engine's work yet (ROADMAP R7).
 """
 
 from __future__ import annotations
@@ -51,7 +55,7 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.store.event_store import resolve_app
 from predictionio_tpu.e2.markov_chain import MarkovChainModel, train_markov_chain
-from predictionio_tpu.models.sequential.metrics import OlmoeInstruments
+from predictionio_tpu.models.sequential.metrics import BackboneInstruments
 from predictionio_tpu.obs.jaxprof import annotate
 from predictionio_tpu.ops import topk
 from predictionio_tpu.workflow.context import WorkflowContext
@@ -707,7 +711,9 @@ class AttentionAlgorithm(JaxAlgorithm):
 
 
 # ---------------------------------------------------------------------------
-# OLMoE algorithm (one prefill through sparse experts -> fused top-k)
+# Backbone algorithms (one prefill through a language model's block -> fused
+# top-k): `olmoe` and `kimi_linear` share the model, its storage, the staging
+# and the launch; the backbone's module and its parameters are what differs
 # ---------------------------------------------------------------------------
 
 
@@ -768,15 +774,125 @@ class OlmoeAlgorithmParams(Params):
         )
 
 
-class OlmoeModel(PersistentModel, SanityCheck):
-    """The backbone's weight tree on the device, the item vocabulary (item
+@dataclasses.dataclass(frozen=True)
+class KimiLinearAlgorithmParams(Params):
+    """The published ``config.json`` of moonshotai/Kimi-Linear-48B-A3B-Instruct,
+    key for key (a variant file carries them verbatim), the seed the weights
+    are drawn from, and the chip's share of a stated deployment:
+    ``experts_held`` ``[first, count]`` of the router's ``num_experts`` (all
+    of them by default) and ``vocab_slice`` ``[first, count]`` of
+    ``vocab_size`` (items are the slice's tokens). ``num_hidden_layers`` may
+    be fewer than published: layers 1 to that, as ``linear_attn_config``
+    numbers them. The keys the program has one answer for are refused at any
+    other value rather than ignored."""
+
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    head_dim: int = 72
+    kv_lora_rank: int = 512
+    q_lora_rank: int | None = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    linear_attn_config: dict = dataclasses.field(
+        default_factory=lambda: {
+            "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+            "head_dim": 128,
+            "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26],
+            "num_heads": 32,
+            "short_conv_kernel_size": 4,
+        }
+    )
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    moe_renormalize: bool = True
+    moe_router_activation_func: str = "sigmoid"
+    routed_scaling_factor: float = 2.446
+    use_grouped_topk: bool = True
+    num_expert_group: int = 1
+    topk_group: int = 1
+    num_nextn_predict_layers: int = 0
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    rope_scaling: dict | None = None
+    tie_word_embeddings: bool = False
+    vocab_size: int = 163840
+    model_max_length: int = 1048576
+    model_type: str = "kimi_linear"
+    experts_held: tuple | None = None
+    vocab_slice: tuple | None = None
+    seed: int = 3
+
+    def config(self):
+        from predictionio_tpu.models.sequential.kimi_linear import KimiLinearConfig
+
+        one_answer = {
+            "model_type": "kimi_linear", "hidden_act": "silu", "mla_use_nope": True,
+            "q_lora_rank": None, "moe_layer_freq": 1, "moe_renormalize": True,
+            "moe_router_activation_func": "sigmoid", "num_expert_group": 1, "topk_group": 1,
+            "num_nextn_predict_layers": 0, "rope_scaling": None, "tie_word_embeddings": False,
+            "num_key_value_heads": self.num_attention_heads,
+        }
+        for key, value in one_answer.items():
+            if getattr(self, key) != value:
+                raise ValueError(
+                    f"kimi_linear: {key}={getattr(self, key)!r} is not implemented (only {value!r})"
+                )
+        linear = self.linear_attn_config
+        return KimiLinearConfig(
+            hidden_size=self.hidden_size,
+            intermediate_size=self.intermediate_size,
+            moe_intermediate_size=self.moe_intermediate_size,
+            num_hidden_layers=self.num_hidden_layers,
+            num_attention_heads=self.num_attention_heads,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim,
+            kda_num_heads=linear["num_heads"],
+            kda_head_dim=linear["head_dim"],
+            short_conv_kernel_size=linear["short_conv_kernel_size"],
+            kda_layers=tuple(linear["kda_layers"]),
+            full_attn_layers=tuple(linear["full_attn_layers"]),
+            first_k_dense_replace=self.first_k_dense_replace,
+            num_experts=self.num_experts,
+            num_experts_per_token=self.num_experts_per_token,
+            num_shared_experts=self.num_shared_experts,
+            routed_scaling_factor=self.routed_scaling_factor,
+            rms_norm_eps=self.rms_norm_eps,
+            experts_held=tuple(self.experts_held or (0, self.num_experts)),
+            vocab_slice=tuple(self.vocab_slice or (0, self.vocab_size)),
+            model_max_length=self.model_max_length,
+        )
+
+
+class BackboneModel(PersistentModel, SanityCheck):
+    """A backbone's weight tree on the device, the item vocabulary (item
     ``i`` is token ``i``) and every user's session tail: the last
-    ``max_position_embeddings`` items, all users' in ONE int32 array with
+    ``config.max_session`` items, all users' in ONE int32 array with
     offsets (``tails[offsets[u]:offsets[u + 1]]``), not Python lists.
 
     It keeps its own storage (``save`` / ``load``): one raw file an array,
     read back array by array onto the device, so 7 GB of weights never
-    pass through ``workflow/model_io``'s one pickled blob."""
+    pass through ``workflow/model_io``'s one pickled blob.
+
+    ``program()`` is the module that holds the backbone's program
+    (``Config``, ``session_vectors``, ``init_weights``, ``bucket_of``,
+    ``program_rows``), imported when first asked for; a subclass a backbone
+    keeps a stored model's class path telling which."""
+
+    @staticmethod
+    def program():
+        raise NotImplementedError
 
     def __init__(self, config, item_vocab, users, tails, offsets, weights):
         self.config = config
@@ -792,10 +908,10 @@ class OlmoeModel(PersistentModel, SanityCheck):
     def sanity_check(self) -> None:
         if not self.item_vocab:
             raise ValueError("empty item vocab")
-        if len(self.item_vocab) > self.config.vocab_size:
+        if len(self.item_vocab) > self.config.table_rows:
             raise ValueError(
                 f"{len(self.item_vocab)} items do not fit a vocabulary of "
-                f"{self.config.vocab_size}"
+                f"{self.config.table_rows}"
             )
 
     def item_index(self) -> dict[str, int]:
@@ -819,9 +935,9 @@ class OlmoeModel(PersistentModel, SanityCheck):
 
     def session_tokens(self, query: Query) -> np.ndarray:
         """The query's session as token ids, oldest first, at most
-        ``max_position_embeddings`` of them: explicit ``recentItems`` win
+        ``config.max_session`` of them: explicit ``recentItems`` win
         (unknown items dropped), a bare ``user`` gets their stored tail."""
-        top = self.config.max_position_embeddings
+        top = self.config.max_session
         if query.recent_items:
             index = self.item_index()
             known = [index[i] for i in query.recent_items if i in index]
@@ -845,7 +961,7 @@ class OlmoeModel(PersistentModel, SanityCheck):
         return True
 
     @classmethod
-    def load(cls, instance_id: str, params: Any, base_dir: str) -> "OlmoeModel":
+    def load(cls, instance_id: str, params: Any, base_dir: str) -> "BackboneModel":
         import jax
 
         from predictionio_tpu.models.sequential import olmoe
@@ -858,7 +974,7 @@ class OlmoeModel(PersistentModel, SanityCheck):
             # a weight goes to the device and leaves the host at once
             arrays[name] = host if name in ("tails", "offsets") else jax.device_put(host)
         return cls(
-            olmoe.OlmoeConfig(**header["config"]),
+            cls.program().Config(**header["config"]),
             header["item_vocab"],
             header["users"],
             arrays.pop("tails"),
@@ -878,72 +994,70 @@ def session_tails(sequences: Sequence[np.ndarray], keep: int):
     return tails, offsets
 
 
-class OlmoeAlgorithm(JaxAlgorithm):
-    """Next-item scoring by one prefill through OLMoE-1B-7B.
+class BackboneAlgorithm(JaxAlgorithm):
+    """Next-item scoring by one prefill through a backbone: what the
+    ``olmoe`` and ``kimi_linear`` algorithms share, which is everything but
+    the backbone's module (``model_class.program()``) and its parameters.
 
     Train: builds the item vocabulary (item ``i`` is token ``i``) and every
     user's session tail from the ordered events, and DRAWS the weights from
-    ``seed`` in bfloat16. Fitting the backbone is not this PR (ROADMAP R7
-    trains Moonlight): the scores are those of a random network, and what
-    is exact is that they are THIS network's, which the reference holds.
+    ``seed`` in bfloat16. Fitting the backbone is not this engine's work yet
+    (ROADMAP R7): the scores are those of a random network, and what is
+    exact is that they are THIS network's, which the reference holds.
 
     Serve: ``predict_batch_dispatch`` does not treat a batch as B equal
     rows. It groups the batch it is handed by length bucket (64, 128, ...,
-    ``max_position_embeddings``), right-pads a group's sessions into one
-    ``[rows, bucket]`` block of ``olmoe.TOKEN_BUDGET`` tokens (a larger
-    group takes several programs, a longer session one row), launches
-    ``olmoe.session_vectors`` and ``topk.dot_top_k_async`` (session items
-    masked) for every program, and returns ONE finalize that answers in the
-    queries' order. The set of program shapes is closed
-    (``OlmoeConfig.program_shapes``) and ``warmup_serving`` compiles all of
-    it. What it launched is counted in ``instruments``, the algorithm's own
+    ``config.max_session``), right-pads a group's sessions into one
+    ``[rows, bucket]`` block of the backbone's ``TOKEN_BUDGET`` tokens (a
+    larger group takes several programs, a longer session one row), launches
+    the backbone's ``session_vectors`` and ``topk.dot_top_k_async`` (session
+    items masked) for every program, and returns ONE finalize that answers
+    in the queries' order. The set of program shapes is closed
+    (``config.program_shapes``) and ``warmup_serving`` compiles all of it.
+    What it launched is counted in ``instruments``, the algorithm's own
     until a query server hands over its registry."""
 
-    params_class = OlmoeAlgorithmParams
-    params: OlmoeAlgorithmParams
+    model_class: type[BackboneModel]
 
-    def __init__(self, params: OlmoeAlgorithmParams | None = None):
+    def __init__(self, params: Params | None = None):
         super().__init__(params)
-        self.instruments = OlmoeInstruments()
+        self.instruments = BackboneInstruments()
 
     def register_metrics(self, registry) -> None:
-        self.instruments = OlmoeInstruments(registry)
+        self.instruments = BackboneInstruments(registry)
 
-    def train(self, ctx: WorkflowContext, td: TrainingData) -> OlmoeModel:
-        from predictionio_tpu.models.sequential import olmoe
-
+    def train(self, ctx: WorkflowContext, td: TrainingData) -> BackboneModel:
         config = self.params.config()
-        tails, offsets = session_tails(td.sequences, config.max_position_embeddings)
-        model = OlmoeModel(
+        tails, offsets = session_tails(td.sequences, config.max_session)
+        model = self.model_class(
             config, td.item_vocab, td.users, tails, offsets,
-            olmoe.init_weights(config, self.params.seed),
+            self.model_class.program().init_weights(config, self.params.seed),
         )
         model.sanity_check()
         return model
 
     # ------------------------------------------------------------- serving
     @staticmethod
-    def _plan(model: OlmoeModel, queries: Sequence[Query]):
+    def _plan(model: BackboneModel, queries: Sequence[Query]):
         """Look-up and bucketing: ``(sessions, programs)``, a program being
         ``(bucket, rows, [query index, ...])``. Queries with no session are
         in no program."""
-        from predictionio_tpu.models.sequential import olmoe
-
+        program = model.program()
         buckets = model.config.buckets()
         sessions = [model.session_tokens(q) for q in queries]
         groups: dict[int, list[int]] = {}
         for i, session in enumerate(sessions):
             if len(session):
-                groups.setdefault(olmoe.bucket_of(len(session), buckets), []).append(i)
+                groups.setdefault(program.bucket_of(len(session), buckets), []).append(i)
         programs = []
         for bucket in sorted(groups):
-            members, rows = groups[bucket], olmoe.program_rows(bucket)
+            members, rows = groups[bucket], program.program_rows(bucket)
             for start in range(0, len(members), rows):
                 programs.append((bucket, rows, members[start : start + rows]))
         return sessions, programs
 
     @staticmethod
-    def _stage(model: OlmoeModel, sessions, program):
+    def _stage(model: BackboneModel, sessions, program):
         """One program's host arrays: tokens right-padded with token 0 (any
         token would do: no real position sees it), each row's last real
         position (-1 for a padding row), and the candidate mask without the
@@ -951,7 +1065,7 @@ class OlmoeAlgorithm(JaxAlgorithm):
         bucket, rows, members = program
         tokens = np.zeros((rows, bucket), np.int32)
         last = np.full(rows, -1, np.int32)
-        mask = np.zeros((rows, model.config.vocab_size), bool)
+        mask = np.zeros((rows, model.config.table_rows), bool)
         mask[: len(members), : len(model.item_vocab)] = True
         for row, i in enumerate(members):
             session = sessions[i]
@@ -960,10 +1074,9 @@ class OlmoeAlgorithm(JaxAlgorithm):
             mask[row, session] = False
         return tokens, last, mask
 
-    def predict_batch_dispatch(self, model: OlmoeModel, queries: Sequence[Query]):
-        from predictionio_tpu.models.sequential import olmoe
-
+    def predict_batch_dispatch(self, model: BackboneModel, queries: Sequence[Query]):
         config = model.config
+        session_vectors = model.program().session_vectors
         t0 = time.perf_counter()
         sessions, programs = self._plan(model, queries)
         with annotate("pio:seq.stage", batch=len(queries), programs=len(programs)):
@@ -975,21 +1088,26 @@ class OlmoeAlgorithm(JaxAlgorithm):
         for (bucket, rows, members), (tokens, last, mask) in zip(programs, staged):
             real = int(last[: len(members)].sum()) + len(members)
             with annotate("pio:seq.launch", bucket=bucket, rows=rows, tokens=real):
-                vectors, busiest = olmoe.session_vectors(
+                vectors, counted = session_vectors(
                     model.weights, topk.upload(tokens, np.int32), topk.upload(last, np.int32),
                     config=config,
                 )
                 handle = topk.dot_top_k_async(model.head(), vectors, mask, kk)
             self.instruments.on_launch(bucket, rows, real)
-            launched.append((handle, busiest, real))
+            launched.append((handle, counted, real))
 
         def finalize() -> list[PredictedResult]:
             out: list[PredictedResult] = [PredictedResult(())] * len(queries)
-            for (bucket, rows, members), (handle, busiest, real) in zip(programs, launched):
+            for (bucket, rows, members), (handle, counted, real) in zip(programs, launched):
                 scores, idx = topk.fetch_topk(handle)
-                # one integer a program rides back with its answer
-                even = real * config.num_experts_per_tok / config.num_experts
-                self.instruments.on_expert_load(int(busiest), config.num_hidden_layers * even)
+                # an integer or two a program ride back with its answer: the
+                # busiest expert's copies and, where the chip holds a share of
+                # the experts, the copies routed to a held one
+                counted = np.atleast_1d(np.asarray(counted, np.int64))
+                routed = config.routed_copies(real)
+                held = int(counted[1]) if counted.size > 1 else routed
+                self.instruments.on_expert_load(int(counted[0]), config.even_expert_load(real))
+                self.instruments.on_copies(held, routed - held)
                 for row, i in enumerate(members):
                     picks = [
                         ItemScore(model.item_vocab[int(item)], float(score))
@@ -1002,14 +1120,14 @@ class OlmoeAlgorithm(JaxAlgorithm):
         return finalize
 
     def predict_batch(
-        self, model: OlmoeModel, queries: Sequence[Query]
+        self, model: BackboneModel, queries: Sequence[Query]
     ) -> list[PredictedResult]:
         return self.predict_batch_dispatch(model, queries)()
 
-    def predict(self, model: OlmoeModel, query: Query) -> PredictedResult:
+    def predict(self, model: BackboneModel, query: Query) -> PredictedResult:
         return self.predict_batch(model, [query])[0]
 
-    def warmup_serving(self, model: OlmoeModel, max_batch: int) -> None:
+    def warmup_serving(self, model: BackboneModel, max_batch: int) -> None:
         """Compile every program shape there is, by the path serving takes:
         for each ``(rows, bucket)`` a batch of ``rows`` sessions of
         ``bucket`` items (the staging copies, ``session_vectors`` and the
@@ -1020,6 +1138,38 @@ class OlmoeAlgorithm(JaxAlgorithm):
         for rows, bucket in model.config.program_shapes():
             items = tuple(model.item_vocab[i % n] for i in range(bucket))
             self.predict_batch(model, [Query(recent_items=items, num=num)] * rows)
+
+
+class OlmoeModel(BackboneModel):
+    @staticmethod
+    def program():
+        from predictionio_tpu.models.sequential import olmoe
+
+        return olmoe
+
+
+class OlmoeAlgorithm(BackboneAlgorithm):
+    """``olmoe``: OLMoE-1B-7B (``olmoe.py``)."""
+
+    params_class = OlmoeAlgorithmParams
+    params: OlmoeAlgorithmParams
+    model_class = OlmoeModel
+
+
+class KimiLinearModel(BackboneModel):
+    @staticmethod
+    def program():
+        from predictionio_tpu.models.sequential import kimi_linear
+
+        return kimi_linear
+
+
+class KimiLinearAlgorithm(BackboneAlgorithm):
+    """``kimi_linear``: Kimi-Linear-48B-A3B's block (``kimi_linear.py``)."""
+
+    params_class = KimiLinearAlgorithmParams
+    params: KimiLinearAlgorithmParams
+    model_class = KimiLinearModel
 
 
 # ---------------------------------------------------------------------------
@@ -1040,6 +1190,7 @@ def engine_factory() -> Engine:
             "markov": MarkovAlgorithm,
             "attention": AttentionAlgorithm,
             "olmoe": OlmoeAlgorithm,
+            "kimi_linear": KimiLinearAlgorithm,
         },
         Serving,
         query_class=Query,

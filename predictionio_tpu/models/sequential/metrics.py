@@ -1,6 +1,6 @@
 """The ``pio_seq_*`` metric families (docs/observability.md): the stream
-trainer's (``SeqInstruments``) and the ``olmoe`` scorer's
-(``OlmoeInstruments``, with ``pio_moe_*``).
+trainer's (``SeqInstruments``) and the backbone scorers' (``olmoe``,
+``kimi_linear``: ``BackboneInstruments``, with ``pio_moe_*``).
 
 ``SeqInstruments`` is registered eagerly (AnnInstruments discipline): the family exists at zero
 from process start so scrapers and the docs metrics-contract test see it
@@ -57,9 +57,10 @@ class SeqInstruments:
         self.snapshots.inc()
 
 
-class OlmoeInstruments:
-    """What the ``olmoe`` scorer launched, counted where it happens
-    (``engine.OlmoeAlgorithm``). An algorithm starts with a registry of its
+class BackboneInstruments:
+    """What a backbone scorer (``olmoe``, ``kimi_linear``) launched, counted
+    where it happens (``engine.BackboneAlgorithm``). The expert counters are
+    over the experts the chip HOLDS. An algorithm starts with a registry of its
     own; a query server that serves it hands over its registry through
     ``register_metrics``, so two deployments in one process count apart."""
 
@@ -101,6 +102,18 @@ class OlmoeInstruments:
             "copies of real tokens an even split would give each expert, "
             "summed over layers and programs",
         )
+
+        self.copies = r.counter(
+            "pio_moe_copies_total",
+            "copies of REAL tokens the routers sent out, by whether the "
+            "expert is one the chip holds (where=held: computed here) or "
+            "another chip's (where=absent: left out)",
+            labelnames=("where",),
+        )
+
+    def on_copies(self, held: int, absent: int) -> None:
+        self.copies.inc(float(held), where="held")
+        self.copies.inc(float(absent), where="absent")
 
     def on_stage(self, seconds: float) -> None:
         self.stage_seconds.inc(seconds)

@@ -23,6 +23,9 @@ away (``pio_seq_tokens_total{kind}`` counts them).
 
 The weights are drawn from a seed, not fitted: fitting the backbone is not
 this engine's work yet (ROADMAP R7).
+
+``save_arrays`` / ``load_array`` at the end are the storage of every
+backbone's model (``engine.BackboneModel``), not OLMoE's alone.
 """
 
 from __future__ import annotations
@@ -76,6 +79,26 @@ class OlmoeConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
 
+    # what the engine asks of any backbone's configuration
+    @property
+    def table_rows(self) -> int:
+        """Rows of ``embed`` and ``lm_head``: the items a session may hold."""
+        return self.vocab_size
+
+    @property
+    def max_session(self) -> int:
+        """Items of a session the engine keeps."""
+        return self.max_position_embeddings
+
+    def routed_copies(self, real_tokens: int) -> int:
+        """Copies of ``real_tokens`` the routers send out, over all layers."""
+        return self.num_hidden_layers * real_tokens * self.num_experts_per_tok
+
+    def even_expert_load(self, real_tokens: float) -> float:
+        """Copies of ``real_tokens`` an even split gives each expert, summed
+        over the layers."""
+        return self.num_hidden_layers * real_tokens * self.num_experts_per_tok / self.num_experts
+
     def buckets(self) -> tuple[int, ...]:
         """The length buckets up to the longest session the model takes."""
         top = self.max_position_embeddings
@@ -85,6 +108,9 @@ class OlmoeConfig:
         """Every ``(rows, bucket)`` a program is launched at: the closed set
         ``warmup`` compiles."""
         return tuple((program_rows(bucket), bucket) for bucket in self.buckets())
+
+
+Config = OlmoeConfig
 
 
 def program_rows(bucket: int) -> int:

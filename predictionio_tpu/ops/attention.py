@@ -288,7 +288,7 @@ def _flash_attention_pallas(
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
     bq, bk = min(block_q, Lq), min(block_k, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, "flash path requires divisible blocks"
     nq, nk = Lq // bq, Lk // bk
@@ -355,32 +355,32 @@ def _flash_attention_pallas(
 
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, D)
+    vr = v.reshape(B * H, Lk, Dv)
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(B, H, Lq, D)
+    return out.reshape(B, H, Lq, Dv)
 
 
 def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool):
     from jax.experimental import pallas as pl
 
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
 
     def kernel(q_ref, k_ref, v_ref, o_ref):
         qb = q_ref[0]  # [Lq, D]
@@ -413,20 +413,20 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool):
     grid = (B * H,)
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, D)
+    vr = v.reshape(B * H, Lk, Dv)
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, Lk, D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, Lk, D), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, Lk, Dv), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, Lq, Dv), lambda i: (i, 0, 0)),
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(B, H, Lq, D)
+    return out.reshape(B, H, Lq, Dv)
 
 
 def fused_attention(
@@ -436,7 +436,11 @@ def fused_attention(
     causal: bool = False,
     force_pallas: bool = False,
 ) -> jnp.ndarray:
-    """Single-device attention. On TPU: pallas kernel — the single-block
+    """Single-device attention over ``q`` [B, H, Lq, D], ``k`` [B, H, Lk, D]
+    and ``v`` [B, H, Lk, Dv]: queries and keys share a head width, the
+    values may have another (latent attention expanded for a prefill: 192
+    and 128; ``v`` is never padded to ``D``), the output is [B, H, Lq, Dv]
+    and the scores are scaled by ``D ** -0.5``. On TPU: pallas kernel — the single-block
     variant when the whole [Lq, Lk] score tile fits VMEM comfortably, the
     tiled flash variant for long sequences. Elsewhere: the jnp reference
     path (``force_pallas`` runs the kernels in interpret mode, which is

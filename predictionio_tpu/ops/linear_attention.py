@@ -1,0 +1,167 @@
+"""Linear attention by the gated delta rule with a decay a CHANNEL (Kimi
+Delta Attention, KDA), evaluated chunk by chunk, and the short causal
+convolution that feeds it.
+
+A head keeps a state ``S`` [keys, values] and reads it with its query::
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T,   o_t = S_t^T q_t
+
+with ``a_t = exp(g_t)`` in (0, 1] a channel of the keys and ``b_t`` in
+(0, 1) a head. Written with the pseudo-value ``u_t = b_t (v_t - (a_t k_t)^T
+S_{t-1})`` the state is ``S_t = Diag(a_t) S_{t-1} + k_t u_t^T``, and over a
+chunk of ``C`` positions, ``G_t`` the running sum of ``g`` from the chunk's
+start::
+
+    (I + A) U = b (V - (K e^G) S_0),   A[t, s] = b_t sum_c k_t[c] k_s[c] e^(G_t[c] - G_s[c])  (s < t)
+    O = (Q e^G) S_0 + M U,             M[t, s] = sum_c q_t[c] k_s[c] e^(G_t[c] - G_s[c])      (s <= t)
+    S_C = Diag(e^(G_C)) S_0 + (K e^(G_C - G))^T U
+
+``A``, ``M`` and the solve with the unit lower-triangular ``I + A`` (the WY /
+UT form) are every chunk's own and computed for all chunks at once; only the
+three lines above with ``S_0`` in them run under a ``lax.scan`` over the
+chunks, which carries the state.
+
+Decays stay in log space and every exponent that is taken is at most 0. The
+triangles are built HALF BY HALF: a block of ``2h`` positions is its two
+halves' own triangles and the square under them, rows of the second half
+against keys of the first, and in that square ``e^(G_t - G_s)`` is split at
+the LAST position ``r`` of the first half, ``e^(G_t - G_r) e^(G_r - G_s)``
+with ``s <= r < t``: one product of two factors that are both at most 1.
+From single positions (the diagonal, where the exponent is 0) up to the
+chunk that is ``log2 C`` levels of batched products. No ``exp(-cumsum g)`` is
+formed, so a decay of any strength neither overflows nor loses the pairs it
+does not kill. ``(I + A)^-1`` grows by the same halves, exactly (no series
+whose terms cancel): the inverse of a unit lower-triangular block is its
+halves' inverses and ``-T_22 A_21 T_11`` under them.
+
+Everything here is float32 and the products run at ``PRECISION`` (float32
+operands in three bf16 passes on the chip): the state is what a later
+position reads every earlier one through.
+
+Right-padded sessions need no mask: a real position never sees what follows
+it. The final state of a padded row is the padding's too, and of use only
+to a caller that passed no padding.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["short_conv", "kda", "CHUNK"]
+
+# positions a chunk holds (a power of two): the published kernels' choice
+CHUNK = 64
+# three bf16 passes a float32 product: on the chip 2.7e-5 of the outputs' size
+# from six passes (`highest`) and 0.8 ms of 6.6 a layer faster at a 2,048-token
+# program; one pass is off by 6e-3 (my chip runs, PR 31). Off the chip: float32
+PRECISION = lax.Precision.HIGH
+
+_dot = functools.partial(jnp.einsum, precision=PRECISION, preferred_element_type=jnp.float32)
+
+
+def short_conv(x, w, tail=None):
+    """``silu`` of a causal depthwise convolution over positions: ``x``
+    [B, L, D] float32, ``w`` [taps, D] (``w[-1]`` meets the position itself),
+    ``tail`` [B, taps - 1, D] the inputs that came before position 0 (zeros
+    when there were none). Returns ``(y [B, L, D], tail')``, ``tail'`` the
+    last ``taps - 1`` inputs, for a caller that goes on from here."""
+    taps, length = w.shape[0], x.shape[1]
+    if tail is None:
+        tail = jnp.zeros((x.shape[0], taps - 1, x.shape[2]), x.dtype)
+    padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = w.astype(x.dtype)
+    y = sum(w[j] * padded[:, j : j + length] for j in range(taps))
+    return jax.nn.silu(y), padded[:, length:]
+
+
+def _triangles(q, k, b, cum):
+    """``(M, T)`` of every chunk, each [..., C, C]: ``M[t, s] = sum_c q_t[c]
+    k_s[c] e^(cum_t[c] - cum_s[c])`` for ``s <= t`` (0 above the diagonal)
+    and ``T = (I + A)^-1`` with ``A[t, s] = b_t sum_c k_t[c] k_s[c] e^(cum_t[c]
+    - cum_s[c])`` for ``s < t``. ``q``, ``k``, ``cum`` [..., C, d], ``b``
+    [..., C, 1]; ``cum`` the running sum of the log decays from the chunk's
+    start; ``C`` a power of two. Built half by half (the module's docstring)."""
+    lead, size = k.shape[:-2], k.shape[-2]
+    m = jnp.sum(q * k, axis=-1)[..., None, None]  # single positions: [..., C, 1, 1]
+    t = jnp.ones_like(m)
+    half = 1
+    while half < size:
+        pairs = size // (2 * half)
+
+        def halves(x):
+            x = x.reshape(lead + (pairs, 2, half, x.shape[-1]))
+            return x[..., 0, :, :], x[..., 1, :, :]
+
+        def grown(blocks, under):
+            # [[first half's, 0], [under, second half's]]
+            blocks = blocks.reshape(lead + (pairs, 2, half, half))
+            top = jnp.concatenate([blocks[..., 0, :, :], jnp.zeros_like(under)], axis=-1)
+            return jnp.concatenate([top, jnp.concatenate([under, blocks[..., 1, :, :]], axis=-1)], axis=-2)
+
+        (k_1, k_2), (_, q_2), (cum_1, cum_2), (_, b_2) = halves(k), halves(q), halves(cum), halves(b)
+        split = cum_1[..., -1:, :]  # at the first half's last position
+        right = k_1 * jnp.exp(split - cum_1)
+        since = jnp.exp(cum_2 - split)
+        a_21 = _dot("...tc,...sc->...ts", b_2 * k_2 * since, right)
+        m_21 = _dot("...tc,...sc->...ts", q_2 * since, right)
+        t_1, t_2 = halves(t.reshape(lead + (2 * pairs * half, half)))
+        t_21 = -_dot("...ts,...sr->...tr", _dot("...ts,...sr->...tr", t_2, a_21), t_1)
+        m, t = grown(m, m_21), grown(t, t_21)
+        half *= 2
+    return m[..., 0, :, :], t[..., 0, :, :]
+
+
+def kda(q, k, v, g, b, state=None):
+    """The gated delta rule over ``q``, ``k``, ``g`` [B, L, heads, d_k],
+    ``v`` [B, L, heads, d_v] and ``b`` [B, L, heads], float32: ``q`` and
+    ``k`` as the state is to meet them (normalised, ``q`` scaled), ``g`` the
+    log decay a channel (at most 0), ``b`` the step size. ``state``
+    [B, heads, d_k, d_v] is what came before position 0 (zeros by default).
+    Returns ``(o [B, L, heads, d_v], the state after position L - 1)``.
+
+    ``L`` is padded to whole chunks of ``CHUNK`` with positions that leave
+    the state as it is (``k`` 0, ``b`` 0, ``g`` 0)."""
+    batch, length, heads, d_k = k.shape
+    d_v = v.shape[-1]
+    chunk = CHUNK
+    if chunk & (chunk - 1):
+        raise ValueError(f"kda: a chunk of {chunk} positions is no power of two")
+    n = -(-length // chunk)
+    pad = n * chunk - length
+
+    def chunked(x):
+        # [B, L, heads, ...] -> [n, B, heads, chunk, ...]
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((batch, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    q, k, v, g = (chunked(x.astype(jnp.float32)) for x in (q, k, v, g))
+    b = chunked(b.astype(jnp.float32)[..., None])  # [n, B, heads, chunk, 1]
+    cum = jnp.cumsum(g, axis=-2)
+    m, t = _triangles(q, k, b, cum)
+    grown = jnp.exp(cum)
+    # (I + A)^-1 of the values and of the keys as S_0 meets them, in one product
+    solved = _dot("...ts,...sn->...tn", t, jnp.concatenate([b * v, b * k * grown], axis=-1))
+    u_own, w = solved[..., :d_v], solved[..., d_v:]
+    q_in = q * grown
+    k_out = k * jnp.exp(cum[..., -1:, :] - cum)
+    kept = jnp.exp(cum[..., -1, :])  # [n, B, heads, d_k]
+
+    def step(s, xs):
+        u_own, w, q_in, m, k_out, kept = xs
+        u = u_own - _dot("bhtc,bhcv->bhtv", w, s)
+        o = _dot("bhtc,bhcv->bhtv", q_in, s) + _dot("bhts,bhsv->bhtv", m, u)
+        s = kept[..., None] * s + _dot("bhtc,bhtv->bhcv", k_out, u)
+        return s, o
+
+    if state is None:
+        state = jnp.zeros((batch, heads, d_k, d_v), jnp.float32)
+    state, o = lax.scan(step, state.astype(jnp.float32), (u_own, w, q_in, m, k_out, kept))
+    # [n, B, heads, chunk, d_v] -> [B, L, heads, d_v]
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(batch, n * chunk, heads, d_v)
+    return o[:, :length], state

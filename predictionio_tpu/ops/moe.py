@@ -1,13 +1,22 @@
 """Sparse experts: router, grouping of tokens by expert, grouped products.
 
-A mixture-of-experts feed-forward as OLMoE has it: the router scores every
-token against every expert in float32, a token goes to its ``k`` best and
-their softmax weights are NOT renormalised; each expert is a gated MLP
-``down(silu(gate(x)) * up(x))``. One chip holds every expert, so the work
-is three GROUPED products: the tokens' copies are sorted by expert, and
-rows ``offsets[e]:offsets[e+1]`` of the sorted block meet expert ``e``'s
-matrix. No token is dropped and there is no capacity factor: a group is as
-long as the router made it, whatever the imbalance.
+A mixture-of-experts feed-forward: the router scores every token against
+every expert in float32 and a token goes to its ``k`` best, in one of two
+published forms: :func:`route` (softmax over the experts, the chosen weights
+as they are) and :func:`route_sigmoid` (sigmoid scores, chosen by score plus
+a selection bias, weighed by the scores over their sum times a scale). Each
+expert is a gated MLP ``down(silu(gate(x)) * up(x))`` (:func:`gated_mlp`,
+which is also a shared expert and a dense feed-forward). The experts' work is
+three GROUPED products: the tokens' copies are sorted by expert, and rows
+``offsets[e]:offsets[e+1]`` of the sorted block meet expert ``e``'s matrix.
+No token is dropped and there is no capacity factor: a group is as long as
+the router made it, whatever the imbalance.
+
+A chip may hold a SHARE of the experts its router knows (``held``): it then
+computes the held experts' part of the result for the tokens routed to them.
+The copies routed to the absent experts are sorted behind the held groups,
+meet no matrix and add nothing; no code stands in for the chips that hold
+the others or for the exchange with them.
 
 Scopes (``jax.named_scope``; the benchmark's per-layer metrics read them):
 ``router`` (the caller wraps :func:`route` in it), and inside ``experts``:
@@ -25,7 +34,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-__all__ = ["route", "expert_load", "grouped_matmul", "expert_ffn"]
+__all__ = [
+    "route", "route_sigmoid", "expert_load", "grouped_matmul", "expert_ffn", "gated_mlp",
+]
 
 # (rows, contraction, columns) of the grouped product's tiles, from the chip
 # (PERF.md, PR 26): 256 rows by 1,024 columns with the contraction WHOLE (so
@@ -45,6 +56,23 @@ def route(x, router_w, k: int):
         precision=lax.Precision.HIGHEST,
     )
     return lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+def route_sigmoid(x, router_w, bias, k: int, scale: float):
+    """``(weights [T, k] float32, experts [T, k] int32)`` in the sigmoid
+    form: ``s = sigmoid(x @ router_w)`` over ALL experts (float32, the
+    product at ``highest``, as in :func:`route`); the ``k`` chosen are the
+    top ``k`` of ``s + bias`` (the bias steers the choice only); their
+    weights are ``s`` at the chosen over their sum, times ``scale``."""
+    logits = jnp.dot(
+        x.astype(jnp.float32),
+        router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True), experts
 
 
 def expert_load(experts, n_experts: int, counted=None):
@@ -90,7 +118,17 @@ def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = Fa
     )
 
 
-def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=0):
+def gated_mlp(x, gate, up, down):
+    """``down(silu(gate(x)) * up(x))`` for ``x`` [T, hidden]: a shared
+    expert or a dense feed-forward. Operands in the weights' type, float32
+    accumulation; returns float32."""
+    xs = x.astype(gate.dtype)
+    g = jnp.dot(xs, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(xs, up, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(down.dtype), down, preferred_element_type=jnp.float32)
+
+
+def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=0, held=None):
     """``sum_j weights[t, j] * ffn_{experts[t, j]}(x[t])`` for tokens ``x``
     [T, hidden]: ``gate`` and ``up`` [G, hidden, width], ``down``
     [G, width, hidden], expert ``e``'s matrices at ``first_group + e``.
@@ -100,16 +138,32 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
     then reads the layer's matrices where they lie; a slice taken in front
     of it is a copy of all of them (0.8 GB a layer at OLMoE's widths, 2 ms).
 
+    ``held`` ``(first, count)`` says WHICH of the router's experts the
+    matrices are: experts ``first`` to ``first + count - 1``, expert ``e``'s
+    at ``first_group + e - first``. The sum then runs over the copies routed
+    to those; a copy routed to an absent expert is sorted behind the held
+    groups, where no product reads or writes its row (the kernel leaves the
+    rows past the last group as it found them), and counts as zero in the
+    combine. By default every expert the router knows is held.
+
     Returns ``y`` [T, hidden] float32."""
     tokens, k = experts.shape
     groups = gate.shape[0]
-    n_experts = groups if n_experts is None else n_experts
     with jax.named_scope("sort"):
         flat = experts.reshape(-1)
-        order = jnp.argsort(flat, stable=True)  # copies, sorted by expert
+        if held is None:
+            n_experts = groups if n_experts is None else n_experts
+            order = jnp.argsort(flat, stable=True)  # copies, sorted by expert
+            load = expert_load(experts, n_experts)
+        else:
+            first, n_experts = held
+            # a held expert's place among the held; the absent behind them all
+            flat = jnp.where((flat >= first) & (flat < first + n_experts), flat - first, n_experts)
+            order = jnp.argsort(flat, stable=True)
+            load = expert_load(flat[:, None], n_experts)
         sizes = lax.dynamic_update_slice(
             jnp.zeros(groups, jnp.int32),
-            expert_load(experts, n_experts),
+            load,
             (jnp.asarray(first_group, jnp.int32),),
         )
         xs = x.astype(gate.dtype)[order // k]  # [T*k, hidden]
@@ -123,6 +177,10 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
         # where each copy went: the inverse of the sort, by one scatter of
         # T*k integers, then a gather of rows (no scatter-add of rows)
         place = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
-        out = out[place].reshape(tokens, k, -1)
+        out = out[place]
+        if held is not None:
+            # a row past the held groups was never written: it is not read as it is
+            out = jnp.where((place < jnp.sum(load))[:, None], out, 0.0)
+        out = out.reshape(tokens, k, -1)
         y = jnp.sum(out * weights[..., None], axis=1)
     return y

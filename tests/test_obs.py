@@ -1316,9 +1316,9 @@ class TestMetricsContract:
         registered.update(StreamInstruments().registry._metrics)
         # the olmoe scorer's family is its algorithm's: a query server that
         # serves it hands over its registry (`register_metrics`)
-        from predictionio_tpu.models.sequential.metrics import OlmoeInstruments
+        from predictionio_tpu.models.sequential.metrics import BackboneInstruments
 
-        registered.update(OlmoeInstruments().registry._metrics)
+        registered.update(BackboneInstruments().registry._metrics)
         # the offline batchpredict family rides the run's own registry
         # (no server to scrape — docs/batch_predict.md)
         from predictionio_tpu.workflow.batch_predict import (

@@ -1,0 +1,479 @@
+"""The sequential engine's ``kimi_linear`` scorer against its plain reference,
+at a tiny size on the CPU with every kind of layer: 4 layers (KDA with the
+dense feed-forward, KDA sparse, latent attention sparse, KDA sparse), hidden
+64, 4 heads of 16, latent rank 24, keys of 16 + 8 and values of 16, 16 routed
+experts of width 32 with 4 a token of which the chip holds experts 4 to 7,
+one shared expert, a vocabulary slice of 128.
+
+Where a test compares values it upcasts the algorithm's own bf16 draws to
+float32 for both sides, as ``test_sequential_olmoe.py`` does.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.controller import PersistentModelManifest
+from predictionio_tpu.models.sequential import (
+    KimiLinearAlgorithm,
+    KimiLinearAlgorithmParams,
+    KimiLinearModel,
+    OlmoeAlgorithm,
+    Query,
+    TrainingData,
+    engine_factory,
+    kimi_linear,
+    kimi_linear_reference as reference,
+)
+from predictionio_tpu.ops import attention, moe
+
+TINY = dict(
+    hidden_size=64, intermediate_size=96, moe_intermediate_size=32, num_hidden_layers=4,
+    num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=24, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16,
+    linear_attn_config={
+        "kda_layers": [1, 2, 4], "full_attn_layers": [3], "num_heads": 4, "head_dim": 16,
+        "short_conv_kernel_size": 4,
+    },
+    num_experts=16, num_experts_per_token=4, vocab_size=512, experts_held=(4, 4),
+    vocab_slice=(128, 128), model_max_length=128,
+)
+N_ITEMS = 120  # 8 rows of the slice are no item
+# float32 against float32 on the CPU: the sides differ by the order of their
+# sums (the chunked scan against the recurrence, blocked attention, grouped
+# products against a loop over experts), through four layers; logits are of
+# unit order and the worst seen over the seeds below is 5e-6. 1e-4 is twenty
+# times that and twenty times under what ONE bf16 product does (2^-9).
+ATOL = 1e-4
+# The algorithm's own bf16 tree against the SAME values in float32 through
+# the reference: every projection rounds its operands to bf16 (2^-9 each)
+# while the scan, the norms and the router stay float32. That error is small
+# and everywhere: over seeds 8 to 10 the MEDIAN position's worst logit is off
+# by 0.016 to 0.042 of unit-order logits; 0.1 is over twice that. And it is
+# large and rare: 2 to 7% of positions are off by 0.15 to 0.9, where a stream
+# off by 1e-2 tips one of the sigmoid router's close choices and a token
+# takes another expert (at 4 of 16 experts of width 32 one expert is a large
+# part of a token's layer), as OLMoE's softmax router's ties do. So the
+# median is held tight and the share of tipped positions loosely; a wrong
+# layer, decay or share moves EVERY position by the logits' own order.
+BF16_MEDIAN, BF16_TIPPED = 0.1, (0.15, 0.2)
+
+
+@pytest.fixture(autouse=True)
+def small_programs(monkeypatch):
+    """A program holds 256 tokens here: bucket 64 comes 4 rows high."""
+    monkeypatch.setattr(kimi_linear, "TOKEN_BUDGET", 256)
+
+
+def training_data(seed=0, n_users=12) -> TrainingData:
+    rng = np.random.default_rng(seed)
+    lengths = rng.choice([3, 17, 40, 64, 65, 70], n_users)
+    lengths[:3] = (3, 64, 70)
+    return TrainingData(
+        [f"u{i}" for i in range(n_users)],
+        [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths],
+        [f"i{i}" for i in range(N_ITEMS)],
+    )
+
+
+def upcast(weights):
+    return jax.tree.map(lambda a: a.astype(jnp.float32), weights)
+
+
+@pytest.fixture(scope="module")
+def trained():
+    algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**TINY, seed=5))
+    model = algorithm.train(None, training_data())
+    model.weights = upcast(model.weights)
+    return algorithm, model
+
+
+def reference_config(params: KimiLinearAlgorithmParams, **changes) -> dict:
+    """What the reference reads: the published keys and the chip's share."""
+    return {**dataclasses.asdict(params), **changes}
+
+
+_logits: dict = {}
+_jitted: dict = {}
+
+
+def reference_answer(algorithm, model, session: np.ndarray, num: int):
+    config = reference_config(algorithm.params)
+    if id(model) not in _jitted:
+        weights = model.weights
+        _jitted[id(model)] = jax.jit(lambda t: reference.next_item_logits(weights, config, t))
+    key = (id(model), session.tobytes())
+    if key not in _logits:
+        _logits[key] = np.asarray(_jitted[id(model)](jnp.asarray(session)))
+    logits = _logits[key]
+    allowed = np.ones(len(logits), bool)
+    allowed[N_ITEMS:] = False
+    allowed[session] = False
+    return logits, np.argsort(-np.where(allowed, logits, -np.inf), kind="stable")[:num]
+
+
+# ---------------------------------------------------------------- ops/moe
+
+
+def test_the_sigmoid_router_selects_by_score_plus_bias_and_weighs_by_score():
+    # three experts, one a token: the bias lifts expert 2 over expert 0 in
+    # the CHOICE; the weight is the score without it, renormalised and scaled
+    x = jnp.eye(2, dtype=jnp.float32)
+    router = jnp.asarray([[2.0, 0.0, 1.5], [0.0, 2.0, -3.0]])
+    bias = jnp.asarray([0.0, 0.0, 0.2])
+    weights, experts = moe.route_sigmoid(x, router, bias, 1, 2.5)
+    assert experts.tolist() == [[2], [1]]  # by s alone token 0 would take expert 0
+    np.testing.assert_allclose(weights, [[2.5], [2.5]], rtol=1e-6)  # one chosen: s / s * scale
+    weights, experts = moe.route_sigmoid(x, router, bias, 2, 2.5)
+    s = jax.nn.sigmoid(router)
+    assert experts.tolist() == [[2, 0], [1, 0]]
+    np.testing.assert_allclose(
+        weights[0], 2.5 * np.array([s[0, 2], s[0, 0]]) / (s[0, 2] + s[0, 0]), rtol=1e-6
+    )
+    _, plain = moe.route_sigmoid(x, router, jnp.zeros(3), 1, 2.5)
+    assert plain.tolist() == [[0], [1]]
+
+
+def expert_case(seed, tokens=96, hidden=32, width=16, n_experts=16, k=4):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(tokens, hidden)), jnp.float32)
+    gate, up = (jnp.asarray(rng.normal(size=(n_experts, hidden, width)) / 6, jnp.float32) for _ in range(2))
+    down = jnp.asarray(rng.normal(size=(n_experts, width, hidden)) / 4, jnp.float32)
+    router = jnp.asarray(rng.normal(size=(hidden, n_experts)) / 6, jnp.float32)
+    bias = jnp.asarray(rng.normal(size=n_experts) * 0.1, jnp.float32)
+    return x, gate, up, down, router, bias
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_every_expert_held_by_default_or_by_name_is_the_same_bit_for_bit(seed):
+    # what OLMoE calls (no `held`) and the same call with every expert named
+    x, gate, up, down, router, _ = expert_case(seed)
+    weights, experts = moe.route(x, router, 4)
+    default = moe.expert_ffn(x, weights, experts, gate, up, down)
+    named = moe.expert_ffn(x, weights, experts, gate, up, down, held=(0, 16))
+    np.testing.assert_array_equal(default, named)
+    # ... and stacked behind another layer's, as OLMoE's scan reads them
+    stacked = [jnp.concatenate([jnp.zeros_like(a), a]) for a in (gate, up, down)]
+    behind = moe.expert_ffn(x, weights, experts, *stacked, n_experts=16, first_group=16)
+    np.testing.assert_array_equal(default, behind)
+
+
+@pytest.mark.parametrize("seed,shares", [(0, 4), (1, 2), (2, 8)])
+def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_once(seed, shares):
+    x, gate, up, down, router, bias = expert_case(seed)
+    shared = [a[0] for a in (gate, up, down)]  # any gated MLP will do
+    layer = {
+        "router": router, "router_bias": bias, "gate": gate, "up": up, "down": down,
+        "shared_gate": shared[0], "shared_up": shared[1], "shared_down": shared[2],
+    }
+    config = {"num_experts_per_token": 4, "routed_scaling_factor": 2.446, "experts_held": [0, 16]}
+    uncut = reference.sparse_ffn(x, layer, config)  # the reference's whole layer
+    weights, experts = moe.route_sigmoid(x, router, bias, 4, 2.446)
+    each = 16 // shares
+    total = moe.gated_mlp(x, *shared)  # what every chip computes alike: once
+    for chip in range(shares):
+        block = slice(chip * each, (chip + 1) * each)
+        part = moe.expert_ffn(
+            x, weights, experts, gate[block], up[block], down[block], held=(chip * each, each)
+        )
+        # the reference, given the same share, gives the same part
+        own = {**layer, "gate": gate[block], "up": up[block], "down": down[block]}
+        theirs = reference.experts(
+            x, reference.router_choice(reference.router_scores(x, layer), bias, 4, 2.446), own,
+            [chip * each, each],
+        )
+        np.testing.assert_allclose(part, theirs, atol=ATOL, rtol=0)
+        total = total + part
+    np.testing.assert_allclose(total, uncut, atol=ATOL, rtol=0)
+
+
+def test_copies_routed_to_absent_experts_never_reach_the_result(monkeypatch):
+    # the chip's kernel leaves the rows past the last held group as it found
+    # them: fill them with NaN, as uninitialised memory may be
+    x, gate, up, down, router, bias = expert_case(3)
+    weights, experts = moe.route_sigmoid(x, router, bias, 4, 2.446)
+    want = moe.expert_ffn(x, weights, experts, gate[4:8], up[4:8], down[4:8], held=(4, 4))
+    plain = moe.grouped_matmul
+
+    def unwritten(lhs, rhs, sizes, out_dtype):
+        out = plain(lhs, rhs, sizes, out_dtype)
+        return jnp.where((jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", unwritten)
+    got = moe.expert_ffn(x, weights, experts, gate[4:8], up[4:8], down[4:8], held=(4, 4))
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(got, want)
+
+
+def test_the_chips_kernel_interpreted_serves_a_share():
+    x, gate, up, down, router, bias = expert_case(4, tokens=64)
+    weights, experts = moe.route_sigmoid(x, router, bias, 4, 2.446)
+    want = moe.expert_ffn(x, weights, experts, gate[8:12], up[8:12], down[8:12], held=(8, 4))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            moe, "grouped_matmul",
+            lambda lhs, rhs, sizes, dtype: moe.grouped_matmul_kernel(lhs, rhs, sizes, dtype, interpret=True),
+        )
+        got = moe.expert_ffn(x, weights, experts, gate[8:12], up[8:12], down[8:12], held=(8, 4))
+    np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
+
+
+# ---------------------------------------------------------- ops/attention
+
+
+@pytest.mark.parametrize("length", [64, 1024])  # the single-block kernel, the tiled one
+def test_fused_attention_takes_values_narrower_than_keys(length):
+    rng = np.random.default_rng(length)
+    q, k = (jnp.asarray(rng.normal(size=(1, 2, length, 192)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, 2, length, 128)), jnp.float32)
+    want = attention.attention_reference(q, k, v, causal=True)
+    got = attention.fused_attention(q, k, v, causal=True, force_pallas=True)
+    assert got.shape == (1, 2, length, 128)
+    # the kernels multiply in bf16, as they do at one width
+    np.testing.assert_allclose(got, want, atol=3e-2, rtol=0)
+
+
+# ------------------------------------------------------------ the program
+
+
+@pytest.mark.parametrize("length,seed", [(64, 5), (100, 6), (128, 7)])
+def test_full_logits_equal_the_references(length, seed):
+    params = KimiLinearAlgorithmParams(**TINY, seed=seed)
+    config = params.config()
+    weights = upcast(kimi_linear.init_weights(config, seed))
+    tokens = np.random.default_rng(seed).integers(0, N_ITEMS, (2, length)).astype(np.int32)
+    got = np.asarray(kimi_linear.all_logits(weights, tokens, config=config))
+    assert got.shape == (2, length, 128) and 0.5 < got.std() < 2.0  # of unit order
+    for row in range(2):
+        want = reference.forward(weights, reference_config(params), tokens[row])
+        np.testing.assert_allclose(got[row], want, atol=ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("seed", [8, 9, 10])
+def test_the_bf16_tree_stays_within_its_own_tolerance_of_the_reference(seed):
+    params = KimiLinearAlgorithmParams(**TINY, seed=seed)
+    config = params.config()
+    weights = kimi_linear.init_weights(config, seed)
+    tokens = np.random.default_rng(seed).integers(0, N_ITEMS, (2, 96)).astype(np.int32)
+    got = np.asarray(kimi_linear.all_logits(weights, tokens, config=config))
+    for row in range(2):
+        want = np.asarray(reference.forward(weights, reference_config(params), tokens[row]))
+        worst = np.abs(got[row] - want).max(axis=-1)  # by position
+        assert 1e-3 < np.median(worst) < BF16_MEDIAN
+        assert (worst > BF16_TIPPED[0]).mean() < BF16_TIPPED[1]
+
+
+@pytest.mark.parametrize(
+    "fault",
+    ["experts_per_token", "no_bias", "other_share", "kda_as_mla"],
+)
+def test_the_reference_tells_a_wrong_layer_from_the_right_one(fault):
+    # ATOL is no formality: each of these is another model by far more
+    params = KimiLinearAlgorithmParams(**TINY, seed=9)
+    config = params.config()
+    weights = upcast(kimi_linear.init_weights(config, 9))
+    tokens = np.random.default_rng(9).integers(0, N_ITEMS, 80).astype(np.int32)
+    got = np.asarray(kimi_linear.all_logits(weights, tokens[None], config=config))[0]
+    wrong = reference_config(params)
+    if fault == "experts_per_token":
+        wrong["num_experts_per_token"] = 3
+    elif fault == "no_bias":
+        weights = {k: (jnp.zeros_like(a) if k.endswith("router_bias") else a) for k, a in weights.items()}
+    elif fault == "other_share":
+        wrong["experts_held"] = (8, 4)
+    else:
+        wrong["first_k_dense_replace"] = 0
+    with pytest.raises((AssertionError, KeyError)):
+        np.testing.assert_allclose(got, reference.forward(weights, wrong, tokens), atol=ATOL, rtol=0)
+
+
+def test_the_decays_drawn_spread_over_where_a_dropped_one_shows(trained):
+    _, model = trained
+    config = model.config
+    layer = kimi_linear.layer_of(model.weights, 1)
+    rate = jax.nn.softplus(layer["dt_bias"]).reshape(config.kda_num_heads, -1)
+    decay = np.exp(-np.exp(np.asarray(layer["A_log"]))[:, None] * np.asarray(rate))
+    assert 0.88 < decay.min() < 0.97 and 0.999 < decay.max() < 1.0
+    assert float(jnp.abs(kimi_linear.layer_of(model.weights, 2)["router_bias"]).max()) > 0.01
+
+
+def test_the_counts_leave_the_padding_out_and_split_held_from_absent(trained):
+    algorithm, model = trained
+    config = model.config
+    tokens = np.zeros((4, 64), np.int32)
+    tokens[0, :10] = np.arange(10)
+    last = np.array([9, -1, -1, -1], np.int32)
+    _, counted = kimi_linear.session_vectors(model.weights, tokens, last, config=config)
+    busiest, held = (int(c) for c in counted)
+    routed = config.routed_copies(10)
+    assert routed == 3 * 10 * 4  # three sparse layers, four copies a token
+    assert 0 < held < routed and held / 12 <= busiest <= min(held, 3 * 10)
+    assert config.even_expert_load(10) == pytest.approx(3 * 10 * 4 / 16)
+
+
+def test_right_padding_changes_no_real_positions_output(trained):
+    _, model = trained
+    config = model.config
+    session = np.random.default_rng(2).integers(0, N_ITEMS, 40).astype(np.int32)
+    vectors = []
+    for bucket, fill in ((64, 0), (64, 77), (128, 5)):
+        tokens = np.full((1, bucket), fill, np.int32)
+        tokens[0, :40] = session
+        out, _ = kimi_linear.session_vectors(model.weights, tokens, np.array([39], np.int32), config=config)
+        vectors.append(np.asarray(out[0]))
+    np.testing.assert_allclose(vectors[0], vectors[1], atol=1e-5, rtol=0)
+    np.testing.assert_allclose(vectors[0], vectors[2], atol=1e-5, rtol=0)
+
+
+# -------------------------------------------------------------- the engine
+
+
+def test_kimi_linear_is_an_algorithm_of_the_engine_that_shares_olmoes_serving():
+    engine = engine_factory()
+    variant = {
+        "datasource": {"params": {"appName": "seq"}},
+        "algorithms": [{"name": "kimi_linear", "params": {**TINY, "seed": 7}}],
+    }
+    _, _, (algorithm,), _ = engine.make_components(engine.engine_params_from_variant(variant))
+    assert type(algorithm) is KimiLinearAlgorithm and algorithm.params.experts_held == (4, 4)
+    for name in ("_plan", "_stage", "predict_batch_dispatch", "warmup_serving", "train", "register_metrics"):
+        assert getattr(KimiLinearAlgorithm, name) is getattr(OlmoeAlgorithm, name), name
+    for name in ("save", "session_tokens", "head"):
+        assert getattr(KimiLinearModel, name) is getattr(OlmoeAlgorithm.model_class, name), name
+    assert KimiLinearModel.load.__func__ is OlmoeAlgorithm.model_class.load.__func__
+
+
+def test_a_batch_of_mixed_lengths_is_answered_in_order_as_the_reference_does(trained):
+    algorithm, model = trained
+    data = training_data()
+    queries = [Query(user=u, num=5) for u in data.users] + [Query(user="nobody", num=5)]
+    before = {k: m.value(where=k) for k, m in (("held", algorithm.instruments.copies), ("absent", algorithm.instruments.copies))}
+    answers = algorithm.predict_batch(model, queries)
+    assert answers[-1].item_scores == ()
+    real = 0
+    for user, session, answer in zip(data.users, data.sequences, answers):
+        logits, order = reference_answer(algorithm, model, session, 5)
+        assert [s.item for s in answer.item_scores] == [f"i{i}" for i in order], user
+        np.testing.assert_allclose([s.score for s in answer.item_scores], logits[order], atol=ATOL, rtol=0)
+        real += len(session)
+    held = algorithm.instruments.copies.value(where="held") - before["held"]
+    absent = algorithm.instruments.copies.value(where="absent") - before["absent"]
+    assert held + absent == model.config.routed_copies(real)
+    # 4 of 16 experts held: about a quarter of the copies
+    assert 0.15 < held / (held + absent) < 0.35
+
+
+def test_olmoe_counts_every_copy_as_held():
+    from predictionio_tpu.models.sequential import OlmoeAlgorithmParams
+
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(
+        hidden_size=64, intermediate_size=32, num_hidden_layers=2, num_attention_heads=4,
+        num_key_value_heads=4, num_experts=8, num_experts_per_tok=2, vocab_size=128,
+        max_position_embeddings=128, seed=1,
+    ))
+    model = algorithm.train(None, training_data(n_users=3))
+    algorithm.predict_batch(model, [Query(user="u0", num=3)])
+    assert algorithm.instruments.copies.value(where="held") == 2 * 3 * 2  # layers, tokens, k
+    assert algorithm.instruments.copies.value(where="absent") == 0
+
+
+def test_program_shapes_are_a_small_closed_set_and_warmup_compiles_them_all():
+    algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**{**TINY, "num_hidden_layers": 2}, seed=2))
+    model = algorithm.train(None, training_data(n_users=5))
+    assert model.config.program_shapes() == ((4, 64), (2, 128))
+    algorithm.warmup_serving(model, 8)
+    compiled = kimi_linear.session_vectors._cache_size()
+    algorithm.predict_batch(model, [Query(user=f"u{i}", num=4) for i in range(5)])
+    assert kimi_linear.session_vectors._cache_size() == compiled
+
+
+@pytest.mark.parametrize(
+    "change",
+    [{"moe_router_activation_func": "softmax"}, {"mla_use_nope": False}, {"q_lora_rank": 1536},
+     {"moe_renormalize": False}, {"num_expert_group": 8}, {"tie_word_embeddings": True}],
+)
+def test_unimplemented_config_values_are_refused_not_ignored(change):
+    with pytest.raises(ValueError, match="not implemented"):
+        KimiLinearAlgorithmParams(**{**TINY, **change}).config()
+
+
+@pytest.mark.parametrize(
+    "change,match",
+    [({"experts_held": (14, 4)}, "no block"),
+     ({"linear_attn_config": {**TINY["linear_attn_config"], "full_attn_layers": []}}, "neither or both")],
+)
+def test_a_share_or_a_layer_pattern_that_cannot_be_is_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        KimiLinearAlgorithmParams(**{**TINY, **change}).config()
+
+
+def test_the_published_defaults_are_the_published_config():
+    import json
+    from pathlib import Path
+
+    catalog = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+    if not catalog.exists():
+        pytest.skip("no catalog beside the guides here")
+    row = next(
+        json.loads(line) for line in catalog.read_text().splitlines() if "Kimi-Linear-48B-A3B" in line
+    )
+    params = dataclasses.asdict(KimiLinearAlgorithmParams())
+    assert {key: params[key] for key in row["config"]} == row["config"]
+    config = KimiLinearAlgorithmParams().config()
+    assert config.experts_held == (0, 256) and config.vocab_slice == (0, 163840)
+    assert config.sparse_layers == 26 and sum(config.is_kda(i) for i in range(1, 28)) == 20
+
+
+def test_the_variant_file_carries_the_published_config_and_states_the_share():
+    import json
+    from pathlib import Path
+
+    import predictionio_tpu.models.sequential as package
+
+    variant = json.loads((Path(package.__file__).parent / "variants" / "kimi-linear-48b-a3b.json").read_text())
+    raw = variant["algorithms"][0]["params"]
+    params = engine_factory().engine_params_from_variant(variant).algorithms[0][1]
+    published = dataclasses.asdict(KimiLinearAlgorithmParams())
+    stated = {"num_hidden_layers": 8, "experts_held": [0, 64], "vocab_slice": [0, 40960], "seed": 3}
+    assert {k: v for k, v in raw.items() if k not in stated} == {
+        k: v for k, v in published.items() if k not in stated
+    }
+    assert {k: raw[k] for k in stated} == stated
+    config = params.config()
+    assert config.experts_held == (0, 64) and config.table_rows == 40960 and config.num_hidden_layers == 8
+    shapes = kimi_linear.weight_shapes(config)
+    parameters = sum(int(np.prod(shape)) for shape in shapes.values())
+    assert 3.7e9 < parameters < 3.8e9  # 7.5 GB in bfloat16
+    assert shapes["2.gate"] == (64, 2304, 1024) and shapes["2.router"] == (2304, 256)
+    assert shapes["4.wq"] == (2304, 32 * 192) and shapes["4.w_kvb"] == (512, 32 * 256)
+    assert shapes["1.dense_gate"] == (2304, 9216) and shapes["lm_head"] == (40960, 2304)
+
+
+def test_save_then_load_is_equal_bit_for_bit_and_the_manifest_names_the_backbone(tmp_path, monkeypatch):
+    from predictionio_tpu.workflow import model_io
+
+    monkeypatch.setenv("PIO_FS_BASEDIR", str(tmp_path / "base"))
+    algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**TINY, seed=7))
+    model = algorithm.train(None, training_data(n_users=4))
+    engine = engine_factory()
+    params = engine.engine_params_from_variant({
+        "datasource": {"params": {"appName": "seq"}},
+        "algorithms": [{"name": "kimi_linear", "params": {**TINY, "seed": 7}}],
+    })
+    (persisted,) = engine.make_serializable_models(None, params, [model])
+    assert isinstance(persisted, PersistentModelManifest)
+    assert persisted.class_path == "predictionio_tpu.models.sequential.engine.KimiLinearModel"
+    (deployed,) = engine.prepare_deploy(None, params, model_io.deserialize_models(model_io.serialize_models([persisted])))
+    assert isinstance(deployed, KimiLinearModel) and deployed.config == model.config
+    assert model.save("m1", algorithm.params, str(tmp_path))
+    loaded = KimiLinearModel.load("m1", algorithm.params, str(tmp_path))
+    assert loaded.config == model.config and loaded.item_vocab == model.item_vocab
+    assert loaded.weights.keys() == model.weights.keys()
+    for name in model.weights:
+        assert loaded.weights[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(loaded.weights[name]), np.asarray(model.weights[name]))
+    queries = [Query(user=f"u{i}", num=4) for i in range(4)]
+    assert algorithm.predict_batch(loaded, queries) == algorithm.predict_batch(model, queries)

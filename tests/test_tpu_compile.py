@@ -75,3 +75,84 @@ def test_als_step_at_the_benchmark_shape_holds_its_systems_unpadded(
     text = compiled.as_text()
     assert "solve/pallas_call" in text and "tpu_custom_call" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
+
+
+# latent attention expanded for a prefill: keys of 192, values of 128; the
+# single-block kernel (L under 1,024) and the tiled one, at the Kimi-Linear
+# cell's program shapes
+@pytest.mark.parametrize("rows, length", [(32, 64), (4, 512), (2, 1024), (1, 4096)])
+def test_fused_attention_with_values_narrower_than_keys_compiles_for_v5e(
+    one_chip, monkeypatch, rows, length
+):
+    from predictionio_tpu.ops.attention import fused_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = _shape(one_chip, (rows, 32, length, 192), jnp.bfloat16)
+    narrow = _shape(one_chip, (rows, 32, length, 128), jnp.bfloat16)
+    compiled = (
+        jax.jit(lambda q, k, v: fused_attention(q, k, v, causal=True))
+        .lower(wide, wide, narrow)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings is not None
+    assert jax.eval_shape(lambda q, k, v: fused_attention(q, k, v, causal=True), wide, wide, narrow).shape == (
+        rows, 32, length, 128,
+    )
+
+
+@pytest.mark.parametrize("rows, length", [(32, 64), (1, 4096)])
+def test_the_chunked_kda_scan_compiles_for_v5e_at_the_published_heads(one_chip, rows, length):
+    from predictionio_tpu.ops.linear_attention import kda
+
+    wide = _shape(one_chip, (rows, length, 32, 128))
+    compiled = jax.jit(kda).lower(wide, wide, wide, wide, _shape(one_chip, (rows, length, 32))).compile()
+    # a 2,048- or 4,096-token program's scan keeps its temporaries under a GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_a_share_of_the_experts_compiles_for_v5e_with_the_grouped_kernel(one_chip, monkeypatch):
+    from predictionio_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, hidden, width = 2048, 2304, 1024
+
+    def share(x, router, bias, gate, up, down):
+        weights, experts = moe.route_sigmoid(x, router, bias, 8, 2.446)
+        return moe.expert_ffn(x, weights, experts, gate, up, down, held=(64, 64))
+
+    compiled = jax.jit(share).lower(
+        _shape(one_chip, (tokens, hidden)), _shape(one_chip, (hidden, 256), jnp.bfloat16),
+        _shape(one_chip, (256,), jnp.bfloat16), _shape(one_chip, (64, hidden, width), jnp.bfloat16),
+        _shape(one_chip, (64, hidden, width), jnp.bfloat16), _shape(one_chip, (64, width, hidden), jnp.bfloat16),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# the benchmark's check of the Kimi-Linear cell runs beside the served model
+# (7.9 GB of 16.9): one session of 4,096 items through a layer of each kind
+@pytest.mark.parametrize("layer, probed", [(1, True), (2, False), (4, False)])
+def test_the_kimi_cells_check_keeps_a_session_of_4096_under_a_gigabyte(one_chip, layer, probed):
+    """The reference takes a layer as it is served and upcasts an expert and
+    a head at a time: 0.91 GB of temporaries with the scan's probe, 0.34 and
+    0.37 without. (A whole layer in float32 is 2.0 GB more, and all 32
+    heads' [4,096, 4,096] scores at once 2.45 GB: PERF.md, PR 31.)"""
+    import json
+    from pathlib import Path
+
+    from benchmark.engines import sequential_kimi_linear as engine
+    from predictionio_tpu.models.sequential import engine_factory, kimi_linear
+
+    config = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-kimi-linear.json").read_text())
+    params = engine_factory().engine_params_from_variant(engine.variant_of(config, 5)).algorithms[0][1]
+    weights = jax.eval_shape(lambda: kimi_linear.init_weights(params.config(), 5))
+    served = {name: _shape(one_chip, a.shape, a.dtype) for name, a in kimi_linear.layer_of(weights, layer).items()}
+    shapes = {key: config[key] for key in engine.PUBLISHED + ("experts_held", "vocab_slice", "published")}
+    compiled = engine.layer_step(shapes).lower(
+        _shape(one_chip, (4096, config["hidden_size"])), served, _shape(one_chip, (), jnp.int32),
+        like=layer, probed=probed,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.0e9
+    # nothing of the layer is copied in: the arguments are the served arrays
+    assert memory.argument_size_in_bytes < 1.1e9
