@@ -511,7 +511,7 @@ class AttentionAlgorithmParams(Params):
     # chip a context of 1024 or more must be a multiple of 256
     # (ops/attention.fused_attention refuses it otherwise). This is the
     # `attention` scorer's window only: `olmoe` takes a session's last
-    # `max_position_embeddings` items, in length buckets
+    # `max_position_embeddings` items, packed into token streams
     context: int = 8
     top_n: int = 10
 
@@ -575,10 +575,12 @@ class AttentionAlgorithm(JaxAlgorithm):
         window's input embeddings and run one causal single-head
         attention pass; the last position's output is the session
         vector. Left-pad slots repeat the window's oldest item — a
-        documented smoothing bias that keeps the program shape static
-        (fused_attention has no key mask by design). True of this
-        scorer only: `olmoe` pads on the RIGHT, where causal attention
-        keeps the padding out of every real position, and is exact."""
+        documented smoothing bias that keeps the program shape static.
+        ``fused_attention`` has a segment mask since the backbones pack
+        their sessions into streams (``segment=``); this scorer passes
+        none, and its program is what it was. True of this scorer only:
+        the backbones pad BEHIND a session, where causal attention keeps
+        the padding out of every real position, and are exact."""
         import jax.numpy as jnp
 
         from predictionio_tpu.ops.attention import fused_attention
@@ -886,8 +888,8 @@ class BackboneModel(PersistentModel, SanityCheck):
     pass through ``workflow/model_io``'s one pickled blob.
 
     ``program()`` is the module that holds the backbone's program
-    (``Config``, ``session_vectors``, ``init_weights``, ``bucket_of``,
-    ``program_rows``), imported when first asked for; a subclass a backbone
+    (``Config``, ``session_vectors``, ``init_weights``,
+    ``SESSION_ALIGN``), imported when first asked for; a subclass a backbone
     keeps a stored model's class path telling which."""
 
     @staticmethod
@@ -994,6 +996,14 @@ def session_tails(sequences: Sequence[np.ndarray], keep: int):
     return tails, offsets
 
 
+def _stream_limits(model: BackboneModel) -> tuple[int, int]:
+    """``(the multiple a session starts on in its token stream, the sessions
+    a stream holds)``: the backbone's ``SESSION_ALIGN``, and its budget's
+    worth of them."""
+    align = model.program().SESSION_ALIGN
+    return align, model.config.stream_shapes()[0] // align
+
+
 class BackboneAlgorithm(JaxAlgorithm):
     """Next-item scoring by one prefill through a backbone: what the
     ``olmoe`` and ``kimi_linear`` algorithms share, which is everything but
@@ -1006,16 +1016,19 @@ class BackboneAlgorithm(JaxAlgorithm):
     exact is that they are THIS network's, which the reference holds.
 
     Serve: ``predict_batch_dispatch`` does not treat a batch as B equal
-    rows. It groups the batch it is handed by length bucket (64, 128, ...,
-    ``config.max_session``), right-pads a group's sessions into one
-    ``[rows, bucket]`` block of the backbone's ``TOKEN_BUDGET`` tokens (a
-    larger group takes several programs, a longer session one row), launches
-    the backbone's ``session_vectors`` and ``topk.dot_top_k_async`` (session
-    items masked) for every program, and returns ONE finalize that answers
-    in the queries' order. The set of program shapes is closed
-    (``config.program_shapes``) and ``warmup_serving`` compiles all of it.
-    What it launched is counted in ``instruments``, the algorithm's own
-    until a query server hands over its registry."""
+    rows. It PACKS the batch's sessions into token streams (``_plan``:
+    longest first, first fit, into streams of the backbone's
+    ``TOKEN_BUDGET`` tokens, of ``config.max_session`` where a session is
+    longer; every session from a multiple of ``SESSION_ALIGN``, at most
+    ``TOKEN_BUDGET // SESSION_ALIGN`` a stream), and a stream is one program:
+    ``[1, T]`` tokens with each token's ``segment`` and ``position``
+    (``_stage``) through the backbone's ``session_vectors``, then
+    ``topk.dot_top_k_async`` over the stream's sessions (their items
+    masked). ONE finalize answers in the queries' order. The stream lengths
+    are a closed set (``config.stream_shapes``: two) and ``warmup_serving``
+    compiles all of it. A single query is one session in a stream. What it
+    launched is counted in ``instruments``, the algorithm's own until a
+    query server hands over its registry."""
 
     model_class: type[BackboneModel]
 
@@ -1039,66 +1052,83 @@ class BackboneAlgorithm(JaxAlgorithm):
     # ------------------------------------------------------------- serving
     @staticmethod
     def _plan(model: BackboneModel, queries: Sequence[Query]):
-        """Look-up and bucketing: ``(sessions, programs)``, a program being
-        ``(bucket, rows, [query index, ...])``. Queries with no session are
-        in no program."""
-        program = model.program()
-        buckets = model.config.buckets()
+        """Look-up and packing: ``(sessions, streams)``, a stream being
+        ``(length, [(query index, where its session starts), ...])``. The
+        sessions go longest first into the first stream that has room for
+        their items rounded up to whole ``SESSION_ALIGN``s and holds fewer
+        than ``TOKEN_BUDGET // SESSION_ALIGN`` of them; a new stream is of
+        ``TOKEN_BUDGET`` tokens, or of the longest session's where the
+        session does not fit that. Queries with no session are in no stream."""
+        align, most = _stream_limits(model)
+        budget, *longer = model.config.stream_shapes()
         sessions = [model.session_tokens(q) for q in queries]
-        groups: dict[int, list[int]] = {}
-        for i, session in enumerate(sessions):
-            if len(session):
-                groups.setdefault(program.bucket_of(len(session), buckets), []).append(i)
-        programs = []
-        for bucket in sorted(groups):
-            members, rows = groups[bucket], program.program_rows(bucket)
-            for start in range(0, len(members), rows):
-                programs.append((bucket, rows, members[start : start + rows]))
-        return sessions, programs
+        streams: list[list] = []  # [length, tokens free at its end, members]
+        for i in sorted(range(len(sessions)), key=lambda i: -len(sessions[i])):
+            room = -(-len(sessions[i]) // align) * align
+            if not room:
+                continue
+            stream = next((s for s in streams if room <= s[1] and len(s[2]) < most), None)
+            if stream is None:
+                length = budget if room <= budget else longer[0]
+                stream = [length, length, []]
+                streams.append(stream)
+            stream[2].append((i, stream[0] - stream[1]))
+            stream[1] -= room
+        return sessions, [(length, members) for length, _, members in streams]
 
     @staticmethod
-    def _stage(model: BackboneModel, sessions, program):
-        """One program's host arrays: tokens right-padded with token 0 (any
-        token would do: no real position sees it), each row's last real
-        position (-1 for a padding row), and the candidate mask without the
-        session's items, the vocabulary's unused rows and the padding rows."""
-        bucket, rows, members = program
-        tokens = np.zeros((rows, bucket), np.int32)
-        last = np.full(rows, -1, np.int32)
-        mask = np.zeros((rows, model.config.table_rows), bool)
+    def _stage(model: BackboneModel, sessions, stream):
+        """One stream's host arrays: ``tokens`` [1, T] (token 0 behind a
+        session's last item: any token would do, no real position sees it),
+        ``segment`` [1, T] (the session's index in the stream, -1 for the
+        padding), ``position`` [1, T] (the index inside the session),
+        ``last`` [S] (each session's last position in the stream, -1 for
+        none) and the candidate mask [S, table rows] without the session's
+        items, the vocabulary's unused rows and the rows of no session."""
+        length, members = stream
+        _, most = _stream_limits(model)
+        tokens = np.zeros((1, length), np.int32)
+        segment = np.full((1, length), -1, np.int32)
+        position = np.zeros((1, length), np.int32)
+        last = np.full(most, -1, np.int32)
+        mask = np.zeros((most, model.config.table_rows), bool)
         mask[: len(members), : len(model.item_vocab)] = True
-        for row, i in enumerate(members):
+        for row, (i, start) in enumerate(members):
             session = sessions[i]
-            tokens[row, : len(session)] = session
-            last[row] = len(session) - 1
+            end = start + len(session)
+            tokens[0, start:end] = session
+            segment[0, start:end] = row
+            position[0, start:end] = np.arange(len(session))
+            last[row] = end - 1
             mask[row, session] = False
-        return tokens, last, mask
+        return tokens, segment, position, last, mask
 
     def predict_batch_dispatch(self, model: BackboneModel, queries: Sequence[Query]):
         config = model.config
         session_vectors = model.program().session_vectors
         t0 = time.perf_counter()
-        sessions, programs = self._plan(model, queries)
-        with annotate("pio:seq.stage", batch=len(queries), programs=len(programs)):
-            staged = [self._stage(model, sessions, program) for program in programs]
+        sessions, streams = self._plan(model, queries)
+        with annotate("pio:seq.stage", batch=len(queries), programs=len(streams)):
+            staged = [self._stage(model, sessions, stream) for stream in streams]
         self.instruments.on_stage(time.perf_counter() - t0)
         n = len(model.item_vocab)
         kk = min(topk.next_pow2(max(1, max(q.num for q in queries))), n)
         launched = []
-        for (bucket, rows, members), (tokens, last, mask) in zip(programs, staged):
-            real = int(last[: len(members)].sum()) + len(members)
-            with annotate("pio:seq.launch", bucket=bucket, rows=rows, tokens=real):
+        for (length, members), (*stream, mask) in zip(streams, staged):
+            real = sum(len(sessions[i]) for i, _ in members)
+            # (`bucket` is the stream's length and `rows` 1: the names the
+            # counters' readers know a program's shape by)
+            with annotate("pio:seq.launch", bucket=length, rows=1, tokens=real):
                 vectors, counted = session_vectors(
-                    model.weights, topk.upload(tokens, np.int32), topk.upload(last, np.int32),
-                    config=config,
+                    model.weights, *(topk.upload(a, np.int32) for a in stream), config=config
                 )
                 handle = topk.dot_top_k_async(model.head(), vectors, mask, kk)
-            self.instruments.on_launch(bucket, rows, real)
+            self.instruments.on_launch(length, 1, real, len(members))
             launched.append((handle, counted, real))
 
         def finalize() -> list[PredictedResult]:
             out: list[PredictedResult] = [PredictedResult(())] * len(queries)
-            for (bucket, rows, members), (handle, counted, real) in zip(programs, launched):
+            for (length, members), (handle, counted, real) in zip(streams, launched):
                 scores, idx = topk.fetch_topk(handle)
                 # an integer or two a program ride back with its answer: the
                 # busiest expert's copies and, where the chip holds a share of
@@ -1108,7 +1138,7 @@ class BackboneAlgorithm(JaxAlgorithm):
                 held = int(counted[1]) if counted.size > 1 else routed
                 self.instruments.on_expert_load(int(counted[0]), config.even_expert_load(real))
                 self.instruments.on_copies(held, routed - held)
-                for row, i in enumerate(members):
+                for row, (i, _) in enumerate(members):
                     picks = [
                         ItemScore(model.item_vocab[int(item)], float(score))
                         for score, item in zip(scores[row], idx[row])
@@ -1128,16 +1158,15 @@ class BackboneAlgorithm(JaxAlgorithm):
         return self.predict_batch(model, [query])[0]
 
     def warmup_serving(self, model: BackboneModel, max_batch: int) -> None:
-        """Compile every program shape there is, by the path serving takes:
-        for each ``(rows, bucket)`` a batch of ``rows`` sessions of
-        ``bucket`` items (the staging copies, ``session_vectors`` and the
-        top-k over ``rows``). ``max_batch`` bounds nothing here: a batch of
-        any size is cut into these shapes."""
+        """Compile every stream length there is, by the path serving takes
+        (the staging copies, ``session_vectors`` and the top-k): for each a
+        query whose session fills it, or is the longest there is.
+        ``max_batch`` bounds nothing here: a batch of any size is packed
+        into these shapes."""
         n = len(model.item_vocab)
-        num = min(10, n)
-        for rows, bucket in model.config.program_shapes():
-            items = tuple(model.item_vocab[i % n] for i in range(bucket))
-            self.predict_batch(model, [Query(recent_items=items, num=num)] * rows)
+        for length in model.config.stream_shapes():
+            items = tuple(model.item_vocab[i % n] for i in range(length))
+            self.predict(model, Query(recent_items=items, num=min(10, n)))
 
 
 class OlmoeModel(BackboneModel):

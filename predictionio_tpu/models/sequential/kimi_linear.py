@@ -31,7 +31,12 @@ the gates, the KDA state and everything in its scan, the router and the
 softmax in float32. A float32 weight tree (the CPU parity tests) computes in
 float32.
 
-Sessions are RIGHT-padded to their length bucket: neither kind of mixer lets
+A program is one token stream of several sessions, as ``olmoe.py``'s is
+(``segment``, ``position``): latent attention sees a key only from inside its
+own segment, the KDA state is zeroed where a chunk begins a session (sessions
+start on multiples of ``SESSION_ALIGN``, which is the scan's ``CHUNK``) and
+the short convolution reaches no further back than a session's first item, so
+a session's positions come out as they would alone; neither kind of mixer lets
 a real position see what follows it.
 """
 
@@ -45,17 +50,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from predictionio_tpu.models.sequential.olmoe import LENGTH_BUCKETS, _normal, _project, _rms, bucket_of
+from predictionio_tpu.models.sequential.olmoe import (
+    LENGTH_BUCKETS, SESSION_ALIGN, _normal, _project, _rms, bucket_of, stream_shapes,
+)
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
-from predictionio_tpu.ops.linear_attention import kda, short_conv
+from predictionio_tpu.ops.linear_attention import CHUNK, kda, short_conv
 
 __all__ = [
-    "KimiLinearConfig", "TOKEN_BUDGET", "MAX_SESSION", "program_rows", "bucket_of", "weight_shapes",
+    "KimiLinearConfig", "TOKEN_BUDGET", "MAX_SESSION", "SESSION_ALIGN", "bucket_of", "weight_shapes",
     "init_weights", "layer_of", "session_vectors", "all_logits",
 ]
 
-# padded tokens a program holds (one row where a session is longer)
+# tokens a stream holds (``MAX_SESSION`` where a session is longer)
 TOKEN_BUDGET = 2048
 # items of a session the engine keeps, and so the longest program: the
 # traffic's bound (the model's own is ``model_max_length``, 1,048,576 as
@@ -135,16 +142,22 @@ class KimiLinearConfig:
         top = self.max_session
         return tuple(b for b in LENGTH_BUCKETS if b < top) + (top,)
 
+    def stream_shapes(self) -> tuple[int, ...]:
+        return stream_shapes(TOKEN_BUDGET, self.max_session)
+
     def program_shapes(self) -> tuple[tuple[int, int], ...]:
-        return tuple((program_rows(bucket), bucket) for bucket in self.buckets())
+        """``(budget // bucket, bucket)`` up the ladder of ``buckets()``, to
+        which the benchmark's check pads its references: the ``[rows,
+        bucket]`` programs of before the streams. NOTHING compiles them any
+        more (``stream_shapes`` are the compiled ones); a test of the
+        benchmark's pins this method, and it goes with that pin."""
+        return tuple((max(1, TOKEN_BUDGET // bucket), bucket) for bucket in self.buckets())
 
 
 Config = KimiLinearConfig
 
-
-def program_rows(bucket: int) -> int:
-    """The height of a bucket's programs."""
-    return max(1, TOKEN_BUDGET // bucket)
+if SESSION_ALIGN % CHUNK:
+    raise ImportError(f"sessions aligned to {SESSION_ALIGN} do not start on the scan's chunks of {CHUNK}")
 
 
 # ---------------------------------------------------------------------------
@@ -255,15 +268,17 @@ def _l2(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def _kda_mixer(n, layer, config: KimiLinearConfig):
-    """``n`` [B, L, hidden] float32 -> the mixer's output [B, L, hidden]."""
+def _kda_mixer(n, position, layer, config: KimiLinearConfig):
+    """``n`` [B, L, hidden] float32 -> the mixer's output [B, L, hidden].
+    ``position`` [B, L] (None: every row one session) is each token's index
+    inside its session; a session begins on a chunk's first position."""
     rows, length, _ = n.shape
     heads, d = config.kda_num_heads, config.kda_head_dim
     split = (rows, length, heads, d)
     with jax.named_scope("conv"):
-        q, _ = short_conv(_project(n, layer["wq"]), layer["conv_q"])
-        k, _ = short_conv(_project(n, layer["wk"]), layer["conv_k"])
-        v, _ = short_conv(_project(n, layer["wv"]), layer["conv_v"])
+        q, _ = short_conv(_project(n, layer["wq"]), layer["conv_q"], position=position)
+        k, _ = short_conv(_project(n, layer["wk"]), layer["conv_k"], position=position)
+        v, _ = short_conv(_project(n, layer["wv"]), layer["conv_v"], position=position)
         q, k, v = _l2(q.reshape(split)) * d**-0.5, _l2(k.reshape(split)), v.reshape(split)
     with jax.named_scope("gates"):
         rate = _project(_project(n, layer["w_fa"]), layer["w_fb"]) + layer["dt_bias"].astype(jnp.float32)
@@ -271,13 +286,14 @@ def _kda_mixer(n, layer, config: KimiLinearConfig):
         b = jax.nn.sigmoid(_project(n, layer["w_b"]))
         gate = jax.nn.sigmoid(_project(_project(n, layer["w_ga"]), layer["w_gb"])).reshape(split)
     with jax.named_scope("scan"):
-        o, _ = kda(q, k, v, g, b)
+        o, _ = kda(q, k, v, g, b, starts=None if position is None else position[:, ::CHUNK] == 0)
     o = _rms(o, layer["o_norm"], config.rms_norm_eps) * gate
     return _project(o.reshape(rows, length, heads * d), layer["wo"])
 
 
-def _mla_mixer(n, layer, config: KimiLinearConfig):
-    """Latent attention with the latent expanded, no rotary embedding."""
+def _mla_mixer(n, segment, layer, config: KimiLinearConfig):
+    """Latent attention with the latent expanded, no rotary embedding;
+    inside ``segment`` [B, L] where rows are shared."""
     rows, length, _ = n.shape
     heads, nope, rope = config.num_attention_heads, config.qk_nope_head_dim, config.qk_rope_head_dim
     d_v, rank = config.v_head_dim, config.kv_lora_rank
@@ -290,21 +306,25 @@ def _mla_mixer(n, layer, config: KimiLinearConfig):
     k_r = jnp.broadcast_to(k_r[:, :, None, :], (rows, length, heads, rope))
     k = jnp.concatenate([expanded[..., :nope], k_r], axis=-1)
     q, k, v = (t.transpose(0, 2, 1, 3).astype(operand) for t in (q, k, expanded[..., nope:]))
-    out = fused_attention(q, k, v, causal=True)
+    out = fused_attention(q, k, v, causal=True, segment=segment)
     out = out.transpose(0, 2, 1, 3).reshape(rows, length, heads * d_v)
     return _project(out, layer["wo"])
 
 
-def _layer(x, real, layer, i: int, config: KimiLinearConfig):
+def _layer(x, segment, position, layer, i: int, config: KimiLinearConfig):
     """Decoder layer ``i`` over ``x`` [B, L, hidden] float32: ``(x', [busiest
     held expert's copies, copies routed to a held expert])`` of REAL tokens
-    (zeros for a dense layer)."""
+    (``segment`` not -1; zeros for a dense layer). ``segment`` and
+    ``position`` None: every row one session."""
     rows, length, hidden = x.shape
     eps = config.rms_norm_eps
     kind = "kda" if config.is_kda(i) else "mla"
     with jax.named_scope(kind):
         n1 = _rms(x, layer["w_in"], eps)
-        h = x + (_kda_mixer if kind == "kda" else _mla_mixer)(n1, layer, config)
+        if kind == "kda":
+            h = x + _kda_mixer(n1, position, layer, config)
+        else:
+            h = x + _mla_mixer(n1, segment, layer, config)
     # the feed-forward's pre-norm stands under its first reader's scope and
     # the residual sum under its last writer's (XLA names a fusion by its
     # root), so that the scopes' times add up to the program's
@@ -321,7 +341,8 @@ def _layer(x, real, layer, i: int, config: KimiLinearConfig):
             n2, layer["router"], layer["router_bias"], config.num_experts_per_token,
             config.routed_scaling_factor,
         )
-        load = moe.expert_load(experts - first, count, real.reshape(-1))
+        real = None if segment is None else (segment >= 0).reshape(-1)
+        load = moe.expert_load(experts - first, count, real)
     with jax.named_scope("experts"):
         y = moe.expert_ffn(
             n2, weights, experts, layer["gate"], layer["up"], layer["down"], held=(first, count)
@@ -332,37 +353,37 @@ def _layer(x, real, layer, i: int, config: KimiLinearConfig):
     return out, jnp.stack([jnp.max(load), jnp.sum(load)])
 
 
-def _layers(weights, x, real, config: KimiLinearConfig):
+def _layers(weights, x, segment, position, config: KimiLinearConfig):
     counts = jnp.zeros(2, jnp.int32)
     for i in range(1, config.num_hidden_layers + 1):
-        x, counted = _layer(x, real, layer_of(weights, i), i, config)
+        x, counted = _layer(x, segment, position, layer_of(weights, i), i, config)
         counts = counts + counted
     return x, counts
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
-def session_vectors(weights, tokens, last, *, config: KimiLinearConfig):
-    """``tokens`` [B, L] int32, right-padded; ``last`` [B] int32, each
-    session's last real position, -1 for a padding row. Returns the session
-    vectors [B, hidden] float32 (``rms(x_L; w_final)`` at ``last``; a
-    padding row's is to be thrown away) and two counts of copies of REAL
-    tokens, summed over the sparse layers: what the busiest held expert got,
-    and what all the held experts got."""
-    real = jnp.arange(tokens.shape[1])[None, :] <= last[:, None]
+def session_vectors(weights, tokens, segment, position, last, *, config: KimiLinearConfig):
+    """One token stream, as ``olmoe.session_vectors`` takes it: ``tokens``,
+    ``segment`` and ``position`` [1, T] int32; ``last`` [S] int32, each
+    session's last position IN THE STREAM, -1 where the stream holds fewer
+    than S. Returns the session vectors [S, hidden] float32 (``rms(x_L;
+    w_final)`` at ``last``; one at -1 is to be thrown away) and two counts
+    of copies of REAL tokens, summed over the sparse layers: what the
+    busiest held expert got, and what all the held experts got."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
-    x, counts = _layers(weights, x, real, config)
+    x, counts = _layers(weights, x, segment, position, config)
     with jax.named_scope("head"):
-        at_last = x[jnp.arange(tokens.shape[0]), jnp.maximum(last, 0)]
-        out = _rms(at_last, weights["final_norm"], config.rms_norm_eps)
+        out = _rms(x[0, jnp.maximum(last, 0)], weights["final_norm"], config.rms_norm_eps)
     return out, counts
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
 def all_logits(weights, tokens, *, config: KimiLinearConfig):
-    """Logits of EVERY position, [B, L, vocabulary's slice]: what the parity
-    tests compare with the reference's ``forward``; serving never runs it."""
+    """Logits of EVERY position of ``tokens`` [B, L], every row one session,
+    [B, L, vocabulary's slice]: what the parity tests compare with the
+    reference's ``forward``; serving never runs it."""
     x = weights["embed"][tokens].astype(jnp.float32)
-    x, _ = _layers(weights, x, jnp.ones(tokens.shape, bool), config)
+    x, _ = _layers(weights, x, None, None, config)
     out = _rms(x, weights["final_norm"], config.rms_norm_eps)
     return jnp.dot(out, weights["lm_head"].astype(jnp.float32).T, precision=lax.Precision.HIGHEST)

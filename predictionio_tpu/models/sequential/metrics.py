@@ -70,24 +70,30 @@ class BackboneInstruments:
         self.tokens = r.counter(
             "pio_seq_tokens_total",
             "tokens of launched session programs: kind=real are session "
-            "items, kind=padded is what the device computed (buckets)",
+            "items, kind=padded is what the device computed (whole streams)",
             labelnames=("kind",),
         )
         self.programs = r.counter(
             "pio_seq_programs_total",
-            "session programs launched, by length bucket",
+            "session programs launched, by the length of their token stream",
             labelnames=("bucket",),
         )
         self.rows = r.counter(
             "pio_seq_rows_total",
-            "rows (sessions and padding rows) of launched session programs, "
-            "by length bucket",
+            "rows of launched session programs (a token stream is one row), "
+            "by the stream's length",
+            labelnames=("bucket",),
+        )
+        self.sessions = r.counter(
+            "pio_seq_sessions_total",
+            "sessions packed into launched session programs, by the stream's "
+            "length (over pio_seq_programs_total: sessions a program)",
             labelnames=("bucket",),
         )
         self.stage_seconds = r.counter(
             "pio_seq_stage_seconds_total",
-            "seconds the dispatch thread spent looking sessions up, "
-            "bucketing and padding them (once a batch)",
+            "seconds the dispatch thread spent looking sessions up and "
+            "packing them into token streams (once a batch)",
         )
         self.batches = r.counter(
             "pio_seq_batches_total", "batches the session scorer staged"
@@ -119,11 +125,12 @@ class BackboneInstruments:
         self.stage_seconds.inc(seconds)
         self.batches.inc()
 
-    def on_launch(self, bucket: int, rows: int, real_tokens: int) -> None:
+    def on_launch(self, bucket: int, rows: int, real_tokens: int, sessions: int) -> None:
         self.tokens.inc(float(real_tokens), kind="real")
         self.tokens.inc(float(rows * bucket), kind="padded")
         self.programs.inc(bucket=str(bucket))
         self.rows.inc(float(rows), bucket=str(bucket))
+        self.sessions.inc(float(sessions), bucket=str(bucket))
 
     def on_expert_load(self, busiest: int, even: float) -> None:
         """One program's layers, summed: the copies of real tokens its

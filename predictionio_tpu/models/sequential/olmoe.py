@@ -16,10 +16,16 @@ that a program compiles one layer, attention through
 through ``ops/moe``. The operands' type follows the weights': a float32
 weight tree (the CPU parity tests) computes in float32.
 
-Sessions are RIGHT-padded to their length bucket: under causal attention a
-real position never sees the padding behind it, so no key mask is needed and
-a real position's output is exact; the padding's rows are computed and thrown
-away (``pio_seq_tokens_total{kind}`` counts them).
+A program is one TOKEN STREAM, ``[1, T]`` with ``T`` one of
+``config.stream_shapes()`` (2,048, or 4,096 where a session is longer than
+that): the engine packs several sessions into it, each from a multiple of
+``SESSION_ALIGN`` and right-padded to the next, and hands over every token's
+``segment`` (its session's index in the stream, -1 for padding) and
+``position`` (its index inside its session). Attention sees a key only from
+inside its own segment (``fused_attention(segment=)``) and RoPE turns by
+``position``, so a session's positions come out as they would alone;
+everything else in a layer is a token's own. The padding is computed and
+thrown away (``pio_seq_tokens_total{kind}`` counts it).
 
 The weights are drawn from a seed, not fitted: fitting the backbone is not
 this engine's work yet (ROADMAP R7).
@@ -43,14 +49,17 @@ from jax import lax
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
 
-# a session is padded to the first of these that holds it
+# the ladder the benchmark's check pads its references by (``buckets()``)
 LENGTH_BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096)
-# padded tokens a program holds (one row where a session is longer). From the
-# chip (PERF.md, PR 26): a program takes about 20 ms and 17 ms a thousand
-# tokens, so taller is cheaper a token, but a batch of 32 sessions spreads
-# over six buckets and fills no tall program: with 8,192 tokens a program the
-# cell answers 43 queries a second, with 2,048 it answers 80, and a second
-# height beside that (8,192 for a group that fills it) wins 1 to 3%
+# a session starts on a multiple of this in its stream (the ladder's first
+# step, and ``ops/linear_attention.CHUNK``: one rule for every backbone)
+SESSION_ALIGN = LENGTH_BUCKETS[0]
+# tokens a stream holds (``max_session`` where a session is longer), and
+# ``TOKEN_BUDGET // SESSION_ALIGN`` the sessions it may hold. From the chip
+# (PERF.md, PR 26): a program takes about 20 ms and 17 ms a thousand tokens,
+# so taller is cheaper a token, but a batch of 32 sessions did not fill
+# 8,192 tokens when programs were cut by length bucket (43 answers a second
+# against 80 at 2,048); packed streams may (ROADMAP S9 (b), (h))
 TOKEN_BUDGET = 2048
 
 LAYER_ARRAYS = (
@@ -104,18 +113,19 @@ class OlmoeConfig:
         top = self.max_position_embeddings
         return tuple(b for b in LENGTH_BUCKETS if b < top) + (top,)
 
-    def program_shapes(self) -> tuple[tuple[int, int], ...]:
-        """Every ``(rows, bucket)`` a program is launched at: the closed set
-        ``warmup`` compiles."""
-        return tuple((program_rows(bucket), bucket) for bucket in self.buckets())
+    def stream_shapes(self) -> tuple[int, ...]:
+        return stream_shapes(TOKEN_BUDGET, self.max_session)
 
 
 Config = OlmoeConfig
 
 
-def program_rows(bucket: int) -> int:
-    """The height of a bucket's programs."""
-    return max(1, TOKEN_BUDGET // bucket)
+def stream_shapes(budget: int, max_session: int) -> tuple[int, ...]:
+    """The lengths a token stream is compiled at, the closed set ``warmup``
+    compiles: the budget and, where a session may be longer, the longest
+    session's (whole ``SESSION_ALIGN``s)."""
+    longest = -(-max_session // SESSION_ALIGN) * SESSION_ALIGN
+    return (budget,) + ((longest,) if longest > budget else ())
 
 
 def bucket_of(length: int, buckets: tuple[int, ...]) -> int:
@@ -181,13 +191,13 @@ def _rms(x, weight, eps: float):
     return weight.astype(jnp.float32) * (x * lax.rsqrt(variance + eps))
 
 
-def _rope(x, theta: float):
-    """``x`` [B, L, heads, d] float32, positions 0..L-1, rotate-half."""
-    length, d = x.shape[1], x.shape[3]
+def _rope(x, position, theta: float):
+    """``x`` [B, L, heads, d] float32 at ``position`` [B, L], rotate-half."""
+    d = x.shape[3]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = position.astype(jnp.float32)[:, :, None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)
-    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
     half = d // 2
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos + rotated * sin
@@ -197,12 +207,13 @@ def _project(x, w):
     return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
-def _layer(x, real, layer, experts_of_all_layers, index, config: OlmoeConfig):
+def _layer(x, segment, position, layer, experts_of_all_layers, index, config: OlmoeConfig):
     """Decoder layer ``index`` over ``x`` [B, L, hidden] float32: ``layer``
     holds its own arrays but the experts', which are read in place out of
-    every layer's, stacked ``[layers * experts, ...]``. ``real`` [B, L]
-    marks the sessions' own positions: the padding is computed like them
-    and left out of the busiest expert's count."""
+    every layer's, stacked ``[layers * experts, ...]``. ``segment`` [B, L]
+    (None: every row one session) keeps attention inside a session and
+    marks the sessions' own positions: the padding (-1) is computed like
+    them and left out of the busiest expert's count."""
     rows, length, hidden = x.shape
     heads, d = config.num_attention_heads, config.head_dim
     eps = config.rms_norm_eps
@@ -212,20 +223,21 @@ def _layer(x, real, layer, experts_of_all_layers, index, config: OlmoeConfig):
         k = _rms(_project(n1, layer["wk"]), layer["k_norm"], eps)
         v = _project(n1, layer["wv"])
         with jax.named_scope("rope"):
-            q = _rope(q.reshape(rows, length, heads, d), config.rope_theta)
-            k = _rope(k.reshape(rows, length, heads, d), config.rope_theta)
+            q = _rope(q.reshape(rows, length, heads, d), position, config.rope_theta)
+            k = _rope(k.reshape(rows, length, heads, d), position, config.rope_theta)
         operand = layer["wq"].dtype
         q, k, v = (
             t.reshape(rows, length, heads, d).transpose(0, 2, 1, 3).astype(operand)
             for t in (q, k, v)
         )
-        out = fused_attention(q, k, v, causal=True)
+        out = fused_attention(q, k, v, causal=True, segment=segment)
         out = out.transpose(0, 2, 1, 3).reshape(rows, length, hidden)
         h = x + _project(out, layer["wo"])
     n2 = _rms(h, layer["w_post"], eps).reshape(rows * length, hidden)
     with jax.named_scope("router"):
         weights, experts = moe.route(n2, layer["router"], config.num_experts_per_tok)
-        busiest = jnp.max(moe.expert_load(experts, config.num_experts, real.reshape(-1)))
+        real = None if segment is None else (segment >= 0).reshape(-1)
+        busiest = jnp.max(moe.expert_load(experts, config.num_experts, real))
     with jax.named_scope("experts"):
         y = moe.expert_ffn(
             n2, weights, experts, *experts_of_all_layers,
@@ -234,7 +246,7 @@ def _layer(x, real, layer, experts_of_all_layers, index, config: OlmoeConfig):
     return h + y.reshape(rows, length, hidden), busiest
 
 
-def _layers(weights, x, real, config: OlmoeConfig):
+def _layers(weights, x, segment, position, config: OlmoeConfig):
     """Every layer over ``x``, under ``lax.scan`` so that a program compiles
     one: ``(x, [layers] copies of real tokens each layer's busiest expert
     got)``. The scan slices the small arrays; the experts' stay whole."""
@@ -245,35 +257,36 @@ def _layers(weights, x, real, config: OlmoeConfig):
 
     def step(x, scanned):
         index, layer = scanned
-        return _layer(x, real, layer, stacked, index, config)
+        return _layer(x, segment, position, layer, stacked, index, config)
 
     return lax.scan(step, x, (jnp.arange(config.num_hidden_layers), sliced))
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
-def session_vectors(weights, tokens, last, *, config: OlmoeConfig):
-    """``tokens`` [B, L] int32, right-padded; ``last`` [B] int32, each
-    session's last real position, -1 for a padding row. Returns the session
-    vectors [B, hidden] float32 (``rms(x_L; w_final)`` at ``last``; a
-    padding row's is to be thrown away) and, summed over the layers, the
-    number of copies of REAL tokens the busiest expert got."""
-    real = jnp.arange(tokens.shape[1])[None, :] <= last[:, None]
+def session_vectors(weights, tokens, segment, position, last, *, config: OlmoeConfig):
+    """One token stream: ``tokens``, ``segment`` and ``position`` [1, T]
+    int32 (the module's docstring); ``last`` [S] int32, each session's last
+    position IN THE STREAM, -1 where the stream holds fewer than S. Returns
+    the session vectors [S, hidden] float32 (``rms(x_L; w_final)`` at
+    ``last``; one at -1 is to be thrown away) and, summed over the layers,
+    the number of copies of REAL tokens the busiest expert got."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
 
-    x, busiest = _layers(weights, x, real, config)
+    x, busiest = _layers(weights, x, segment, position, config)
     with jax.named_scope("head"):
-        at_last = x[jnp.arange(tokens.shape[0]), jnp.maximum(last, 0)]
-        out = _rms(at_last, weights["final_norm"], config.rms_norm_eps)
+        out = _rms(x[0, jnp.maximum(last, 0)], weights["final_norm"], config.rms_norm_eps)
     return out, jnp.sum(busiest)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
 def all_logits(weights, tokens, *, config: OlmoeConfig):
-    """Logits of EVERY position, [B, L, vocabulary]: what the parity tests
-    compare with the reference's ``forward``; serving never runs it."""
+    """Logits of EVERY position of ``tokens`` [B, L], every row one session:
+    what the parity tests compare with the reference's ``forward``; serving
+    never runs it."""
     x = weights["embed"][tokens].astype(jnp.float32)
-    x, _ = _layers(weights, x, jnp.ones(tokens.shape, bool), config)
+    position = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    x, _ = _layers(weights, x, None, position, config)
     out = _rms(x, weights["final_norm"], config.rms_norm_eps)
     return jnp.dot(
         out, weights["lm_head"].astype(jnp.float32).T, precision=lax.Precision.HIGHEST

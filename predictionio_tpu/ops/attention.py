@@ -17,7 +17,9 @@ Design:
   - ``fused_attention``: a pallas TPU kernel for the within-block attention
     (grid over batch x heads, K/V streamed through VMEM); falls back to the
     jnp reference path off-TPU. Used by ring_attention for its local block
-    when running on TPU.
+    when running on TPU. With ``segment=`` several sequences share one row
+    (a packed stream, ``models/sequential``): a key is seen only from inside
+    its own segment.
 """
 
 from __future__ import annotations
@@ -51,13 +53,21 @@ def attention_reference(
     causal: bool = False,
     q_offset: int = 0,
     k_offset: int = 0,
+    segment: jnp.ndarray | None = None,  # [B, L] int32, Lq == Lk == L
 ) -> jnp.ndarray:
+    """``segment``, where given, packs several sequences into a row: a key
+    is seen where it has the query's id and the id is not negative (a
+    negative id is padding: it sees nothing, is seen by none and comes out
+    as 0)."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         qi = jnp.arange(q.shape[2])[:, None] + q_offset
         ki = jnp.arange(k.shape[2])[None, :] + k_offset
         scores = jnp.where(qi >= ki, scores, -jnp.inf)
+    if segment is not None:
+        seg_q, seg_k = _segment_ids(segment)
+        scores = jnp.where((seg_q == seg_k)[:, None], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     # rows with no visible keys produce NaN from softmax(-inf row): zero
     # them. Not jnp.nan_to_num: fused into the einsum below it sends
@@ -258,6 +268,85 @@ def ulysses_attention(
 # ---------------------------------------------------------------------------
 
 
+def _segment_ids(segment):
+    """``(ids as the queries carry them [B, L, 1], as the keys do [B, 1, L])``:
+    padding is -1 on one side and -2 on the other, so that equality alone
+    is the mask."""
+    segment = segment.astype(jnp.int32)
+    pad = segment < 0
+    return jnp.where(pad, -1, segment)[:, :, None], jnp.where(pad, -2, segment)[:, None, :]
+
+
+def _first_keys(segment, block_q: int):
+    """[B * L / block_q] int32: the first key any query of a block of
+    ``block_q`` queries sees, L where none sees any. A segment's positions
+    are CONTIGUOUS and no two segments share an id: a query sees back to
+    where its run of equal ids began."""
+    rows, length = segment.shape
+    at = jnp.arange(length, dtype=jnp.int32)[None, :]
+    begins = jnp.concatenate(
+        [jnp.ones((rows, 1), bool), segment[:, 1:] != segment[:, :-1]], axis=1
+    )
+    began = lax.cummax(jnp.where(begins, at, 0), axis=1)
+    began = jnp.where(segment >= 0, began, length)
+    return jnp.min(began.reshape(rows, length // block_q, block_q), axis=2).reshape(-1)
+
+
+# queries and keys a step of the packed path off the chip
+OFF_CHIP_BLOCK = 128
+
+
+def _segmented_attention_blocked(q, k, v, causal: bool, segment, block: int):
+    """Attention over packed rows OFF the chip, by the flash kernel's own
+    schedule and arithmetic in ``jax.numpy``: blocks of ``block`` queries
+    against blocks of keys under an online softmax, float32 accumulation of
+    the operands as they come, and a block of keys that ends before the
+    first key any query of the block sees (in any row), or lies above the
+    diagonal, is not computed. The dense ``attention_reference`` does
+    ``L * L`` work a head whatever the segments are: at a stream of 2,048
+    tokens that made the CPU rehearsal of a serving cell thirty times as
+    slow as its sessions are long."""
+    rows, heads, length, _ = q.shape
+    n, scale = length // block, 1.0 / math.sqrt(q.shape[-1])
+    seg_q, seg_k = _segment_ids(segment)
+    first = jnp.min(_first_keys(segment, block).reshape(rows, n), axis=0)
+    within = jnp.arange(block)
+
+    def block_of(x, i, axis):
+        return lax.dynamic_slice_in_dim(x, i * block, block, axis)
+
+    def queries(i):
+        q_i, ids_i = block_of(q, i, 2).astype(jnp.float32), block_of(seg_q, i, 1)
+
+        def keys(j, carry):
+            def attend(carry):
+                acc, top, total = carry
+                scores = jnp.einsum("bhqd,bhkd->bhqk", q_i, block_of(k, j, 2).astype(jnp.float32)) * scale
+                seen = ids_i == block_of(seg_k, j, 2)  # [B, block, block]
+                if causal:
+                    seen = seen & (i * block + within[:, None] >= j * block + within[None, :])
+                scores = jnp.where(seen[:, None], scores, -jnp.inf)
+                new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+                safe = jnp.where(jnp.isneginf(new_top), 0.0, new_top)
+                p = jnp.exp(scores - safe[..., None])
+                shrink = jnp.exp(top - safe)
+                acc = acc * shrink[..., None] + jnp.einsum(
+                    "bhqk,bhkd->bhqd", p, block_of(v, j, 2).astype(jnp.float32)
+                )
+                return acc, new_top, total * shrink + jnp.sum(p, axis=-1)
+
+            needed = (j + 1) * block > first[i]
+            return lax.cond(needed & (j <= i) if causal else needed, attend, lambda c: c, carry)
+
+        acc = jnp.zeros((rows, heads, block, v.shape[-1]), jnp.float32)
+        total = jnp.zeros((rows, heads, block), jnp.float32)
+        acc, _, total = lax.fori_loop(0, n, keys, (acc, total - jnp.inf, total))
+        return acc / jnp.where(total == 0.0, 1.0, total)[..., None]
+
+    out = lax.map(queries, jnp.arange(n))  # [n, B, H, block, Dv]
+    return jnp.moveaxis(out, 0, 2).reshape(rows, heads, length, v.shape[-1]).astype(q.dtype)
+
+
 def _best_block(L: int) -> int:
     """Largest of 1024/512/256 dividing L. A round-4 sweep on a v5e at
     B4 H8 D64 causal measured (block_q, block_k) = (1024, 1024) fastest at
@@ -265,7 +354,19 @@ def _best_block(L: int) -> int:
     for the XLA dense reference); L=4096 1.74ms vs 2.75ms (XLA reference
     9.04ms — the [L, L] score materialization falls off a cliff). Bigger
     tiles amortize the online-softmax rescale and keep the MXU on longer
-    contractions; [1024, 1024] f32 scores + accumulators still fit VMEM."""
+    contractions; [1024, 1024] f32 scores + accumulators still fit VMEM.
+
+    Over a PACKED stream (``segment=``; 26 streams of four batches of 32
+    sessions drawn as the benchmark's cells draw them, five sessions a
+    stream; my chip run, PR 33) the same tile wins although smaller ones
+    skip more blocks: 16 heads of 128 at L 2,048 **0.241** ms for 1024,
+    0.256 for 512, 0.501 for 256, 1.30 for 128 (0.287 with no segment); at
+    4,096 **0.790** / 0.965 / 2.17 / 5.72 (0.849); keys of 192 and values
+    of 128 over 32 heads at 2,048 0.612 / 0.566 / 0.720 / 1.60 and at
+    4,096 **2.24** / 2.84 / 5.17 / 14.1; mixed (512, 256), (256, 512) and
+    (1024, 512) lie between. OLMoE's whole program at 2,048 / 4,096 tokens:
+    36.1 / 66.0 ms with 1024, 35.9 / 66.1 with 512, 36.6 / 71.6 with 256.
+    So a packed row takes this function's answer like any other."""
     for b in (1024, 512, 256):
         if L % b == 0:
             return b
@@ -273,7 +374,8 @@ def _best_block(L: int) -> int:
 
 
 def _flash_attention_pallas(
-    q, k, v, causal: bool, interpret: bool, block_q: int = 1024, block_k: int = 1024
+    q, k, v, causal: bool, interpret: bool, block_q: int = 1024, block_k: int = 1024,
+    segment=None,
 ):
     """Tiled flash-attention pallas kernel: grid (B*H, Lq/bq, Lk/bk), online
     softmax carried across the (sequential, innermost) K-block grid axis in
@@ -281,7 +383,12 @@ def _flash_attention_pallas(
     [Lq, Lk] score matrix in VMEM, which blows the ~16MB scoped-VMEM limit
     at L=2048 (first observed on real hardware in the round-3 bench — the
     kernel had only ever run in interpret mode before); this one peaks at
-    [bq, bk] scores + [bq, D] accumulators regardless of L."""
+    [bq, bk] scores + [bq, D] accumulators regardless of L.
+
+    ``segment`` [B, L] (``attention_reference``'s): the ids ride in as two
+    more blocked inputs and join the mask, and a K block that ends before
+    the first key any query of the Q block sees (``_first_keys``, prefetched
+    as scalars) is skipped as the blocks above the diagonal are."""
     import math as _math
 
     from jax.experimental import pallas as pl
@@ -294,11 +401,17 @@ def _flash_attention_pallas(
     nq, nk = Lq // bq, Lk // bk
     scale = 1.0 / _math.sqrt(D)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+    def kernel(*refs):
+        if segment is None:
+            q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        else:
+            first_ref, q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref, l_ref = refs
         # program ids hoisted out of the pl.when bodies: the interpret-mode
         # lowering can't evaluate program_id inside a nested cond
         qi_blk = pl.program_id(1)
         kj = pl.program_id(2)
+        if segment is not None:
+            first_key = first_ref[(pl.program_id(0) // H) * nq + qi_blk]
 
         @pl.when(kj == 0)
         def _init():
@@ -323,6 +436,8 @@ def _flash_attention_pallas(
                 qi = qi_blk * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
                 ki = kj * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
                 s = jnp.where(qi >= ki, s, -jnp.inf)
+            if segment is not None:
+                s = jnp.where(sq_ref[0] == sk_ref[0], s, -jnp.inf)
             m_prev = m_ref[...]  # [bq, 1]
             m_blk = jnp.max(s, axis=1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_blk)
@@ -341,9 +456,15 @@ def _flash_attention_pallas(
         if causal:
             # skip K blocks lying entirely above the diagonal: they are
             # fully masked and would only burn MXU cycles (~2x at nq == nk)
-            @pl.when(kj * bk <= (qi_blk + 1) * bq - 1)
+            needed = kj * bk <= (qi_blk + 1) * bq - 1
+            if segment is not None:
+                needed = needed & ((kj + 1) * bk > first_key)
+
+            @pl.when(needed)
             def _():
                 compute()
+        elif segment is not None:
+            pl.when((kj + 1) * bk > first_key)(compute)
         else:
             compute()
 
@@ -356,33 +477,47 @@ def _flash_attention_pallas(
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H, Lk, D)
     vr = v.reshape(B * H, Lk, Dv)
-    out = pl.pallas_call(
-        kernel,
+    # (the index maps take the prefetched scalars of a packed row behind the
+    # grid's indices and do not look at them)
+    grid = dict(
         grid=(B * H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j, *_: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j, *_: (b, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+    )
+    operands = (qr, kr, vr)
+    if segment is not None:
+        grid["in_specs"] += [
+            pl.BlockSpec((1, bq, 1), lambda b, i, j, *_: (b // H, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, i, j, *_: (b // H, 0, j)),
+        ]
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid))
+        operands = (_first_keys(segment, bq), *operands, *_segment_ids(segment))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr)
+        **grid,
+    )(*operands)
     return out.reshape(B, H, Lq, Dv)
 
 
-def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool):
+def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None):
     from jax.experimental import pallas as pl
 
     B, H, Lq, D = q.shape
     Lk, Dv = k.shape[2], v.shape[3]
 
-    def kernel(q_ref, k_ref, v_ref, o_ref):
+    def kernel(q_ref, k_ref, v_ref, *rest):
+        o_ref = rest[-1]
         qb = q_ref[0]  # [Lq, D]
         kb = k_ref[0]
         vb = v_ref[0]
@@ -400,7 +535,13 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool):
             qi = lax.broadcasted_iota(jnp.int32, (Lq, Lk), 0)
             ki = lax.broadcasted_iota(jnp.int32, (Lq, Lk), 1)
             scores = jnp.where(qi >= ki, scores, -jnp.inf)
+        if segment is not None:
+            sq_ref, sk_ref = rest[:2]
+            scores = jnp.where(sq_ref[0] == sk_ref[0], scores, -jnp.inf)
         m = jnp.max(scores, axis=-1, keepdims=True)
+        if segment is not None:
+            # a padding row sees no key: its maximum is -inf and its sum 0
+            m = jnp.where(jnp.isneginf(m), 0.0, m)
         p = jnp.exp(scores - m)
         out = jnp.dot(
             p.astype(jnp.bfloat16),
@@ -408,24 +549,33 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool):
             preferred_element_type=jnp.float32,
         )
         denom = jnp.sum(p, axis=-1, keepdims=True)
+        if segment is not None:
+            denom = jnp.where(denom == 0.0, 1.0, denom)
         o_ref[0] = (out / denom).astype(o_ref.dtype)
 
     grid = (B * H,)
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H, Lk, D)
     vr = v.reshape(B * H, Lk, Dv)
+    operands, in_specs = [qr, kr, vr], [
+        pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
+        pl.BlockSpec((1, Lk, D), lambda i: (i, 0, 0)),
+        pl.BlockSpec((1, Lk, Dv), lambda i: (i, 0, 0)),
+    ]
+    if segment is not None:
+        operands += _segment_ids(segment)
+        in_specs += [
+            pl.BlockSpec((1, Lq, 1), lambda i: (i // H, 0, 0)),
+            pl.BlockSpec((1, 1, Lk), lambda i: (i // H, 0, 0)),
+        ]
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, Lk, D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, Lk, Dv), lambda i: (i, 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Lq, Dv), lambda i: (i, 0, 0)),
         interpret=interpret,
-    )(qr, kr, vr)
+    )(*operands)
     return out.reshape(B, H, Lq, Dv)
 
 
@@ -435,6 +585,7 @@ def fused_attention(
     v: jnp.ndarray,
     causal: bool = False,
     force_pallas: bool = False,
+    segment: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-device attention over ``q`` [B, H, Lq, D], ``k`` [B, H, Lk, D]
     and ``v`` [B, H, Lk, Dv]: queries and keys share a head width, the
@@ -448,13 +599,21 @@ def fused_attention(
     always compiled). Platform is read from ``jax.default_backend()`` so
     the choice also works on tracers (e.g. inside shard_map).
 
+    ``segment`` [B, L] int32 (Lq == Lk == L) packs several sequences into a
+    row: a key is seen where it carries the query's id (and, under
+    ``causal``, does not follow it). A segment's positions are contiguous
+    and its id its own; a negative id is padding, which sees nothing, is
+    seen by none and comes out as 0. Without it every path is what it was.
+
     Limit of the kernel path: a sequence whose score tile is past the
     single-block budget (Lq * Lk >= 2**20, i.e. L >= 1024 square) must be
     a multiple of 256 in both lengths, or the call raises ValueError."""
     on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
-        return attention_reference(q, k, v, causal=causal)
     Lq, Lk = q.shape[2], k.shape[2]
+    if not (on_tpu or force_pallas):
+        if segment is not None and Lq % OFF_CHIP_BLOCK == 0:
+            return _segmented_attention_blocked(q, k, v, causal, segment, OFF_CHIP_BLOCK)
+        return attention_reference(q, k, v, causal=causal, segment=segment)
     # single-block kernel holds the [Lq, Lk] f32 score tile in VMEM
     # (strict <: a 4MiB tile — L=1024 square — already takes the flash
     # path, which the interpret-mode routing test pins)
@@ -471,10 +630,10 @@ def fused_attention(
         )
     interpret = not on_tpu
     if single_block:
-        return _fused_attention_pallas(q, k, v, causal, interpret=interpret)
+        return _fused_attention_pallas(q, k, v, causal, interpret=interpret, segment=segment)
     # block sizes tuned per-shape (see _best_block): the largest
     # dividing tile wins on the MXU at every measured length
     return _flash_attention_pallas(
         q, k, v, causal, interpret=interpret,
-        block_q=_best_block(Lq), block_k=_best_block(Lk),
+        block_q=_best_block(Lq), block_k=_best_block(Lk), segment=segment,
     )

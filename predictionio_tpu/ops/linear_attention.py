@@ -38,9 +38,15 @@ Everything here is float32 and the products run at ``PRECISION`` (float32
 operands in three bf16 passes on the chip): the state is what a later
 position reads every earlier one through.
 
-Right-padded sessions need no mask: a real position never sees what follows
-it. The final state of a padded row is the padding's too, and of use only
-to a caller that passed no padding.
+A right-padded session needs no mask: a real position never sees what
+follows it. Several sessions PACKED into one row (``models/sequential``'s
+streams) need one thing each: where a session begins on a chunk's first
+position (``kda(starts=)``) the state that reaches the chunk is zeroed, and
+every quantity of a chunk is the session's own as if it stood alone (the
+padding behind its last real position follows them inside the chunk and is
+seen by none); ``short_conv(position=)`` leaves out the taps that would reach
+before a session's first position. The final state of a padded row is the
+padding's too, and of use only to a caller that passed no padding.
 """
 
 from __future__ import annotations
@@ -63,18 +69,28 @@ PRECISION = lax.Precision.HIGH
 _dot = functools.partial(jnp.einsum, precision=PRECISION, preferred_element_type=jnp.float32)
 
 
-def short_conv(x, w, tail=None):
+def short_conv(x, w, tail=None, position=None):
     """``silu`` of a causal depthwise convolution over positions: ``x``
     [B, L, D] float32, ``w`` [taps, D] (``w[-1]`` meets the position itself),
     ``tail`` [B, taps - 1, D] the inputs that came before position 0 (zeros
-    when there were none). Returns ``(y [B, L, D], tail')``, ``tail'`` the
-    last ``taps - 1`` inputs, for a caller that goes on from here."""
+    when there were none). ``position`` [B, L] int32, where several sessions
+    share a row, is each input's index inside its own session: a tap that
+    would reach before index 0 meets a zero, not the session in front.
+    Returns ``(y [B, L, D], tail')``, ``tail'`` the last ``taps - 1`` inputs,
+    for a caller that goes on from here."""
     taps, length = w.shape[0], x.shape[1]
     if tail is None:
         tail = jnp.zeros((x.shape[0], taps - 1, x.shape[2]), x.dtype)
     padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     w = w.astype(x.dtype)
-    y = sum(w[j] * padded[:, j : j + length] for j in range(taps))
+
+    def tap(j):
+        reached = padded[:, j : j + length]
+        if position is None or j == taps - 1:
+            return reached
+        return jnp.where((position >= taps - 1 - j)[:, :, None], reached, 0.0)
+
+    y = sum(w[j] * tap(j) for j in range(taps))
     return jax.nn.silu(y), padded[:, length:]
 
 
@@ -115,12 +131,15 @@ def _triangles(q, k, b, cum):
     return m[..., 0, :, :], t[..., 0, :, :]
 
 
-def kda(q, k, v, g, b, state=None):
+def kda(q, k, v, g, b, state=None, starts=None):
     """The gated delta rule over ``q``, ``k``, ``g`` [B, L, heads, d_k],
     ``v`` [B, L, heads, d_v] and ``b`` [B, L, heads], float32: ``q`` and
     ``k`` as the state is to meet them (normalised, ``q`` scaled), ``g`` the
     log decay a channel (at most 0), ``b`` the step size. ``state``
     [B, heads, d_k, d_v] is what came before position 0 (zeros by default).
+    ``starts`` [B, chunks] bool marks the chunks on whose first position a
+    session begins: no state reaches them (the one thing sessions packed
+    into a row, each from a multiple of ``CHUNK``, need).
     Returns ``(o [B, L, heads, d_v], the state after position L - 1)``.
 
     ``L`` is padded to whole chunks of ``CHUNK`` with positions that leave
@@ -153,7 +172,9 @@ def kda(q, k, v, g, b, state=None):
     kept = jnp.exp(cum[..., -1, :])  # [n, B, heads, d_k]
 
     def step(s, xs):
-        u_own, w, q_in, m, k_out, kept = xs
+        u_own, w, q_in, m, k_out, kept, *fresh = xs
+        if fresh:
+            s = jnp.where(fresh[0][:, None, None, None], 0.0, s)
         u = u_own - _dot("bhtc,bhcv->bhtv", w, s)
         o = _dot("bhtc,bhcv->bhtv", q_in, s) + _dot("bhts,bhsv->bhtv", m, u)
         s = kept[..., None] * s + _dot("bhtc,bhtv->bhcv", k_out, u)
@@ -161,7 +182,8 @@ def kda(q, k, v, g, b, state=None):
 
     if state is None:
         state = jnp.zeros((batch, heads, d_k, d_v), jnp.float32)
-    state, o = lax.scan(step, state.astype(jnp.float32), (u_own, w, q_in, m, k_out, kept))
+    xs = (u_own, w, q_in, m, k_out, kept) + (() if starts is None else (starts.T,))
+    state, o = lax.scan(step, state.astype(jnp.float32), xs)
     # [n, B, heads, chunk, d_v] -> [B, L, heads, d_v]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(batch, n * chunk, heads, d_v)
     return o[:, :length], state

@@ -72,6 +72,54 @@ def test_a_right_padded_batch_gives_every_session_its_own_output(chunk, monkeypa
         np.testing.assert_allclose(out[row, :n], alone[0], atol=ATOL, rtol=0)
 
 
+def packed(lengths, chunk):
+    """Where each session of a packed row starts (whole chunks each), the
+    row's length, and the chunks that begin one."""
+    starts = np.concatenate([[0], np.cumsum([-(-n // chunk) * chunk for n in lengths])])
+    fresh = np.zeros((1, starts[-1] // chunk), bool)
+    fresh[0, starts[:-1] // chunk] = True
+    return starts[:-1], int(starts[-1]), fresh
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("lengths", [(5, 64, 97, 128), (64, 64, 1), (200,)])
+def test_sessions_packed_into_a_row_equal_the_sessions_one_at_a_time(chunk, lengths, monkeypatch):
+    # float32 against float32: a state leaked from the session in front shows
+    # at the outputs' own order (0.1), a hundred thousand times the tolerance
+    starts, total, fresh = packed(lengths, chunk)
+    args = inputs(11, 1, total, DECAYS["0.9 to 0.9999"])
+    kda = kda_at(chunk, monkeypatch)
+    out, _ = jax.jit(lambda *a: linear_attention.kda(*a, starts=jnp.asarray(fresh)))(*args)
+    leaked, _ = kda(*args)
+    for start, n in zip(starts, lengths):
+        alone, _ = kda(*(x[:, start : start + n] for x in args))
+        np.testing.assert_allclose(out[:, start : start + n], alone, atol=1e-6, rtol=0)
+        if start:
+            assert float(np.abs(leaked[:, start : start + n] - alone).max()) > 1e-3
+    # a state handed in reaches the first chunk only where no session begins there
+    state = jnp.ones((1, HEADS, WIDTH, WIDTH))
+    again, _ = linear_attention.kda(*args, state, starts=jnp.asarray(fresh))
+    np.testing.assert_allclose(again, out, atol=1e-6, rtol=0)
+
+
+def test_short_conv_reaches_no_further_back_than_a_sessions_first_position():
+    rng = np.random.default_rng(4)
+    x = rng.normal(size=(1, 24, 8)).astype(np.float32)
+    taps = rng.normal(size=(4, 8)).astype(np.float32)
+    # sessions of 5, 3 and 9 positions from 0, 8 and 12; the rest is padding, at position 0
+    position = np.zeros((1, 24), np.int32)
+    for start, n in ((0, 5), (8, 3), (12, 9)):
+        position[0, start : start + n] = np.arange(n)
+    y, _ = linear_attention.short_conv(x, taps, position=jnp.asarray(position))
+    for start, n in ((0, 5), (8, 3), (12, 9)):
+        alone, _ = linear_attention.short_conv(x[:, start : start + n], taps)
+        np.testing.assert_array_equal(y[:, start : start + n], alone)
+    # without it, a session's first three positions read the one in front
+    plain, _ = linear_attention.short_conv(x, taps)
+    differs = np.abs(np.asarray(plain) - np.asarray(y)).max(axis=(0, 2)) > 0
+    assert differs[8:11].all() and differs[12:15].all() and not differs[15:21].any()
+
+
 @pytest.mark.parametrize("cut", [1, 37, 64, 100])
 def test_a_prefix_then_the_rest_from_the_returned_state_equals_the_whole_pass(cut):
     # the scan AND the convolution in front of it: the state and the tail

@@ -64,8 +64,15 @@ BF16_MEDIAN, BF16_TIPPED = 0.1, (0.15, 0.2)
 
 @pytest.fixture(autouse=True)
 def small_programs(monkeypatch):
-    """A program holds 256 tokens here: bucket 64 comes 4 rows high."""
+    """A stream holds 256 tokens here, and four sessions at most."""
     monkeypatch.setattr(kimi_linear, "TOKEN_BUDGET", 256)
+
+
+def staged(algorithm, model, sessions, starts, length):
+    """The sessions as ONE stream of ``length`` tokens, each from its start:
+    ``_stage``'s arrays but the mask."""
+    stream = (length, list(enumerate(starts)))
+    return [jnp.asarray(a) for a in algorithm._stage(model, sessions, stream)[:4]]
 
 
 def training_data(seed=0, n_users=12) -> TrainingData:
@@ -303,10 +310,8 @@ def test_the_decays_drawn_spread_over_where_a_dropped_one_shows(trained):
 def test_the_counts_leave_the_padding_out_and_split_held_from_absent(trained):
     algorithm, model = trained
     config = model.config
-    tokens = np.zeros((4, 64), np.int32)
-    tokens[0, :10] = np.arange(10)
-    last = np.array([9, -1, -1, -1], np.int32)
-    _, counted = kimi_linear.session_vectors(model.weights, tokens, last, config=config)
+    stream = staged(algorithm, model, [np.arange(10, dtype=np.int32)], [64], 256)
+    _, counted = kimi_linear.session_vectors(model.weights, *stream, config=config)
     busiest, held = (int(c) for c in counted)
     routed = config.routed_copies(10)
     assert routed == 3 * 10 * 4  # three sparse layers, four copies a token
@@ -314,18 +319,58 @@ def test_the_counts_leave_the_padding_out_and_split_held_from_absent(trained):
     assert config.even_expert_load(10) == pytest.approx(3 * 10 * 4 / 16)
 
 
-def test_right_padding_changes_no_real_positions_output(trained):
-    _, model = trained
+def test_the_padding_around_a_session_changes_nothing_of_it(trained):
+    algorithm, model = trained
     config = model.config
     session = np.random.default_rng(2).integers(0, N_ITEMS, 40).astype(np.int32)
     vectors = []
-    for bucket, fill in ((64, 0), (64, 77), (128, 5)):
-        tokens = np.full((1, bucket), fill, np.int32)
-        tokens[0, :40] = session
-        out, _ = kimi_linear.session_vectors(model.weights, tokens, np.array([39], np.int32), config=config)
+    for start, length, fill in ((0, 64, 0), (0, 64, 77), (64, 128, 5), (192, 256, 9)):
+        tokens, segment, position, last = staged(algorithm, model, [session], [start], length)
+        tokens = jnp.where(segment < 0, fill, tokens)
+        out, _ = kimi_linear.session_vectors(model.weights, tokens, segment, position, last, config=config)
         vectors.append(np.asarray(out[0]))
-    np.testing.assert_allclose(vectors[0], vectors[1], atol=1e-5, rtol=0)
-    np.testing.assert_allclose(vectors[0], vectors[2], atol=1e-5, rtol=0)
+    for other in vectors[1:]:
+        np.testing.assert_allclose(vectors[0], other, atol=1e-5, rtol=0)
+
+
+# sessions (their lengths) of ONE stream, where each starts, the stream's
+# length, the budget and the longest session the engine keeps
+PACKED = {
+    "one ends inside a chunk, one is exactly 64": ((37, 64, 100), (0, 64, 128), 256, 256, 512),
+    "one longer than the budget shares its stream": ((300, 64, 17, 40), (0, 320, 384, 448), 512, 256, 512),
+    "32 sessions at the chip's budget": (tuple(range(33, 65)), tuple(range(0, 2048, 64)), 2048, 2048, 4096),
+    "2,049 to 4,096 items beside others": ((2100, 1000, 64, 500), (0, 2112, 3136, 3200), 4096, 2048, 4096),
+}
+
+
+@pytest.mark.parametrize("case", list(PACKED))
+def test_a_packed_streams_session_vectors_equal_the_sessions_alone(case, monkeypatch):
+    lengths, starts, length, budget, longest = PACKED[case]
+    monkeypatch.setattr(kimi_linear, "TOKEN_BUDGET", budget)
+    monkeypatch.setattr(kimi_linear, "MAX_SESSION", longest)
+    params = KimiLinearAlgorithmParams(**{**TINY, "model_max_length": longest}, seed=4)
+    algorithm = KimiLinearAlgorithm(params)
+    rng = np.random.default_rng(len(lengths))
+    sessions = [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths]
+    model = algorithm.train(None, TrainingData(["u"], [sessions[0]], [f"i{i}" for i in range(N_ITEMS)]))
+    model.weights = upcast(model.weights)
+    assert length in model.config.stream_shapes()
+    packed, _ = kimi_linear.session_vectors(
+        model.weights, *staged(algorithm, model, sessions, starts, length), config=model.config
+    )
+    assert packed.shape == (budget // 64, 64)
+    for row, session in enumerate(sessions):
+        # alone, from the stream's first position: the same compiled program.
+        # A state, a convolution's taps or a key leaked from the session in
+        # front would move it by the vectors' own order
+        alone, _ = kimi_linear.session_vectors(
+            model.weights, *staged(algorithm, model, [session], [0], length), config=model.config
+        )
+        np.testing.assert_allclose(packed[row], alone[0], atol=ATOL, rtol=0, err_msg=f"session {row}")
+    # ... and the model's own answer at the session's true length
+    logits = kimi_linear.all_logits(model.weights, jnp.asarray(sessions[1])[None], config=model.config)
+    head = np.asarray(model.weights["lm_head"], np.float32)
+    np.testing.assert_allclose(np.asarray(packed[1]) @ head.T, np.asarray(logits)[0, -1], atol=ATOL)
 
 
 # -------------------------------------------------------------- the engine
@@ -383,7 +428,8 @@ def test_olmoe_counts_every_copy_as_held():
 def test_program_shapes_are_a_small_closed_set_and_warmup_compiles_them_all():
     algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**{**TINY, "num_hidden_layers": 2}, seed=2))
     model = algorithm.train(None, training_data(n_users=5))
-    assert model.config.program_shapes() == ((4, 64), (2, 128))
+    # the streams compile; `program_shapes` is the ladder of before them, kept for the benchmark's pin
+    assert model.config.stream_shapes() == (256,) and model.config.program_shapes() == ((4, 64), (2, 128))
     algorithm.warmup_serving(model, 8)
     compiled = kimi_linear.session_vectors._cache_size()
     algorithm.predict_batch(model, [Query(user=f"u{i}", num=4) for i in range(5)])

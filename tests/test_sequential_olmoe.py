@@ -1,7 +1,7 @@
 """The sequential engine's ``olmoe`` scorer against its plain reference, at a
 tiny size on the CPU: 2 layers, hidden 64, 4 heads of 16, 8 experts of width
-32 with 2 a token, vocabulary 128, sessions of 3 to 70 items, so that two
-length buckets and a split of one bucket into two programs occur.
+32 with 2 a token, vocabulary 128, sessions of 3 to 70 items packed into
+token streams of 256, so that streams of one to four sessions occur.
 
 The weights are the algorithm's own (drawn in bfloat16 from its seed). Where
 a test compares values it upcasts the SAME weights to float32 for both sides:
@@ -57,10 +57,17 @@ MEMORY_STORAGE = {
 
 @pytest.fixture(autouse=True)
 def small_programs(monkeypatch):
-    """The token budget is a constant the chip set (2,048): here a program
-    holds 256 tokens, so bucket 64 comes 4 rows high and bucket 128 comes 2,
-    and a dozen sessions split."""
+    """The token budget is a constant the chip set (2,048): here a stream
+    holds 256 tokens and four sessions at most, so a dozen sessions take
+    several."""
     monkeypatch.setattr(olmoe, "TOKEN_BUDGET", 256)
+
+
+def staged(algorithm, model, sessions, starts, length):
+    """The sessions as ONE stream of ``length`` tokens, each from its start:
+    ``_stage``'s arrays but the mask."""
+    stream = (length, list(enumerate(starts)))
+    return [jnp.asarray(a) for a in algorithm._stage(model, sessions, stream)[:4]]
 
 
 def training_data(seed=0, n_users=14) -> TrainingData:
@@ -219,16 +226,14 @@ def test_full_logits_equal_the_references(trained, length, kernel, monkeypatch):
 
 
 def test_the_busiest_experts_count_leaves_the_padding_out(trained):
-    _, model = trained
+    algorithm, model = trained
     session = np.random.default_rng(13).integers(0, N_ITEMS, 37).astype(np.int32)
     counts = []
-    for rows, bucket, pad in ((1, 64, 0), (2, 64, 5), (2, 128, 0)):
-        tokens = np.full((rows, bucket), pad, np.int32)
-        tokens[0, :37] = session
-        last = np.full(rows, -1, np.int32)
-        last[0] = 36
+    for start, length, pad in ((0, 64, 0), (64, 128, 5), (128, 256, 0)):
+        tokens, segment, position, last = staged(algorithm, model, [session], [start], length)
+        tokens = jnp.where(segment < 0, pad, tokens)
         _, busiest = olmoe.session_vectors(
-            model.weights, jnp.asarray(tokens), jnp.asarray(last), config=model.config
+            model.weights, tokens, segment, position, last, config=model.config
         )
         counts.append(int(busiest))
     # 37 real tokens with 2 experts each over 2 layers, whatever is padded around them
@@ -277,11 +282,11 @@ def test_a_batch_of_mixed_lengths_is_answered_in_order_as_the_reference_does(tra
     recent = tuple(f"i{i}" for i in rng.integers(0, N_ITEMS, 9)) + ("no-such-item",)
     queries.insert(4, Query(user="u0", recent_items=recent, num=7))
     queries.insert(9, Query(user="nobody", num=5))  # no session: no items
-    sessions, programs = algorithm._plan(model, queries)
-    assert {bucket for bucket, _, _ in programs} == {64, 128}
-    # more sessions in bucket 64 than one program holds: split
-    assert sum(1 for bucket, _, _ in programs if bucket == 64) >= 2
-    assert sorted(i for _, _, members in programs for i in members) == [
+    sessions, streams = algorithm._plan(model, queries)
+    assert {length for length, _ in streams} == {256}  # no session is longer: one shape
+    # more sessions than one stream holds, and some streams hold several
+    assert len(streams) >= 2 and max(len(members) for _, members in streams) >= 2
+    assert sorted(i for _, members in streams for i, _ in members) == [
         i for i in range(len(queries)) if i != 9
     ]
     answers = algorithm.predict_batch_dispatch(model, queries)()
@@ -310,14 +315,98 @@ def test_one_query_equals_its_row_of_a_batch(trained):
     )
 
 
-def test_program_shapes_are_a_small_closed_set(monkeypatch):
+def test_stream_shapes_are_a_small_closed_set(monkeypatch):
     monkeypatch.undo()  # the constants the chip set
     config = OlmoeAlgorithmParams().config()
-    shapes = config.program_shapes()
+    # the ladder the benchmark's check pads its references by
     assert config.buckets() == (64, 128, 256, 512, 1024, 2048, 4096)
-    assert len(shapes) == len(set(shapes)) <= 16
-    # one height a bucket: the budget's rows, one row where a session is longer
-    assert shapes == ((32, 64), (16, 128), (8, 256), (4, 512), (2, 1024), (1, 2048), (1, 4096))
+    # what compiles: the budget, and the longest session's where it is longer
+    assert config.stream_shapes() == (2048, 4096)
+    assert olmoe.SESSION_ALIGN == 64 and olmoe.TOKEN_BUDGET // olmoe.SESSION_ALIGN == 32
+    assert olmoe.stream_shapes(256, 128) == (256,) and olmoe.stream_shapes(256, 300) == (256, 320)
+
+
+# sessions (their lengths) of ONE stream, where each starts, the stream's
+# length, the budget and the longest session the model takes
+PACKED = {
+    "one ends inside a chunk, one is exactly 64": ((37, 64, 100), (0, 64, 128), 256, 256, 512),
+    "one longer than the budget shares its stream": ((300, 64, 17, 40), (0, 320, 384, 448), 512, 256, 512),
+    "32 sessions at the chip's budget": (tuple(range(33, 65)), tuple(range(0, 2048, 64)), 2048, 2048, 4096),
+    "2,049 to 4,096 items beside others": ((2100, 1000, 64, 500), (0, 2112, 3136, 3200), 4096, 2048, 4096),
+}
+
+
+@pytest.mark.parametrize("case", list(PACKED))
+def test_a_packed_streams_session_vectors_equal_the_sessions_alone(case, monkeypatch):
+    lengths, starts, length, budget, longest = PACKED[case]
+    monkeypatch.setattr(olmoe, "TOKEN_BUDGET", budget)
+    params = OlmoeAlgorithmParams(**{**TINY, "max_position_embeddings": longest}, seed=4)
+    algorithm = OlmoeAlgorithm(params)
+    rng = np.random.default_rng(len(lengths))
+    sessions = [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths]
+    model = algorithm.train(None, TrainingData(["u"], [sessions[0]], [f"i{i}" for i in range(N_ITEMS)]))
+    model.weights = jax.tree.map(lambda a: a.astype(jnp.float32), model.weights)
+    assert length in model.config.stream_shapes()
+    packed, _ = olmoe.session_vectors(
+        model.weights, *staged(algorithm, model, sessions, starts, length), config=model.config
+    )
+    assert packed.shape == (budget // 64, 64)
+    for row, session in enumerate(sessions):
+        # alone, from the stream's first position: the same compiled program
+        alone, _ = olmoe.session_vectors(
+            model.weights, *staged(algorithm, model, [session], [0], length), config=model.config
+        )
+        np.testing.assert_allclose(packed[row], alone[0], atol=ATOL, rtol=0, err_msg=f"session {row}")
+    # ... and the model's own answer at the session's true length
+    logits = olmoe.all_logits(model.weights, jnp.asarray(sessions[1])[None], config=model.config)
+    head = np.asarray(model.weights["lm_head"], np.float32)
+    np.testing.assert_allclose(np.asarray(packed[1]) @ head.T, np.asarray(logits)[0, -1], atol=ATOL)
+
+
+def drawn_as_the_cells_draw(seed, n):
+    rng = np.random.default_rng(seed)
+    return np.clip(np.rint(np.exp(rng.normal(np.log(256), 1.0, n))), 16, 4096).astype(int)
+
+
+@pytest.mark.parametrize("seed,n", [(0, 32), (1, 32), (2, 64), (3, 7), (4, 1)])
+def test_the_plan_never_splits_a_session_nor_overfills_a_stream(seed, n, monkeypatch):
+    monkeypatch.undo()  # the constants the chip set
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(**{**TINY, "max_position_embeddings": 4096}, seed=1))
+    lengths = drawn_as_the_cells_draw(seed, n)
+    lengths[0] = 4096 if seed == 0 else lengths[0]
+    users = [f"u{i}" for i in range(n)]
+    rng = np.random.default_rng(seed)
+    model = algorithm.train(None, TrainingData(
+        users, [rng.integers(0, N_ITEMS, k).astype(np.int32) for k in lengths],
+        [f"i{i}" for i in range(N_ITEMS)],
+    ))
+    queries = [Query(user=u, num=3) for u in users] + [Query(user="nobody", num=3)]
+    sessions, streams = algorithm._plan(model, queries)
+    assert [len(s) for s in sessions] == [*lengths, 0]
+    placed = sorted(i for _, members in streams for i, _ in members)
+    assert placed == list(range(n))  # each session once, whole; the empty one nowhere
+    for length, members in streams:
+        assert length in (2048, 4096) and 1 <= len(members) <= 32
+        # a stream of 4,096 is opened only by a session that needs it
+        assert length == 2048 or len(sessions[members[0][0]]) > 2048
+        ends = [0]
+        for i, start in members:
+            assert start % 64 == 0 and start >= ends[-1]  # aligned, behind the one before
+            ends.append(start + len(sessions[i]))
+        assert ends[-1] <= length
+    padded = sum(length for length, _ in streams)
+    assert padded < 2 * max(2048, int(lengths.sum()))  # packed: under half of it padding
+    tokens, segment, position, last, mask = algorithm._stage(model, sessions, streams[0])
+    assert tokens.shape == segment.shape == position.shape == (1, streams[0][0])
+    assert last.shape == (32,) and mask.shape == (32, 128)
+    for row, (i, start) in enumerate(streams[0][1]):
+        own = slice(start, start + len(sessions[i]))
+        assert (segment[0, own] == row).all() and last[row] == own.stop - 1
+        assert np.array_equal(tokens[0, own], sessions[i])
+        assert np.array_equal(position[0, own], np.arange(len(sessions[i])))
+        assert not mask[row, sessions[i]].any() and not mask[row, N_ITEMS:].any()
+    assert int((segment >= 0).sum()) == sum(len(sessions[i]) for i, _ in streams[0][1])
+    assert (last[len(streams[0][1]):] == -1).all() and not mask[len(streams[0][1]):].any()
 
 
 def test_warmup_serving_leaves_nothing_to_compile():
@@ -336,7 +425,7 @@ def test_warmup_serving_leaves_nothing_to_compile():
     monitoring.register_event_duration_secs_listener(listener)
     algorithm.warmup_serving(model, 64)
     warmed = len(compiled)
-    assert warmed >= len(model.config.program_shapes())
+    assert warmed >= len(model.config.stream_shapes())
     answers = algorithm.predict_batch(model, [Query(user=u, num=10) for u in td.users])
     assert all(len(a.item_scores) == 10 for a in answers)
     assert len(compiled) == warmed
@@ -524,9 +613,10 @@ def test_query_server_answers_mixed_lengths_over_http_and_counts_them(trained):
         real = sum(len(s) for s in td.sequences) + 3
         assert grown('pio_seq_tokens_total{kind="real"}') == real
         padded = grown('pio_seq_tokens_total{kind="padded"}')
-        by_bucket = {b: grown(f'pio_seq_rows_total{{bucket="{b}"}}') for b in (64, 128)}
-        assert padded == 64 * by_bucket[64] + 128 * by_bucket[128] > real
-        programs = sum(grown(f'pio_seq_programs_total{{bucket="{b}"}}') for b in (64, 128))
+        # a program is one stream of 256 tokens here: one row of its "bucket"
+        programs = grown('pio_seq_programs_total{bucket="256"}')
+        assert padded == 256 * grown('pio_seq_rows_total{bucket="256"}') == 256 * programs > real
+        assert grown('pio_seq_sessions_total{bucket="256"}') == len(td.users) + 1
         assert programs >= 2 and grown("pio_seq_batches_total") >= 1
         assert grown("pio_seq_stage_seconds_total") > 0
         # per layer and program the busiest expert has at least the mean

@@ -78,36 +78,60 @@ def test_als_step_at_the_benchmark_shape_holds_its_systems_unpadded(
 
 
 # latent attention expanded for a prefill: keys of 192, values of 128; the
-# single-block kernel (L under 1,024) and the tiled one, at the Kimi-Linear
-# cell's program shapes
-@pytest.mark.parametrize("rows, length", [(32, 64), (4, 512), (2, 1024), (1, 4096)])
+# tiled kernel over a packed stream at the Kimi-Linear cell's two program
+# shapes, and the single-block kernel (L under 1,024) and the tiled one
+# without a segment
+@pytest.mark.parametrize(
+    "rows, length, packed", [(1, 2048, True), (1, 4096, True), (32, 64, False), (2, 1024, False)]
+)
 def test_fused_attention_with_values_narrower_than_keys_compiles_for_v5e(
-    one_chip, monkeypatch, rows, length
+    one_chip, monkeypatch, rows, length, packed
 ):
     from predictionio_tpu.ops.attention import fused_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     wide = _shape(one_chip, (rows, 32, length, 192), jnp.bfloat16)
     narrow = _shape(one_chip, (rows, 32, length, 128), jnp.bfloat16)
+    segment = (_shape(one_chip, (rows, length), jnp.int32),) if packed else ()
+
+    def attend(q, k, v, *segment):
+        return fused_attention(q, k, v, causal=True, segment=segment[0] if segment else None)
+
+    compiled = jax.jit(attend).lower(wide, wide, narrow, *segment).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings is not None
+    assert jax.eval_shape(attend, wide, wide, narrow, *segment).shape == (rows, 32, length, 128)
+
+
+# OLMoE's 16 heads of 128 over a packed stream, at both stream lengths
+@pytest.mark.parametrize("length", [2048, 4096])
+def test_fused_attention_over_a_packed_stream_compiles_for_v5e(one_chip, monkeypatch, length):
+    from predictionio_tpu.ops.attention import fused_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    heads = _shape(one_chip, (1, 16, length, 128), jnp.bfloat16)
     compiled = (
-        jax.jit(lambda q, k, v: fused_attention(q, k, v, causal=True))
-        .lower(wide, wide, narrow)
+        jax.jit(lambda q, k, v, segment: fused_attention(q, k, v, causal=True, segment=segment))
+        .lower(heads, heads, heads, _shape(one_chip, (1, length), jnp.int32))
         .compile()
     )
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.output_shardings is not None
-    assert jax.eval_shape(lambda q, k, v: fused_attention(q, k, v, causal=True), wide, wide, narrow).shape == (
-        rows, 32, length, 128,
+
+
+@pytest.mark.parametrize("length", [2048, 4096])
+def test_the_chunked_kda_scan_compiles_for_v5e_at_the_published_heads(one_chip, length):
+    from predictionio_tpu.ops.linear_attention import CHUNK, kda
+
+    wide = _shape(one_chip, (1, length, 32, 128))
+    compiled = (
+        jax.jit(lambda q, k, v, g, b, starts: kda(q, k, v, g, b, starts=starts))
+        .lower(
+            wide, wide, wide, wide, _shape(one_chip, (1, length, 32)),
+            _shape(one_chip, (1, length // CHUNK), jnp.bool_),
+        )
+        .compile()
     )
-
-
-@pytest.mark.parametrize("rows, length", [(32, 64), (1, 4096)])
-def test_the_chunked_kda_scan_compiles_for_v5e_at_the_published_heads(one_chip, rows, length):
-    from predictionio_tpu.ops.linear_attention import kda
-
-    wide = _shape(one_chip, (rows, length, 32, 128))
-    compiled = jax.jit(kda).lower(wide, wide, wide, wide, _shape(one_chip, (rows, length, 32))).compile()
-    # a 2,048- or 4,096-token program's scan keeps its temporaries under a GB
+    # a stream's scan keeps its temporaries under a GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
