@@ -143,6 +143,15 @@ class BaseAlgorithm(Generic[PD, M, Q, P]):
         compiles. Called by the query server at start and after /reload.
         Default: nothing to warm."""
 
+    def batch_limit(self) -> int | None:
+        """The most queries ONE dispatched batch should bring this
+        algorithm, where its programs have a size of their own (a group of
+        device state that holds so many sessions: one query more costs a
+        second group's whole work). The query server closes its batches at
+        the least of its ``max_batch_size`` and its algorithms' limits.
+        Default: none, the server's own."""
+        return None
+
     def register_metrics(self, registry: Any) -> None:
         """Declare this algorithm's own instruments in ``registry`` (an
         ``obs.metrics.MetricsRegistry``): the query server calls it with its

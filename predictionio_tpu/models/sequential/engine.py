@@ -27,8 +27,13 @@ The ``kimi_linear`` scorer is a second backbone behind the same staging and
 launch (``BackboneAlgorithm``): Kimi-Linear-48B-A3B's block
 (``kimi_linear.py``: layers of four kinds, a chunked gated-delta-rule scan
 beside latent attention, a shared expert and the chip's share of 256
-sigmoid-routed experts). Both backbones' weights are drawn from a seed, not
-fitted: fitting a backbone is not this engine's work yet (ROADMAP R7).
+sigmoid-routed experts). The ``sdar`` algorithm is a third, and the first
+that GENERATES: SDAR-30B-A3B-Chat's block (``sdar.py``: grouped queries
+under a block-causal mask, 128 softmax-routed experts) answers with ``num``
+items in order, produced block by block by masked diffusion over a cache of
+keys and values that lives for the batch. Every backbone's weights are drawn
+from a seed, not fitted: fitting a backbone is not this engine's work yet
+(ROADMAP R7).
 """
 
 from __future__ import annotations
@@ -86,11 +91,17 @@ class Query:
 
 @dataclasses.dataclass(frozen=True)
 class ItemScore:
+    """``step``, where an answer was GENERATED (``sdar``): the 0-based
+    denoise pass of the item's block that fixed it; ``score`` is then the
+    log-probability it was fixed at."""
+
     item: str
     score: float
+    step: int | None = None
 
     def to_json_dict(self) -> dict[str, Any]:
-        return {"item": self.item, "score": self.score}
+        out = {"item": self.item, "score": self.score}
+        return out if self.step is None else {**out, "step": self.step}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -877,6 +888,79 @@ class KimiLinearAlgorithmParams(Params):
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class SdarAlgorithmParams(Params):
+    """The published ``config.json`` of JetLM/SDAR-30B-A3B-Chat, key for key
+    (a variant file carries them verbatim), the seed the weights are drawn
+    from, and what the generation needs and ``config.json`` has no key for:
+    ``block_length``, ``denoising_steps`` (denoise passes a block) and
+    ``mask_token_id`` (the vocabulary's last id by default; never an item).
+    ``intermediate_size`` names a dense feed-forward that no layer has
+    (``mlp_only_layers`` []): stated, not built. The keys the program has
+    one answer for are refused at any other value rather than ignored."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple = ()
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    rope_scaling: dict | None = None
+    attention_bias: bool = False
+    sliding_window: int | None = None
+    use_sliding_window: bool = False
+    max_window_layers: int = 48
+    tie_word_embeddings: bool = False
+    vocab_size: int = 151936
+    max_position_embeddings: int = 32768
+    model_type: str = "sdar_moe"
+    block_length: int = 4
+    denoising_steps: int = 4
+    mask_token_id: int | None = None
+    seed: int = 3
+
+    def config(self):
+        from predictionio_tpu.models.sequential.sdar import SdarConfig
+
+        one_answer = {
+            "model_type": "sdar_moe", "hidden_act": "silu", "norm_topk_prob": True,
+            "decoder_sparse_step": 1, "mlp_only_layers": (), "rope_scaling": None,
+            "attention_bias": False, "sliding_window": None, "use_sliding_window": False,
+            "tie_word_embeddings": False,
+        }
+        for key, value in one_answer.items():
+            mine = getattr(self, key)
+            if (tuple(mine) if isinstance(mine, list) else mine) != value:
+                raise ValueError(f"sdar: {key}={mine!r} is not implemented (only {value!r})")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("sdar: the key/value heads do not divide the heads")
+        return SdarConfig(
+            hidden_size=self.hidden_size,
+            moe_intermediate_size=self.moe_intermediate_size,
+            num_hidden_layers=self.num_hidden_layers,
+            num_attention_heads=self.num_attention_heads,
+            num_key_value_heads=self.num_key_value_heads,
+            head_dim=self.head_dim,
+            num_experts=self.num_experts,
+            num_experts_per_tok=self.num_experts_per_tok,
+            vocab_size=self.vocab_size,
+            rms_norm_eps=self.rms_norm_eps,
+            rope_theta=self.rope_theta,
+            block_length=self.block_length,
+            denoising_steps=self.denoising_steps,
+            mask_token_id=self.vocab_size - 1 if self.mask_token_id is None else self.mask_token_id,
+        )
+
+
 class BackboneModel(PersistentModel, SanityCheck):
     """A backbone's weight tree on the device, the item vocabulary (item
     ``i`` is token ``i``) and every user's session tail: the last
@@ -1005,9 +1089,12 @@ def _stream_limits(model: BackboneModel) -> tuple[int, int]:
 
 
 class BackboneAlgorithm(JaxAlgorithm):
-    """Next-item scoring by one prefill through a backbone: what the
-    ``olmoe`` and ``kimi_linear`` algorithms share, which is everything but
-    the backbone's module (``model_class.program()``) and its parameters.
+    """A query answered through a language model's block: what the ``olmoe``,
+    ``kimi_linear`` and ``sdar`` algorithms share, which is everything but
+    the backbone's module (``model_class.program()``), its parameters and
+    HOW a staged batch is answered (``_answer``, the one hook): next-item
+    scoring by one prefill (here; ``olmoe``, ``kimi_linear``) or a generation
+    over the batch's cache (``SdarAlgorithm``).
 
     Train: builds the item vocabulary (item ``i`` is token ``i``) and every
     user's session tail from the ordered events, and DRAWS the weights from
@@ -1022,9 +1109,10 @@ class BackboneAlgorithm(JaxAlgorithm):
     longer; every session from a multiple of ``SESSION_ALIGN``, at most
     ``TOKEN_BUDGET // SESSION_ALIGN`` a stream), and a stream is one program:
     ``[1, T]`` tokens with each token's ``segment`` and ``position``
-    (``_stage``) through the backbone's ``session_vectors``, then
-    ``topk.dot_top_k_async`` over the stream's sessions (their items
-    masked). ONE finalize answers in the queries' order. The stream lengths
+    (``_stage``). ``_answer`` then launches: here the backbone's
+    ``session_vectors`` a stream, then ``topk.dot_top_k_async`` over the
+    stream's sessions (their items masked). ONE finalize answers in the
+    queries' order. The stream lengths
     are a closed set (``config.stream_shapes``: two) and ``warmup_serving``
     compiles all of it. A single query is one session in a stream. What it
     launched is counted in ``instruments``, the algorithm's own until a
@@ -1104,13 +1192,19 @@ class BackboneAlgorithm(JaxAlgorithm):
         return tokens, segment, position, last, mask
 
     def predict_batch_dispatch(self, model: BackboneModel, queries: Sequence[Query]):
-        config = model.config
-        session_vectors = model.program().session_vectors
         t0 = time.perf_counter()
         sessions, streams = self._plan(model, queries)
         with annotate("pio:seq.stage", batch=len(queries), programs=len(streams)):
             staged = [self._stage(model, sessions, stream) for stream in streams]
         self.instruments.on_stage(time.perf_counter() - t0)
+        return self._answer(model, queries, sessions, streams, staged)
+
+    def _answer(self, model: BackboneModel, queries, sessions, streams, staged):
+        """The staged streams launched, and the ``finalize`` that answers the
+        queries in their order: one prefill a stream and one fused top-k
+        over its sessions' vectors."""
+        config = model.config
+        session_vectors = model.program().session_vectors
         n = len(model.item_vocab)
         kk = min(topk.next_pow2(max(1, max(q.num for q in queries))), n)
         launched = []
@@ -1201,6 +1295,177 @@ class KimiLinearAlgorithm(BackboneAlgorithm):
     model_class = KimiLinearModel
 
 
+class SdarModel(BackboneModel):
+    @staticmethod
+    def program():
+        from predictionio_tpu.models.sequential import sdar
+
+        return sdar
+
+    def sanity_check(self) -> None:
+        super().sanity_check()
+        if len(self.item_vocab) > self.config.mask_token_id:
+            raise ValueError(
+                f"{len(self.item_vocab)} items reach the mask's id {self.config.mask_token_id}: "
+                "the mask is no item"
+            )
+
+
+class SdarAlgorithm(BackboneAlgorithm):
+    """``sdar``: SDAR-30B-A3B-Chat's block (``sdar.py``), and the one
+    backbone whose answer is GENERATED: ``num`` items in order, each chosen
+    given the ones before it, by masked diffusion block by block.
+
+    ``_answer``: the staged streams are put into GROUPS, each of at most
+    ``sdar.SESSIONS`` sessions and ``config.cache_tokens`` stream tokens (a
+    batch is one group but for a rare long one). A group is: its state and
+    an empty cache (``sdar.new_state``: the first device state of this
+    engine that outlives a program; its bytes are counted and it is freed
+    when the group's last pass has run); a PREFILL a stream, which writes the
+    stream's keys and values into the cache where the stream lies; then as
+    many ``sdar.denoise_pass`` as the slowest session's schedule has, all
+    sessions in each, nothing fetched between them; ``finalize`` fetches the
+    items, log-probabilities and steps of a group in one transfer."""
+
+    params_class = SdarAlgorithmParams
+    params: SdarAlgorithmParams
+    model_class = SdarModel
+
+    def batch_limit(self) -> int:
+        """The sessions ONE group of passes holds: a batch of more is answered
+        in a second group, by passes of its own that cost what the first's
+        do however few sessions they carry."""
+        return self.model_class.program().SESSIONS
+
+    @staticmethod
+    def _groups(model: BackboneModel, streams) -> list[list[int]]:
+        """The streams' indices, in order, cut where a group would pass the
+        cache's tokens or the passes' sessions."""
+        most, room = model.program().SESSIONS, model.config.cache_tokens
+        groups: list[list[int]] = []
+        tokens = held = 0
+        for i, (length, members) in enumerate(streams):
+            if not groups or tokens + length > room or held + len(members) > most:
+                groups.append([])
+                tokens = held = 0
+            groups[-1].append(i)
+            tokens, held = tokens + length, held + len(members)
+        return groups
+
+    def _launch_group(self, model: BackboneModel, queries, sessions, streams, staged):
+        """One group's programs: ``(members [(query, row of the state)],
+        the answer's handle, the busiest experts' counts, the copies of real
+        rows the routers sent out, the experts the passes' real rows reached
+        (a handle) of those the passes could have)``."""
+        config, program = model.config, model.program()
+        t0 = time.perf_counter()
+        block, slots = config.block_length, config.generated_slots
+        seg = np.full(config.cache_tokens, -1, np.int32)
+        commits = np.full((config.most_passes, config.chunk), -1, np.int32)
+        tokens = np.full((program.SESSIONS, slots), config.mask_token_id, np.int32)
+        step = np.full((program.SESSIONS, slots), -2, np.int32)
+        blocks, reach, start = (np.zeros(program.SESSIONS, np.int32) for _ in range(3))
+        allowed = np.zeros((program.SESSIONS, config.table_rows), bool)
+        members, schedules, offsets, offset = [], [], [], 0
+        for (length, packed), (*_, mask) in zip(streams, staged):
+            offsets.append(offset)
+            for row, (i, at) in enumerate(packed):
+                session, s = sessions[i], len(members)
+                r = len(session) % block
+                num = config.fit(len(session), queries[i].num)
+                # whole blocks are the cache's; the rest opens the first generated block
+                seg[offset + at : offset + at + len(session) - r] = s
+                tokens[s, :r] = session[len(session) - r :]
+                step[s, r : r + num] = -1
+                blocks[s] = -(-(num + r) // block) if num else 0
+                reach[s], start[s] = r + num, len(session) - r
+                allowed[s] = mask[row]
+                members.append((i, s))
+                schedules.append(config.schedule(len(session), num))
+                # the passes whose chunk holds one of this session's clean blocks
+                for t in (t for t, kind in enumerate(schedules[-1]) if kind == "c"):
+                    commits[t, s * block : (s + 1) * block] = s
+            offset += length
+        allowed[:, config.mask_token_id] = False
+        # the host's part ends here: what follows are launches, and a launch
+        # waits in the device's queue behind the other batch's programs
+        self.instruments.stage_seconds.inc(time.perf_counter() - t0)
+        state = program.new_state(
+            model.weights, config, seg, commits, tokens, step, blocks, reach, start, allowed
+        )
+        # the prefill's last layer makes keys and values only: no router there
+        cache, counted, layers = state.pop("cache"), [], config.num_hidden_layers
+        routed = 0
+        for (length, packed), (*stream, _, _), at in zip(streams, staged, offsets):
+            real = sum(len(sessions[i]) for i, _ in packed)
+            with annotate("pio:seq.launch", bucket=length, rows=1, tokens=real):
+                cache, busiest = program.session_vectors(
+                    model.weights, cache, *(topk.upload(a, np.int32) for a in stream),
+                    np.int32(at), config=config,
+                )
+            self.instruments.on_launch(length, 1, real, len(packed))
+            counted.append(busiest)
+            routed += (layers - 1) * real * config.num_experts_per_tok
+        state["cache"] = cache
+        passes = max(map(len, schedules), default=0)
+        kinds = ["d" if any(s[t : t + 1] == "d" for s in schedules) else "c" for t in range(passes)]
+        with annotate(
+            "pio:seq.denoise", batch=len(queries), sessions=len(members),
+            blocks=int(blocks.sum()), passes=passes,
+        ):
+            for _ in range(passes):
+                state = program.denoise_pass(model.weights, state, config=config)
+            answer = program.answer_of(state)
+        counted.append(state["busiest"])
+        # a pass takes a session's whole current block through every layer,
+        # whether it denoises or commits
+        for s, schedule in enumerate(schedules):
+            made = schedule.split("c")
+            for b, denoises in enumerate(made):
+                rows = min(block, int(reach[s]) - b * block)
+                routed += layers * config.num_experts_per_tok * rows * (len(denoises) + (b < len(made) - 1))
+        self.instruments.on_generation(
+            denoise=kinds.count("d"), commit=kinds.count("c"), blocks=int(blocks.sum()),
+            items=int((step == -1).sum()),
+            cache_bytes=config.cache_bytes(offset + passes * config.chunk),
+        )
+        offered = passes * layers * config.num_experts
+        return members, answer, counted, routed, (state["reached"], offered)
+
+    def _answer(self, model: BackboneModel, queries, sessions, streams, staged):
+        config = model.config
+        launched = [
+            self._launch_group(
+                model, queries, sessions, [streams[i] for i in group], [staged[i] for i in group]
+            )
+            for group in self._groups(model, streams)
+        ]
+
+        def finalize() -> list[PredictedResult]:
+            out: list[PredictedResult] = [PredictedResult(())] * len(queries)
+            for members, answer, counted, routed, (reached, offered) in launched:
+                with annotate("pio:fetch.block"):  # the host blocked on the device
+                    packed = np.asarray(answer, np.int32)
+                busiest = sum(int(np.asarray(c, np.int64)) for c in counted)
+                self.instruments.on_expert_load(busiest, routed / config.num_experts)
+                self.instruments.on_copies(routed, 0)
+                self.instruments.on_experts_reached(int(np.asarray(reached, np.int64)), offered)
+                items, steps = packed[:, 0, :], packed[:, 2, :]
+                logp = np.ascontiguousarray(packed[:, 1, :]).view(np.float32)
+                for i, s in members:
+                    # (a session that holds every item leaves no candidate: the answer ends there)
+                    made = np.flatnonzero(steps[s] >= 0)
+                    finite = np.isfinite(logp[s, made])
+                    made = made[: len(made) if finite.all() else int(np.argmin(finite))]
+                    out[i] = PredictedResult(tuple(
+                        ItemScore(model.item_vocab[int(items[s, g])], float(logp[s, g]), int(steps[s, g]))
+                        for g in made
+                    ))
+            return out
+
+        return finalize
+
+
 # ---------------------------------------------------------------------------
 # Serving / factory
 # ---------------------------------------------------------------------------
@@ -1220,6 +1485,7 @@ def engine_factory() -> Engine:
             "attention": AttentionAlgorithm,
             "olmoe": OlmoeAlgorithm,
             "kimi_linear": KimiLinearAlgorithm,
+            "sdar": SdarAlgorithm,
         },
         Serving,
         query_class=Query,

@@ -1,6 +1,6 @@
 """The ``pio_seq_*`` metric families (docs/observability.md): the stream
 trainer's (``SeqInstruments``) and the backbone scorers' (``olmoe``,
-``kimi_linear``: ``BackboneInstruments``, with ``pio_moe_*``).
+``kimi_linear``, ``sdar``: ``BackboneInstruments``, with ``pio_moe_*``).
 
 ``SeqInstruments`` is registered eagerly (AnnInstruments discipline): the family exists at zero
 from process start so scrapers and the docs metrics-contract test see it
@@ -58,7 +58,7 @@ class SeqInstruments:
 
 
 class BackboneInstruments:
-    """What a backbone scorer (``olmoe``, ``kimi_linear``) launched, counted
+    """What a backbone scorer (``olmoe``, ``kimi_linear``, ``sdar``) launched, counted
     where it happens (``engine.BackboneAlgorithm``). The expert counters are
     over the experts the chip HOLDS. An algorithm starts with a registry of its
     own; a query server that serves it hands over its registry through
@@ -98,6 +98,25 @@ class BackboneInstruments:
         self.batches = r.counter(
             "pio_seq_batches_total", "batches the session scorer staged"
         )
+        self.passes = r.counter(
+            "pio_seq_passes_total",
+            "passes of a generating backbone over a batch's sessions and its "
+            "cache: kind=denoise fixed a position of some session's block, "
+            "kind=commit only appended clean blocks' keys and values",
+            labelnames=("kind",),
+        )
+        self.blocks = r.counter(
+            "pio_seq_blocks_total", "blocks of items generated, summed over sessions"
+        )
+        self.generated_items = r.counter(
+            "pio_seq_generated_items_total", "items generated into answers"
+        )
+        self.cache_bytes = r.counter(
+            "pio_seq_cache_bytes_total",
+            "bytes of keys and values a batch's cache held for its sessions "
+            "(its streams as they lie and the generated blocks), summed over "
+            "batches",
+        )
         self.expert_tokens_max = r.counter(
             "pio_moe_expert_tokens_max_total",
             "copies of REAL tokens (no padding) the busiest expert got, "
@@ -108,7 +127,16 @@ class BackboneInstruments:
             "copies of real tokens an even split would give each expert, "
             "summed over layers and programs",
         )
-
+        self.experts_reached = r.counter(
+            "pio_moe_experts_reached_total",
+            "experts that got a copy of a REAL row, summed over the layers of "
+            "a generating backbone's passes: whose matrices a pass had to read",
+        )
+        self.experts_offered = r.counter(
+            "pio_moe_experts_offered_total",
+            "experts the same layers and passes hold (over it "
+            "pio_moe_experts_reached_total is the share reached)",
+        )
         self.copies = r.counter(
             "pio_moe_copies_total",
             "copies of REAL tokens the routers sent out, by whether the "
@@ -117,9 +145,24 @@ class BackboneInstruments:
             labelnames=("where",),
         )
 
+    def on_generation(
+        self, denoise: int, commit: int, blocks: int, items: int, cache_bytes: int
+    ) -> None:
+        """One group of sessions answered by generation (``sdar``)."""
+        self.passes.inc(float(denoise), kind="denoise")
+        self.passes.inc(float(commit), kind="commit")
+        self.blocks.inc(float(blocks))
+        self.generated_items.inc(float(items))
+        self.cache_bytes.inc(float(cache_bytes))
+
     def on_copies(self, held: int, absent: int) -> None:
         self.copies.inc(float(held), where="held")
         self.copies.inc(float(absent), where="absent")
+
+    def on_experts_reached(self, reached: int, offered: int) -> None:
+        """One group's passes, counted with its answer's fetch."""
+        self.experts_reached.inc(float(reached))
+        self.experts_offered.inc(float(offered))
 
     def on_stage(self, seconds: float) -> None:
         self.stage_seconds.inc(seconds)

@@ -53,17 +53,28 @@ def attention_reference(
     causal: bool = False,
     q_offset: int = 0,
     k_offset: int = 0,
-    segment: jnp.ndarray | None = None,  # [B, L] int32, Lq == Lk == L
+    segment=None,  # [B, L] int32 (Lq == Lk == L), or ([B, Lq], [B, Lk])
+    block: int | None = None,
 ) -> jnp.ndarray:
     """``segment``, where given, packs several sequences into a row: a key
     is seen where it has the query's id and the id is not negative (a
     negative id is padding: it sees nothing, is seen by none and comes out
-    as 0)."""
+    as 0); a pair gives the queries' ids and the keys' apart, for queries of
+    one length against keys of another. ``k`` and ``v`` may carry FEWER
+    heads than ``q`` (grouped queries): query head ``h`` reads key/value
+    head ``h // (H / Hkv)``. ``block`` makes ``causal`` block-causal: a key
+    is seen where ``key index // block <= query index // block`` (two-way
+    inside a block of ``block``, causal across blocks)."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         qi = jnp.arange(q.shape[2])[:, None] + q_offset
         ki = jnp.arange(k.shape[2])[None, :] + k_offset
+        if block:
+            qi, ki = qi // block, ki // block
         scores = jnp.where(qi >= ki, scores, -jnp.inf)
     if segment is not None:
         seg_q, seg_k = _segment_ids(segment)
@@ -271,10 +282,37 @@ def ulysses_attention(
 def _segment_ids(segment):
     """``(ids as the queries carry them [B, L, 1], as the keys do [B, 1, L])``:
     padding is -1 on one side and -2 on the other, so that equality alone
-    is the mask."""
-    segment = segment.astype(jnp.int32)
-    pad = segment < 0
-    return jnp.where(pad, -1, segment)[:, :, None], jnp.where(pad, -2, segment)[:, None, :]
+    is the mask. ``segment`` is one array for both, or the pair."""
+    if not isinstance(segment, tuple):
+        segment = segment.astype(jnp.int32)
+        pad = segment < 0
+        return jnp.where(pad, -1, segment)[:, :, None], jnp.where(pad, -2, segment)[:, None, :]
+    of_q, of_k = (ids.astype(jnp.int32) for ids in segment)
+    return jnp.where(of_q < 0, -1, of_q)[:, :, None], jnp.where(of_k < 0, -2, of_k)[:, None, :]
+
+
+def _needed_blocks(segment, block_q: int, block_k: int):
+    """For a PAIR of ids (queries [B, Lq], keys [B, Lk]; no order among
+    them is known): ``(needed, fetch)``, both [B, Lq / block_q, Lk / block_k]
+    int32. ``needed`` is 0 where no query of the block of queries can see a
+    key of the block of keys (the ranges of their ids do not meet: exact
+    where ids lie sorted, never too few elsewhere, and the mask itself is
+    applied inside). ``fetch`` is the block of keys a step should hold: its
+    own where needed, else the nearest needed one before it (the first
+    needed one, for the steps in front of it), so that a step that computes
+    nothing moves nothing either."""
+    of_q, of_k = segment
+    rows = of_q.shape[0]
+    top = jnp.iinfo(jnp.int32).max
+    of_q = of_q.astype(jnp.int32).reshape(rows, -1, block_q)
+    of_k = of_k.astype(jnp.int32).reshape(rows, -1, block_k)
+    q_low, q_high = jnp.min(jnp.where(of_q < 0, top, of_q), axis=2), jnp.max(of_q, axis=2)
+    k_low, k_high = jnp.min(jnp.where(of_k < 0, top, of_k), axis=2), jnp.max(of_k, axis=2)
+    needed = (q_low[:, :, None] <= k_high[:, None, :]) & (k_low[:, None, :] <= q_high[:, :, None])
+    at = jnp.arange(of_k.shape[1], dtype=jnp.int32)
+    before = lax.cummax(jnp.where(needed, at, -1), axis=2)
+    first = jnp.argmax(needed, axis=2).astype(jnp.int32)[:, :, None]
+    return needed.astype(jnp.int32), jnp.where(before < 0, first, before)
 
 
 def _first_keys(segment, block_q: int):
@@ -296,24 +334,32 @@ def _first_keys(segment, block_q: int):
 OFF_CHIP_BLOCK = 128
 
 
-def _segmented_attention_blocked(q, k, v, causal: bool, segment, block: int):
+def _segmented_attention_blocked(q, k, v, causal: bool, segment, tile: int, block=None):
     """Attention over packed rows OFF the chip, by the flash kernel's own
-    schedule and arithmetic in ``jax.numpy``: blocks of ``block`` queries
-    against blocks of keys under an online softmax, float32 accumulation of
-    the operands as they come, and a block of keys that ends before the
-    first key any query of the block sees (in any row), or lies above the
-    diagonal, is not computed. The dense ``attention_reference`` does
-    ``L * L`` work a head whatever the segments are: at a stream of 2,048
-    tokens that made the CPU rehearsal of a serving cell thirty times as
-    slow as its sessions are long."""
+    schedule and arithmetic in ``jax.numpy``: tiles of ``tile`` queries
+    against tiles of keys under an online softmax, float32 accumulation of
+    the operands as they come, and a tile of keys that ends before the
+    first key any query of the tile sees (in any row), or lies above the
+    diagonal (or, for a pair of ids, whose ids no query of the tile
+    carries: ``_needed_blocks``), is not computed. The dense
+    ``attention_reference`` does ``L * L`` work a head whatever the segments
+    are: at a stream of 2,048 tokens that made the CPU rehearsal of a
+    serving cell thirty times as slow as its sessions are long."""
     rows, heads, length, _ = q.shape
-    n, scale = length // block, 1.0 / math.sqrt(q.shape[-1])
+    group = heads // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    n, n_k, scale = length // tile, k.shape[2] // tile, 1.0 / math.sqrt(q.shape[-1])
     seg_q, seg_k = _segment_ids(segment)
-    first = jnp.min(_first_keys(segment, block).reshape(rows, n), axis=0)
-    within = jnp.arange(block)
+    if isinstance(segment, tuple):
+        wanted = jnp.any(_needed_blocks(segment, tile, tile)[0] > 0, axis=0)  # [n, n_k]
+    else:
+        first = jnp.min(_first_keys(segment, tile).reshape(rows, n), axis=0)
+        wanted = (jnp.arange(n_k)[None, :] + 1) * tile > first[:, None]
+    within = jnp.arange(tile)
 
     def block_of(x, i, axis):
-        return lax.dynamic_slice_in_dim(x, i * block, block, axis)
+        return lax.dynamic_slice_in_dim(x, i * tile, tile, axis)
 
     def queries(i):
         q_i, ids_i = block_of(q, i, 2).astype(jnp.float32), block_of(seg_q, i, 1)
@@ -322,9 +368,10 @@ def _segmented_attention_blocked(q, k, v, causal: bool, segment, block: int):
             def attend(carry):
                 acc, top, total = carry
                 scores = jnp.einsum("bhqd,bhkd->bhqk", q_i, block_of(k, j, 2).astype(jnp.float32)) * scale
-                seen = ids_i == block_of(seg_k, j, 2)  # [B, block, block]
+                seen = ids_i == block_of(seg_k, j, 2)  # [B, tile, tile]
                 if causal:
-                    seen = seen & (i * block + within[:, None] >= j * block + within[None, :])
+                    qi, ki = i * tile + within[:, None], j * tile + within[None, :]
+                    seen = seen & ((qi // block >= ki // block) if block else (qi >= ki))
                 scores = jnp.where(seen[:, None], scores, -jnp.inf)
                 new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
                 safe = jnp.where(jnp.isneginf(new_top), 0.0, new_top)
@@ -335,15 +382,15 @@ def _segmented_attention_blocked(q, k, v, causal: bool, segment, block: int):
                 )
                 return acc, new_top, total * shrink + jnp.sum(p, axis=-1)
 
-            needed = (j + 1) * block > first[i]
+            needed = wanted[i, j]
             return lax.cond(needed & (j <= i) if causal else needed, attend, lambda c: c, carry)
 
-        acc = jnp.zeros((rows, heads, block, v.shape[-1]), jnp.float32)
-        total = jnp.zeros((rows, heads, block), jnp.float32)
-        acc, _, total = lax.fori_loop(0, n, keys, (acc, total - jnp.inf, total))
+        acc = jnp.zeros((rows, heads, tile, v.shape[-1]), jnp.float32)
+        total = jnp.zeros((rows, heads, tile), jnp.float32)
+        acc, _, total = lax.fori_loop(0, n_k, keys, (acc, total - jnp.inf, total))
         return acc / jnp.where(total == 0.0, 1.0, total)[..., None]
 
-    out = lax.map(queries, jnp.arange(n))  # [n, B, H, block, Dv]
+    out = lax.map(queries, jnp.arange(n))  # [n, B, H, tile, Dv]
     return jnp.moveaxis(out, 0, 2).reshape(rows, heads, length, v.shape[-1]).astype(q.dtype)
 
 
@@ -373,9 +420,20 @@ def _best_block(L: int) -> int:
     return L
 
 
+# The most a tile of queries and a tile of keys hold under a PAIR of ids
+# (``fused_attention(segment=(ids of queries, ids of keys))``): there a tile
+# of keys costs a tile of queries its whole product however few of its rows
+# carry the keys' ids, so fewer rows a tile waste less and skip more. A
+# denoise pass of the sequential engine's ``sdar`` (1,024 query rows of 32
+# sessions against 32,768 slots, six layers, 4 heads; the whole pass on the
+# chip, PR 34): 16.0 ms at (1024, 1024), **14.8** at (256, 1024), 14.9 at
+# (128, 1024), 15.2 at (512, 1024), 15.1 at (256, 512), 16.4 at (1024, 512).
+PAIR_TILES = (256, 1024)
+
+
 def _flash_attention_pallas(
     q, k, v, causal: bool, interpret: bool, block_q: int = 1024, block_k: int = 1024,
-    segment=None,
+    segment=None, block=None,
 ):
     """Tiled flash-attention pallas kernel: grid (B*H, Lq/bq, Lk/bk), online
     softmax carried across the (sequential, innermost) K-block grid axis in
@@ -388,7 +446,15 @@ def _flash_attention_pallas(
     ``segment`` [B, L] (``attention_reference``'s): the ids ride in as two
     more blocked inputs and join the mask, and a K block that ends before
     the first key any query of the Q block sees (``_first_keys``, prefetched
-    as scalars) is skipped as the blocks above the diagonal are."""
+    as scalars) is skipped as the blocks above the diagonal are. A PAIR of
+    ids (queries of one length against keys of another, not causal) brings
+    ``_needed_blocks`` instead: a K block no query of the Q block can see is
+    neither computed nor fetched (its step holds the nearest needed block).
+
+    ``k`` and ``v`` of fewer heads than ``q`` (grouped queries) are read
+    where they lie: the index map sends query head ``h`` to key/value head
+    ``h // group``. ``block`` turns the causal mask block-causal; the tiles
+    are whole blocks, so the diagonal's skipping stands."""
     import math as _math
 
     from jax.experimental import pallas as pl
@@ -396,21 +462,28 @@ def _flash_attention_pallas(
 
     B, H, Lq, D = q.shape
     Lk, Dv = k.shape[2], v.shape[3]
+    group = H // k.shape[1]
     bq, bk = min(block_q, Lq), min(block_k, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, "flash path requires divisible blocks"
+    assert not block or bq % block == 0, "a tile of queries holds whole blocks"
     nq, nk = Lq // bq, Lk // bk
     scale = 1.0 / _math.sqrt(D)
+    paired = isinstance(segment, tuple)
 
     def kernel(*refs):
         if segment is None:
             q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        elif paired:
+            needed_ref, _, q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref, l_ref = refs
         else:
             first_ref, q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref, l_ref = refs
         # program ids hoisted out of the pl.when bodies: the interpret-mode
         # lowering can't evaluate program_id inside a nested cond
         qi_blk = pl.program_id(1)
         kj = pl.program_id(2)
-        if segment is not None:
+        if paired:
+            is_needed = needed_ref[((pl.program_id(0) // H) * nq + qi_blk) * nk + kj] > 0
+        elif segment is not None:
             first_key = first_ref[(pl.program_id(0) // H) * nq + qi_blk]
 
         @pl.when(kj == 0)
@@ -435,6 +508,9 @@ def _flash_attention_pallas(
             if causal:
                 qi = qi_blk * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
                 ki = kj * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+                if block:
+                    # the last position of the query's block: every key up to it is seen
+                    qi = qi // block * block + (block - 1)
                 s = jnp.where(qi >= ki, s, -jnp.inf)
             if segment is not None:
                 s = jnp.where(sq_ref[0] == sk_ref[0], s, -jnp.inf)
@@ -463,6 +539,8 @@ def _flash_attention_pallas(
             @pl.when(needed)
             def _():
                 compute()
+        elif paired:
+            pl.when(is_needed)(compute)
         elif segment is not None:
             pl.when((kj + 1) * bk > first_key)(compute)
         else:
@@ -475,16 +553,26 @@ def _flash_attention_pallas(
             o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
     qr = q.reshape(B * H, Lq, D)
-    kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, Dv)
-    # (the index maps take the prefetched scalars of a packed row behind the
-    # grid's indices and do not look at them)
+    kr = k.reshape(B * H // group, Lk, D)
+    vr = v.reshape(B * H // group, Lk, Dv)
+
+    def head_of(b):
+        # (row b of `qr` is batch b // H, head b % H: its keys are row
+        # b // group of `kr`)
+        return b if group == 1 else b // group
+
+    def keys_at(b, i, j, *scalars):
+        # a pair's steps hold the block `_needed_blocks` says; the others'
+        # index maps take the prefetched scalars of a packed row behind the
+        # grid's indices and do not look at them
+        return scalars[1][((b // H) * nq + i) * nk + j] if paired else j
+
     grid = dict(
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j, *s: (head_of(b), keys_at(b, i, j, *s), 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j, *s: (head_of(b), keys_at(b, i, j, *s), 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j, *_: (b, i, 0)),
         scratch_shapes=[
@@ -497,10 +585,14 @@ def _flash_attention_pallas(
     if segment is not None:
         grid["in_specs"] += [
             pl.BlockSpec((1, bq, 1), lambda b, i, j, *_: (b // H, i, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, i, j, *_: (b // H, 0, j)),
+            pl.BlockSpec((1, 1, bk), lambda b, i, j, *s: (b // H, 0, keys_at(b, i, j, *s))),
         ]
-        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid))
-        operands = (_first_keys(segment, bq), *operands, *_segment_ids(segment))
+        scalars = (
+            tuple(a.reshape(-1) for a in _needed_blocks(segment, bq, bk))
+            if paired else (_first_keys(segment, bq),)
+        )
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(scalars), **grid))
+        operands = (*scalars, *operands, *_segment_ids(segment))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
@@ -510,11 +602,12 @@ def _flash_attention_pallas(
     return out.reshape(B, H, Lq, Dv)
 
 
-def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None):
+def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None, block=None):
     from jax.experimental import pallas as pl
 
     B, H, Lq, D = q.shape
     Lk, Dv = k.shape[2], v.shape[3]
+    group = H // k.shape[1]
 
     def kernel(q_ref, k_ref, v_ref, *rest):
         o_ref = rest[-1]
@@ -534,6 +627,8 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None
         if causal:
             qi = lax.broadcasted_iota(jnp.int32, (Lq, Lk), 0)
             ki = lax.broadcasted_iota(jnp.int32, (Lq, Lk), 1)
+            if block:
+                qi = qi // block * block + (block - 1)
             scores = jnp.where(qi >= ki, scores, -jnp.inf)
         if segment is not None:
             sq_ref, sk_ref = rest[:2]
@@ -555,12 +650,16 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None
 
     grid = (B * H,)
     qr = q.reshape(B * H, Lq, D)
-    kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, Dv)
+    kr = k.reshape(B * H // group, Lk, D)
+    vr = v.reshape(B * H // group, Lk, Dv)
+
+    def head_of(i):
+        return i if group == 1 else i // group
+
     operands, in_specs = [qr, kr, vr], [
         pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, Lk, D), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, Lk, Dv), lambda i: (i, 0, 0)),
+        pl.BlockSpec((1, Lk, D), lambda i: (head_of(i), 0, 0)),
+        pl.BlockSpec((1, Lk, Dv), lambda i: (head_of(i), 0, 0)),
     ]
     if segment is not None:
         operands += _segment_ids(segment)
@@ -585,13 +684,17 @@ def fused_attention(
     v: jnp.ndarray,
     causal: bool = False,
     force_pallas: bool = False,
-    segment: jnp.ndarray | None = None,
+    segment=None,
+    block: int | None = None,
 ) -> jnp.ndarray:
-    """Single-device attention over ``q`` [B, H, Lq, D], ``k`` [B, H, Lk, D]
-    and ``v`` [B, H, Lk, Dv]: queries and keys share a head width, the
+    """Single-device attention over ``q`` [B, H, Lq, D], ``k`` [B, Hkv, Lk, D]
+    and ``v`` [B, Hkv, Lk, Dv]: queries and keys share a head width, the
     values may have another (latent attention expanded for a prefill: 192
     and 128; ``v`` is never padded to ``D``), the output is [B, H, Lq, Dv]
-    and the scores are scaled by ``D ** -0.5``. On TPU: pallas kernel — the single-block
+    and the scores are scaled by ``D ** -0.5``. ``Hkv`` is ``H`` or divides
+    it (GROUPED queries: query head ``h`` reads key/value head
+    ``h // (H / Hkv)``; the keys and values are read where they lie, never
+    repeated in memory). On TPU: pallas kernel — the single-block
     variant when the whole [Lq, Lk] score tile fits VMEM comfortably, the
     tiled flash variant for long sequences. Elsewhere: the jnp reference
     path (``force_pallas`` runs the kernels in interpret mode, which is
@@ -604,16 +707,33 @@ def fused_attention(
     ``causal``, does not follow it). A segment's positions are contiguous
     and its id its own; a negative id is padding, which sees nothing, is
     seen by none and comes out as 0. Without it every path is what it was.
+    A PAIR ``(ids of the queries [B, Lq], ids of the keys [B, Lk])`` sets
+    queries of one length against keys of another (a batch's block
+    positions against its cached keys): a key is seen where it carries the
+    query's id, nothing is known of their order (``causal`` is refused),
+    and blocks of keys whose ids no query of a block carries cost nothing.
+
+    ``block`` (with ``causal``) makes the mask BLOCK-causal: a key is seen
+    where ``key index // block <= query index // block``, two-way inside a
+    block of ``block`` positions and causal across blocks. Under ``segment``
+    a segment starts on a multiple of ``block``, so that the index's blocks
+    are the segment's own.
 
     Limit of the kernel path: a sequence whose score tile is past the
     single-block budget (Lq * Lk >= 2**20, i.e. L >= 1024 square) must be
     a multiple of 256 in both lengths, or the call raises ValueError."""
     on_tpu = jax.default_backend() == "tpu"
     Lq, Lk = q.shape[2], k.shape[2]
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"fused_attention: {q.shape[1]} query heads over {k.shape[1]} key/value heads")
+    if block and not causal:
+        raise ValueError("fused_attention: `block` shapes the causal mask; give `causal=True`")
+    if causal and (isinstance(segment, tuple) or (segment is not None and Lq != Lk)):
+        raise ValueError("fused_attention: ids given apart carry no order: `causal` is refused")
     if not (on_tpu or force_pallas):
-        if segment is not None and Lq % OFF_CHIP_BLOCK == 0:
-            return _segmented_attention_blocked(q, k, v, causal, segment, OFF_CHIP_BLOCK)
-        return attention_reference(q, k, v, causal=causal, segment=segment)
+        if segment is not None and Lq % OFF_CHIP_BLOCK == 0 and Lk % OFF_CHIP_BLOCK == 0:
+            return _segmented_attention_blocked(q, k, v, causal, segment, OFF_CHIP_BLOCK, block)
+        return attention_reference(q, k, v, causal=causal, segment=segment, block=block)
     # single-block kernel holds the [Lq, Lk] f32 score tile in VMEM
     # (strict <: a 4MiB tile — L=1024 square — already takes the flash
     # path, which the interpret-mode routing test pins)
@@ -630,10 +750,15 @@ def fused_attention(
         )
     interpret = not on_tpu
     if single_block:
-        return _fused_attention_pallas(q, k, v, causal, interpret=interpret, segment=segment)
+        return _fused_attention_pallas(
+            q, k, v, causal, interpret=interpret, segment=segment, block=block
+        )
     # block sizes tuned per-shape (see _best_block): the largest
     # dividing tile wins on the MXU at every measured length
+    block_q, block_k = _best_block(Lq), _best_block(Lk)
+    if isinstance(segment, tuple):
+        block_q, block_k = min(block_q, PAIR_TILES[0]), min(block_k, PAIR_TILES[1])
     return _flash_attention_pallas(
         q, k, v, causal, interpret=interpret,
-        block_q=_best_block(Lq), block_k=_best_block(Lk), segment=segment,
+        block_q=block_q, block_k=block_k, segment=segment, block=block,
     )

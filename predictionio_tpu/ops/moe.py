@@ -3,7 +3,7 @@
 A mixture-of-experts feed-forward: the router scores every token against
 every expert in float32 and a token goes to its ``k`` best, in one of two
 published forms: :func:`route` (softmax over the experts, the chosen weights
-as they are) and :func:`route_sigmoid` (sigmoid scores, chosen by score plus
+as they are or, ``renormalise``, over their sum) and :func:`route_sigmoid` (sigmoid scores, chosen by score plus
 a selection bias, weighed by the scores over their sum times a scale). Each
 expert is a gated MLP ``down(silu(gate(x)) * up(x))`` (:func:`gated_mlp`,
 which is also a shared expert and a dense feed-forward). The experts' work is
@@ -43,19 +43,31 @@ __all__ = [
 # that an output tile is written once) was the best of twelve at [16 k and
 # 131 k, 2048] x [64, 2048, 1024] and at [.., 1024] x [64, 1024, 2048]
 TILING = (256, 2048, 1024)
+# A tile of rows is multiplied whole once for every group with a row in it,
+# so groups of FEW rows want a shorter one, until the steps it adds cost
+# more. Under this many rows a live group the tile is halved. From the chip
+# (PERF.md, PR 34 and PR 31): at 8 rows a group (a denoise pass of ``sdar``,
+# [1024, 2048] x 128 live groups) a pass took 16.0 ms at 256 rows, **15.3**
+# at 128, 15.5 at 64, 16.0 at 32; at 64 rows a group no tile won.
+SHORT_GROUP = 64
 
 
-def route(x, router_w, k: int):
+def route(x, router_w, k: int, renormalise: bool = False):
     """``(weights [T, k] float32, experts [T, k] int32)`` of tokens ``x``
     [T, hidden]: softmax over ALL experts in float32 (the product at
     ``highest`` too: a choice between the k-th and the (k+1)-th expert must
-    not hang on a bf16 rounding), then the top k, not renormalised."""
+    not hang on a bf16 rounding), then the top k. Their weights are the
+    softmax's as they are (OLMoE's ``norm_topk_prob`` false) or,
+    ``renormalise``, divided by their sum (Qwen3-MoE's and SDAR's true)."""
     logits = jnp.dot(
         x.astype(jnp.float32),
         router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    return lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts
 
 
 def route_sigmoid(x, router_w, bias, k: int, scale: float):
@@ -85,7 +97,7 @@ def expert_load(experts, n_experts: int, counted=None):
     return jnp.sum(hits, axis=(0, 1), dtype=jnp.int32)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype, live=None):
     """``lhs[offsets[g]:offsets[g+1]] @ rhs[g]`` for every group ``g``:
     ``lhs`` [M, K] sorted by group, ``rhs`` [G, K, N], ``group_sizes`` [G]
     int32 summing to M (a multiple of 8); float32 accumulation, rounded to
@@ -100,19 +112,33 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
     (sandbox, PR 26). So there XLA's own ``ragged_dot`` stands in, as
     ``ops/attention.fused_attention`` takes its jnp path off the chip; the
     tests run the kernel interpreted against it, alone and through a whole
-    program."""
+    program.
+
+    ``live`` is how many of ``rhs``'s groups ``group_sizes`` can fill, where
+    that is not all of them (every layer's experts lie stacked and one
+    layer's are live): the kernel takes its tile of rows from the rows a
+    live group has (``SHORT_GROUP``)."""
     if jax.default_backend() != "tpu":
         out = lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
         return out.astype(out_dtype)
-    return grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype)
+    return grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, live=live)
 
 
-def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
+def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = False, live=None):
     """The kernel itself (``interpret`` is how a test runs it off the chip)."""
     rows, contraction, columns = TILING
+    if lhs.shape[0] < SHORT_GROUP * (live or rhs.shape[0]):
+        rows //= 2
     # a contraction tile past the operand's own is masked on every step: it
-    # took 1.5 times as long at [.., 1024] x [64, 1024, 2048]
-    tiling = (math.gcd(rows, lhs.shape[0]), min(contraction, lhs.shape[1]), columns)
+    # took 1.5 times as long at [.., 1024] x [64, 1024, 2048]; a tile of
+    # columns past the matrices' own whole lanes (experts 768 wide) is
+    # multiplied and thrown away
+    width = rhs.shape[2]
+    tiling = (
+        math.gcd(rows, lhs.shape[0]),
+        min(contraction, lhs.shape[1]),
+        columns if width % 128 else min(columns, width),
+    )
     return gmm(
         lhs, rhs, group_sizes, preferred_element_type=out_dtype, tiling=tiling, interpret=interpret
     )
@@ -170,9 +196,9 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
     with jax.named_scope("gmm"):
         # gate and up leave the kernel in the operands' type: `down` takes
         # them in it anyway, and float32 would double what the gate moves
-        g = grouped_matmul(xs, gate, sizes, gate.dtype).astype(jnp.float32)
-        u = grouped_matmul(xs, up, sizes, gate.dtype).astype(jnp.float32)
-        out = grouped_matmul((jax.nn.silu(g) * u).astype(down.dtype), down, sizes, jnp.float32)
+        g = grouped_matmul(xs, gate, sizes, gate.dtype, live=n_experts).astype(jnp.float32)
+        u = grouped_matmul(xs, up, sizes, gate.dtype, live=n_experts).astype(jnp.float32)
+        out = grouped_matmul((jax.nn.silu(g) * u).astype(down.dtype), down, sizes, jnp.float32, live=n_experts)
     with jax.named_scope("combine"):
         # where each copy went: the inverse of the sort, by one scatter of
         # T*k integers, then a gather of rows (no scatter-add of rows)

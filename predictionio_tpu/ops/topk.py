@@ -185,14 +185,19 @@ def fetch_topk(handle) -> tuple[np.ndarray, np.ndarray]:
 # ---------------------------------------------------------------------------
 
 
-def select_top_k(scores, k: int, mask=None, weights=None):
+def select_top_k(scores, k: int, mask=None, weights=None, log_sum_exp: bool = False):
     """The one ending, traced inside a jitted program: ``scores`` [B, n]
     times ``weights`` ([n] per-item multiplier, or None), entries whose
     ``mask`` ([n] or [B, n] bool, or None) is False sent to -inf, the k
     best of each row packed as [B, 2, k]. The scopes name each HLO
     operation's op_name (``jit(_serve_by_index_batch)/topk/...``), so a
     trace can follow the product and the selection from build to build; a
-    front puts its own product under ``score`` as well."""
+    front puts its own product under ``score`` as well.
+
+    ``log_sum_exp`` hands back ``(packed, [B] float32)``: beside the k best
+    the log of the sum of ``exp(score)`` over the row's candidates that the
+    mask leaves, which turns a best score into its log-probability among
+    them (a generated item's confidence)."""
     with jax.named_scope("score"):
         if weights is not None:
             scores = scores * weights[None, :]
@@ -201,7 +206,10 @@ def select_top_k(scores, k: int, mask=None, weights=None):
                 mask if mask.ndim == 2 else mask[None, :], scores, -jnp.inf
             )
     with jax.named_scope("topk"):
-        return pack_batch(*lax.top_k(scores, k))
+        packed = pack_batch(*lax.top_k(scores, k))
+        if log_sum_exp:
+            return packed, jax.nn.logsumexp(scores.astype(jnp.float32), axis=-1)
+        return packed
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

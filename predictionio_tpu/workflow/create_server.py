@@ -2999,6 +2999,11 @@ class QueryServer:
         # this server's registry before it takes traffic
         for algo in algorithms:
             algo.register_metrics(self.metrics)
+        # a batch is closed at the least of the operator's limit and the
+        # algorithms' own (`BaseAlgorithm.batch_limit`; the lane warmed last
+        # decides: a reload brings new models, seldom another algorithm)
+        limits = [n for algo in algorithms if (n := algo.batch_limit()) is not None]
+        self._batcher.max_batch = max(1, min([self.config.max_batch_size, *limits]))
         # a warmup failure is not swallowed: a program the device's
         # compiler refuses would otherwise be paid for, or thrown, on the
         # first request. At startup it fails the start; /reload and the

@@ -233,6 +233,164 @@ class TestSegmentedAttention:
         assert first.tolist() == [[0, 0, 448, 704], [0, 0, 0, 1024]]
 
 
+def grouped_qkv(B, H, Hkv, Lq, Lk, D=8, seed=0):
+    rng = np.random.default_rng(seed)
+    return (
+        jnp.asarray(rng.normal(size=(B, H, Lq, D)), jnp.float32),
+        jnp.asarray(rng.normal(size=(B, Hkv, Lk, D)), jnp.float32),
+        jnp.asarray(rng.normal(size=(B, Hkv, Lk, D)), jnp.float32),
+    )
+
+
+def cached_ids(Lq, Lk, sessions, seed=0):
+    """Queries of ``sessions`` sessions, ``Lq / sessions`` each in order (the
+    last session absent: -1), against keys laid out as a batch's cache is:
+    each session's keys a contiguous run from a multiple of 16, in the
+    sessions' order, padding (-1) between them and behind."""
+    rng = np.random.default_rng(seed)
+    of_q = np.repeat(np.arange(sessions), Lq // sessions).astype(np.int32)
+    of_q[-(Lq // sessions) :] = -1
+    lengths = rng.integers(1, Lk // sessions - 16, sessions - 1)
+    return of_q[None], segments(Lk, lengths, 16)[None]
+
+
+class TestGroupedBlockCausalAndCachedKeys:
+    """What a block-diffusion backbone asks of the kernels (``models/sequential``'s
+    ``sdar``): grouped queries, a block-causal mask, and a batch's block
+    positions against its cached keys (queries of one length, keys of
+    another, ids given apart)."""
+
+    def test_the_reference_reads_head_h_from_key_value_head_h_over_the_group(self):
+        q, k, v = grouped_qkv(1, 8, 2, 32, 32, seed=1)
+        got = np.asarray(attention_reference(q, k, v, causal=True, block=4))
+        for h in range(8):
+            alone = attention_reference(
+                q[:, h : h + 1], k[:, h // 4 : h // 4 + 1], v[:, h // 4 : h // 4 + 1], causal=True, block=4
+            )
+            np.testing.assert_allclose(got[:, h], np.asarray(alone)[:, 0], atol=1e-6)
+        # planted: h % Hkv in place of h // group reads another head
+        wrong = attention_reference(q[:, 1:2], k[:, 1:2], v[:, 1:2], causal=True, block=4)
+        assert np.abs(got[:, 1] - np.asarray(wrong)[:, 0]).max() > 0.1
+
+    def test_block_causal_is_two_way_inside_a_block_and_causal_across(self):
+        q, k, v = grouped_qkv(1, 2, 2, 16, 16, seed=2)
+        got = np.asarray(attention_reference(q, k, v, causal=True, block=4))
+        # position 5 (block 1) sees keys 0..7 and no more
+        for at, last in ((5, 8), (0, 4), (15, 16), (8, 12)):
+            alone = attention_reference(q[:, :, at : at + 1], k[:, :, :last], v[:, :, :last])
+            np.testing.assert_allclose(got[:, :, at], np.asarray(alone)[:, :, 0], atol=1e-6)
+        token_causal = np.asarray(attention_reference(q, k, v, causal=True))
+        assert np.abs(got - token_causal).max() > 0.05  # planted: a token-causal mask inside the block
+        np.testing.assert_allclose(got[:, :, 3::4], token_causal[:, :, 3::4], atol=1e-6)  # a block's last row
+
+    @pytest.mark.parametrize("path", ["off the chip", "single block", "flash 256", "flash 512", "routed"])
+    def test_grouped_block_causal_packed_rows_match_the_reference(self, path):
+        from predictionio_tpu.ops.attention import _flash_attention_pallas, _fused_attention_pallas
+
+        L = 256 if path == "single block" else 1024
+        q, k, v = grouped_qkv(2, 8, 2, L, L, seed=3)
+        ids = jnp.asarray(np.stack([
+            segments(L, (37, 64, 1, 50) if L == 256 else (300, 64, 1, 200, 130), 64),
+            segments(L, (130, 60) if L == 256 else (513, 255), 64),
+        ]))
+        want = attention_reference(q, k, v, causal=True, segment=ids, block=4)
+        if path == "off the chip":
+            got, atol = fused_attention(q, k, v, causal=True, segment=ids, block=4), 1e-5
+        elif path == "routed":
+            got, atol = fused_attention(q, k, v, causal=True, segment=ids, block=4, force_pallas=True), 2e-2
+        elif path == "single block":
+            got, atol = _fused_attention_pallas(q, k, v, True, interpret=True, segment=ids, block=4), 2e-2
+        else:
+            tile = int(path.split()[1])
+            got = _flash_attention_pallas(
+                q, k, v, True, interpret=True, block_q=tile, block_k=tile, segment=ids, block=4
+            )
+            atol = 2e-2
+        assert not bool(jnp.isnan(got).any())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
+        # a key of the NEXT block seen (the block's index off by one) is another answer
+        leaked = attention_reference(q, k, v, causal=True, segment=ids, block=8)
+        assert np.abs(np.asarray(leaked) - np.asarray(want)).max() > 0.05
+
+    @pytest.mark.parametrize("path", ["off the chip", "single block", "flash 128x256", "flash 256x512", "routed"])
+    def test_block_positions_against_cached_keys_match_the_reference(self, path):
+        from predictionio_tpu.ops.attention import _flash_attention_pallas, _fused_attention_pallas
+
+        Lq, Lk = (128, 512) if path == "single block" else (256, 2048)
+        q, k, v = grouped_qkv(1, 8, 2, Lq, Lk, seed=4)
+        of_q, of_k = cached_ids(Lq, Lk, sessions=Lq // 16, seed=4)
+        pair = (jnp.asarray(of_q), jnp.asarray(of_k))
+        want = np.asarray(attention_reference(q, k, v, segment=pair))
+        if path == "off the chip":
+            got, atol = fused_attention(q, k, v, segment=pair), 1e-5
+        elif path == "routed":
+            got, atol = fused_attention(q, k, v, segment=pair, force_pallas=True), 2e-2
+        elif path == "single block":
+            got, atol = _fused_attention_pallas(q, k, v, False, interpret=True, segment=pair), 2e-2
+        else:
+            bq, bk = map(int, path.split()[1].split("x"))
+            got = _flash_attention_pallas(
+                q, k, v, False, interpret=True, block_q=bq, block_k=bk, segment=pair
+            )
+            atol = 2e-2
+        got = np.asarray(got)
+        assert not np.isnan(got).any()
+        np.testing.assert_allclose(got, want, atol=atol)
+        # a session's queries equal the same queries against its own keys alone
+        own_q, own_k = np.flatnonzero(of_q[0] == 1), np.flatnonzero(of_k[0] == 1)
+        alone = attention_reference(q[:, :, own_q], k[:, :, own_k], v[:, :, own_k])
+        np.testing.assert_allclose(want[:, :, own_q], np.asarray(alone), atol=1e-5)
+        # absent queries (-1) come out as 0; a key leaked from the session in front moves the rest
+        assert not want[:, :, of_q[0] < 0].any()
+        shifted = (pair[0], jnp.where(pair[1] >= 0, jnp.maximum(pair[1] - 1, 0), -1))
+        assert np.abs(np.asarray(attention_reference(q, k, v, segment=shifted)) - want).max() > 0.05
+
+    def test_blocks_of_keys_no_query_carries_are_neither_needed_nor_fetched(self):
+        from predictionio_tpu.ops.attention import _needed_blocks
+
+        of_q = np.repeat(np.arange(8), 4).astype(np.int32)[None]  # two tiles of 16: sessions 0-3, 4-7
+        of_k = np.full((1, 512), -1, np.int32)
+        for session, start in enumerate((0, 64, 128, 130, 256, 300, 320, 448)):
+            of_k[0, start : start + 2] = session
+        needed, fetch = _needed_blocks((jnp.asarray(of_q), jnp.asarray(of_k)), 16, 64)
+        # keys in tiles of 64: sessions 0 | 1 | 2, 3 | - | 4, 5 | 6 | - | 7
+        assert np.asarray(needed)[0].tolist() == [[1, 1, 1, 0, 0, 0, 0, 0], [0, 0, 0, 0, 1, 1, 0, 1]]
+        # a step that computes nothing holds the nearest needed tile before it (the first, in front)
+        assert np.asarray(fetch)[0].tolist() == [[0, 1, 2, 2, 2, 2, 2, 2], [4, 4, 4, 4, 4, 5, 5, 7]]
+
+    def test_what_carries_no_order_refuses_causal_and_block_needs_it(self):
+        q, k, v = grouped_qkv(1, 4, 2, 128, 256)
+        pair = (jnp.zeros((1, 128), jnp.int32), jnp.zeros((1, 256), jnp.int32))
+        with pytest.raises(ValueError, match="no order"):
+            fused_attention(q, k, v, causal=True, segment=pair)
+        with pytest.raises(ValueError, match="block"):
+            fused_attention(q, k[:, :, :128], v[:, :, :128], block=4)
+        with pytest.raises(ValueError, match="key/value heads"):
+            fused_attention(q[:, :3], k[:, :, :128], v[:, :, :128])
+
+    @pytest.mark.parametrize("path", ["reference", "single block", "flash"])
+    def test_without_the_new_arguments_a_path_traces_to_what_it_did(self, path):
+        """No grouped head, no ``block``, one array of ids: the index maps
+        and the masks are the ones there were (the jaxpr of the call, kernel
+        body and all, is the same text as with the arguments left out)."""
+        L = 1024 if path == "flash" else 64
+        q, k, v = qkv(B=1, H=2, L=L, D=8, seed=7)
+        force = path != "reference"
+        ids = jnp.asarray(segments(L, (L // 2, L // 4), 16)[None])
+        for segment in (None, ids):
+            plain = jax.make_jaxpr(
+                lambda *a: fused_attention(*a, causal=True, force_pallas=force, segment=segment)
+            )(q, k, v)
+            named = jax.make_jaxpr(
+                lambda *a: fused_attention(*a, causal=True, force_pallas=force, segment=segment, block=None)
+            )(q, k, v)
+            assert str(plain) == str(named)
+            blocked = jax.make_jaxpr(
+                lambda *a: fused_attention(*a, causal=True, force_pallas=force, segment=segment, block=4)
+            )(q, k, v)
+            assert str(blocked) != str(plain)
+
+
 class TestUlyssesAttention:
     """All-to-all sequence parallelism (DeepSpeed-Ulysses scheme) must match
     the dense reference exactly — full sequence is reconstructed per head."""

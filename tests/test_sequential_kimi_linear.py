@@ -205,8 +205,8 @@ def test_copies_routed_to_absent_experts_never_reach_the_result(monkeypatch):
     want = moe.expert_ffn(x, weights, experts, gate[4:8], up[4:8], down[4:8], held=(4, 4))
     plain = moe.grouped_matmul
 
-    def unwritten(lhs, rhs, sizes, out_dtype):
-        out = plain(lhs, rhs, sizes, out_dtype)
+    def unwritten(lhs, rhs, sizes, out_dtype, **kw):
+        out = plain(lhs, rhs, sizes, out_dtype, **kw)
         return jnp.where((jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None], out, jnp.nan)
 
     monkeypatch.setattr(moe, "grouped_matmul", unwritten)
@@ -222,7 +222,9 @@ def test_the_chips_kernel_interpreted_serves_a_share():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(
             moe, "grouped_matmul",
-            lambda lhs, rhs, sizes, dtype: moe.grouped_matmul_kernel(lhs, rhs, sizes, dtype, interpret=True),
+            lambda lhs, rhs, sizes, dtype, **kw: moe.grouped_matmul_kernel(
+                lhs, rhs, sizes, dtype, interpret=True, **kw
+            ),
         )
         got = moe.expert_ffn(x, weights, experts, gate[8:12], up[8:12], down[8:12], held=(8, 4))
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
@@ -523,3 +525,48 @@ def test_save_then_load_is_equal_bit_for_bit_and_the_manifest_names_the_backbone
         np.testing.assert_array_equal(np.asarray(loaded.weights[name]), np.asarray(model.weights[name]))
     queries = [Query(user=f"u{i}", num=4) for i in range(4)]
     assert algorithm.predict_batch(loaded, queries) == algorithm.predict_batch(model, queries)
+
+
+@pytest.mark.parametrize("users", [(0,), (0, 1, 2, 3, 4, 5, 6, 7), (3, 3, 9, 1)])
+def test_the_answer_hook_launches_one_prefill_and_one_top_k_a_stream_and_counts_as_before(
+    trained, users, monkeypatch
+):
+    """``predict_batch_dispatch`` stages, then ``_answer`` (the hook a
+    generating backbone answers otherwise) launches: for this backbone one
+    ``session_vectors`` and one ``dot_top_k_async`` a stream, the counters of
+    before, and none of a generation's."""
+    from predictionio_tpu.models.sequential.engine import BackboneAlgorithm
+    from predictionio_tpu.ops import topk
+
+    _, model = trained
+    algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**TINY, seed=5))
+    assert type(algorithm)._answer is BackboneAlgorithm._answer
+    assert type(algorithm).predict_batch_dispatch is BackboneAlgorithm.predict_batch_dispatch
+    calls = {"session_vectors": 0, "dot_top_k_async": 0}
+    program, prefill, ending = model.program(), model.program().session_vectors, topk.dot_top_k_async
+
+    def counted(name, function):
+        def call(*args, **kwargs):
+            calls[name] += 1
+            return function(*args, **kwargs)
+
+        return call
+
+    monkeypatch.setattr(program, "session_vectors", counted("session_vectors", prefill))
+    monkeypatch.setattr(topk, "dot_top_k_async", counted("dot_top_k_async", ending))
+    queries = [Query(user=f"u{u}", num=4) for u in users]
+    sessions, streams = algorithm._plan(model, queries)
+    answers = algorithm.predict_batch_dispatch(model, queries)()
+    assert [len(a.item_scores) for a in answers] == [4] * len(users)
+    assert all(s.step is None and set(s.to_json_dict()) == {"item", "score"} for a in answers for s in a.item_scores)
+    assert calls == {"session_vectors": len(streams), "dot_top_k_async": len(streams)}
+    counters = algorithm.instruments
+    real = sum(len(s) for s in sessions)
+    assert counters.tokens.value(kind="real") == real
+    assert counters.tokens.value(kind="padded") == sum(length for length, _ in streams)
+    assert sum(counters.programs.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(streams)
+    assert sum(counters.sessions.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(users)
+    assert counters.batches.value() == 1 and counters.stage_seconds.value() > 0
+    assert counters.expert_tokens_mean.value() == model.config.even_expert_load(real)
+    assert counters.passes.value(kind="denoise") == counters.passes.value(kind="commit") == 0
+    assert counters.blocks.value() == counters.generated_items.value() == counters.cache_bytes.value() == 0

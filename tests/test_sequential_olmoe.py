@@ -206,9 +206,9 @@ def test_full_logits_equal_the_references(trained, length, kernel, monkeypatch):
         # whole program: every layer's experts stacked, the layer's first group
         traced = []
 
-        def interpreted(*args):
+        def interpreted(*args, **kw):
             traced.append(args[0].shape)
-            return moe.grouped_matmul_kernel(*args, interpret=True)
+            return moe.grouped_matmul_kernel(*args, interpret=True, **kw)
 
         monkeypatch.setattr(moe, "grouped_matmul", interpreted)
         olmoe.all_logits.clear_cache()
@@ -239,6 +239,51 @@ def test_the_busiest_experts_count_leaves_the_padding_out(trained):
     # 37 real tokens with 2 experts each over 2 layers, whatever is padded around them
     assert counts[0] == counts[1] == counts[2]
     assert 2 * 37 * 2 / 8 <= counts[0] <= 2 * 37
+
+
+@pytest.mark.parametrize("users", [(0,), (0, 1, 2, 3, 4, 5, 6, 7), (3, 3, 9, 1)])
+def test_the_answer_hook_launches_one_prefill_and_one_top_k_a_stream_and_counts_as_before(
+    trained, users, monkeypatch
+):
+    """``predict_batch_dispatch`` stages, then ``_answer`` (the hook a
+    generating backbone answers otherwise) launches: for this backbone one
+    ``session_vectors`` and one ``dot_top_k_async`` a stream, the counters of
+    before, and none of a generation's."""
+    from predictionio_tpu.models.sequential.engine import BackboneAlgorithm
+    from predictionio_tpu.ops import topk
+
+    _, model = trained
+    algorithm = OlmoeAlgorithm(OlmoeAlgorithmParams(**TINY, seed=5))
+    assert type(algorithm)._answer is BackboneAlgorithm._answer
+    assert type(algorithm).predict_batch_dispatch is BackboneAlgorithm.predict_batch_dispatch
+    calls = {"session_vectors": 0, "dot_top_k_async": 0}
+    program, prefill, ending = model.program(), model.program().session_vectors, topk.dot_top_k_async
+
+    def counted(name, function):
+        def call(*args, **kwargs):
+            calls[name] += 1
+            return function(*args, **kwargs)
+
+        return call
+
+    monkeypatch.setattr(program, "session_vectors", counted("session_vectors", prefill))
+    monkeypatch.setattr(topk, "dot_top_k_async", counted("dot_top_k_async", ending))
+    queries = [Query(user=f"u{u}", num=4) for u in users]
+    sessions, streams = algorithm._plan(model, queries)
+    answers = algorithm.predict_batch_dispatch(model, queries)()
+    assert [len(a.item_scores) for a in answers] == [4] * len(users)
+    assert all(s.step is None and set(s.to_json_dict()) == {"item", "score"} for a in answers for s in a.item_scores)
+    assert calls == {"session_vectors": len(streams), "dot_top_k_async": len(streams)}
+    counters = algorithm.instruments
+    real = sum(len(s) for s in sessions)
+    assert counters.tokens.value(kind="real") == real
+    assert counters.tokens.value(kind="padded") == sum(length for length, _ in streams)
+    assert sum(counters.programs.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(streams)
+    assert sum(counters.sessions.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(users)
+    assert counters.batches.value() == 1 and counters.stage_seconds.value() > 0
+    assert counters.expert_tokens_mean.value() == model.config.even_expert_load(real)
+    assert counters.passes.value(kind="denoise") == counters.passes.value(kind="commit") == 0
+    assert counters.blocks.value() == counters.generated_items.value() == counters.cache_bytes.value() == 0
 
 
 def test_two_deployments_in_one_process_count_apart(trained):
@@ -582,6 +627,8 @@ def test_query_server_answers_mixed_lengths_over_http_and_counts_them(trained):
         return {k: float(v) for k, _, v in (l.rpartition(" ") for l in lines if l and l[0] != "#")}
 
     try:
+        # a scorer has no batch limit of its own: the operator's stands
+        assert server.algorithms[0].batch_limit() is None and server._batcher.max_batch == 32
         before = scrape()
         td = training_data()
         replies = {}

@@ -180,3 +180,83 @@ def test_the_kimi_cells_check_keeps_a_session_of_4096_under_a_gigabyte(one_chip,
     assert memory.temp_size_in_bytes < 1.0e9
     # nothing of the layer is copied in: the arguments are the served arrays
     assert memory.argument_size_in_bytes < 1.1e9
+
+
+# SDAR's 32 query heads over 4 key/value heads of 128 under a block-causal
+# mask, over a packed stream at both stream lengths (the prefill's kernel)
+@pytest.mark.parametrize("length", [2048, 4096])
+def test_grouped_query_block_causal_attention_compiles_for_v5e(one_chip, monkeypatch, length):
+    from predictionio_tpu.ops.attention import fused_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    queries = _shape(one_chip, (1, 32, length, 128), jnp.bfloat16)
+    keys = _shape(one_chip, (1, 4, length, 128), jnp.bfloat16)
+
+    def attend(q, k, v, segment):
+        return fused_attention(q, k, v, causal=True, segment=segment, block=4)
+
+    compiled = jax.jit(attend).lower(queries, keys, keys, _shape(one_chip, (1, length), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert jax.eval_shape(attend, queries, keys, keys, jax.ShapeDtypeStruct((1, length), jnp.int32)).shape == queries.shape
+
+
+def _sdar_at_the_cell():
+    import json
+    from pathlib import Path
+
+    from benchmark.engines import sequential_sdar as engine
+    from predictionio_tpu.models.sequential import engine_factory
+
+    config = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-sdar-moe.json").read_text())
+    return engine_factory().engine_params_from_variant(engine.variant_of(config, 5)).algorithms[0][1].config()
+
+
+def _sdar_state(one_chip, config, weights):
+    from predictionio_tpu.models.sequential import sdar
+
+    sessions, slots, vocabulary = sdar.SESSIONS, config.generated_slots, config.vocab_size
+    a_layer = _shape(
+        one_chip, (config.num_key_value_heads, config.cache_slots, config.head_dim), weights["wk"].dtype
+    )
+    cache = tuple(tuple(a_layer for _ in range(config.num_hidden_layers)) for _ in "kv")
+    whole = lambda shape, dtype=jnp.int32: _shape(one_chip, shape, dtype)  # noqa: E731
+    return cache, {
+        "cache": cache, "seg": whole((config.cache_tokens,)), "commits": whole((config.most_passes, config.chunk)),
+        "pass": whole(()), "tokens": whole((sessions, slots)), "step": whole((sessions, slots)),
+        "logp": whole((sessions, slots), jnp.float32), "block": whole((sessions,)), "tick": whole((sessions,)),
+        "blocks": whole((sessions,)), "reach": whole((sessions,)), "start": whole((sessions,)),
+        "allowed": whole((sessions, vocabulary), jnp.bool_), "busiest": whole(()), "reached": whole(()),
+    }
+
+
+@pytest.mark.parametrize("program", ["a pass", "a prefill of 2,048", "a prefill of 4,096"])
+def test_the_sdar_cells_programs_compile_for_v5e_beside_the_model(one_chip, monkeypatch, program):
+    """``seq-sdar-moe``'s three programs at the published widths, six layers:
+    the arguments are the served model (8.7 GB) and the batch's cache (0.4
+    GB, donated and handed back in place), the temporaries under 1.5 GB (0.1
+    a pass, 0.7 and 1.3 a prefill), and every kernel is there (attention a layer, three grouped products
+    a sparse layer)."""
+    from predictionio_tpu.models.sequential import sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = _sdar_at_the_cell()
+    weights = {
+        name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in sdar.weight_shapes(config).items()
+    }
+    cache, state = _sdar_state(one_chip, config, weights)
+    if program == "a pass":
+        compiled = sdar.denoise_pass.lower(weights, state, config=config).compile()
+        kernels = 4 * config.num_hidden_layers
+    else:
+        length = 2048 if "2,048" in program else 4096
+        stream = _shape(one_chip, (1, length), jnp.int32)
+        compiled = sdar.session_vectors.lower(
+            weights, cache, stream, stream, stream, _shape(one_chip, (), jnp.int32), config=config
+        ).compile()
+        kernels = 4  # the layers but the last are one scanned body
+    memory = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert 8.4e9 < memory.argument_size_in_bytes < 9.3e9  # (a prefill reads no `lm_head`)
+    # the cache is updated where it lies
+    assert memory.alias_size_in_bytes >= config.cache_bytes(config.cache_slots)
