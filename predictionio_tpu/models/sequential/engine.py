@@ -1107,16 +1107,18 @@ class BackboneAlgorithm(JaxAlgorithm):
     longest first, first fit, into streams of the backbone's
     ``TOKEN_BUDGET`` tokens, of ``config.max_session`` where a session is
     longer; every session from a multiple of ``SESSION_ALIGN``, at most
-    ``TOKEN_BUDGET // SESSION_ALIGN`` a stream), and a stream is one program:
+    ``TOKEN_BUDGET // SESSION_ALIGN`` a stream) and lays each out as
     ``[1, T]`` tokens with each token's ``segment`` and ``position``
-    (``_stage``). ``_answer`` then launches: here the backbone's
-    ``session_vectors`` a stream, then ``topk.dot_top_k_async`` over the
-    stream's sessions (their items masked). ONE finalize answers in the
-    queries' order. The stream lengths
-    are a closed set (``config.stream_shapes``: two) and ``warmup_serving``
-    compiles all of it. A single query is one session in a stream. What it
-    launched is counted in ``instruments``, the algorithm's own until a
-    query server hands over its registry."""
+    (``_stage``). ``_answer`` then launches: here the staged streams go
+    ``STACKED_ROWS`` at a time as the ROWS of one program (``_programs``,
+    ``_stack``), the backbone's ``session_vectors`` a program, then
+    ``topk.dot_top_k_async`` over the program's sessions (their items
+    masked). ONE finalize answers in the queries' order. The programs'
+    shapes are a closed set (``[STACKED_ROWS, budget]`` and a single row of
+    each of ``config.stream_shapes``) and ``warmup_serving`` compiles all of
+    it. A single query is one session in a stream in a program of one row.
+    What it launched is counted in ``instruments``, the algorithm's own until
+    a query server hands over its registry."""
 
     model_class: type[BackboneModel]
 
@@ -1194,35 +1196,71 @@ class BackboneAlgorithm(JaxAlgorithm):
     def predict_batch_dispatch(self, model: BackboneModel, queries: Sequence[Query]):
         t0 = time.perf_counter()
         sessions, streams = self._plan(model, queries)
-        with annotate("pio:seq.stage", batch=len(queries), programs=len(streams)):
+        with annotate("pio:seq.stage", batch=len(queries), streams=len(streams)):
             staged = [self._stage(model, sessions, stream) for stream in streams]
         self.instruments.on_stage(time.perf_counter() - t0)
         return self._answer(model, queries, sessions, streams, staged)
 
+    @staticmethod
+    def _programs(model: BackboneModel, streams) -> list[list[int]]:
+        """The staged streams' indices as PROGRAMS, a program's streams being
+        its rows: the streams of the budget's length go ``STACKED_ROWS`` at a
+        time (the backbone module's constant) and what is left of them, like
+        every longer stream, one by one. The shapes are a closed set:
+        ``[STACKED_ROWS, budget]`` and ``[1, T]`` for every ``T`` of
+        ``config.stream_shapes()``."""
+        budget = model.config.stream_shapes()[0]
+        height = model.program().STACKED_ROWS
+        short = [i for i, (length, _) in enumerate(streams) if length == budget]
+        whole = len(short) - len(short) % height
+        stacks = [short[at : at + height] for at in range(0, whole, height)]
+        return stacks + [[i] for i in sorted(set(range(len(streams))) - set(short[:whole]))]
+
+    @staticmethod
+    def _stack(staged):
+        """Staged streams of one length as the rows of ONE program:
+        ``tokens``, ``segment``, ``position`` [R, T], ``last`` [R, S] and the
+        mask [R * S, table rows], row by row. A row keeps its own segment
+        ids: no kernel looks across rows."""
+        tokens, segment, position, last, mask = zip(*staged)
+        return (
+            np.concatenate(tokens), np.concatenate(segment), np.concatenate(position),
+            np.stack(last), np.concatenate(mask),
+        )
+
     def _answer(self, model: BackboneModel, queries, sessions, streams, staged):
         """The staged streams launched, and the ``finalize`` that answers the
-        queries in their order: one prefill a stream and one fused top-k
-        over its sessions' vectors."""
+        queries in their order: one prefill a PROGRAM (``_programs``: up to
+        ``STACKED_ROWS`` streams as its rows, so that a layer's experts meet
+        all their tokens at once) and one fused top-k over its sessions'
+        vectors."""
         config = model.config
         session_vectors = model.program().session_vectors
         n = len(model.item_vocab)
         kk = min(topk.next_pow2(max(1, max(q.num for q in queries))), n)
+        _, most = _stream_limits(model)
         launched = []
-        for (length, members), (*stream, mask) in zip(streams, staged):
-            real = sum(len(sessions[i]) for i, _ in members)
-            # (`bucket` is the stream's length and `rows` 1: the names the
-            # counters' readers know a program's shape by)
-            with annotate("pio:seq.launch", bucket=length, rows=1, tokens=real):
+        for rows in self._programs(model, streams):
+            length = streams[rows[0]][0]
+            # (the query, where its vector and its answer lie among the program's)
+            places = [
+                (i, r * most + k) for r, row in enumerate(rows) for k, (i, _) in enumerate(streams[row][1])
+            ]
+            real = sum(len(sessions[i]) for i, _ in places)
+            # (`bucket` is a row's length and `rows` the streams stacked: the
+            # names the counters' readers know a program's shape by)
+            with annotate("pio:seq.launch", bucket=length, rows=len(rows), tokens=real):
+                *arrays, mask = self._stack([staged[row] for row in rows])
                 vectors, counted = session_vectors(
-                    model.weights, *(topk.upload(a, np.int32) for a in stream), config=config
+                    model.weights, *(topk.upload(a, np.int32) for a in arrays), config=config
                 )
                 handle = topk.dot_top_k_async(model.head(), vectors, mask, kk)
-            self.instruments.on_launch(length, 1, real, len(members))
-            launched.append((handle, counted, real))
+            self.instruments.on_launch(length, len(rows), real, len(places))
+            launched.append((places, handle, counted, real))
 
         def finalize() -> list[PredictedResult]:
             out: list[PredictedResult] = [PredictedResult(())] * len(queries)
-            for (length, members), (handle, counted, real) in zip(streams, launched):
+            for places, handle, counted, real in launched:
                 scores, idx = topk.fetch_topk(handle)
                 # an integer or two a program ride back with its answer: the
                 # busiest expert's copies and, where the chip holds a share of
@@ -1232,10 +1270,10 @@ class BackboneAlgorithm(JaxAlgorithm):
                 held = int(counted[1]) if counted.size > 1 else routed
                 self.instruments.on_expert_load(int(counted[0]), config.even_expert_load(real))
                 self.instruments.on_copies(held, routed - held)
-                for row, (i, _) in enumerate(members):
+                for i, place in places:
                     picks = [
                         ItemScore(model.item_vocab[int(item)], float(score))
-                        for score, item in zip(scores[row], idx[row])
+                        for score, item in zip(scores[place], idx[place])
                         if np.isfinite(score)
                     ]
                     out[i] = PredictedResult(tuple(picks[: queries[i].num]))
@@ -1252,15 +1290,31 @@ class BackboneAlgorithm(JaxAlgorithm):
         return self.predict_batch(model, [query])[0]
 
     def warmup_serving(self, model: BackboneModel, max_batch: int) -> None:
-        """Compile every stream length there is, by the path serving takes
-        (the staging copies, ``session_vectors`` and the top-k): for each a
-        query whose session fills it, or is the longest there is.
+        """Compile every program shape there is, by the path serving takes
+        (the staging copies, ``session_vectors`` and the top-k): a query
+        whose session is the longest there is, then batches of 1 to
+        ``STACKED_ROWS`` streams' worth of sessions that each fill a stream of
+        the budget: whatever a batch leaves behind its whole stacks is
+        compiled then too.
         ``max_batch`` bounds nothing here: a batch of any size is packed
         into these shapes."""
         n = len(model.item_vocab)
-        for length in model.config.stream_shapes():
-            items = tuple(model.item_vocab[i % n] for i in range(length))
-            self.predict(model, Query(recent_items=items, num=min(10, n)))
+        align, _ = _stream_limits(model)
+        budget, *longer = model.config.stream_shapes()
+
+        def filling(length: int) -> list[Query]:
+            """Sessions that leave a stream of ``length`` no room for another
+            of them: the longest there are, as many as fit."""
+            items = min(length, model.config.max_session)
+            recent = tuple(model.item_vocab[i % n] for i in range(items))
+            room = -(-items // align) * align
+            return [Query(recent_items=recent, num=min(10, n))] * (length // room)
+
+        for length in longer:
+            self.predict_batch(model, filling(length))
+        stream = filling(budget)
+        for streams in range(1, model.program().STACKED_ROWS + 1):
+            self.predict_batch(model, stream * streams)
 
 
 class OlmoeModel(BackboneModel):

@@ -31,13 +31,15 @@ the gates, the KDA state and everything in its scan, the router and the
 softmax in float32. A float32 weight tree (the CPU parity tests) computes in
 float32.
 
-A program is one token stream of several sessions, as ``olmoe.py``'s is
-(``segment``, ``position``): latent attention sees a key only from inside its
-own segment, the KDA state is zeroed where a chunk begins a session (sessions
-start on multiples of ``SESSION_ALIGN``, which is the scan's ``CHUNK``) and
-the short convolution reaches no further back than a session's first item, so
-a session's positions come out as they would alone; neither kind of mixer lets
-a real position see what follows it.
+A program is ``[R, T]`` tokens, ``R`` token streams of several sessions each
+as its rows, as ``olmoe.py``'s is (``segment``, ``position``): latent
+attention sees a key only from inside its own row and segment, the KDA state
+is zeroed where a chunk begins a session (sessions start on multiples of
+``SESSION_ALIGN``, which is the scan's ``CHUNK``) and the short convolution
+reaches no further back than a session's first item, so a session's positions
+come out as they would alone; neither kind of mixer lets a real position see
+what follows it, and both work row by row. The experts and the shared expert
+take the tokens of all rows at once.
 """
 
 from __future__ import annotations
@@ -51,7 +53,7 @@ import numpy as np
 from jax import lax
 
 from predictionio_tpu.models.sequential.olmoe import (
-    LENGTH_BUCKETS, SESSION_ALIGN, _normal, _project, _rms, bucket_of, stream_shapes,
+    LENGTH_BUCKETS, SESSION_ALIGN, _at_last, _normal, _project, _rms, bucket_of, stream_shapes,
 )
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
@@ -64,6 +66,22 @@ __all__ = [
 
 # tokens a stream holds (``MAX_SESSION`` where a session is longer)
 TOKEN_BUDGET = 2048
+# streams that ride as the rows of one program (``olmoe.STACKED_ROWS``: why
+# and how). ONE here, from the chip (PERF.md, PR 38; ms a stream, the top-k
+# included): [1, 2048] 76.4, [2, 2048] 82.5, [4, 2048] 85.4, two streams end
+# to end as [1, 4096] 77.1. The experts do gain by rows (a sparse layer's
+# router and experts 4.38 ms a stream alone, 2.86 in a four), but the KDA
+# mixer LOSES more (6.95 ms a layer and stream alone, 9.11 in a four: the
+# scan 5.22 -> 6.83, the projections and convolutions 0.96 -> 1.43). With
+# the mixer mapped row by row (``lax.map``) a four takes 70.5 ms a stream and
+# a batch of 32 sessions 509 ms for 537, but only beside a single-row program
+# of each length, and a THIRD compiled shape costs this unrolled program 3.7
+# to 4.9 s of a 38 s set-up (the benchmark's bound is 10%); with two shapes
+# (what a batch leaves behind its stacks riding a half-empty 4,096 row) every
+# height lost 11 to 13% a batch. So the program takes [R, T] and the engine
+# sends it one row; a scan over the alike layers (ROADMAP S9 (g)) is what
+# would pay for the third shape
+STACKED_ROWS = 1
 # items of a session the engine keeps, and so the longest program: the
 # traffic's bound (the model's own is ``model_max_length``, 1,048,576 as
 # published)
@@ -363,18 +381,19 @@ def _layers(weights, x, segment, position, config: KimiLinearConfig):
 
 @functools.partial(jax.jit, static_argnames=("config",))
 def session_vectors(weights, tokens, segment, position, last, *, config: KimiLinearConfig):
-    """One token stream, as ``olmoe.session_vectors`` takes it: ``tokens``,
-    ``segment`` and ``position`` [1, T] int32; ``last`` [S] int32, each
-    session's last position IN THE STREAM, -1 where the stream holds fewer
-    than S. Returns the session vectors [S, hidden] float32 (``rms(x_L;
+    """``R`` token streams as the rows of one program, as
+    ``olmoe.session_vectors`` takes them: ``tokens``, ``segment`` and
+    ``position`` [R, T] int32; ``last`` [R, S] int32, each session's last
+    position IN ITS STREAM, -1 where a stream holds fewer than S. Returns
+    the session vectors [R * S, hidden] float32, row by row (``rms(x_L;
     w_final)`` at ``last``; one at -1 is to be thrown away) and two counts
     of copies of REAL tokens, summed over the sparse layers: what the
-    busiest held expert got, and what all the held experts got."""
+    program's busiest held expert got, and what all the held experts got."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
     x, counts = _layers(weights, x, segment, position, config)
     with jax.named_scope("head"):
-        out = _rms(x[0, jnp.maximum(last, 0)], weights["final_norm"], config.rms_norm_eps)
+        out = _rms(_at_last(x, last), weights["final_norm"], config.rms_norm_eps)
     return out, counts
 
 

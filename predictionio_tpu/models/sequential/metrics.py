@@ -80,14 +80,15 @@ class BackboneInstruments:
         )
         self.rows = r.counter(
             "pio_seq_rows_total",
-            "rows of launched session programs (a token stream is one row), "
-            "by the stream's length",
+            "rows of launched session programs (a token stream is one row; over "
+            "pio_seq_programs_total: the streams a program stacks), by the "
+            "stream's length",
             labelnames=("bucket",),
         )
         self.sessions = r.counter(
             "pio_seq_sessions_total",
             "sessions packed into launched session programs, by the stream's "
-            "length (over pio_seq_programs_total: sessions a program)",
+            "length (over pio_seq_rows_total: sessions a stream)",
             labelnames=("bucket",),
         )
         self.stage_seconds = r.counter(

@@ -16,16 +16,19 @@ that a program compiles one layer, attention through
 through ``ops/moe``. The operands' type follows the weights': a float32
 weight tree (the CPU parity tests) computes in float32.
 
-A program is one TOKEN STREAM, ``[1, T]`` with ``T`` one of
-``config.stream_shapes()`` (2,048, or 4,096 where a session is longer than
-that): the engine packs several sessions into it, each from a multiple of
-``SESSION_ALIGN`` and right-padded to the next, and hands over every token's
-``segment`` (its session's index in the stream, -1 for padding) and
-``position`` (its index inside its session). Attention sees a key only from
-inside its own segment (``fused_attention(segment=)``) and RoPE turns by
+A program is ``[R, T]`` tokens: ``R`` TOKEN STREAMS as its rows, each of
+``T`` tokens, ``T`` one of ``config.stream_shapes()`` (2,048, or 4,096 where
+a session is longer than that). The engine packs several sessions into a
+stream, each from a multiple of ``SESSION_ALIGN`` and right-padded to the
+next, and hands over every token's ``segment`` (its session's index in its
+stream, -1 for padding) and ``position`` (its index inside its session).
+Attention sees a key only from inside its own row and segment
+(``fused_attention(segment=)`` works row by row) and RoPE turns by
 ``position``, so a session's positions come out as they would alone;
-everything else in a layer is a token's own. The padding is computed and
-thrown away (``pio_seq_tokens_total{kind}`` counts it).
+everything else in a layer is a token's own, and the experts' grouped
+products take the tokens of ALL rows at once (``STACKED_ROWS``: why). The
+padding is computed and thrown away (``pio_seq_tokens_total{kind}`` counts
+it).
 
 The weights are drawn from a seed, not fitted: fitting the backbone is not
 this engine's work yet (ROADMAP R7).
@@ -59,8 +62,22 @@ SESSION_ALIGN = LENGTH_BUCKETS[0]
 # (PERF.md, PR 26): a program takes about 20 ms and 17 ms a thousand tokens,
 # so taller is cheaper a token, but a batch of 32 sessions did not fill
 # 8,192 tokens when programs were cut by length bucket (43 answers a second
-# against 80 at 2,048); packed streams may (ROADMAP S9 (b), (h))
+# against 80 at 2,048); packed streams do, and ride as ROWS (below), which
+# beat the same streams end to end in a taller row
 TOKEN_BUDGET = 2048
+# streams of ``TOKEN_BUDGET`` tokens that ride as the ROWS of one program:
+# the engine stacks a batch's streams this many at a time and sends what is
+# left one by one, so the compiled shapes are the closed set [STACKED_ROWS,
+# TOKEN_BUDGET] and [1, T] for T of ``stream_shapes()``. A layer's experts
+# then meet all the rows' tokens at once: 1,024 rows an expert, not 256 (one
+# tile of ``ops/moe.TILING`` and a bit for the busiest), and the layer's
+# matrices are read once for the rows. From the chip (PERF.md, PR 38; ms a
+# STREAM, the top-k included): [1, 2048] 37.5, [2, 2048] 32.7, [4, 2048]
+# 31.6, [8, 2048] 31.4, two streams end to end as [1, 4096] 32.8, four as
+# [1, 8192] 33.1. A batch of 32 sessions is 6 or 7 streams, so fours leave
+# fewer behind than eights: batches a second 4.35 by fours, 4.06 by eights,
+# 4.33 by twos, 3.88 one by one
+STACKED_ROWS = 4
 
 LAYER_ARRAYS = (
     "w_in", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "w_post", "router", "gate", "up", "down",
@@ -262,20 +279,28 @@ def _layers(weights, x, segment, position, config: OlmoeConfig):
     return lax.scan(step, x, (jnp.arange(config.num_hidden_layers), sliced))
 
 
+def _at_last(x, last):
+    """``x`` [R, T, hidden] at ``last`` [R, S], a row's positions from its
+    own row: [R * S, hidden], row by row."""
+    rows = jnp.arange(x.shape[0])[:, None]
+    return x[rows, jnp.maximum(last, 0)].reshape(-1, x.shape[2])
+
+
 @functools.partial(jax.jit, static_argnames=("config",))
 def session_vectors(weights, tokens, segment, position, last, *, config: OlmoeConfig):
-    """One token stream: ``tokens``, ``segment`` and ``position`` [1, T]
-    int32 (the module's docstring); ``last`` [S] int32, each session's last
-    position IN THE STREAM, -1 where the stream holds fewer than S. Returns
-    the session vectors [S, hidden] float32 (``rms(x_L; w_final)`` at
-    ``last``; one at -1 is to be thrown away) and, summed over the layers,
-    the number of copies of REAL tokens the busiest expert got."""
+    """``R`` token streams as the rows of one program: ``tokens``,
+    ``segment`` and ``position`` [R, T] int32 (the module's docstring);
+    ``last`` [R, S] int32, each session's last position IN ITS STREAM, -1
+    where a stream holds fewer than S. Returns the session vectors [R * S,
+    hidden] float32, row by row (``rms(x_L; w_final)`` at ``last``; one at
+    -1 is to be thrown away) and, summed over the layers, the number of
+    copies of REAL tokens the busiest expert of the PROGRAM got."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
 
     x, busiest = _layers(weights, x, segment, position, config)
     with jax.named_scope("head"):
-        out = _rms(x[0, jnp.maximum(last, 0)], weights["final_norm"], config.rms_norm_eps)
+        out = _rms(_at_last(x, last), weights["final_norm"], config.rms_norm_eps)
     return out, jnp.sum(busiest)
 
 

@@ -72,6 +72,9 @@ __all__ = [
 MAX_SESSION = 4096
 # sessions a group of passes holds: a stream's most, a short group is padded
 SESSIONS = TOKEN_BUDGET // SESSION_ALIGN
+# streams a prefill takes (``olmoe.STACKED_ROWS``): one, written into the
+# group's cache where the stream lies; stacking them is ROADMAP S9's follow-up
+STACKED_ROWS = 1
 # passes a group may make, each with a chunk of the cache for its blocks'
 # keys and values: 24 take an answer of 16 items (19 or 20 passes), and with
 # the streams' slots below the keys are 32 of the attention kernel's tiles

@@ -375,6 +375,177 @@ def test_a_packed_streams_session_vectors_equal_the_sessions_alone(case, monkeyp
     np.testing.assert_allclose(np.asarray(packed[1]) @ head.T, np.asarray(logits)[0, -1], atol=ATOL)
 
 
+# ------------------------------------------- streams as the rows of a program
+
+# the sessions (their lengths) of four streams of 256 tokens, each from its
+# start: a full one, one with a padded end, a lone short session, four sessions
+ROWS = (
+    ((37, 64, 100), (0, 64, 128)), ((70, 17), (0, 128)), ((3,), (0,)), ((64, 64, 64, 40), (0, 64, 128, 192)),
+)
+
+
+def stacked_streams(algorithm, model, seed=0):
+    """``ROWS`` staged one by one, as ``_answer`` is handed them."""
+    rng = np.random.default_rng(seed)
+    sessions, staged_rows = [], []
+    for lengths, starts in ROWS:
+        members = [(len(sessions) + j, start) for j, start in enumerate(starts)]
+        sessions += [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths]
+        staged_rows.append(algorithm._stage(model, sessions, (256, members)))
+    return staged_rows
+
+
+def vectors_of(algorithm, model, staged_rows, rows, program=kimi_linear.session_vectors):
+    *arrays, _ = algorithm._stack([staged_rows[r] for r in rows])
+    out, _ = program(model.weights, *map(jnp.asarray, arrays), config=model.config)
+    return np.asarray(out).reshape(len(rows), -1, out.shape[-1])
+
+
+@pytest.mark.parametrize("rows", [(0, 1), (0, 1, 2, 3), (3, 3, 0, 2)])
+def test_streams_stacked_as_rows_equal_the_streams_alone(trained, rows):
+    algorithm, model = trained
+    staged_rows = stacked_streams(algorithm, model)
+    stacked = vectors_of(algorithm, model, staged_rows, rows)
+    assert stacked.shape == (len(rows), 4, 64)
+    for r, row in enumerate(rows):
+        alone = vectors_of(algorithm, model, staged_rows, (row,))
+        held = len(ROWS[row][0])
+        np.testing.assert_allclose(stacked[r, :held], alone[0, :held], atol=ATOL, rtol=0, err_msg=f"row {r}")
+
+
+def _a_key(monkeypatch):
+    attend = kimi_linear.fused_attention
+
+    def leaky(q, k, v, **kwargs):  # the first position's key is the row in front's
+        k = k.at[:, :, 0].set(jnp.roll(k, 1, axis=0)[:, :, 0])
+        return attend(q, k, v, **kwargs)
+
+    monkeypatch.setattr(kimi_linear, "fused_attention", leaky)
+
+
+def _a_scan_state(monkeypatch):
+    scan = kimi_linear.kda
+
+    def leaky(q, k, v, g, b, starts=None):  # a row begins from the state the row in front ended in
+        _, state = scan(q, k, v, g, b, starts=starts)
+        return scan(q, k, v, g, b, state=jnp.roll(state, 1, axis=0), starts=starts.at[:, 0].set(False))
+
+    monkeypatch.setattr(kimi_linear, "kda", leaky)
+
+
+def _a_convolution_tap(monkeypatch):
+    convolve = kimi_linear.short_conv
+
+    def leaky(x, w, position=None):  # the row in front's last input reaches a row's first position
+        return convolve(x.at[:, 0].add(jnp.roll(x, 1, axis=0)[:, -1]), w, position=position)
+
+    monkeypatch.setattr(kimi_linear, "short_conv", leaky)
+
+
+@pytest.mark.parametrize("plant", [_a_key, _a_scan_state, _a_convolution_tap])
+def test_a_leak_from_the_row_in_front_moves_the_vectors(trained, plant, monkeypatch):
+    """What the equality above can tell: a key, a scan state or a convolution
+    tap of the row in front, each planted alone, moves a row's vectors by far
+    more than the tolerance."""
+    algorithm, model = trained
+    staged_rows = stacked_streams(algorithm, model)
+    sound = vectors_of(algorithm, model, staged_rows, (0, 1, 2, 3))
+    plant(monkeypatch)
+    # (a function of its own: a jit's traces are kept by the function traced)
+    planted = jax.jit(lambda *a, config: kimi_linear.session_vectors.__wrapped__(*a, config=config), static_argnames=("config",))
+    leaked = vectors_of(algorithm, model, staged_rows, (0, 1, 2, 3), planted)
+    for r, (lengths, _) in enumerate(ROWS):
+        assert np.abs(leaked[r, : len(lengths)] - sound[r, : len(lengths)]).max() > 100 * ATOL, r
+
+
+@pytest.fixture(scope="module")
+def stacking():
+    """An algorithm and a model whose sessions reach 512 items, so that the
+    streams are of 256 tokens and of 512, and users by their session's length."""
+    lengths = [17, 40, 60, 500] + [150] * 8
+    rng = np.random.default_rng(38)
+    algorithm = KimiLinearAlgorithm(KimiLinearAlgorithmParams(**{**TINY, "model_max_length": 512}, seed=6))
+    users = [f"u{i}" for i in range(len(lengths))]
+    model = algorithm.train(None, TrainingData(
+        users, [rng.integers(0, N_ITEMS, n).astype(np.int32) for n in lengths], [f"i{i}" for i in range(N_ITEMS)],
+    ))
+    model.weights = upcast(model.weights)
+    return algorithm, model, users
+
+
+@pytest.fixture(autouse=True)
+def sessions_of_up_to_512_items_in_stacks_of_four(request, monkeypatch):
+    """The engine's stacking, which this backbone shares with ``olmoe``, at
+    ``olmoe``'s height: as shipped its own is ONE (the chip's readings stand
+    beside ``kimi_linear.STACKED_ROWS``), and one row is the path of every
+    other test of this file."""
+    if "stacking" in request.fixturenames:
+        assert kimi_linear.STACKED_ROWS == 1
+        monkeypatch.setattr(kimi_linear, "MAX_SESSION", 512)
+        monkeypatch.setattr(kimi_linear, "STACKED_ROWS", 4)
+
+
+# batches by the streams they make at a budget of 256 tokens and sessions of
+# up to 512 items: (items a session; the programs as (rows, a row's tokens))
+BATCHES = {
+    "one query": ((40,), [(1, 256)]),
+    "three streams and a bit": ((150, 150, 150, 40, 17, 60), [(1, 256)] * 3),
+    "a four, two left over and a long one": (
+        (150, 150, 500, 150, 40, 150, 150, 17, 150), [(4, 256), (1, 256), (1, 256), (1, 512)],
+    ),
+    "two fours": ((150,) * 8, [(4, 256), (4, 256)]),
+}
+
+
+@pytest.mark.parametrize("case", list(BATCHES))
+def test_the_answer_hook_stacks_whole_fours_and_answers_in_the_queries_order(stacking, case):
+    algorithm, model, users = stacking
+    lengths, shapes = BATCHES[case]
+    assert model.program().STACKED_ROWS == 4
+    pool = {n: [u for u in users if len(model.session_tokens(Query(user=u))) == n] for n in set(lengths)}
+    queries = [Query(user=pool[n].pop(), num=5) for n in lengths]
+    _, streams = algorithm._plan(model, queries)
+    programs = algorithm._programs(model, streams)
+    assert sorted((len(rows), streams[rows[0]][0]) for rows in programs) == sorted(shapes)
+    assert all(len({streams[i][0] for i in rows}) == 1 for rows in programs)  # a program's rows are of one length
+    counters = algorithm.instruments
+    before = {b: (counters.programs.value(bucket=b), counters.rows.value(bucket=b)) for b in ("256", "512")}
+    answers = algorithm.predict_batch(model, queries)
+    for b in ("256", "512"):
+        launched = [rows for rows, length in shapes if str(length) == b]
+        assert counters.programs.value(bucket=b) - before[b][0] == len(launched)
+        assert counters.rows.value(bucket=b) - before[b][1] == sum(launched)
+    for query, answer in zip(queries, answers):
+        alone = algorithm.predict(model, query)  # one stream, one row
+        assert [s.item for s in answer.item_scores] == [s.item for s in alone.item_scores], query.user
+        assert 1 <= len(answer.item_scores) <= 5  # (a session of 500 leaves few of 120 items)
+        np.testing.assert_allclose(
+            [s.score for s in answer.item_scores], [s.score for s in alone.item_scores], atol=ATOL, rtol=0
+        )
+
+
+def test_warmup_serving_compiles_every_shape_of_the_closed_set(stacking):
+    """After the warm-up no batch compiles: not one query, not streams short
+    of a four, not a four with leftovers and a long stream beside it."""
+    from jax import monitoring
+
+    algorithm, model, users = stacking
+    compiled = []
+
+    def listener(event, duration_secs, **kw):
+        if event.endswith("/backend_compile_duration"):
+            compiled.append(event)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    algorithm.warmup_serving(model, 64)
+    warmed, traced = len(compiled), model.program().session_vectors._cache_size()
+    for lengths, _ in BATCHES.values():
+        pool = {n: [u for u in users if len(model.session_tokens(Query(user=u))) == n] for n in set(lengths)}
+        answers = algorithm.predict_batch(model, [Query(user=pool[n].pop(), num=10) for n in lengths])
+        assert len(answers) == len(lengths) and all(a.item_scores for a in answers)
+    assert len(compiled) == warmed and model.program().session_vectors._cache_size() == traced
+
+
 # -------------------------------------------------------------- the engine
 
 
@@ -528,13 +699,14 @@ def test_save_then_load_is_equal_bit_for_bit_and_the_manifest_names_the_backbone
 
 
 @pytest.mark.parametrize("users", [(0,), (0, 1, 2, 3, 4, 5, 6, 7), (3, 3, 9, 1)])
-def test_the_answer_hook_launches_one_prefill_and_one_top_k_a_stream_and_counts_as_before(
+def test_the_answer_hook_launches_one_prefill_and_one_top_k_a_program_and_counts_as_before(
     trained, users, monkeypatch
 ):
     """``predict_batch_dispatch`` stages, then ``_answer`` (the hook a
     generating backbone answers otherwise) launches: for this backbone one
-    ``session_vectors`` and one ``dot_top_k_async`` a stream, the counters of
-    before, and none of a generation's."""
+    ``session_vectors`` and one ``dot_top_k_async`` a PROGRAM (``_programs``:
+    a stream, or several as its rows), the counters of before, and none of a
+    generation's."""
     from predictionio_tpu.models.sequential.engine import BackboneAlgorithm
     from predictionio_tpu.ops import topk
 
@@ -556,15 +728,18 @@ def test_the_answer_hook_launches_one_prefill_and_one_top_k_a_stream_and_counts_
     monkeypatch.setattr(topk, "dot_top_k_async", counted("dot_top_k_async", ending))
     queries = [Query(user=f"u{u}", num=4) for u in users]
     sessions, streams = algorithm._plan(model, queries)
+    programs = algorithm._programs(model, streams)
+    assert sorted(i for rows in programs for i in rows) == list(range(len(streams)))
     answers = algorithm.predict_batch_dispatch(model, queries)()
     assert [len(a.item_scores) for a in answers] == [4] * len(users)
     assert all(s.step is None and set(s.to_json_dict()) == {"item", "score"} for a in answers for s in a.item_scores)
-    assert calls == {"session_vectors": len(streams), "dot_top_k_async": len(streams)}
+    assert calls == {"session_vectors": len(programs), "dot_top_k_async": len(programs)}
     counters = algorithm.instruments
     real = sum(len(s) for s in sessions)
     assert counters.tokens.value(kind="real") == real
     assert counters.tokens.value(kind="padded") == sum(length for length, _ in streams)
-    assert sum(counters.programs.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(streams)
+    assert sum(counters.programs.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(programs)
+    assert sum(counters.rows.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(streams)
     assert sum(counters.sessions.value(bucket=str(b)) for b in model.config.stream_shapes()) == len(users)
     assert counters.batches.value() == 1 and counters.stage_seconds.value() > 0
     assert counters.expert_tokens_mean.value() == model.config.even_expert_load(real)

@@ -260,3 +260,50 @@ def test_the_sdar_cells_programs_compile_for_v5e_beside_the_model(one_chip, monk
     assert 8.4e9 < memory.argument_size_in_bytes < 9.3e9  # (a prefill reads no `lm_head`)
     # the cache is updated where it lies
     assert memory.alias_size_in_bytes >= config.cache_bytes(config.cache_slots)
+
+
+# the scorers' STACKED programs (four token streams as the rows of one, PR 38:
+# what ``olmoe`` serves, and what ``kimi_linear`` takes though its engine sends
+# one row) at their cells' widths: (the benchmark's engine, its configuration,
+# the most the temporaries may take, the kernels a layer body holds: OLMoE's
+# layers are one scanned body, Kimi-Linear's unrolled)
+STACKED = {
+    "olmoe": ("sequential_olmoe", "seq-olmoe", 1.5e9, 4),
+    "kimi_linear": ("sequential_kimi_linear", "seq-kimi-linear", 2.5e9, 2 + 3 * 7),
+}
+
+
+@pytest.mark.parametrize("backbone", list(STACKED))
+def test_the_scorers_stacked_programs_compile_for_v5e_beside_the_model(one_chip, monkeypatch, backbone):
+    """``session_vectors`` over ``[4, TOKEN_BUDGET]`` tokens, the tallest
+    program of ``olmoe``'s closed set: the served weights are its arguments
+    and its temporaries (the experts' copies of every row's tokens) stay a
+    small part of what the model leaves of the chip's 16.9 GB."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    from predictionio_tpu.models.sequential import engine_factory
+
+    engine_name, cell, temporaries, kernels = STACKED[backbone]
+    engine = importlib.import_module(f"benchmark.engines.{engine_name}")
+    program = importlib.import_module(f"predictionio_tpu.models.sequential.{backbone}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    file = json.loads((Path(engine.__file__).parents[1] / "configs" / f"{cell}.json").read_text())
+    config = engine_factory().engine_params_from_variant(engine.variant_of(file, 5)).algorithms[0][1].config()
+    weights = {
+        name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in program.weight_shapes(config).items()
+    }
+    rows, budget = 4, program.TOKEN_BUDGET
+    assert program.STACKED_ROWS in (1, rows) and config.stream_shapes()[0] == budget
+    stream = _shape(one_chip, (rows, budget), jnp.int32)
+    last = _shape(one_chip, (rows, budget // program.SESSION_ALIGN), jnp.int32)
+    compiled = program.session_vectors.lower(weights, stream, stream, stream, last, config=config).compile()
+    memory = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    assert memory.temp_size_in_bytes < temporaries
+    assert 6.5e9 < memory.argument_size_in_bytes < 8e9
+    vectors, _ = jax.eval_shape(
+        lambda *a: program.session_vectors(*a, config=config), weights, stream, stream, stream, last
+    )
+    assert vectors.shape == (rows * (budget // program.SESSION_ALIGN), config.hidden_size)
