@@ -32,6 +32,7 @@ import asyncio
 import collections
 import dataclasses
 import datetime as _dt
+import functools
 import json
 import logging
 import os
@@ -383,7 +384,10 @@ class _Slot:
     the moment ``finalize`` has the packed results on the host and before
     it serves them, or ``release`` on the event loop (a failed dispatch, and
     the end of the batch's ``_finish`` task however it ends: result,
-    exception, watchdog trip, cancellation)."""
+    exception, watchdog trip, cancellation). ``device_answered`` also reads
+    the fetch thread's clock and hands the reading to ``on_answer`` on the
+    loop, with the release: the batcher counts from it how long the device
+    owed this answer (``_MicroBatcher._answered``)."""
 
     __slots__ = ("_slots", "_loop", "_held")
 
@@ -397,9 +401,17 @@ class _Slot:
             self._held = False
             self._slots.release()
 
-    def device_answered(self) -> None:
+    def _hand_back(self, on_answer, *answer) -> None:
+        self.release()
+        on_answer(*answer)
+
+    def device_answered(self, on_answer, *args) -> None:
+        """Runs on the fetch thread; ``on_answer(*args, the answer's time)``
+        runs on the loop."""
         try:
-            self._loop.call_soon_threadsafe(self.release)
+            self._loop.call_soon_threadsafe(
+                self._hand_back, on_answer, *args, time.perf_counter()
+            )
         except RuntimeError:
             pass  # the loop closed under a finalize that outlived shutdown
 
@@ -472,6 +484,15 @@ class _MicroBatcher:
         self._batch_seq = 0
         self.watchdog_trips = 0  # batches failed for blowing their deadline
         self.shed_count = 0  # requests rejected by admission control
+        # queries of dispatched batches whose futures are not resolved yet:
+        # up where a batch's `_finish` is scheduled, down in `_settled`
+        self._inflight = 0
+        # batches whose `_finish` task has not taken its first step, by batch
+        # number: the task's body answers a cancelled batch, and `close()`
+        # may cancel a task before its body ever runs
+        self._unstarted: dict[int, list[_QItem]] = {}
+        # the loop's clock at the device's newest answer (`_answered`)
+        self._last_answer = 0.0
 
     @property
     def queue_depth(self) -> int:
@@ -529,6 +550,33 @@ class _MicroBatcher:
             if not item.fut.done():
                 item.fut.set_exception(exc)
 
+    def _tick(self, state: str, since: float) -> float:
+        """Add the time from ``since`` to now to one state of the loop's
+        clock (``pio_batch_loop_seconds_total``); returns now, the next
+        interval's start, so that consecutive intervals tile."""
+        now = time.perf_counter()
+        self._server._m_loop.inc(now - since, state=state)
+        return now
+
+    def _answered(self, dispatch_end: float, t_answered: float) -> None:
+        """The device answered a batch at ``t_answered``: it owed this answer
+        since the later of its previous answer and the end of this batch's
+        dispatch, so a server with nothing dispatched adds no time. The gaps
+        and their squares are summed: the quotient of the two sums' growth is
+        the gap a random moment of the busy time falls into."""
+        gap = max(0.0, t_answered - max(self._last_answer, dispatch_end))
+        self._last_answer = max(self._last_answer, t_answered)
+        self._server._m_answer_gap.inc(gap)
+        self._server._m_answer_gap_squared.inc(gap * gap)
+
+    def _settled(self, queries: int, _finished: asyncio.Task) -> None:
+        """Done-callback of a batch's ``_finish`` task, however it ended:
+        result, exception, watchdog trip or cancellation. It is given the
+        batch's SIZE and not the batch: a callback that keeps the queries
+        alive until it runs, behind their callers' wake-ups, cost the
+        saturated webgraph cell its first seconds (PERF.md section 6, PR 39)."""
+        self._inflight -= queries
+
     def _dispatch_combined(self, items: list[_QItem], batch_no: int = 0):
         """Idle fast path: dispatch AND finalize in ONE executor hop.
 
@@ -582,18 +630,28 @@ class _MicroBatcher:
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
+        # the loop's clock (`_tick`): every interval from here on goes to one
+        # of idle, the slot wait's own counter, collect and dispatch
+        mark = time.perf_counter()
+        # `submit` starts this task on an arrival: its first turn is a wake
+        started = True
         while True:
             # slot first, batch closed last: wait for a pending query, then
             # for a slot, and only then drain the queue into the batch. A
             # cancellation (close()) at any of these awaits holds nothing:
             # the queries are still queued and close() answers them
+            woken, started = started or not self._queue, False
             if not self._queue:
                 self._arrived.clear()
                 await self._arrived.wait()
             if self.window_s > 0:
                 await asyncio.sleep(self.window_s)
             queued_before = len(self._queue)
-            wait_t0 = time.perf_counter()
+            # what this turn last waited on is what closes its batch: a slot
+            # (whatever arrives meanwhile rides along), else the first
+            # arrival on an empty queue, else the previous batch's dispatch
+            after = "slot" if self._slots.locked() else "idle" if woken else "dispatch"
+            wait_t0 = self._tick("idle", mark)
             await self._slots.acquire()
             slot = _Slot(self._slots, loop)
             collect_t = time.perf_counter()
@@ -606,6 +664,8 @@ class _MicroBatcher:
                 # would only deepen an overload
                 live = []
                 joined = 0
+                cut = len(self._queue) > self.max_batch
+                inflight = self._inflight
                 for arrival in range(min(len(self._queue), self.max_batch)):
                     item = self._queue.popleft()
                     if item.fut.done():  # client gone / cancelled
@@ -627,6 +687,7 @@ class _MicroBatcher:
                         )
                 if not live:
                     slot.release()
+                    mark = self._tick("collect", collect_t)
                     continue
                 batch = live
                 batch_deadline = Deadline.min_of([it.deadline for it in batch])
@@ -643,7 +704,7 @@ class _MicroBatcher:
                     and not self._queue
                     and not self._finish_tasks
                 )
-                dispatch_t0 = time.perf_counter()
+                dispatch_t0 = self._tick("collect", collect_t)
                 # the batch list itself is the handoff — the dispatch
                 # thread reads payload/trace_id straight off the queued
                 # items (no per-batch tuple-list materialization)
@@ -683,6 +744,7 @@ class _MicroBatcher:
                     batch,
                     DeadlineExceeded("micro-batch dispatch: deadline exceeded"),
                 )
+                mark = self._tick("dispatch", dispatch_t0)
                 continue
             dispatch_s = time.perf_counter() - dispatch_t0
             try:
@@ -693,17 +755,21 @@ class _MicroBatcher:
                 for item in batch:
                     if not item.fut.done():
                         item.fut.set_exception(exc)
+                mark = self._tick("dispatch", dispatch_t0)
                 continue
             if getattr(finalize, "resolved", False):
                 # combined fast path: the measured dispatch window swallowed
                 # device compute + serve; carve them back out so _finish's
                 # device/serve observations keep the waterfall tiling
                 t = getattr(finalize, "timings", None) or {}
+                device_s = max(0.0, t.get("device_s", 0.0))
                 dispatch_s = max(
-                    0.0,
-                    dispatch_s
-                    - t.get("device_s", 0.0)
-                    - t.get("serve_s", 0.0),
+                    0.0, dispatch_s - device_s - t.get("serve_s", 0.0)
+                )
+                # the device's answer came inside the one call: the dispatch
+                # proper ended where its wait for the device began
+                self._answered(
+                    dispatch_t0 + dispatch_s, dispatch_t0 + dispatch_s + device_s
                 )
             # batch-scoped waterfall phases: every rider waits out the whole
             # batch, so each query is accounted the batch's duration
@@ -718,6 +784,11 @@ class _MicroBatcher:
             self.batches_dispatched += 1
             self.queries_dispatched += len(batch)
             self._server._m_joined_in_slot_wait.inc(joined)
+            self._server._m_closed.inc(after=after)
+            self._server._m_closed_queries.inc(len(batch), after=after)
+            if cut:
+                self._server._m_cut.inc()
+            self._server._m_inflight_at_close.inc(inflight)
             # finish asynchronously: the collect loop immediately forms and
             # dispatches the next batch while this one's fetch is in flight
             task = asyncio.ensure_future(
@@ -732,8 +803,12 @@ class _MicroBatcher:
                 )
             )
             self._finish_tasks.add(task)
+            self._inflight += len(batch)
+            self._unstarted[batch_no] = batch
             task.add_done_callback(self._finish_tasks.discard)
             task.add_done_callback(slot.release)  # if finalize has not already
+            task.add_done_callback(functools.partial(self._settled, len(batch)))
+            mark = self._tick("dispatch", dispatch_t0)
 
     async def _finish(
         self,
@@ -746,6 +821,8 @@ class _MicroBatcher:
         batch_no: int = 0,
     ) -> None:
         loop = asyncio.get_running_loop()
+        # the first step: from here on a cancellation is answered below
+        self._unstarted.pop(batch_no, None)
         fetch_t0 = time.perf_counter()
         if getattr(finalize, "resolved", False):
             # combined fast path (_dispatch_combined): the dispatch call
@@ -768,7 +845,9 @@ class _MicroBatcher:
             # moment the device's results are on the host: the next batch is
             # closed and dispatched while this one is still being served
             exec_fut = loop.run_in_executor(
-                self._fetch_pool, finalize, device_answered
+                self._fetch_pool,
+                finalize,
+                functools.partial(device_answered, self._answered, dispatch_end),
             )
             exec_fut.add_done_callback(_swallow_result)
             try:
@@ -874,8 +953,12 @@ class _MicroBatcher:
             self._cancelled_tasks.append(task)
         # fail everything still queued: enqueued-but-never-collected items
         # have handlers awaiting their futures (collected/dispatched batches
-        # are resolved by the _run/_finish cancellation paths)
+        # are resolved by the _run/_finish cancellation paths, and a batch
+        # whose _finish was cancelled before its first step right here)
         exc = ShuttingDownError()
+        for batch in self._unstarted.values():
+            self._fail_batch(batch, exc)
+        self._unstarted.clear()
         while self._queue:
             item = self._queue.popleft()
             if not item.fut.done():
@@ -993,6 +1076,61 @@ class QueryServer:
             "pio_batch_joined_in_slot_wait_total",
             "queries of dispatched micro-batches that arrived after the "
             "batcher began waiting for that batch's slot",
+        )
+        # a batch's life between `submit` and the device's answer, on the
+        # loop's clock, once a batch (docs/observability.md, "Reading a
+        # batch's life"); every label's series stands at 0 from the start
+        self._m_loop = m.counter(
+            "pio_batch_loop_seconds_total",
+            "seconds of the micro-batcher's loop by what it was doing: "
+            "state=idle the queue was empty (the flush window's sleep too), "
+            "state=collect a slot in hand to the batch's hand-off to the "
+            "dispatch thread, state=dispatch awaiting that thread (and the "
+            "loop's own way back to the batcher) to the scheduling of the "
+            "batch's finish; with "
+            "pio_batch_slot_wait_seconds_total they tile the loop's wall",
+            labelnames=("state",),
+        )
+        self._m_closed = m.counter(
+            "pio_batch_closed_total",
+            "dispatched micro-batches by what the loop last waited on before "
+            "it drained the queue: after=slot a slot (both were taken when it "
+            "asked), after=idle the first arrival on an empty queue with a "
+            "slot free, after=dispatch neither (back from the previous "
+            "batch's dispatch to a queue that was not empty)",
+            labelnames=("after",),
+        )
+        self._m_closed_queries = m.counter(
+            "pio_batch_closed_queries_total",
+            "queries of dispatched micro-batches, by the same label",
+            labelnames=("after",),
+        )
+        for state in ("idle", "collect", "dispatch"):
+            self._m_loop.inc(0.0, state=state)
+        for after in ("slot", "idle", "dispatch"):
+            self._m_closed.inc(0.0, after=after)
+            self._m_closed_queries.inc(0.0, after=after)
+        self._m_cut = m.counter(
+            "pio_batch_cut_total",
+            "dispatched micro-batches whose drain stopped at the batch limit "
+            "with queries left queued: over pio_batch_closed_total, the share "
+            "of batches that the limit closed and not the slot",
+        )
+        self._m_inflight_at_close = m.counter(
+            "pio_batch_inflight_at_close_total",
+            "queries of earlier batches not yet answered to their callers, "
+            "summed over the moments a dispatched micro-batch was closed",
+        )
+        self._m_answer_gap = m.counter(
+            "pio_batch_answer_gap_seconds_total",
+            "seconds the device owed an answer: from the later of its "
+            "previous answer and the end of the batch's dispatch to the "
+            "batch's answer, summed over batches",
+        )
+        self._m_answer_gap_squared = m.counter(
+            "pio_batch_answer_gap_squared_seconds_total",
+            "the same gaps squared and summed: over the sum of the gaps, the "
+            "gap a random moment of the busy time falls into",
         )
         # what power-of-two bucketing launched (ops/topk.batch_bucket keeps
         # the tallies, the engines know no server): mirrored at scrape

@@ -173,8 +173,8 @@ async def the_slot_comes_back_once_after_a_watchdog_trip(server, device):
 
 async def the_slot_comes_back_once_after_a_cancellation(server, device):
     first = [ask(server, 1), ask(server, 2)]
-    # its finalize runs on a fetch thread: _finish is past its first step (a
-    # task cancelled before that never runs the body that fails the batch)
+    # its finalize runs on a fetch thread: _finish is past its first step (one
+    # cancelled before that: tests/test_microbatcher_lifecycle.py)
     await until(lambda: device.batches and device.batches[0].thread)
     (finishing,) = server._batcher._finish_tasks
     finishing.cancel()
