@@ -102,7 +102,12 @@ def _exact_device_table(model: Any):
     if hasattr(model, "device_factors"):
         return model.device_factors()
     if hasattr(model, "serving_index"):
-        return model.serving_index().item_factors
+        # ALSModel: its ServingIndex keeps the item table at the width the
+        # product multiplies in (ops/topk.item_table_dtype), so the float32
+        # rows are an upload of their own, as the other templates' are
+        import jax.numpy as jnp
+
+        return jnp.asarray(model.item_factors, jnp.float32)
     return None
 
 

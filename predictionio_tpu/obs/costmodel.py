@@ -118,7 +118,8 @@ def topk_costs(
 ) -> tuple[list[dict[str, Any]], int]:
     """The score->mask->top-k serving programs (ops/topk.PROGRAMS), each
     with the operands its engines pass: the by-index front its all-true
-    [n] mask, the other two a [B, n] mask and no weights."""
+    [n] mask and its item table at the width ``ServingIndex`` stores it in,
+    the other two a [B, n] mask and no weights."""
     import jax
     import jax.numpy as jnp
 
@@ -129,7 +130,10 @@ def topk_costs(
     mask = S((b, n), jnp.bool_)
     operands = {
         "_serve_by_index_batch": (
-            S((b,), jnp.int32), table, table, S((n,), jnp.bool_),
+            S((b,), jnp.int32),
+            table,
+            S((n, f), topk.item_table_dtype()),
+            S((n,), jnp.bool_),
         ),
         "_dot_top_k": (table, S((b, f), jnp.float32), mask, None),
         "_gather_sum_top_k": (

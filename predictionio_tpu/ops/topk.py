@@ -10,7 +10,9 @@ them.
     (the two-tower's tower -> scores) ends in it too.
   - THREE jitted fronts that make the scores and end in the body
     (``PROGRAMS``): ``_serve_by_index_batch`` (gather user rows by index
-    from a resident table, then the product: ``ServingIndex``),
+    from a resident table, then the product: ``ServingIndex``, which keeps
+    the item table every batch reads whole in the type the platform's
+    product multiplies in, :func:`item_table_dtype`),
     ``_dot_top_k`` (query vectors given: ``dot_top_k_async``) and
     ``_gather_sum_top_k`` (gather, weight and sum the query rows:
     ``gather_sum_top_k_async``). An operand a caller leaves out (mask,
@@ -68,10 +70,12 @@ __all__ = [
     "fetch_topk",
     "gather_sum_top_k_async",
     "host_top_k",
+    "item_table_dtype",
     "next_pow2",
     "pack_batch",
     "scratch",
     "select_top_k",
+    "table_bytes",
     "unpack_batch",
     "upload",
     "warmup_pow2_buckets",
@@ -124,6 +128,18 @@ def bucket_counts() -> tuple[int, int, dict[int, int]]:
         sum(bucket * batches for bucket, (batches, _) in tallies.items()),
         {bucket: batches for bucket, (batches, _) in tallies.items()},
     )
+
+
+# the resident tables' bytes, process-wide too ({table: bytes} of the
+# ``ServingIndex`` built last) and mirrored the same way
+# (``pio_serve_table_bytes{table}``)
+_table_bytes = {"item": 0, "user": 0}
+
+
+def table_bytes() -> dict[str, int]:
+    """``{"item": bytes, "user": bytes}`` of the newest ``ServingIndex``."""
+    with _bucket_lock:
+        return dict(_table_bytes)
 
 
 def upload(x, dtype=None):
@@ -214,11 +230,18 @@ def select_top_k(scores, k: int, mask=None, weights=None, log_sum_exp: bool = Fa
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _serve_by_index_batch(uidxs, user_factors, item_factors, mask, k: int):
-    """Both tables resident; uidxs [B] int32 is the whole upload."""
+    """Both tables resident; uidxs [B] int32 is the whole upload. The
+    product multiplies in the type ``item_factors`` is STORED in
+    (:func:`item_table_dtype`): the B gathered float32 user rows are cast to
+    it, the item rows are read as they lie, the scores are float32."""
     with jax.named_scope("gather"):
         user_vecs = user_factors[uidxs]
     with jax.named_scope("score"):
-        scores = user_vecs @ item_factors.T  # [B, n_items] on the MXU
+        scores = jnp.matmul(  # [B, n_items] on the MXU
+            user_vecs.astype(item_factors.dtype),
+            item_factors.T,
+            preferred_element_type=jnp.float32,
+        )
     return select_top_k(scores, k, mask)
 
 
@@ -280,6 +303,21 @@ def gather_sum_top_k_async(table, qidx, qweight, mask, k: int, weights=None):
     )
 
 
+def item_table_dtype():
+    """The type a resident ITEM table is stored in: the one the platform's
+    default float32 product multiplies in. On a TPU that product is ONE
+    bfloat16 pass on the MXU unless a higher ``jax_default_matmul_precision``
+    is configured, so a float32 table is read at 32 bits an entry every batch
+    and rounded to 16 inside the fusion; rounded once when it is made
+    resident it is read at the width it is multiplied in, and nothing is lost
+    that the program did not already drop. Everywhere else a float32 product
+    multiplies float32, and the table stays float32."""
+    one_pass = jax.config.jax_default_matmul_precision in (None, "default", "bfloat16")
+    if jax.default_backend() == "tpu" and one_pass:
+        return jnp.bfloat16
+    return jnp.float32
+
+
 class ServingIndex:
     """Device-resident factor tables with index-addressed top-k serve.
 
@@ -287,12 +325,20 @@ class ServingIndex:
     (``CreateServer.scala:196-200`` deserializes the kryo model into the
     server heap; here the model lives in HBM and every batch is one compiled
     program). Per-batch cost: one [B] int32 upload + one [B,2,k] int32 fetch.
+
+    The item table, which every batch reads whole, is kept in
+    :func:`item_table_dtype` and in no other copy (round-to-nearest-even,
+    once, here); the user table, read B rows a batch, stays as it is given.
     """
 
     def __init__(self, user_factors, item_factors):
         self.user_factors = jnp.asarray(user_factors)
-        self.item_factors = jnp.asarray(item_factors)
+        self.item_factors = jnp.asarray(item_factors).astype(item_table_dtype())
         self._full_mask = jnp.ones((self.item_factors.shape[0],), bool)
+        with _bucket_lock:
+            _table_bytes.update(
+                item=self.item_factors.nbytes, user=self.user_factors.nbytes
+            )
 
     @property
     def n_users(self) -> int:

@@ -1145,6 +1145,13 @@ class QueryServer:
             "serving batches launched, by power-of-two bucket",
             labelnames=("bucket",),
         )
+        self._m_serve_table_bytes = m.gauge(
+            "pio_serve_table_bytes",
+            "bytes of the factor tables a ServingIndex holds on the device: "
+            "table=item is read whole by every batch and kept at the width "
+            "the product multiplies in (ops/topk.item_table_dtype)",
+            labelnames=("table",),
+        )
         m.register_collector(self._collect_buckets)
         # the interpreter's collection pauses (hook installed by start(),
         # removed by stop())
@@ -2286,14 +2293,18 @@ class QueryServer:
             logger.info("result cache: flushed %d entries (%s)", n, why)
 
     def _collect_buckets(self) -> None:
-        """Registry collector: mirror ops/topk's bucket tallies (a process
-        that never imported it has launched no bucket: explicit zeros)."""
+        """Registry collector: mirror ops/topk's bucket tallies and the bytes
+        of its resident tables (a process that never imported it has launched
+        no bucket and holds no table: explicit zeros)."""
         topk = sys.modules.get("predictionio_tpu.ops.topk")
         real, bucket, batches = topk.bucket_counts() if topk else (0, 0, {})
         self._m_serve_rows.set_total(real, kind="real")
         self._m_serve_rows.set_total(bucket, kind="bucket")
         for size, count in batches.items():
             self._m_serve_batches.set_total(count, bucket=str(size))
+        tables = topk.table_bytes() if topk else {"item": 0, "user": 0}
+        for table, nbytes in tables.items():
+            self._m_serve_table_bytes.set(nbytes, table=table)
 
     def _collect_cache(self) -> None:
         """Scrape-time mirror of the cache's monotonic stats into the

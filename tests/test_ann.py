@@ -552,6 +552,31 @@ class TestStreamRefreshE2E:
         assert report is None
 
 
+def test_the_rescore_table_of_an_als_model_is_float32_whatever_its_index_stores(
+    monkeypatch,
+):
+    # ServingIndex keeps its item table at the width its product multiplies in
+    # (bfloat16 on the chip: forced here); the int8 rescore gathers survivor
+    # rows at full precision, from an upload of the model's own rows
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.recommendation.engine import ALSModel
+    from predictionio_tpu.ops import topk
+
+    monkeypatch.setattr(topk, "item_table_dtype", lambda: jnp.bfloat16)
+    rng = np.random.default_rng(3)
+    model = ALSModel(
+        rng.normal(size=(5, 8)).astype(np.float32),
+        rng.normal(size=(40, 8)).astype(np.float32),
+        [f"u{i}" for i in range(5)],
+        [f"i{i}" for i in range(40)],
+    )
+    assert model.serving_index().item_factors.dtype == jnp.bfloat16
+    table = lifecycle._exact_device_table(model)
+    assert table.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(table), model.item_factors)
+
+
 # ---------------------------------------------------------------------------
 # engine integration
 # ---------------------------------------------------------------------------

@@ -319,6 +319,29 @@ def test_bucket_rows_count_queries_against_what_the_device_scored():
     assert buckets and all(b & (b - 1) == 0 for b in buckets)
 
 
+@pytest.mark.parametrize("item_bytes", [4, 2], ids=["float32", "bfloat16"])
+def test_the_resident_tables_bytes_are_scraped_at_the_width_they_are_stored_in(
+    monkeypatch, item_bytes
+):
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import topk
+
+    if item_bytes == 2:  # the chip's answer, forced: off the chip it is float32
+        monkeypatch.setattr(topk, "item_table_dtype", lambda: jnp.bfloat16)
+    server = make_server()
+
+    async def body():
+        await submit_all(server, [1])  # the first query builds the index
+        server._batcher.close()
+        await server._batcher.wait_closed()
+
+    asyncio.run(body())
+    scraped = metrics_of(server)
+    assert scraped['pio_serve_table_bytes{table="item"}'] == 30 * 8 * item_bytes
+    assert scraped['pio_serve_table_bytes{table="user"}'] == 40 * 8 * 4
+
+
 def test_gc_hook_counts_full_collections_and_is_gone_after_stop(recorded):
     server = make_server()
 

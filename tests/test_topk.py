@@ -241,6 +241,56 @@ class TestServingIndex:
         np.testing.assert_allclose(bs[3, :10], scores, rtol=1e-6)
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+@pytest.mark.parametrize("bucket", [1, 8, 32])
+def test_the_item_table_is_stored_in_the_type_the_product_multiplies_in(
+    monkeypatch, bucket, masked
+):
+    # the storage rule is read off the platform (item_table_dtype); forced to
+    # the chip's answer here, the served answer is that of a float32 index
+    # whose operands were rounded to bfloat16 first: one rounding, made once
+    import jax.numpy as jnp
+
+    # at the cells' width: over 128 terms the roundings average out as they do
+    # on the chip (2^-9.3 of Σ|u·v| at worst there: benchmark/reference.py)
+    f = 128
+    users, items = _tables(f=f, seed=5)
+    uidx = np.random.default_rng(6).integers(0, USERS, bucket).astype(np.int32)
+    mask = _mask("n", bucket) if masked else None
+    k = 16
+
+    def rounded(x):
+        return np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
+
+    plain = topk.ServingIndex(users, items)  # off the chip, unforced
+    assert plain.item_factors.dtype == jnp.float32
+    twin = topk.ServingIndex(rounded(users), rounded(items))
+    monkeypatch.setattr(topk, "item_table_dtype", lambda: jnp.bfloat16)
+    forced = topk.ServingIndex(users, items)
+    assert forced.item_factors.dtype == jnp.bfloat16
+    assert forced.user_factors.dtype == jnp.float32
+    assert not [
+        name
+        for name, held in vars(forced).items()
+        if getattr(held, "shape", None) == items.shape and held.dtype == jnp.float32
+    ]
+    assert topk.table_bytes() == {"item": N * f * 2, "user": USERS * f * 4}
+
+    got_s, got_i = topk.fetch_topk(forced.serve_batch_async(uidx, k, mask))
+    want_s, want_i = topk.fetch_topk(twin.serve_batch_async(uidx, k, mask))
+    assert got_s.dtype == np.float32 and got_s.shape == (bucket, k)
+    np.testing.assert_array_equal(got_i, want_i)
+    u64, v64 = users.astype(np.float64)[uidx], items.astype(np.float64)
+    at = lambda dense, idx: np.take_along_axis(dense, idx, axis=1)
+    magnitude = at(np.abs(u64) @ np.abs(v64).T, got_i)  # Σ|u·v| of each answer
+    assert np.all(np.abs(got_s - want_s) <= 1e-6 * magnitude)
+    exact = at(u64 @ v64.T, got_i)
+    for served in (got_s, want_s):
+        assert np.all(np.abs(served - exact) <= 2.0**-8 * magnitude)
+    if mask is not None:
+        assert mask[got_i].all()
+
+
 def test_top_k_by_vector_and_mask():
     vf = np.diag(np.arange(1.0, 6.0)).astype(np.float32)  # 5 items, rank 5
     user = np.ones((1, 5), np.float32)

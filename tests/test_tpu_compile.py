@@ -77,6 +77,33 @@ def test_als_step_at_the_benchmark_shape_holds_its_systems_unpadded(
     assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
 
 
+@pytest.mark.parametrize("bucket", [8, 32, 128])
+def test_the_serve_program_reads_its_bfloat16_item_table_as_it_lies(one_chip, bucket):
+    """``_serve_by_index_batch`` at the webgraph cells' shape with the item
+    table as ``ServingIndex`` stores it on the chip: the ``[n, f]`` bfloat16
+    table goes into the product's fusion as it lies (a copy, a transpose or
+    a convert of it would cost 1.46 GB and more a batch: PERF.md section 6,
+    PR 40), the scores are float32, and the temporaries are the scores."""
+    import re
+
+    from predictionio_tpu.ops import topk
+
+    n, f = 5_700_000, 128
+    compiled = topk._serve_by_index_batch.lower(
+        _shape(one_chip, (bucket,), jnp.int32),
+        _shape(one_chip, (n, f)),
+        _shape(one_chip, (n, f), jnp.bfloat16),
+        _shape(one_chip, (n,), jnp.bool_),
+        k=16,
+    ).compile()
+    text = compiled.as_text()
+    whole_table = rf"= \w+\[({n},{f}|{f},{n})\]\S* (copy|transpose|convert)\("
+    assert not re.findall(whole_table, text)
+    (product,) = re.findall(r"= (\w+)\[\d+,\d+\]\S* convolution\(", text)
+    assert product == "f32"
+    assert compiled.memory_analysis().temp_size_in_bytes <= bucket * n * 4 * 1.01
+
+
 # latent attention expanded for a prefill: keys of 192, values of 128; the
 # tiled kernel over a packed stream at the Kimi-Linear cell's two program
 # shapes, and the single-block kernel (L under 1,024) and the tiled one
