@@ -31,7 +31,11 @@ sigmoid-routed experts). The ``sdar`` algorithm is a third, and the first
 that GENERATES: SDAR-30B-A3B-Chat's block (``sdar.py``: grouped queries
 under a block-causal mask, 128 softmax-routed experts) answers with ``num``
 items in order, produced block by block by masked diffusion over a cache of
-keys and values that lives for the batch. Every backbone's weights are drawn
+keys and values that lives for the batch. The ``lfm2`` scorer is a fourth, and
+the first whose program holds a model's WHOLE depth: LFM2-8B-A1B (``lfm2.py``:
+24 layers, gated short convolutions in 18 and grouped-query attention at a
+head width of 64 in 6, two dense feed-forwards and then the chip's share of 32
+sigmoid-routed experts). Every backbone's weights are drawn
 from a seed, not fitted: fitting a backbone is not this engine's work yet
 (ROADMAP R7).
 """
@@ -725,7 +729,7 @@ class AttentionAlgorithm(JaxAlgorithm):
 
 # ---------------------------------------------------------------------------
 # Backbone algorithms (one prefill through a language model's block -> fused
-# top-k): `olmoe` and `kimi_linear` share the model, its storage, the staging
+# top-k): `olmoe`, `kimi_linear` and `lfm2` share the model, its storage, the staging
 # and the launch; the backbone's module and its parameters are what differs
 # ---------------------------------------------------------------------------
 
@@ -961,6 +965,72 @@ class SdarAlgorithmParams(Params):
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class Lfm2AlgorithmParams(Params):
+    """The published ``config.json`` of LiquidAI/LFM2-8B-A1B, key for key (a
+    variant file carries them verbatim), the seed the weights are drawn from,
+    and the chip's share of a stated deployment: ``experts_held`` ``[first,
+    count]`` of the router's ``num_experts`` (all of them by default).
+    ``layer_types`` is the published LIST, a mixer's kind a layer. The keys
+    the program has one answer for are refused at any other value rather than
+    ignored."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_hidden_layers: int = 24
+    layer_types: tuple = ("conv", "conv", "full_attention", "conv") * 5 + (
+        "conv", "full_attention", "conv", "conv",
+    )
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    num_dense_layers: int = 2
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    vocab_size: int = 65536
+    max_position_embeddings: int = 128000
+    model_type: str = "lfm2_moe"
+    experts_held: tuple | None = None
+    seed: int = 3
+
+    def config(self):
+        from predictionio_tpu.models.sequential.lfm2 import Lfm2Config
+
+        one_answer = {
+            "model_type": "lfm2_moe", "conv_bias": False, "norm_topk_prob": True,
+            "use_expert_bias": True,
+        }
+        for key, value in one_answer.items():
+            if getattr(self, key) != value:
+                raise ValueError(f"lfm2: {key}={getattr(self, key)!r} is not implemented (only {value!r})")
+        return Lfm2Config(
+            hidden_size=self.hidden_size,
+            intermediate_size=self.intermediate_size,
+            moe_intermediate_size=self.moe_intermediate_size,
+            num_hidden_layers=self.num_hidden_layers,
+            layer_types=tuple(self.layer_types),
+            conv_L_cache=self.conv_L_cache,
+            num_attention_heads=self.num_attention_heads,
+            num_key_value_heads=self.num_key_value_heads,
+            num_dense_layers=self.num_dense_layers,
+            num_experts=self.num_experts,
+            num_experts_per_tok=self.num_experts_per_tok,
+            routed_scaling_factor=float(self.routed_scaling_factor),
+            norm_eps=self.norm_eps,
+            rope_theta=float(self.rope_theta),
+            vocab_size=self.vocab_size,
+            max_position_embeddings=self.max_position_embeddings,
+            experts_held=tuple(self.experts_held or (0, self.num_experts)),
+        )
+
+
 class BackboneModel(PersistentModel, SanityCheck):
     """A backbone's weight tree on the device, the item vocabulary (item
     ``i`` is token ``i``) and every user's session tail: the last
@@ -1011,12 +1081,14 @@ class BackboneModel(PersistentModel, SanityCheck):
         return self._user_index
 
     def head(self):
-        """``lm_head`` as ``ops/topk`` scores against it: float32 on the
-        device (the bf16 values, exactly), one conversion a model."""
+        """``lm_head`` (``embed`` where the head is tied to it and the tree
+        holds none) as ``ops/topk`` scores against it: float32 on the device
+        (the bf16 values, exactly), one conversion a model."""
         if self._head is None:
             import jax.numpy as jnp
 
-            self._head = self.weights["lm_head"].astype(jnp.float32)
+            table = self.weights.get("lm_head", self.weights["embed"])
+            self._head = table.astype(jnp.float32)
         return self._head
 
     def session_tokens(self, query: Query) -> np.ndarray:
@@ -1090,10 +1162,10 @@ def _stream_limits(model: BackboneModel) -> tuple[int, int]:
 
 class BackboneAlgorithm(JaxAlgorithm):
     """A query answered through a language model's block: what the ``olmoe``,
-    ``kimi_linear`` and ``sdar`` algorithms share, which is everything but
+    ``kimi_linear``, ``lfm2`` and ``sdar`` algorithms share, which is everything but
     the backbone's module (``model_class.program()``), its parameters and
     HOW a staged batch is answered (``_answer``, the one hook): next-item
-    scoring by one prefill (here; ``olmoe``, ``kimi_linear``) or a generation
+    scoring by one prefill (here; ``olmoe``, ``kimi_linear``, ``lfm2``) or a generation
     over the batch's cache (``SdarAlgorithm``).
 
     Train: builds the item vocabulary (item ``i`` is token ``i``) and every
@@ -1349,6 +1421,22 @@ class KimiLinearAlgorithm(BackboneAlgorithm):
     model_class = KimiLinearModel
 
 
+class Lfm2Model(BackboneModel):
+    @staticmethod
+    def program():
+        from predictionio_tpu.models.sequential import lfm2
+
+        return lfm2
+
+
+class Lfm2Algorithm(BackboneAlgorithm):
+    """``lfm2``: LFM2-8B-A1B at its whole depth (``lfm2.py``)."""
+
+    params_class = Lfm2AlgorithmParams
+    params: Lfm2AlgorithmParams
+    model_class = Lfm2Model
+
+
 class SdarModel(BackboneModel):
     @staticmethod
     def program():
@@ -1540,6 +1628,7 @@ def engine_factory() -> Engine:
             "olmoe": OlmoeAlgorithm,
             "kimi_linear": KimiLinearAlgorithm,
             "sdar": SdarAlgorithm,
+            "lfm2": Lfm2Algorithm,
         },
         Serving,
         query_class=Query,

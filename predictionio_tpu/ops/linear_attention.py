@@ -69,8 +69,10 @@ PRECISION = lax.Precision.HIGH
 _dot = functools.partial(jnp.einsum, precision=PRECISION, preferred_element_type=jnp.float32)
 
 
-def short_conv(x, w, tail=None, position=None):
-    """``silu`` of a causal depthwise convolution over positions: ``x``
+def short_conv(x, w, tail=None, position=None, activation=jax.nn.silu):
+    """``activation`` (``silu``, Kimi-Linear's; None for none, LFM2's, whose
+    caller gates the input and the output itself) of a causal depthwise
+    convolution over positions: ``x``
     [B, L, D] float32, ``w`` [taps, D] (``w[-1]`` meets the position itself),
     ``tail`` [B, taps - 1, D] the inputs that came before position 0 (zeros
     when there were none). ``position`` [B, L] int32, where several sessions
@@ -91,7 +93,7 @@ def short_conv(x, w, tail=None, position=None):
         return jnp.where((position >= taps - 1 - j)[:, :, None], reached, 0.0)
 
     y = sum(w[j] * tap(j) for j in range(taps))
-    return jax.nn.silu(y), padded[:, length:]
+    return (y if activation is None else activation(y)), padded[:, length:]
 
 
 def _triangles(q, k, b, cum):

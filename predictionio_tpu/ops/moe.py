@@ -50,6 +50,16 @@ TILING = (256, 2048, 1024)
 # [1024, 2048] x 128 live groups) a pass took 16.0 ms at 256 rows, **15.3**
 # at 128, 15.5 at 64, 16.0 at 32; at 64 rows a group no tile won.
 SHORT_GROUP = 64
+# Columns of a tile for matrices whose width ``TILING``'s 1,024 do not divide
+# and that were measured: LFM2's experts are 1,792 wide (7 x 256 = 2 x 896),
+# and at 1,024 the second tile of two is a quarter beyond the matrix. From the
+# chip (PERF.md, PR 41; ``lfm2``'s whole program of 22 sparse layers, 8 experts
+# held, ms at [1, 2048] and at [4, 2048] tokens): **45.3 / 195.5** at 896,
+# 46.0 / 201.0 at 256, 45.5 / 200.3 at 1,024 (this table's default rule);
+# 1,792 whole is refused by Mosaic (16.96 MB of scoped VMEM of 16). A width
+# with no entry keeps the rule below, so OLMoE's and Kimi-Linear's 1,024 and
+# SDAR's 768 compile to the tiles they had
+COLUMN_TILES = {1792: 896}
 
 
 def route(x, router_w, k: int, renormalise: bool = False):
@@ -70,12 +80,13 @@ def route(x, router_w, k: int, renormalise: bool = False):
     return weights, experts
 
 
-def route_sigmoid(x, router_w, bias, k: int, scale: float):
+def route_sigmoid(x, router_w, bias, k: int, scale: float, eps: float = 0.0):
     """``(weights [T, k] float32, experts [T, k] int32)`` in the sigmoid
     form: ``s = sigmoid(x @ router_w)`` over ALL experts (float32, the
     product at ``highest``, as in :func:`route`); the ``k`` chosen are the
     top ``k`` of ``s + bias`` (the bias steers the choice only); their
-    weights are ``s`` at the chosen over their sum, times ``scale``."""
+    weights are ``s`` at the chosen over their sum plus ``eps`` (LFM2's
+    published ``1e-6``; Kimi-Linear's has none), times ``scale``."""
     logits = jnp.dot(
         x.astype(jnp.float32),
         router_w.astype(jnp.float32),
@@ -84,7 +95,9 @@ def route_sigmoid(x, router_w, bias, k: int, scale: float):
     scores = jax.nn.sigmoid(logits)
     _, experts = lax.top_k(scores + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    return scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True), experts
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    # (no `+ 0.0` in Kimi-Linear's program: it compiles to what it did)
+    return scale * chosen / (total + eps if eps else total), experts
 
 
 def expert_load(experts, n_experts: int, counted=None):
@@ -137,7 +150,7 @@ def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = Fa
     tiling = (
         math.gcd(rows, lhs.shape[0]),
         min(contraction, lhs.shape[1]),
-        columns if width % 128 else min(columns, width),
+        COLUMN_TILES.get(width) or (columns if width % 128 else min(columns, width)),
     )
     return gmm(
         lhs, rhs, group_sizes, preferred_element_type=out_dtype, tiling=tiling, interpret=interpret

@@ -334,3 +334,31 @@ def test_the_scorers_stacked_programs_compile_for_v5e_beside_the_model(one_chip,
         lambda *a: program.session_vectors(*a, config=config), weights, stream, stream, stream, last
     )
     assert vectors.shape == (rows * (budget // program.SESSION_ALIGN), config.hidden_size)
+
+
+@pytest.mark.parametrize("length", [2048, 4096])
+def test_lfm2s_whole_depth_compiles_for_v5e_beside_the_model(one_chip, monkeypatch, length):
+    """``lfm2.session_vectors`` at ``seq-lfm2-moe``'s widths, all 24 layers,
+    at both lengths of its closed set: six attention kernels at a head width
+    of 64 (32 query heads over 8) and 22 × 3 grouped products at a width of
+    1,792 under the column tile ``ops/moe.COLUMN_TILES`` gives (1,792 whole
+    asks for 16.96 MB of scoped VMEM of 16 and is refused); the served
+    weights are its arguments, 5.05 GB."""
+    import json
+    from pathlib import Path
+
+    from benchmark.engines import sequential_lfm2 as engine
+    from predictionio_tpu.models.sequential import engine_factory, lfm2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    file = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-lfm2-moe.json").read_text())
+    config = engine_factory().engine_params_from_variant(engine.variant_of(file, 5)).algorithms[0][1].config()
+    weights = {name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in lfm2.weight_shapes(config).items()}
+    assert lfm2.STACKED_ROWS == 1 and config.stream_shapes() == (2048, 4096)
+    stream = _shape(one_chip, (1, length), jnp.int32)
+    last = _shape(one_chip, (1, lfm2.TOKEN_BUDGET // lfm2.SESSION_ALIGN), jnp.int32)
+    compiled = lfm2.session_vectors.lower(weights, stream, stream, stream, last, config=config).compile()
+    memory = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") == 6 + 22 * 3
+    assert memory.temp_size_in_bytes < 2.0e9
+    assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
