@@ -1334,14 +1334,18 @@ class BackboneAlgorithm(JaxAlgorithm):
             out: list[PredictedResult] = [PredictedResult(())] * len(queries)
             for places, handle, counted, real in launched:
                 scores, idx = topk.fetch_topk(handle)
-                # an integer or two a program ride back with its answer: the
-                # busiest expert's copies and, where the chip holds a share of
-                # the experts, the copies routed to a held one
+                # up to three integers a program ride back with its answer:
+                # the busiest expert's copies and, where the chip holds a
+                # share of the experts, the copies routed to a held one and
+                # the sparse layers whose held copies took more than one round
                 counted = np.atleast_1d(np.asarray(counted, np.int64))
                 routed = config.routed_copies(real)
                 held = int(counted[1]) if counted.size > 1 else routed
                 self.instruments.on_expert_load(int(counted[0]), config.even_expert_load(real))
                 self.instruments.on_copies(held, routed - held)
+                if counted.size > 2:
+                    more = int(counted[2])
+                    self.instruments.on_held_blocks(config.sparse_layers - more, more)
                 for i, place in places:
                     picks = [
                         ItemScore(model.item_vocab[int(item)], float(score))

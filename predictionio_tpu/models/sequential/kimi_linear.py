@@ -331,9 +331,11 @@ def _mla_mixer(n, segment, layer, config: KimiLinearConfig):
 
 def _layer(x, segment, position, layer, i: int, config: KimiLinearConfig):
     """Decoder layer ``i`` over ``x`` [B, L, hidden] float32: ``(x', [busiest
-    held expert's copies, copies routed to a held expert])`` of REAL tokens
-    (``segment`` not -1; zeros for a dense layer). ``segment`` and
-    ``position`` None: every row one session."""
+    held expert's copies, copies routed to a held expert, 1 if they overflowed
+    the held block (``ops/moe.held_expert_ffn`` took more than one round)])``
+    of REAL tokens (``segment`` not -1; the padding's copies get no row);
+    zeros for a dense layer. ``segment`` and ``position`` None: every row one
+    session."""
     rows, length, hidden = x.shape
     eps = config.rms_norm_eps
     kind = "kda" if config.is_kda(i) else "mla"
@@ -351,7 +353,7 @@ def _layer(x, segment, position, layer, i: int, config: KimiLinearConfig):
             n2 = _rms(h, layer["w_post"], eps).reshape(rows * length, hidden)
             y = moe.gated_mlp(n2, layer["dense_gate"], layer["dense_up"], layer["dense_down"])
             out = h + y.reshape(rows, length, hidden)
-        return out, jnp.zeros(2, jnp.int32)
+        return out, jnp.zeros(3, jnp.int32)
     first, count = config.experts_held
     with jax.named_scope("router"):
         n2 = _rms(h, layer["w_post"], eps).reshape(rows * length, hidden)
@@ -362,17 +364,18 @@ def _layer(x, segment, position, layer, i: int, config: KimiLinearConfig):
         real = None if segment is None else (segment >= 0).reshape(-1)
         load = moe.expert_load(experts - first, count, real)
     with jax.named_scope("experts"):
-        y = moe.expert_ffn(
-            n2, weights, experts, layer["gate"], layer["up"], layer["down"], held=(first, count)
+        y, rounds = moe.held_expert_ffn(
+            n2, weights, experts, layer["gate"], layer["up"], layer["down"],
+            held=(first, count, config.num_experts), counted=real,
         )
     with jax.named_scope("shared"):
         y = y + moe.gated_mlp(n2, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
         out = h + y.reshape(rows, length, hidden)
-    return out, jnp.stack([jnp.max(load), jnp.sum(load)])
+    return out, jnp.stack([jnp.max(load), jnp.sum(load), (rounds > 1).astype(jnp.int32)])
 
 
 def _layers(weights, x, segment, position, config: KimiLinearConfig):
-    counts = jnp.zeros(2, jnp.int32)
+    counts = jnp.zeros(3, jnp.int32)
     for i in range(1, config.num_hidden_layers + 1):
         x, counted = _layer(x, segment, position, layer_of(weights, i), i, config)
         counts = counts + counted
@@ -386,9 +389,10 @@ def session_vectors(weights, tokens, segment, position, last, *, config: KimiLin
     ``position`` [R, T] int32; ``last`` [R, S] int32, each session's last
     position IN ITS STREAM, -1 where a stream holds fewer than S. Returns
     the session vectors [R * S, hidden] float32, row by row (``rms(x_L;
-    w_final)`` at ``last``; one at -1 is to be thrown away) and two counts
-    of copies of REAL tokens, summed over the sparse layers: what the
-    program's busiest held expert got, and what all the held experts got."""
+    w_final)`` at ``last``; one at -1 is to be thrown away) and three counts
+    summed over the sparse layers: the copies of REAL tokens the program's
+    busiest held expert got, those all the held experts got, and the layers
+    where they overflowed the held block."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
     x, counts = _layers(weights, x, segment, position, config)

@@ -146,6 +146,15 @@ class BackboneInstruments:
             labelnames=("where",),
         )
 
+        self.held_blocks = r.counter(
+            "pio_moe_held_blocks_total",
+            "sparse layers of launched programs that lay out the held copies "
+            "alone (ops/moe.held_expert_ffn), by whether those fitted the "
+            "compact block (rounds=one) or overflowed it and ran further "
+            "rounds over the rest (rounds=more)",
+            labelnames=("rounds",),
+        )
+
     def on_generation(
         self, denoise: int, commit: int, blocks: int, items: int, cache_bytes: int
     ) -> None:
@@ -159,6 +168,10 @@ class BackboneInstruments:
     def on_copies(self, held: int, absent: int) -> None:
         self.copies.inc(float(held), where="held")
         self.copies.inc(float(absent), where="absent")
+
+    def on_held_blocks(self, one: int, more: int) -> None:
+        self.held_blocks.inc(float(one), rounds="one")
+        self.held_blocks.inc(float(more), rounds="more")
 
     def on_experts_reached(self, reached: int, offered: int) -> None:
         """One group's passes, counted with its answer's fetch."""

@@ -12,15 +12,28 @@ three GROUPED products: the tokens' copies are sorted by expert, and rows
 No token is dropped and there is no capacity factor: a group is as long as
 the router made it, whatever the imbalance.
 
-A chip may hold a SHARE of the experts its router knows (``held``): it then
-computes the held experts' part of the result for the tokens routed to them.
-The copies routed to the absent experts are sorted behind the held groups,
-meet no matrix and add nothing; no code stands in for the chips that hold
-the others or for the exchange with them.
+A chip may hold a SHARE of the experts its router knows: it then computes
+the held experts' part of the result for the tokens routed to them, in one of
+two layouts that the model's layer chooses between. ``expert_ffn(held=)``
+sorts all ``T * k`` copies, the absent behind the held groups.
+:func:`held_expert_ffn`, told the router's width, lays out the copies it
+holds and no others, every held group from a multiple of the grouped
+product's row tile, so that no tile of rows meets two experts, and sorts,
+gathers and multiplies a COMPACT block of ``held_block`` rows (a static part
+of the ``T * k``); a copy routed to an absent expert, or a padding token's,
+gets no row, meets no matrix and adds nothing. The block is a window on that
+layout: a routing whose held copies do not fit it (every token may send all
+its ``k`` to held experts) runs the same body over the next window too, under
+one ``lax.while_loop``: one round but for an overflow, and exact for any
+routing, since no copy is dropped. The loop costs a program by its sparse
+layers, so the choice is the layer's, which knows its depth (the readings
+stand beside ``HELD_ROOM``). No code stands in for the chips that hold the
+other experts or for the exchange with them.
 
 Scopes (``jax.named_scope``; the benchmark's per-layer metrics read them):
 ``router`` (the caller wraps :func:`route` in it), and inside ``experts``:
-``sort`` (argsort by expert, gather of the copies, group sizes), ``gmm``
+``sort`` (each copy's place by expert: an argsort, or the compact block's
+running sums; the gather of the copies' rows, group sizes), ``gmm``
 (the three grouped products and the gate) and ``combine`` (un-sort and the
 weighted sum over a token's ``k`` experts).
 """
@@ -36,6 +49,7 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 __all__ = [
     "route", "route_sigmoid", "expert_load", "grouped_matmul", "expert_ffn", "gated_mlp",
+    "held_block", "held_expert_ffn",
 ]
 
 # (rows, contraction, columns) of the grouped product's tiles, from the chip
@@ -60,6 +74,36 @@ SHORT_GROUP = 64
 # with no entry keeps the rule below, so OLMoE's and Kimi-Linear's 1,024 and
 # SDAR's 768 compile to the tiles they had
 COLUMN_TILES = {1792: 896}
+# The compact block of a chip that holds a SHARE of its router's experts
+# (``held_block``): room for ``HELD_ROOM`` times the copies the held experts
+# are expected to get (half of all ``T * k`` at a quarter of the experts), in
+# row tiles of the power of two at or under the rows a held group is expected
+# to have, from 8 (a vector register's sublanes: the kernel takes no less) to
+# ``TILING``'s own. From the chip (PERF.md, PR 42).
+# The tile and the room, by the WHOLE served program at [1, 2048] / [1, 4096]
+# tokens (ms; the models' own routers, four seeded streams; "all" lays out
+# all the copies, as ``expert_ffn(held=)`` does; the block's overflow as the
+# other branch of a `cond`):
+#   ``kimi_linear`` (64 of 256 held, 8 a token, 64 / 128 rows a group, seven
+#   sparse layers): all 75.5 / 153.4; **66.4 / 151.0** at this rule (tiles of
+#   64 / 128, half the copies); 65.6 / 152.9 at tiles of 128 / 256 and three
+#   quarters; tiles of 128 / 256 in half the copies OVERFLOW in every layer
+#   (64 groups x half a tile of padding beside 4,096 held copies): 76.3 / 161.5;
+#   ``lfm2`` (8 of 32, 4 a token, 256 / 512 rows a group, 22 sparse layers):
+#   all 45.2 / 100.8; **39.8 / 84.6** at this rule (tiles of 256, half the
+#   copies); 40.3 / 84.9 at tiles of 128.
+# The overflow, by the cells (`answered_qps` / cached `setup_s`, parent ->
+# change, pairs sharing a seed): as the other branch of a `lax.cond` (twice
+# the kernels a sparse layer) ``kimi_linear`` 56.5 -> 63.5 / 37.2 -> 40.7 and
+# ``lfm2`` 93.9 -> 106.7 / 43.6 -> 55.9: 12 s more to load 132 kernels' worth
+# of program, over the set-up's bound; as further ROUNDS of one body under a
+# `lax.while_loop` (the form here) ``kimi_linear`` **57.1 -> 64.1 / 38.9 ->
+# 39.8** and ``lfm2`` 93.6 -> 96.2 / 42.6 -> 47.1. The block itself is worth
+# as much at 256 rows a group as at 64; what differs is the DEPTH: a loop a
+# sparse layer costs a program of 22 unrolled layers most of what the block
+# saves and its set-up 10%, and one of 7 nothing. So ``lfm2``'s layer calls
+# ``expert_ffn(held=)`` until its sparse layers are one scanned body
+HELD_ROOM = 2
 
 
 def route(x, router_w, k: int, renormalise: bool = False):
@@ -110,7 +154,7 @@ def expert_load(experts, n_experts: int, counted=None):
     return jnp.sum(hits, axis=(0, 1), dtype=jnp.int32)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype, live=None):
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype, live=None, tile=None):
     """``lhs[offsets[g]:offsets[g+1]] @ rhs[g]`` for every group ``g``:
     ``lhs`` [M, K] sorted by group, ``rhs`` [G, K, N], ``group_sizes`` [G]
     int32 summing to M (a multiple of 8); float32 accumulation, rounded to
@@ -130,17 +174,22 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype, live=None):
     ``live`` is how many of ``rhs``'s groups ``group_sizes`` can fill, where
     that is not all of them (every layer's experts lie stacked and one
     layer's are live): the kernel takes its tile of rows from the rows a
-    live group has (``SHORT_GROUP``)."""
+    live group has (``SHORT_GROUP``). ``tile`` names the tile of rows instead,
+    where the caller has laid every group out from a multiple of it."""
     if jax.default_backend() != "tpu":
         out = lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
         return out.astype(out_dtype)
-    return grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, live=live)
+    return grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, live=live, tile=tile)
 
 
-def grouped_matmul_kernel(lhs, rhs, group_sizes, out_dtype, interpret: bool = False, live=None):
+def grouped_matmul_kernel(
+    lhs, rhs, group_sizes, out_dtype, interpret: bool = False, live=None, tile=None
+):
     """The kernel itself (``interpret`` is how a test runs it off the chip)."""
     rows, contraction, columns = TILING
-    if lhs.shape[0] < SHORT_GROUP * (live or rhs.shape[0]):
+    if tile is not None:
+        rows = tile
+    elif lhs.shape[0] < SHORT_GROUP * (live or rhs.shape[0]):
         rows //= 2
     # a contraction tile past the operand's own is masked on every step: it
     # took 1.5 times as long at [.., 1024] x [64, 1024, 2048]; a tile of
@@ -167,6 +216,61 @@ def gated_mlp(x, gate, up, down):
     return jnp.dot((jax.nn.silu(g) * u).astype(down.dtype), down, preferred_element_type=jnp.float32)
 
 
+def held_block(tokens: int, k: int, count: int, n_experts: int):
+    """``(rows, tile)`` of the compact block that ``count`` held experts of a
+    router ``n_experts`` wide get for ``tokens`` tokens at ``k`` copies each,
+    from static shapes alone: the row tile is the power of two at or under
+    the rows a held group is EXPECTED to have (``tokens * k / n_experts``),
+    from 8 to ``TILING``'s own, and the block holds ``HELD_ROOM`` times the
+    copies the held experts are expected to get, a whole number of tiles.
+    None where the block would be no less than all the copies (the share is
+    too large for it to save a row)."""
+    copies = tokens * k
+    group = max(copies // n_experts, 1)
+    tile = min(TILING[0], max(8, 1 << (group.bit_length() - 1)))
+    rows = -(-HELD_ROOM * copies * count // (n_experts * tile)) * tile
+    return (rows, tile) if rows < copies else None
+
+
+def _held_layout(experts, first: int, count: int, tile: int, counted=None):
+    """Where each copy of ``experts`` [T, k] lies among the held copies laid
+    out by expert, every group from a multiple of ``tile``: ``(place [T, k],
+    held [T, k] bool, start [count], padded [count])``. Held group ``e`` takes
+    rows ``start[e]`` to ``start[e] + padded[e]``, its size rounded up to
+    ``tile`` and ``start`` the running sum; a held copy's place is
+    ``start[e]`` plus its rank among the group's copies in token order (an
+    absent copy's means nothing). A token that ``counted`` [T] leaves out
+    holds no copy. A comparison, a running sum over the tokens and sums: no
+    sort."""
+    hits = (experts - first)[..., None] == jnp.arange(count, dtype=experts.dtype)  # [T, k, count]
+    if counted is not None:
+        hits = hits & counted[:, None, None]
+    each = jnp.sum(hits, axis=1, dtype=jnp.int32)  # a token's copies by held expert
+    before = jnp.cumsum(each, axis=0) - each  # ... and the earlier tokens'
+    padded = (before[-1] + each[-1] + tile - 1) // tile * tile
+    start = jnp.cumsum(padded) - padded
+    # a top-k names an expert once a token; a routing that names one twice
+    # keeps both copies: the later lies behind the earlier
+    earlier = jnp.tril(experts[:, :, None] == experts[:, None, :], -1)
+    place = jnp.sum(jnp.where(hits, (start + before)[:, None, :], 0), axis=2) + jnp.sum(earlier, axis=2)
+    return place, jnp.any(hits, axis=2), start, padded
+
+
+def _group_sizes(load, groups: int, first_group):
+    return lax.dynamic_update_slice(
+        jnp.zeros(groups, jnp.int32), load, (jnp.asarray(first_group, jnp.int32),)
+    )
+
+
+def _gated_products(xs, gate, up, down, sizes, live, tile=None):
+    """The three grouped products of rows ``xs`` laid out by group."""
+    # gate and up leave the kernel in the operands' type: `down` takes
+    # them in it anyway, and float32 would double what the gate moves
+    g = grouped_matmul(xs, gate, sizes, gate.dtype, live=live, tile=tile).astype(jnp.float32)
+    u = grouped_matmul(xs, up, sizes, gate.dtype, live=live, tile=tile).astype(jnp.float32)
+    return grouped_matmul((jax.nn.silu(g) * u).astype(down.dtype), down, sizes, jnp.float32, live=live, tile=tile)
+
+
 def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=0, held=None):
     """``sum_j weights[t, j] * ffn_{experts[t, j]}(x[t])`` for tokens ``x``
     [T, hidden]: ``gate`` and ``up`` [G, hidden, width], ``down``
@@ -183,7 +287,8 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
     to those; a copy routed to an absent expert is sorted behind the held
     groups, where no product reads or writes its row (the kernel leaves the
     rows past the last group as it found them), and counts as zero in the
-    combine. By default every expert the router knows is held.
+    combine (:func:`held_expert_ffn` gives such a copy no row at all). By
+    default every expert the router knows is held.
 
     Returns ``y`` [T, hidden] float32."""
     tokens, k = experts.shape
@@ -200,18 +305,10 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
             flat = jnp.where((flat >= first) & (flat < first + n_experts), flat - first, n_experts)
             order = jnp.argsort(flat, stable=True)
             load = expert_load(flat[:, None], n_experts)
-        sizes = lax.dynamic_update_slice(
-            jnp.zeros(groups, jnp.int32),
-            load,
-            (jnp.asarray(first_group, jnp.int32),),
-        )
+        sizes = _group_sizes(load, groups, first_group)
         xs = x.astype(gate.dtype)[order // k]  # [T*k, hidden]
     with jax.named_scope("gmm"):
-        # gate and up leave the kernel in the operands' type: `down` takes
-        # them in it anyway, and float32 would double what the gate moves
-        g = grouped_matmul(xs, gate, sizes, gate.dtype, live=n_experts).astype(jnp.float32)
-        u = grouped_matmul(xs, up, sizes, gate.dtype, live=n_experts).astype(jnp.float32)
-        out = grouped_matmul((jax.nn.silu(g) * u).astype(down.dtype), down, sizes, jnp.float32, live=n_experts)
+        out = _gated_products(xs, gate, up, down, sizes, n_experts)
     with jax.named_scope("combine"):
         # where each copy went: the inverse of the sort, by one scatter of
         # T*k integers, then a gather of rows (no scatter-add of rows)
@@ -223,3 +320,66 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
         out = out.reshape(tokens, k, -1)
         y = jnp.sum(out * weights[..., None], axis=1)
     return y
+
+
+def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, counted=None):
+    """:func:`expert_ffn` over the share ``held`` ``(first, count, width)``
+    of a router ``width`` experts wide, with only the held copies laid out:
+    ``(y [T, hidden] float32, rounds)``.
+
+    Group ``e`` lies from a multiple of the row tile, its copies in token
+    order, then rows of padding up to the next multiple (they read token 0's
+    row, meet the matrix and are never read back); a copy routed to an
+    absent expert gets no row, nor does any copy of a token that ``counted``
+    [T] bool leaves out (a stream's padding: its ``y`` is zero). The block
+    that is sorted, gathered and multiplied has :func:`held_block` rows, not
+    ``T * k``. It is a WINDOW on that layout: a routing whose groups take
+    more rows than it has (one that sends every copy to held experts does)
+    runs the same body again over the next window, and again, under one
+    ``lax.while_loop``, until every held copy has met its matrix. ``rounds``
+    (int32) says how many it took: ONE but for an overflow, none where no
+    copy is held. A window's edge is a tile's edge, so it may cut a group and
+    never a tile; a token whose copies lie in two windows has them summed
+    window by window, not in the order of its ``k`` (float32: the last bit
+    may differ from :func:`expert_ffn`'s). Where :func:`held_block` has no
+    block (half the experts or more held) this is ``expert_ffn(held=)``, in
+    one round."""
+    first, count, width = held
+    block = held_block(*experts.shape, count, width)
+    if block is None:
+        if counted is not None:
+            weights = jnp.where(counted[:, None], weights, 0.0)
+        y = expert_ffn(x, weights, experts, gate, up, down, first_group=first_group, held=(first, count))
+        return y, jnp.int32(1)
+    (tokens, k), (rows, tile) = experts.shape, block
+    with jax.named_scope("sort"):
+        place, at_home, start, padded = _held_layout(experts, first, count, tile, counted)
+        token = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32)[:, None], (tokens, k)).reshape(-1)
+        xs_of = x.astype(gate.dtype)
+
+    def window(carry):
+        low, y = carry
+        with jax.named_scope("sort"):
+            # the copies whose rows this window holds, and the token each of
+            # its rows reads: a scatter of T*k integers (a copy of another
+            # window's or of an absent expert falls off the end)
+            local = place - low
+            here = at_home & (local >= 0) & (local < rows)
+            source = jnp.zeros(rows, jnp.int32).at[jnp.where(here, local, rows).reshape(-1)].set(token, mode="drop")
+            # what of each group lies in the window: whole tiles, in order
+            inside = jnp.clip(start + padded, low, low + rows) - jnp.clip(start, low, low + rows)
+            sizes = _group_sizes(inside, gate.shape[0], first_group)
+            xs = xs_of[source]  # [rows, hidden]
+        with jax.named_scope("gmm"):
+            out = _gated_products(xs, gate, up, down, sizes, count, tile)
+        with jax.named_scope("combine"):
+            # a copy reads its row; one that has none here counts as zero
+            # (the select rides in the sum's own pass over the gathered rows)
+            out = out[jnp.where(here, local, 0).reshape(-1)]
+            out = jnp.where(here.reshape(-1)[:, None], out, 0.0).reshape(tokens, k, -1)
+            return low + rows, y + jnp.sum(out * weights[..., None], axis=1)
+
+    total = jnp.sum(padded)
+    y = jnp.zeros((tokens, x.shape[1]), jnp.float32)
+    low, y = lax.while_loop(lambda carry: carry[0] < total, window, (jnp.int32(0), y))
+    return y, low // rows

@@ -230,6 +230,131 @@ def test_the_chips_kernel_interpreted_serves_a_share():
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
 
 
+# the compact block (``held_expert_ffn``): 96 tokens at 4 copies over 16
+# experts of which 4 to 7 are held is a block of 192 rows in tiles of 16
+HELD_CASES = (
+    "an even router", "every copy held", "no copy held", "one held expert takes every held copy",
+    "exactly the block", "one copy over the block", "groups off the tile and NaN in the padding",
+    "stacked behind another layer", "every copy held and half the tokens padding",
+)
+
+
+def held_routing(case, tokens, k, n_experts, held, rng):
+    """``experts`` [tokens, k] of ``case``, a token's all different."""
+    first, count = held
+    rows, tile = moe.held_block(tokens, k, count, n_experts)
+    absent = [e for e in range(n_experts) if not first <= e < first + count]
+    takers = {e: [] for e in range(first, first + count)}  # the tokens routed to each held expert
+    if case in ("an even router", "stacked behind another layer"):
+        return np.stack([rng.permutation(n_experts)[:k] for _ in range(tokens)]).astype(np.int32)
+    if case.startswith("every copy held"):
+        return np.stack([first + rng.permutation(count)[:k] for _ in range(tokens)]).astype(np.int32)
+    if case == "one held expert takes every held copy":
+        takers[first + 2] = [t for t in range(tokens) if t % 2]
+    elif case in ("exactly the block", "one copy over the block"):
+        each = rows // count
+        assert each % tile == 0 and each <= tokens and count * each <= tokens * k
+        for i, e in enumerate(takers):
+            # (each expert's from another end of the stream, so that no token takes more than k)
+            takers[e] = list(range(each) if i % 2 == 0 else range(tokens - each, tokens))
+        if case == "one copy over the block":
+            takers[first + 2].append(each)
+    elif case.startswith("groups off the tile"):
+        for i, e in enumerate(takers):
+            at = 1 + i * tile  # never token 0, and no token in more than three groups
+            takers[e] = list(range(at, at + (5, tile + 1, 2 * tile - 1, 1)[i % 4]))
+    experts = np.zeros((tokens, k), np.int32)
+    for t in range(tokens):
+        mine = [e for e, ts in takers.items() if t in ts]
+        assert len(mine) <= k
+        experts[t] = mine + list(rng.permutation(absent)[: k - len(mine)])
+    return experts
+
+
+def check_held_block(case, kernel, tokens, k, n_experts, held, monkeypatch):
+    """The compact layout against the layout of all the copies and against
+    the plain float32 reference, with XLA's ``ragged_dot`` or the chip's
+    kernel interpreted."""
+    rng = np.random.default_rng(HELD_CASES.index(case))
+    first, count = held
+    x, gate, up, down, _, _ = expert_case(HELD_CASES.index(case), tokens=tokens, n_experts=n_experts)
+    experts = held_routing(case, tokens, k, n_experts, held, rng)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (tokens, k)), jnp.float32)
+    # two tokens of three are padding: their copies get no row
+    real = jnp.arange(tokens) % 3 == 0 if case.endswith("padding") else None
+    # the rounds the held groups, each from a tile's edge, take over the block
+    rows, tile = moe.held_block(tokens, k, count, n_experts)
+    sizes = [int((experts[slice(None) if real is None else np.asarray(real)] == e).sum()) for e in range(first, first + count)]
+    rounds = -(-sum(-(-size // tile) * tile for size in sizes) // rows)
+    # (twice the block's copies, and a third round where the groups end off a tile's edge)
+    assert rounds in {"no copy held": (0,), "every copy held": (2, 3), "one copy over the block": (2,)}.get(case, (1,))
+    experts = jnp.asarray(experts)
+    counted = weights if real is None else jnp.where(real[:, None], weights, 0.0)
+    own = [a[first : first + count] for a in (gate, up, down)]
+    if kernel == "interpreted":
+        monkeypatch.setattr(
+            moe, "grouped_matmul",
+            lambda lhs, rhs, sizes, dtype, **kw: moe.grouped_matmul_kernel(lhs, rhs, sizes, dtype, interpret=True, **kw),
+        )
+    at = {}
+    if case == "stacked behind another layer":
+        own, at = [jnp.concatenate([jnp.zeros_like(a), a]) for a in own], {"first_group": count}
+    whole = moe.expert_ffn(x, counted, experts, *own, held=held, **at)
+    if case.startswith("groups off the tile"):
+        # a row of padding reads token 0, which no held expert takes: what
+        # the first two products leave there is NaN, and the rows past the
+        # last group are NaN as uninitialised memory may be
+        x = x.at[0].set(jnp.nan)
+        plain = moe.grouped_matmul
+
+        def unwritten(lhs, rhs, sizes, out_dtype, **kw):
+            out = plain(lhs, rhs, sizes, out_dtype, **kw)
+            return jnp.where((jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None], out, jnp.nan)
+
+        monkeypatch.setattr(moe, "grouped_matmul", unwritten)
+    got, took = moe.held_expert_ffn(x, weights, experts, *own, held=(*held, n_experts), counted=real, **at)
+    assert int(took) == rounds
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, whole, atol=ATOL, rtol=0)
+    dense = jnp.zeros((tokens, n_experts)).at[jnp.arange(tokens)[:, None], experts].add(counted)
+    layer = dict(zip(("gate", "up", "down"), (a[-count:] for a in own)))
+    want = reference.experts(jnp.nan_to_num(x), dense, layer, list(held))
+    np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
+    if real is not None:
+        assert not np.asarray(got)[~np.asarray(real)].any()
+    if case == "no copy held":
+        assert not np.asarray(got).any()
+    else:
+        assert float(jnp.abs(got).max()) > 100 * ATOL
+
+
+# Kimi-Linear's share in small (4 of 16 held, tiles of 16) and LFM2's (8 of
+# 32 at the same 4 copies a token: 12 rows a group expected, tiles of 8)
+@pytest.mark.parametrize("kernel", ["ragged_dot", "interpreted"])
+@pytest.mark.parametrize("n_experts,held,tile", [(16, (4, 4), 16), (32, (8, 8), 8)], ids=["4 of 16", "8 of 32"])
+@pytest.mark.parametrize("case", HELD_CASES)
+def test_the_compact_block_of_the_held_copies_is_all_the_copies_laid_out_and_the_reference(
+    case, n_experts, held, tile, kernel, monkeypatch
+):
+    assert moe.held_block(96, 4, held[1], n_experts) == (192, tile)
+    check_held_block(case, kernel, 96, 4, n_experts, held, monkeypatch)
+
+
+def test_the_block_and_its_tile_follow_the_shapes_and_a_large_share_has_none():
+    # Kimi-Linear's streams of 2,048 and 4,096 tokens: 64 and 128 rows a held expert
+    assert moe.held_block(2048, 8, 64, 256) == (8192, 64) and moe.held_block(4096, 8, 64, 256) == (16384, 128)
+    # LFM2's shapes: 256 and 512 rows a held expert take the kernel's own tile and no longer one
+    assert moe.held_block(2048, 4, 8, 32) == (4096, 256) and moe.held_block(4096, 4, 8, 32) == (8192, 256)
+    # half of the experts or more: a block of all the copies saves nothing,
+    # and all the copies are laid out, in one round
+    assert moe.held_block(96, 4, 8, 16) is None and moe.held_block(96, 4, 16, 16) is None
+    x, gate, up, down, router, _ = expert_case(5)
+    weights, experts = moe.route(x, router, 4)
+    named, rounds = moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(0, 16, 16))
+    np.testing.assert_array_equal(named, moe.expert_ffn(x, weights, experts, gate, up, down))
+    assert int(rounds) == 1
+
+
 # ---------------------------------------------------------- ops/attention
 
 
@@ -314,7 +439,10 @@ def test_the_counts_leave_the_padding_out_and_split_held_from_absent(trained):
     config = model.config
     stream = staged(algorithm, model, [np.arange(10, dtype=np.int32)], [64], 256)
     _, counted = kimi_linear.session_vectors(model.weights, *stream, config=config)
-    busiest, held = (int(c) for c in counted)
+    busiest, held, more = (int(c) for c in counted)
+    # ten tokens' held copies take a tile an expert, half the block: the
+    # padding's 246 tokens, which route alike, get no row in it
+    assert more == 0
     routed = config.routed_copies(10)
     assert routed == 3 * 10 * 4  # three sparse layers, four copies a token
     assert 0 < held < routed and held / 12 <= busiest <= min(held, 3 * 10)
@@ -582,6 +710,45 @@ def test_a_batch_of_mixed_lengths_is_answered_in_order_as_the_reference_does(tra
     assert held + absent == model.config.routed_copies(real)
     # 4 of 16 experts held: about a quarter of the copies
     assert 0.15 < held / (held + absent) < 0.35
+
+
+def test_the_share_of_held_blocks_that_overflowed_reads_from_two_scrapes_of_the_counter(trained):
+    """``held_whole_path_share``: the benchmark's entry found BY NAME, in the
+    held cell whose shapes take the compact block, read by the harness from
+    the algorithm's own counter scraped before and after a batch."""
+    import json
+    from pathlib import Path
+
+    from benchmark import harness
+    from benchmark.engines.recommendation_als import parse_metrics
+
+    root = Path(__file__).resolve().parents[1]
+    entries = {m["name"]: m for m in json.loads((root / "BENCHMARK.json").read_text())["per_layer"]}
+    entry = entries["held_whole_path_share"]
+    assert "seq-kimi-linear.serve-sat" in entry["workloads"]
+    assert (entry["moves"], entry["better"], entry["source"]) == ("answered_qps", "lower", "program_counter")
+    assert entry["layer"] == entries["absent_copy_share"]["layer"]
+    algorithm, model = trained
+    registry = algorithm.instruments.registry
+    start = parse_metrics(registry.render_prometheus())
+    data = training_data()
+    algorithm.predict_batch(model, [Query(user=u, num=5) for u in data.users])
+    end = parse_metrics(registry.render_prometheus())
+    run = harness.Run(0.0, 51.0, 1, 0, True, counters_start=start, counters_end=end)
+    share = harness.read_metric(root, True, "held_whole_path_share", run)
+    blocks = {n: run.grown(f'pio_moe_held_blocks_total{{rounds="{n}"}}') for n in ("one", "more")}
+    # three sparse layers a program, each counted once under one of the two
+    assert sum(blocks.values()) > 0 and sum(blocks.values()) % model.config.sparse_layers == 0
+    assert share == pytest.approx(100.0 * blocks["more"] / sum(blocks.values())) and 0.0 <= share <= 100.0
+    # a program that has no such counter (this PR's parent): nothing is read, nothing raised
+    assert harness.read_metric(root, True, "held_whole_path_share", harness.Run(0.0, 51.0, 1, 0, True)) is None
+    # every block on one path, by hand
+    by_hand = harness.Run(
+        0.0, 51.0, 1, 0, True,
+        counters_start={'pio_moe_held_blocks_total{rounds="one"}': 7.0, 'pio_moe_held_blocks_total{rounds="more"}': 0.0},
+        counters_end={'pio_moe_held_blocks_total{rounds="one"}': 700.0, 'pio_moe_held_blocks_total{rounds="more"}': 7.0},
+    )
+    assert harness.read_metric(root, True, "held_whole_path_share", by_hand) == pytest.approx(1.0)
 
 
 def test_olmoe_counts_every_copy_as_held():

@@ -162,14 +162,21 @@ def test_the_chunked_kda_scan_compiles_for_v5e_at_the_published_heads(one_chip, 
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-def test_a_share_of_the_experts_compiles_for_v5e_with_the_grouped_kernel(one_chip, monkeypatch):
+# (the layout, tokens): all the copies, or the compact block of the held ones,
+# 8,192 rows in tiles of 64 (16,384 in tiles of 128 at 4,096 tokens), in rounds
+# under one `while_loop`: the same three grouped products a layer either way
+@pytest.mark.parametrize("compact, tokens", [(False, 2048), (True, 2048), (True, 4096)])
+def test_a_share_of_the_experts_compiles_for_v5e_with_the_grouped_kernel(one_chip, monkeypatch, compact, tokens):
     from predictionio_tpu.ops import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tokens, hidden, width = 2048, 2304, 1024
+    hidden, width = 2304, 1024
+    assert moe.held_block(tokens, 8, 64, 256) == (4 * tokens, tokens // 32)
 
     def share(x, router, bias, gate, up, down):
         weights, experts = moe.route_sigmoid(x, router, bias, 8, 2.446)
+        if compact:
+            return moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(64, 64, 256))
         return moe.expert_ffn(x, weights, experts, gate, up, down, held=(64, 64))
 
     compiled = jax.jit(share).lower(
@@ -177,7 +184,7 @@ def test_a_share_of_the_experts_compiles_for_v5e_with_the_grouped_kernel(one_chi
         _shape(one_chip, (256,), jnp.bfloat16), _shape(one_chip, (64, hidden, width), jnp.bfloat16),
         _shape(one_chip, (64, hidden, width), jnp.bfloat16), _shape(one_chip, (64, width, hidden), jnp.bfloat16),
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") == 3
 
 
 # the benchmark's check of the Kimi-Linear cell runs beside the served model
