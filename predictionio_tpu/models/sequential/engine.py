@@ -1031,6 +1031,90 @@ class Lfm2AlgorithmParams(Params):
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class KananaAlgorithmParams(Params):
+    """The published ``config.json`` of kakaocorp/kanana-2-30b-a3b-instruct-2601
+    (``model_type: deepseek_v3``), key for key (a variant file carries them
+    verbatim), and the seed the weights are drawn from. ``head_dim`` (64, the
+    rotary width) and ``qk_head_dim`` (128 + 64) restate other keys and are
+    held to them. The keys the program has one answer for are refused at any
+    other value rather than ignored: no low-rank queries (``q_lora_rank``
+    null), a sigmoid router whose group limit is the identity (``n_group`` 1,
+    ``topk_group`` 1), the chosen weights renormalised, interleaved RoPE
+    without scaling."""
+
+    attention_bias: bool = False
+    first_k_dense_replace: int = 1
+    head_dim: int = 64
+    hidden_act: str = "silu"
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    kv_lora_rank: int = 512
+    max_position_embeddings: int = 32768
+    model_type: str = "deepseek_v3"
+    moe_intermediate_size: int = 768
+    moe_layer_freq: int = 1
+    n_group: int = 1
+    n_routed_experts: int = 128
+    n_shared_experts: int = 2
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 32
+    num_experts_per_tok: int = 6
+    num_hidden_layers: int = 48
+    num_key_value_heads: int = 32
+    q_lora_rank: int | None = None
+    qk_head_dim: int = 192
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    rms_norm_eps: float = 1e-6
+    rope_interleave: bool = True
+    rope_scaling: dict | None = None
+    rope_theta: float = 1000000.0
+    routed_scaling_factor: float = 2.448
+    scoring_func: str = "sigmoid"
+    tie_word_embeddings: bool = False
+    topk_group: int = 1
+    topk_method: str = "noaux_tc"
+    v_head_dim: int = 128
+    vocab_size: int = 128256
+    seed: int = 3
+
+    def config(self):
+        from predictionio_tpu.models.sequential.kanana import KananaConfig
+
+        one_answer = {
+            "model_type": "deepseek_v3", "hidden_act": "silu", "attention_bias": False,
+            "q_lora_rank": None, "moe_layer_freq": 1, "n_group": 1, "topk_group": 1,
+            "norm_topk_prob": True, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+            "rope_interleave": True, "rope_scaling": None, "tie_word_embeddings": False,
+            "num_key_value_heads": self.num_attention_heads, "head_dim": self.qk_rope_head_dim,
+            "qk_head_dim": self.qk_nope_head_dim + self.qk_rope_head_dim,
+        }
+        for key, value in one_answer.items():
+            if getattr(self, key) != value:
+                raise ValueError(f"kanana: {key}={getattr(self, key)!r} is not implemented (only {value!r})")
+        return KananaConfig(
+            hidden_size=self.hidden_size,
+            intermediate_size=self.intermediate_size,
+            moe_intermediate_size=self.moe_intermediate_size,
+            num_hidden_layers=self.num_hidden_layers,
+            num_attention_heads=self.num_attention_heads,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim,
+            first_k_dense_replace=self.first_k_dense_replace,
+            n_routed_experts=self.n_routed_experts,
+            num_experts_per_tok=self.num_experts_per_tok,
+            n_shared_experts=self.n_shared_experts,
+            routed_scaling_factor=float(self.routed_scaling_factor),
+            rms_norm_eps=self.rms_norm_eps,
+            rope_theta=float(self.rope_theta),
+            vocab_size=self.vocab_size,
+            max_position_embeddings=self.max_position_embeddings,
+        )
+
+
 class BackboneModel(PersistentModel, SanityCheck):
     """A backbone's weight tree on the device, the item vocabulary (item
     ``i`` is token ``i``) and every user's session tail: the last
@@ -1162,11 +1246,12 @@ def _stream_limits(model: BackboneModel) -> tuple[int, int]:
 
 class BackboneAlgorithm(JaxAlgorithm):
     """A query answered through a language model's block: what the ``olmoe``,
-    ``kimi_linear``, ``lfm2`` and ``sdar`` algorithms share, which is everything but
+    ``kimi_linear``, ``lfm2``, ``sdar`` and ``kanana`` algorithms share, which is everything but
     the backbone's module (``model_class.program()``), its parameters and
     HOW a staged batch is answered (``_answer``, the one hook): next-item
     scoring by one prefill (here; ``olmoe``, ``kimi_linear``, ``lfm2``) or a generation
-    over the batch's cache (``SdarAlgorithm``).
+    over the batch's cache (``GroupedAlgorithm``: ``SdarAlgorithm`` block by
+    block, ``KananaAlgorithm`` token by token).
 
     Train: builds the item vocabulary (item ``i`` is token ``i``) and every
     user's session tail from the ordered events, and DRAWS the weights from
@@ -1457,7 +1542,45 @@ class SdarModel(BackboneModel):
             )
 
 
-class SdarAlgorithm(BackboneAlgorithm):
+class GroupedAlgorithm(BackboneAlgorithm):
+    """What an algorithm whose answer is GENERATED over a per-batch cache
+    shares (``sdar``, ``kanana``): the staged streams are put into GROUPS,
+    each of at most the backbone's ``SESSIONS`` sessions and
+    ``config.cache_tokens`` stream tokens (a batch is one group but for a
+    rare long one), and a group is launched by the algorithm's own
+    ``_launch_group``."""
+
+    def batch_limit(self) -> int:
+        """The sessions ONE group holds: a batch of more is answered in a
+        second group, by passes or steps of its own that cost what the
+        first's do however few sessions they carry."""
+        return self.model_class.program().SESSIONS
+
+    @staticmethod
+    def _groups(model: BackboneModel, streams) -> list[list[int]]:
+        """The streams' indices, in order, cut where a group would pass the
+        cache's tokens or the group's sessions."""
+        most, room = model.program().SESSIONS, model.config.cache_tokens
+        groups: list[list[int]] = []
+        tokens = held = 0
+        for i, (length, members) in enumerate(streams):
+            if not groups or tokens + length > room or held + len(members) > most:
+                groups.append([])
+                tokens = held = 0
+            groups[-1].append(i)
+            tokens, held = tokens + length, held + len(members)
+        return groups
+
+    def _launched(self, model: BackboneModel, queries, sessions, streams, staged) -> list:
+        return [
+            self._launch_group(
+                model, queries, sessions, [streams[i] for i in group], [staged[i] for i in group]
+            )
+            for group in self._groups(model, streams)
+        ]
+
+
+class SdarAlgorithm(GroupedAlgorithm):
     """``sdar``: SDAR-30B-A3B-Chat's block (``sdar.py``), and the one
     backbone whose answer is GENERATED: ``num`` items in order, each chosen
     given the ones before it, by masked diffusion block by block.
@@ -1476,27 +1599,6 @@ class SdarAlgorithm(BackboneAlgorithm):
     params_class = SdarAlgorithmParams
     params: SdarAlgorithmParams
     model_class = SdarModel
-
-    def batch_limit(self) -> int:
-        """The sessions ONE group of passes holds: a batch of more is answered
-        in a second group, by passes of its own that cost what the first's
-        do however few sessions they carry."""
-        return self.model_class.program().SESSIONS
-
-    @staticmethod
-    def _groups(model: BackboneModel, streams) -> list[list[int]]:
-        """The streams' indices, in order, cut where a group would pass the
-        cache's tokens or the passes' sessions."""
-        most, room = model.program().SESSIONS, model.config.cache_tokens
-        groups: list[list[int]] = []
-        tokens = held = 0
-        for i, (length, members) in enumerate(streams):
-            if not groups or tokens + length > room or held + len(members) > most:
-                groups.append([])
-                tokens = held = 0
-            groups[-1].append(i)
-            tokens, held = tokens + length, held + len(members)
-        return groups
 
     def _launch_group(self, model: BackboneModel, queries, sessions, streams, staged):
         """One group's programs: ``(members [(query, row of the state)],
@@ -1580,12 +1682,7 @@ class SdarAlgorithm(BackboneAlgorithm):
 
     def _answer(self, model: BackboneModel, queries, sessions, streams, staged):
         config = model.config
-        launched = [
-            self._launch_group(
-                model, queries, sessions, [streams[i] for i in group], [staged[i] for i in group]
-            )
-            for group in self._groups(model, streams)
-        ]
+        launched = self._launched(model, queries, sessions, streams, staged)
 
         def finalize() -> list[PredictedResult]:
             out: list[PredictedResult] = [PredictedResult(())] * len(queries)
@@ -1606,6 +1703,113 @@ class SdarAlgorithm(BackboneAlgorithm):
                     out[i] = PredictedResult(tuple(
                         ItemScore(model.item_vocab[int(items[s, g])], float(logp[s, g]), int(steps[s, g]))
                         for g in made
+                    ))
+            return out
+
+        return finalize
+
+
+class KananaModel(BackboneModel):
+    @staticmethod
+    def program():
+        from predictionio_tpu.models.sequential import kanana
+
+        return kanana
+
+
+class KananaAlgorithm(GroupedAlgorithm):
+    """``kanana``: kanana-2-30b-a3b's block (``kanana.py``), the backbone
+    whose answer is generated TOKEN BY TOKEN: ``num`` items in order, each the
+    likeliest candidate given the session and the items before it, one
+    position a step against a latent cache.
+
+    A group (``GroupedAlgorithm``) is: its state and an empty cache
+    (``kanana.new_state``); a PREFILL a stream (the expanded form), which
+    writes the stream's latents into the cache where the stream lies and each
+    session's last hidden state beside them; ``kanana.first_pick`` (the first
+    item, from those); then ``max(num) - 1`` times ``kanana.decode_step`` (the
+    absorbed form), all sessions in each, nothing fetched between them;
+    ``finalize`` fetches a group's items and log-probabilities in one
+    transfer. An item's place in the answer is its step, so ``ItemScore.step``
+    stays None."""
+
+    params_class = KananaAlgorithmParams
+    params: KananaAlgorithmParams
+    model_class = KananaModel
+
+    def _launch_group(self, model: BackboneModel, queries, sessions, streams, staged):
+        """One group's programs: ``(members [(query, row of the state, its
+        items)], the answer's handle, the busiest experts' counts, the copies
+        of real rows the routers sent out, the experts the steps' real rows
+        reached (a handle) of those the steps could have)``."""
+        config, program = model.config, model.program()
+        t0 = time.perf_counter()
+        seg = np.full(config.cache_tokens, -1, np.int32)
+        length, num = (np.zeros(program.SESSIONS, np.int32) for _ in range(2))
+        allowed = np.zeros((program.SESSIONS, config.table_rows), bool)
+        members, places, offset = [], [], 0
+        for (stream_length, packed), (*_, mask) in zip(streams, staged):
+            places.append((offset, len(members)))
+            for row, (i, at) in enumerate(packed):
+                s = len(members)
+                seg[offset + at : offset + at + len(sessions[i])] = s
+                length[s], num[s] = len(sessions[i]), config.fit(queries[i].num)
+                allowed[s] = mask[row]
+                members.append((i, s, int(num[s])))
+            offset += stream_length
+        # the host's part ends here: what follows are launches
+        self.instruments.stage_seconds.inc(time.perf_counter() - t0)
+        state = program.new_state(model.weights, config, seg, length, num, allowed)
+        cache, counted, routed = state.pop("cache"), [], 0
+        # a sparse last layer routes each session's last position alone
+        spared = 0 if config.is_dense(config.num_hidden_layers - 1) else config.num_experts_per_tok
+        for (stream_length, packed), (*stream, last, _), (at, first) in zip(streams, staged, places):
+            real = sum(len(sessions[i]) for i, _ in packed)
+            with annotate("pio:seq.launch", bucket=stream_length, rows=1, tokens=real):
+                cache, busiest = program.session_vectors(
+                    model.weights, cache, *(topk.upload(a, np.int32) for a in (*stream, last[None])),
+                    np.int32(at), np.int32(first), config=config,
+                )
+            self.instruments.on_launch(stream_length, 1, real, len(packed))
+            counted.append(busiest)
+            routed += config.routed_copies(real) - spared * (real - len(packed))
+        state["cache"] = cache
+        steps = max(int(num.max()) - 1, 0)
+        with annotate("pio:seq.decode", batch=len(queries), sessions=len(members), steps=steps):
+            state = program.first_pick(model.weights, state, config=config)
+            for _ in range(steps):
+                state = program.decode_step(model.weights, state, config=config)
+            answer = program.answer_of(state)
+        counted.append(state["busiest"])
+        stepped = int(np.maximum(num - 1, 0).sum())  # real rows of the steps, and the positions they cached
+        routed += config.routed_copies(stepped)
+        self.instruments.on_generation(
+            decode=steps, items=int(num.sum()), cache_bytes=config.cache_bytes(int(length.sum()) + stepped)
+        )
+        offered = steps * config.sparse_layers * config.n_routed_experts
+        return members, answer, counted, routed, (state["reached"], offered)
+
+    def _answer(self, model: BackboneModel, queries, sessions, streams, staged):
+        config = model.config
+        launched = self._launched(model, queries, sessions, streams, staged)
+
+        def finalize() -> list[PredictedResult]:
+            out: list[PredictedResult] = [PredictedResult(())] * len(queries)
+            for members, answer, counted, routed, (reached, offered) in launched:
+                with annotate("pio:fetch.block"):  # the host blocked on the device
+                    packed = np.asarray(answer, np.int32)
+                busiest = sum(int(np.asarray(c, np.int64)) for c in counted)
+                self.instruments.on_expert_load(busiest, routed / config.n_routed_experts)
+                self.instruments.on_copies(routed, 0)
+                self.instruments.on_experts_reached(int(np.asarray(reached, np.int64)), offered)
+                items = packed[:, 0, :]
+                logp = np.ascontiguousarray(packed[:, 1, :]).view(np.float32)
+                for i, s, made in members:
+                    # (a session that holds every item leaves no candidate: the answer ends there)
+                    finite = np.isfinite(logp[s, :made])
+                    made = made if finite.all() else int(np.argmin(finite))
+                    out[i] = PredictedResult(tuple(
+                        ItemScore(model.item_vocab[int(items[s, g])], float(logp[s, g])) for g in range(made)
                     ))
             return out
 
@@ -1633,6 +1837,7 @@ def engine_factory() -> Engine:
             "kimi_linear": KimiLinearAlgorithm,
             "sdar": SdarAlgorithm,
             "lfm2": Lfm2Algorithm,
+            "kanana": KananaAlgorithm,
         },
         Serving,
         query_class=Query,

@@ -58,7 +58,7 @@ class SeqInstruments:
 
 
 class BackboneInstruments:
-    """What a backbone scorer (``olmoe``, ``kimi_linear``, ``sdar``) launched, counted
+    """What a backbone scorer (``olmoe``, ``kimi_linear``, ``lfm2``, ``sdar``, ``kanana``) launched, counted
     where it happens (``engine.BackboneAlgorithm``). The expert counters are
     over the experts the chip HOLDS. An algorithm starts with a registry of its
     own; a query server that serves it hands over its registry through
@@ -103,7 +103,8 @@ class BackboneInstruments:
             "pio_seq_passes_total",
             "passes of a generating backbone over a batch's sessions and its "
             "cache: kind=denoise fixed a position of some session's block, "
-            "kind=commit only appended clean blocks' keys and values",
+            "kind=commit only appended clean blocks' keys and values, "
+            "kind=decode chose one more item a session (one position a step)",
             labelnames=("kind",),
         )
         self.blocks = r.counter(
@@ -114,9 +115,9 @@ class BackboneInstruments:
         )
         self.cache_bytes = r.counter(
             "pio_seq_cache_bytes_total",
-            "bytes of keys and values a batch's cache held for its sessions "
-            "(its streams as they lie and the generated blocks), summed over "
-            "batches",
+            "bytes of keys and values (sdar) or of latents (kanana) a batch's "
+            "cache held for its sessions (its streams and what was generated), "
+            "summed over batches",
         )
         self.expert_tokens_max = r.counter(
             "pio_moe_expert_tokens_max_total",
@@ -156,11 +157,13 @@ class BackboneInstruments:
         )
 
     def on_generation(
-        self, denoise: int, commit: int, blocks: int, items: int, cache_bytes: int
+        self, items: int, cache_bytes: int, blocks: int = 0, **passes: int
     ) -> None:
-        """One group of sessions answered by generation (``sdar``)."""
-        self.passes.inc(float(denoise), kind="denoise")
-        self.passes.inc(float(commit), kind="commit")
+        """One group of sessions answered by generation; ``passes`` by kind:
+        ``sdar``'s ``denoise`` and ``commit`` over ``blocks``, ``kanana``'s
+        ``decode`` steps."""
+        for kind, count in passes.items():
+            self.passes.inc(float(count), kind=kind)
         self.blocks.inc(float(blocks))
         self.generated_items.inc(float(items))
         self.cache_bytes.inc(float(cache_bytes))

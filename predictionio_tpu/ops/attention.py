@@ -433,7 +433,7 @@ PAIR_TILES = (256, 1024)
 
 def _flash_attention_pallas(
     q, k, v, causal: bool, interpret: bool, block_q: int = 1024, block_k: int = 1024,
-    segment=None, block=None,
+    segment=None, block=None, value_width=None,
 ):
     """Tiled flash-attention pallas kernel: grid (B*H, Lq/bq, Lk/bk), online
     softmax carried across the (sequential, innermost) K-block grid axis in
@@ -454,14 +454,18 @@ def _flash_attention_pallas(
     ``k`` and ``v`` of fewer heads than ``q`` (grouped queries) are read
     where they lie: the index map sends query head ``h`` to key/value head
     ``h // group``. ``block`` turns the causal mask block-causal; the tiles
-    are whole blocks, so the diagonal's skipping stands."""
+    are whole blocks, so the diagonal's skipping stands.
+
+    ``value_width`` (``v`` None): the values are the first ``value_width``
+    columns of the keys, cut out of the tile of keys a step already holds;
+    no second operand is read."""
     import math as _math
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    Lk, Dv = k.shape[2], v.shape[3]
+    Lk, Dv = k.shape[2], value_width or v.shape[3]
     group = H // k.shape[1]
     bq, bk = min(block_q, Lq), min(block_k, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, "flash path requires divisible blocks"
@@ -471,6 +475,10 @@ def _flash_attention_pallas(
     paired = isinstance(segment, tuple)
 
     def kernel(*refs):
+        if value_width:
+            # the keys stand in for the operand the values would have been
+            at = 1 + (0 if segment is None else 2 if paired else 1)
+            refs = refs[: at + 1] + (None,) + refs[at + 1 :]
         if segment is None:
             q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         elif paired:
@@ -523,7 +531,7 @@ def _flash_attention_pallas(
             p = jnp.where(jnp.isneginf(s), 0.0, p)
             acc_ref[...] = acc_ref[...] * corr + jnp.dot(
                 p.astype(jnp.bfloat16),
-                v_ref[0].astype(jnp.bfloat16),
+                (k_ref[0][:, :Dv] if value_width else v_ref[0]).astype(jnp.bfloat16),
                 preferred_element_type=jnp.float32,
             )
             l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
@@ -554,7 +562,6 @@ def _flash_attention_pallas(
 
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H // group, Lk, D)
-    vr = v.reshape(B * H // group, Lk, Dv)
 
     def head_of(b):
         # (row b of `qr` is batch b // H, head b % H: its keys are row
@@ -581,7 +588,10 @@ def _flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
     )
-    operands = (qr, kr, vr)
+    if value_width:
+        operands, grid["in_specs"] = (qr, kr), grid["in_specs"][:2]
+    else:
+        operands = (qr, kr, v.reshape(B * H // group, Lk, Dv))
     if segment is not None:
         grid["in_specs"] += [
             pl.BlockSpec((1, bq, 1), lambda b, i, j, *_: (b // H, i, 0)),
@@ -602,18 +612,21 @@ def _flash_attention_pallas(
     return out.reshape(B, H, Lq, Dv)
 
 
-def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None, block=None):
+def _fused_attention_pallas(
+    q, k, v, causal: bool, interpret: bool, segment=None, block=None, value_width=None
+):
     from jax.experimental import pallas as pl
 
     B, H, Lq, D = q.shape
-    Lk, Dv = k.shape[2], v.shape[3]
+    Lk, Dv = k.shape[2], value_width or v.shape[3]
     group = H // k.shape[1]
 
-    def kernel(q_ref, k_ref, v_ref, *rest):
+    def kernel(q_ref, k_ref, *rest):
         o_ref = rest[-1]
         qb = q_ref[0]  # [Lq, D]
         kb = k_ref[0]
-        vb = v_ref[0]
+        # (`value_width`: the values are the keys' first columns, read once)
+        vb, rest = (kb[:, :Dv], rest) if value_width else (rest[0][0], rest[1:])
         scale = 1.0 / math.sqrt(D)
         # bf16 multiply / f32 accumulate — see _flash_attention_pallas
         scores = (
@@ -651,16 +664,17 @@ def _fused_attention_pallas(q, k, v, causal: bool, interpret: bool, segment=None
     grid = (B * H,)
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H // group, Lk, D)
-    vr = v.reshape(B * H // group, Lk, Dv)
 
     def head_of(i):
         return i if group == 1 else i // group
 
-    operands, in_specs = [qr, kr, vr], [
+    operands, in_specs = [qr, kr], [
         pl.BlockSpec((1, Lq, D), lambda i: (i, 0, 0)),
         pl.BlockSpec((1, Lk, D), lambda i: (head_of(i), 0, 0)),
-        pl.BlockSpec((1, Lk, Dv), lambda i: (head_of(i), 0, 0)),
     ]
+    if not value_width:
+        operands.append(v.reshape(B * H // group, Lk, Dv))
+        in_specs.append(pl.BlockSpec((1, Lk, Dv), lambda i: (head_of(i), 0, 0)))
     if segment is not None:
         operands += _segment_ids(segment)
         in_specs += [
@@ -686,6 +700,7 @@ def fused_attention(
     force_pallas: bool = False,
     segment=None,
     block: int | None = None,
+    value_width: int | None = None,
 ) -> jnp.ndarray:
     """Single-device attention over ``q`` [B, H, Lq, D], ``k`` [B, Hkv, Lk, D]
     and ``v`` [B, Hkv, Lk, Dv]: queries and keys share a head width, the
@@ -719,6 +734,12 @@ def fused_attention(
     a segment starts on a multiple of ``block``, so that the index's blocks
     are the segment's own.
 
+    ``value_width`` (with ``v`` None): the VALUES are the keys' own first
+    ``value_width`` columns (latent attention ABSORBED for a decoding step:
+    one key/value head of 512 + 64, the latent and the rotary key, whose
+    first 512 are what the probabilities sum). The kernels cut them out of
+    the tile of keys they hold, so the cache is read once a step.
+
     Limit of the kernel path: a sequence whose score tile is past the
     single-block budget (Lq * Lk >= 2**20, i.e. L >= 1024 square) must be
     a multiple of 256 in both lengths, or the call raises ValueError."""
@@ -730,7 +751,11 @@ def fused_attention(
         raise ValueError("fused_attention: `block` shapes the causal mask; give `causal=True`")
     if causal and (isinstance(segment, tuple) or (segment is not None and Lq != Lk)):
         raise ValueError("fused_attention: ids given apart carry no order: `causal` is refused")
+    if (v is None) != bool(value_width):
+        raise ValueError("fused_attention: `value_width` stands in for `v`; give one of them")
     if not (on_tpu or force_pallas):
+        if value_width:
+            v = k[..., :value_width]
         if segment is not None and Lq % OFF_CHIP_BLOCK == 0 and Lk % OFF_CHIP_BLOCK == 0:
             return _segmented_attention_blocked(q, k, v, causal, segment, OFF_CHIP_BLOCK, block)
         return attention_reference(q, k, v, causal=causal, segment=segment, block=block)
@@ -751,7 +776,7 @@ def fused_attention(
     interpret = not on_tpu
     if single_block:
         return _fused_attention_pallas(
-            q, k, v, causal, interpret=interpret, segment=segment, block=block
+            q, k, v, causal, interpret=interpret, segment=segment, block=block, value_width=value_width
         )
     # block sizes tuned per-shape (see _best_block): the largest
     # dividing tile wins on the MXU at every measured length
@@ -760,5 +785,5 @@ def fused_attention(
         block_q, block_k = min(block_q, PAIR_TILES[0]), min(block_k, PAIR_TILES[1])
     return _flash_attention_pallas(
         q, k, v, causal, interpret=interpret,
-        block_q=block_q, block_k=block_k, segment=segment, block=block,
+        block_q=block_q, block_k=block_k, segment=segment, block=block, value_width=value_width,
     )

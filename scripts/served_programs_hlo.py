@@ -7,9 +7,10 @@ compared with every source location taken out.
     python scripts/served_programs_hlo.py diff OUT_A OUT_B
 
 ``compile`` writes the optimised HLO of the programs that the cells serve
-whose layers call ``ops/moe.expert_ffn`` (``seq-olmoe``: ``[4, 2048]``,
+whose layers call ``ops/moe`` and ``ops/attention`` (``seq-olmoe``: ``[4, 2048]``,
+``[1, 2048]``, ``[1, 4096]``; ``seq-kimi-linear`` and ``seq-lfm2-moe``:
 ``[1, 2048]``, ``[1, 4096]``; ``seq-sdar-moe``: a denoise pass and both
-prefills; ``seq-lfm2-moe``: ``[1, 2048]``, ``[1, 4096]``), from the code
+prefills), from the code
 under ROOT, one file a program. ``diff`` prints, a program, the lines
 that differ once locations are gone: the tables of files, functions and frames
 at a module's head, and the locations INSIDE each Mosaic kernel (its body is
@@ -17,7 +18,7 @@ base64 MLIR bytecode in the custom call's ``backend_config`` and embeds the
 tree's path and line numbers, so a plain text diff always shows every kernel
 as changed: it is parsed and printed without debug information). 0 everywhere
 says the edit left those cells' programs alone (PERF.md section 6: PR 33, 34,
-41 and 42 showed it so). One process a tree: only one may load libtpu.
+41, 42 and 44 showed it so). One process a tree: only one may load libtpu.
 """
 import base64
 import glob
@@ -48,7 +49,7 @@ def compile_programs(root: str, out: str) -> None:
     chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
     jax.default_backend = lambda: "tpu"  # the chip's kernels, not the CPU's stand-ins
     import test_tpu_compile as shapes  # the tree's own shapes of SDAR's state
-    from predictionio_tpu.models.sequential import engine_factory, lfm2, olmoe, sdar
+    from predictionio_tpu.models.sequential import engine_factory, kimi_linear, lfm2, olmoe, sdar
 
     assert olmoe.__file__.startswith(root), olmoe.__file__
 
@@ -63,6 +64,7 @@ def compile_programs(root: str, out: str) -> None:
 
     for backbone, cell, streams in (
         (olmoe, "seq-olmoe", ((4, 2048), (1, 2048), (1, 4096))),
+        (kimi_linear, "seq-kimi-linear", ((1, 2048), (1, 4096))),
         (lfm2, "seq-lfm2-moe", ((1, 2048), (1, 4096))),
     ):
         name = backbone.__name__.rsplit(".", 1)[1]

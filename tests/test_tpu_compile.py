@@ -234,15 +234,22 @@ def test_grouped_query_block_causal_attention_compiles_for_v5e(one_chip, monkeyp
     assert jax.eval_shape(attend, queries, keys, keys, jax.ShapeDtypeStruct((1, length), jnp.int32)).shape == queries.shape
 
 
-def _sdar_at_the_cell():
+def _config_at_the_cell(engine_name: str, cell: str):
+    """The backbone's configuration as the benchmark's engine builds it from
+    the cell's configuration file."""
+    import importlib
     import json
     from pathlib import Path
 
-    from benchmark.engines import sequential_sdar as engine
     from predictionio_tpu.models.sequential import engine_factory
 
-    config = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-sdar-moe.json").read_text())
+    engine = importlib.import_module(f"benchmark.engines.{engine_name}")
+    config = json.loads((Path(engine.__file__).parents[1] / "configs" / f"{cell}.json").read_text())
     return engine_factory().engine_params_from_variant(engine.variant_of(config, 5)).algorithms[0][1].config()
+
+
+def _sdar_at_the_cell():
+    return _config_at_the_cell("sequential_sdar", "seq-sdar-moe")
 
 
 def _sdar_state(one_chip, config, weights):
@@ -369,3 +376,73 @@ def test_lfm2s_whole_depth_compiles_for_v5e_beside_the_model(one_chip, monkeypat
     assert compiled.as_text().count("tpu_custom_call") == 6 + 22 * 3
     assert memory.temp_size_in_bytes < 2.0e9
     assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
+
+
+@pytest.mark.parametrize("slots", [512, 32768])
+def test_absorbed_latent_attention_compiles_for_v5e_with_the_values_cut_out_of_the_keys(one_chip, monkeypatch, slots):
+    """A step's attention at the published widths: 32 sessions' 32 heads as
+    1,024 rows of ONE head 576 wide against a cache of ``slots`` whose values
+    are the keys' first 512 columns (``value_width``): the single-block kernel
+    (512 slots) and the tiled one (32,768) take the 4.5 lane tiles whole and
+    read ONE key/value operand."""
+    from predictionio_tpu.ops.attention import fused_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    queries, keys = _shape(one_chip, (1, 1, 1024, 576), jnp.bfloat16), _shape(one_chip, (1, 1, slots, 576), jnp.bfloat16)
+    ids = lambda n: _shape(one_chip, (1, n), jnp.int32)  # noqa: E731
+
+    def attend(q, k, ids_q, ids_k):
+        return fused_attention(q, k, None, segment=(ids_q, ids_k), value_width=512)
+
+    compiled = jax.jit(attend).lower(queries, keys, ids(1024), ids(slots)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    assert jax.eval_shape(attend, queries, keys, ids(1024), ids(slots)).shape == (1, 1, 1024, 512)
+    # the queries, the cache and the ids are all it is given: no second operand of values
+    assert compiled.memory_analysis().argument_size_in_bytes < 2 * (1024 + slots) * 576 + 8 * (1024 + slots) + 4096
+
+
+@pytest.mark.parametrize("program", ["a step", "the first pick", "a prefill of 2,048", "a prefill of 4,096"])
+def test_the_kanana_cells_programs_compile_for_v5e_beside_the_model(one_chip, monkeypatch, program):
+    """``seq-kanana-2``'s four programs at the published widths, six layers:
+    the arguments are the served model (7.58 GB; a prefill reads no
+    ``lm_head``) and the group's latent cache (0.23 GB, donated and handed
+    back in place), the temporaries under a gigabyte (printed), and every
+    kernel is there: attention a layer (192 / 128 in the prefill, the last
+    layer's by the single-block kernel; 576 / 512 in a step) and three grouped
+    products a sparse layer."""
+    from predictionio_tpu.models.sequential import kanana
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = _config_at_the_cell("sequential_kanana", "seq-kanana-2")
+    weights = {name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in kanana.weight_shapes(config).items()}
+    whole = lambda shape, dtype=jnp.int32: _shape(one_chip, shape, dtype)  # noqa: E731
+    sessions, layers = kanana.SESSIONS, config.num_hidden_layers
+    cache = (
+        tuple(whole((config.cache_slots, config.latent_width), jnp.bfloat16) for _ in range(layers)),
+        whole((2 * sessions, config.hidden_size), jnp.float32),
+    )
+    state = {
+        "cache": cache, "seg": whole((config.cache_tokens,)), "length": whole((sessions,)), "num": whole((sessions,)),
+        "made": whole((sessions,)), "items": whole((sessions, config.generated_slots)),
+        "logp": whole((sessions, config.generated_slots), jnp.float32),
+        "allowed": whole((sessions, config.vocab_size), jnp.bool_), "busiest": whole(()), "reached": whole(()),
+    }
+    kernels = layers + 3 * config.sparse_layers
+    if program == "a step":
+        compiled = kanana.decode_step.lower(weights, state, config=config).compile()
+    elif program == "the first pick":
+        compiled, kernels = kanana.first_pick.lower(weights, state, config=config).compile(), 0
+    else:
+        length = 2048 if "2,048" in program else 4096
+        stream = whole((1, length))
+        compiled = kanana.session_vectors.lower(
+            weights, cache, stream, stream, stream, whole((1, sessions)), whole(()), whole(()), config=config
+        ).compile()
+    memory = compiled.memory_analysis()
+    print(f"{program}: {memory.temp_size_in_bytes / 1e9:.3f} GB of temporaries, {memory.argument_size_in_bytes / 1e9:.3f} of arguments")
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    assert memory.temp_size_in_bytes < 1.0e9
+    if program != "the first pick":
+        assert 7.0e9 < memory.argument_size_in_bytes < 8.0e9
+    # the cache is updated where it lies
+    assert memory.alias_size_in_bytes >= config.cache_bytes(config.cache_slots)
