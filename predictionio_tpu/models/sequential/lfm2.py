@@ -10,10 +10,20 @@ against the embedding (the head is TIED to it: ``engine.BackboneModel.head``).
 Layer equations: ``lfm2_reference.py``, which the tests and the benchmark hold
 this to.
 
-The model's WHOLE depth is one program: 24 layers, unrolled, each with its own
-arrays (a flat tree, layer ``i``'s as ``"<i>.<name>"``, numbered from 0 as the
-published ``layer_types`` LIST numbers them; the kinds are read from the list,
-its last period is irregular). The token mixer is a GATED SHORT CONVOLUTION
+The model's WHOLE depth is one program of 24 layers, numbered from 0 as the
+published ``layer_types`` LIST numbers them (the kinds are read from the list,
+its last period is irregular). Sparse layers that REPEAT a pattern of mixers
+are one ``lax.scan`` whose body is the pattern's layers (``scan_plan``: for
+the published list the two dense layers unrolled, a scan of 4 over
+``[attention, conv, conv, conv]`` and a scan of 2 over ``[attention, conv,
+conv]``: 7 sparse bodies for 22 layers), so that what a sparse body holds
+twice (the held experts' compact block and its way out on an overflow,
+below) is compiled, loaded and kept 7 times a program and not 22; a layer
+in no repeat stays unrolled. The weight tree is flat: an unrolled layer
+``i``'s arrays as ``"<i>.<name>"``, a scan's as ``"<start>+<period>x<repeats>.
+<slot>.<name>"`` with the repeats stacked in front (stacked ONCE, in
+``init_weights``); ``layer_of`` gives any layer's arrays under their
+published names. The token mixer is a GATED SHORT CONVOLUTION
 (``conv``: ``C * conv3(B * u)`` with ``B, C, u`` one projection's thirds, a
 causal depthwise convolution of ``conv_L_cache`` taps with no bias and no
 activation, ``ops/linear_attention.short_conv``) or grouped-query attention
@@ -21,8 +31,14 @@ activation, ``ops/linear_attention.short_conv``) or grouped-query attention
 head on q and k, RoPE, ``ops/attention.fused_attention``); the feed-forward is
 dense (the first ``num_dense_layers``) or sparse (``ops/moe``: a sigmoid
 router over ALL ``num_experts``, chosen by score plus ``expert_bias``, and the
-grouped products over the experts HELD here). No ``lax.scan`` over layers:
-``STACKED_ROWS`` has the cold start's reading.
+grouped products over the experts HELD here, ``ops/moe.held_expert_ffn``:
+only the copies routed to a held expert are laid out, in a compact block;
+a routing that overflows it takes the whole path, ``expert_ffn(held=)``, as
+the other branch of one ``lax.cond``, which a scanned body can afford: the
+readings stand beside ``ops/moe.HELD_ROOM``). In a scan the small arrays ride
+as its ``xs``; the experts' matrices stay WHOLE, a scan's repeats stacked,
+and reach the kernel through ``first_group`` (a slice in front of a Pallas
+call is a copy of the matrices).
 
 What a chip holds is a share of a stated deployment (``experts_held``): the
 router keeps its published width and its experts per token, and the held
@@ -45,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,11 +92,12 @@ TOKEN_BUDGET = 2048
 # its 256 rows (a whole tile of ``ops/moe.TILING``) at ONE stream already.
 # The cell's answers a second agree (one seed, 4100000101): **93.2** one by
 # one, 88.9 by twos, 87.0 by fours. The closed set is then
-# [1, 2048] and [1, 4096]; each compiles in 13 to 27 s on the chip's host, the
-# server's cold start with an EMPTY compile cache is 57.0 s (a set-up of about
-# 100 s with 27.0 s of drawing weights) against Kimi-Linear's 128 to 138 s, and
-# the 24 layers stay unrolled: a ``lax.scan`` over the four alike periods
-# would save compile time this program does not spend
+# [1, 2048] and [1, 4096]; unrolled (PR 41 to 46) each compiled in 13 to 27 s
+# on the chip's host and the server's cold start with an EMPTY compile cache
+# was 57.0 s (a set-up of about 100 s with 27.0 s of drawing weights) against
+# Kimi-Linear's 128 to 138 s. The sparse layers are scanned bodies since PR 47
+# (``scan_plan``), not for that compile time but for the SECOND path a sparse
+# body then affords (``ops/moe.HELD_ROOM``: the readings)
 STACKED_ROWS = 1
 # items of a session the engine keeps, and so the longest program: the
 # traffic's bound (the model's own is ``max_position_embeddings``, 128,000)
@@ -89,6 +107,8 @@ MAX_SESSION = 4096
 ROUTER_EPS = 1e-6
 
 CONV, ATTENTION = "conv", "full_attention"
+# the arrays a scan does not slice (``_layers``)
+EXPERT_ARRAYS = ("gate", "up", "down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,12 +228,59 @@ def _layer_shapes(config: Lfm2Config, i: int) -> dict[str, tuple[tuple[int, ...]
     return shapes
 
 
-def weight_shapes(config: Lfm2Config) -> dict[str, tuple[int, ...]]:
+def scan_plan(config: Lfm2Config) -> tuple[tuple[int, int, int], ...]:
+    """The program's runs of layers, ``(start, period, repeats)`` each, from
+    ``layer_types`` and ``num_dense_layers`` alone: ``repeats`` over 1 is ONE
+    ``lax.scan`` of that many steps whose body is layers ``start`` to
+    ``start + period - 1``; ``(i, 1, 1)`` is layer ``i`` unrolled. The dense
+    layers stay unrolled (the first few, they hold no expert block). Among
+    the sparse ones, from the left: the repeat of a pattern of mixers that
+    covers most layers from here (at least twice; of two that cover as many
+    the shorter pattern), or, where nothing repeats from here, this layer
+    alone."""
+    runs, n = [], config.num_hidden_layers
+    i = 0
+    while i < n:
+        best = (1, 1)
+        for period in range(1, 0 if config.is_dense(i) else (n - i) // 2 + 1):
+            pattern, repeats = config.layer_types[i : i + period], 1
+            while config.layer_types[i + repeats * period : i + (repeats + 1) * period] == pattern:
+                repeats += 1
+            if repeats > 1 and period * repeats > best[0] * best[1]:
+                best = (period, repeats)
+        runs.append((i, *best))
+        i += best[0] * best[1]
+    return tuple(runs)
+
+
+def _stacked_name(start: int, period: int, repeats: int, slot: int, name: str) -> str:
+    return f"{start}+{period}x{repeats}.{slot}.{name}"
+
+
+_STACKED = re.compile(r"(\d+)\+(\d+)x(\d+)\.(\d+)\.(.+)")
+
+
+def _tree(config: Lfm2Config) -> dict[str, tuple[tuple[str, ...], tuple[int, ...], int | None]]:
+    """The weight tree as it is kept: ``name -> (the published arrays
+    ``"<i>.<name>"`` it holds, in order; ONE such array's shape; its
+    fan-in)``. A scan's array holds its steps' stacked in front."""
     h = config.hidden_size
-    shapes = {"embed": (config.vocab_size, h), "embedding_norm": (h,)}
-    for i in range(config.num_hidden_layers):
-        shapes.update({f"{i}.{name}": shape for name, (shape, _) in _layer_shapes(config, i).items()})
-    return shapes
+    tree = {"embed": (("embed",), (config.vocab_size, h), h), "embedding_norm": (("embedding_norm",), (h,), None)}
+    for start, period, repeats in scan_plan(config):
+        for slot in range(period):
+            for name, spec in _layer_shapes(config, start + slot).items():
+                members = tuple(f"{start + slot + r * period}.{name}" for r in range(repeats))
+                kept = members[0] if repeats == 1 else _stacked_name(start, period, repeats, slot, name)
+                tree[kept] = (members, *spec)
+    return tree
+
+
+def weight_shapes(config: Lfm2Config) -> dict[str, tuple[int, ...]]:
+    """``name -> shape`` of the tree ``init_weights`` gives."""
+    return {
+        name: shape if len(members) == 1 else (len(members), *shape)
+        for name, (members, shape, _) in _tree(config).items()
+    }
 
 
 def init_weights(config: Lfm2Config, seed: int, dtype=jnp.bfloat16) -> dict:
@@ -223,29 +290,47 @@ def init_weights(config: Lfm2Config, seed: int, dtype=jnp.bfloat16) -> dict:
     come out of unit order (a session's first stream is that small; the
     first norm takes it to one); a norm's weight near one; the router's
     selection bias small and not zero, so that the choice by ``s + bias`` is
-    another than the choice by ``s``."""
-    fan_in: dict = {"embed": config.hidden_size, "embedding_norm": None}
-    for i in range(config.num_hidden_layers):
-        fan_in.update({f"{i}.{name}": f for name, (_, f) in _layer_shapes(config, i).items()})
-    shapes = weight_shapes(config)
-    keys = jax.random.split(jax.random.key(seed, impl="rbg"), len(shapes))
-    weights = {}
-    for key, (name, shape) in zip(keys, sorted(shapes.items())):
-        if fan_in[name] is not None:
-            weights[name] = _normal(key, shape, 1.0 / float(np.sqrt(fan_in[name])), 0.0, dtype)
-        elif name.endswith(".expert_bias"):
+    another than the choice by ``s``.
+
+    Every PUBLISHED array (``"<i>.<name>"``, ``embed``, ``embedding_norm``)
+    has one key of the seed's, in the order of the sorted names, whatever
+    ``scan_plan`` makes of the layers: ``layer_of`` gives the same numbers
+    for a seed however the tree is stacked. A scan's arrays are stacked
+    here, once, one at a time (its members leave the device as it is made),
+    and never inside a program."""
+    tree = _tree(config)
+    published = sorted(member for members, _, _ in tree.values() for member in members)
+    keys = dict(zip(published, jax.random.split(jax.random.key(seed, impl="rbg"), len(published))))
+
+    def draw(member, shape, fan_in):
+        if fan_in is not None:
+            return _normal(keys[member], shape, 1.0 / float(np.sqrt(fan_in)), 0.0, dtype)
+        if member.endswith(".expert_bias"):
             # ``kimi_linear.init_weights``: at 0.1 one held expert was ten
             # times as busy as an even split (PERF.md, PR 31)
-            weights[name] = _normal(key, shape, 0.02, 0.0, dtype)
-        else:  # a norm's weight
-            weights[name] = _normal(key, shape, 0.1, 1.0, dtype)
+            return _normal(keys[member], shape, 0.02, 0.0, dtype)
+        return _normal(keys[member], shape, 0.1, 1.0, dtype)  # a norm's weight
+
+    weights = {}
+    for name, (members, shape, fan_in) in tree.items():
+        drawn = [draw(member, shape, fan_in) for member in members]
+        weights[name] = drawn[0] if len(drawn) == 1 else jnp.stack(drawn)
     return weights
 
 
 def layer_of(weights: dict, i: int) -> dict:
-    """Layer ``i``'s arrays (numbered from 0) under their own names."""
+    """Layer ``i``'s arrays (numbered from 0) under their published names,
+    whether the tree keeps them alone or as one step of a scan's."""
     prefix = f"{i}."
-    return {name[len(prefix) :]: a for name, a in weights.items() if name.startswith(prefix)}
+    layer = {name[len(prefix) :]: a for name, a in weights.items() if name.startswith(prefix)}
+    for name, a in weights.items():
+        stacked = _STACKED.fullmatch(name)
+        if stacked:
+            start, period, repeats, slot = map(int, stacked.groups()[:4])
+            step, at = divmod(i - start, period)
+            if 0 <= step < repeats and at == slot:
+                layer[stacked.group(5)] = a[step]
+    return layer
 
 
 # ---------------------------------------------------------------------------
@@ -294,14 +379,17 @@ def _attention_mixer(n, segment, position, layer, config: Lfm2Config):
     return _project(out.transpose(0, 2, 1, 3).reshape(rows, length, heads * d), layer["out_proj"])
 
 
-def _layer(x, segment, position, layer, i: int, config: Lfm2Config):
-    """Decoder layer ``i`` over ``x`` [B, L, hidden] float32: ``(x', [busiest
-    held expert's copies, copies routed to a held expert])`` of REAL tokens
-    (``segment`` not -1; zeros for a dense layer). ``segment`` None: every
-    row one session."""
+def _layer(x, segment, position, layer, conv: bool, dense: bool, config: Lfm2Config, first_group=0):
+    """One decoder layer over ``x`` [B, L, hidden] float32, its mixer a
+    convolution or attention and its feed-forward dense or sparse: ``(x',
+    [busiest held expert's copies, copies routed to a held expert, 1 if they
+    overflowed the held block (``ops/moe.held_expert_ffn``)])`` of REAL
+    tokens (``segment`` not -1; the padding's copies get no row); zeros for
+    a dense layer. ``segment`` None: every row one session. ``layer``'s
+    experts may be several layers' stacked, this one's from ``first_group``."""
     rows, length, hidden = x.shape
     eps = config.norm_eps
-    if config.is_conv(i):
+    if conv:
         with jax.named_scope("conv"):
             h = x + _conv_mixer(_rms(x, layer["operator_norm"], eps), position, layer)
     else:
@@ -309,12 +397,12 @@ def _layer(x, segment, position, layer, i: int, config: Lfm2Config):
             h = x + _attention_mixer(_rms(x, layer["operator_norm"], eps), segment, position, layer, config)
     # the feed-forward's pre-norm stands under its first reader's scope and
     # the residual sum under its last writer's (``kimi_linear._layer``: why)
-    if config.is_dense(i):
+    if dense:
         with jax.named_scope("dense"):
             n2 = _rms(h, layer["ffn_norm"], eps).reshape(rows * length, hidden)
             y = moe.gated_mlp(n2, layer["w1"], layer["w3"], layer["w2"])
             out = h + y.reshape(rows, length, hidden)
-        return out, jnp.zeros(2, jnp.int32)
+        return out, jnp.zeros(3, jnp.int32)
     first, count = config.experts_held
     with jax.named_scope("router"):
         n2 = _rms(h, layer["ffn_norm"], eps).reshape(rows * length, hidden)
@@ -325,18 +413,53 @@ def _layer(x, segment, position, layer, i: int, config: Lfm2Config):
         real = None if segment is None else (segment >= 0).reshape(-1)
         load = moe.expert_load(experts - first, count, real)
     with jax.named_scope("experts"):
-        y = moe.expert_ffn(
-            n2, weights, experts, layer["gate"], layer["up"], layer["down"], held=(first, count)
+        # the overflow's way out is the whole path behind a `cond`: this
+        # program's sparse bodies are few (``scan_plan``), and the rounds'
+        # loop costs every execution its carry (``ops/moe.HELD_ROOM``)
+        y, rounds = moe.held_expert_ffn(
+            n2, weights, experts, layer["gate"], layer["up"], layer["down"],
+            held=(first, count, config.num_experts), first_group=first_group, counted=real,
+            overflow="whole",
         )
         out = h + y.reshape(rows, length, hidden)
-    return out, jnp.stack([jnp.max(load), jnp.sum(load)])
+    return out, jnp.stack([jnp.max(load), jnp.sum(load), (rounds > 1).astype(jnp.int32)])
 
 
 def _layers(weights, x, segment, position, config: Lfm2Config):
-    counts = jnp.zeros(2, jnp.int32)
-    for i in range(config.num_hidden_layers):
-        x, counted = _layer(x, segment, position, layer_of(weights, i), i, config)
-        counts = counts + counted
+    """Every layer over ``x`` as ``scan_plan`` lays them out: ``(x, the
+    layers' three counts summed)``. A scan slices its small arrays; its
+    experts' stay whole, ``[repeats * held, ...]``, a step's from
+    ``step * held``."""
+    counts = jnp.zeros(3, jnp.int32)
+    held = config.experts_held[1]
+    for start, period, repeats in scan_plan(config):
+        kinds = [(config.is_conv(start + slot), config.is_dense(start + slot)) for slot in range(period)]
+        if repeats == 1:
+            x, counted = _layer(x, segment, position, layer_of(weights, start), *kinds[0], config)
+            counts = counts + counted
+            continue
+        small = [
+            {
+                name: weights[_stacked_name(start, period, repeats, slot, name)]
+                for name in _layer_shapes(config, start + slot)
+            }
+            for slot in range(period)
+        ]
+        # (a scan's layers are sparse; merging the two leading axes moves nothing)
+        whole = [
+            {name: (a := arrays.pop(name)).reshape(-1, *a.shape[2:]) for name in EXPERT_ARRAYS} for arrays in small
+        ]
+
+        def body(carry, scanned):
+            (x, counts), (step, sliced) = carry, scanned
+            for (conv, dense), arrays, experts in zip(kinds, sliced, whole):
+                x, counted = _layer(
+                    x, segment, position, {**arrays, **experts}, conv, dense, config, first_group=step * held
+                )
+                counts = counts + counted
+            return (x, counts), None
+
+        (x, counts), _ = lax.scan(body, (x, counts), (jnp.arange(repeats, dtype=jnp.int32), small))
     return x, counts
 
 
@@ -347,9 +470,10 @@ def session_vectors(weights, tokens, segment, position, last, *, config: Lfm2Con
     ``position`` [R, T] int32; ``last`` [R, S] int32, each session's last
     position IN ITS STREAM, -1 where a stream holds fewer than S. Returns
     the session vectors [R * S, hidden] float32, row by row (``rms(x_L;
-    embedding_norm)`` at ``last``; one at -1 is to be thrown away) and two
-    counts of copies of REAL tokens, summed over the sparse layers: what the
-    program's busiest held expert got, and what all the held experts got."""
+    embedding_norm)`` at ``last``; one at -1 is to be thrown away) and three
+    counts summed over the sparse layers, as ``kimi_linear``'s: the copies of
+    REAL tokens the program's busiest held expert got, those all the held
+    experts got, and the layers where they overflowed the held block."""
     with jax.named_scope("embed"):
         x = weights["embed"][tokens].astype(jnp.float32)
     x, counts = _layers(weights, x, segment, position, config)

@@ -23,12 +23,15 @@ gathers and multiplies a COMPACT block of ``held_block`` rows (a static part
 of the ``T * k``); a copy routed to an absent expert, or a padding token's,
 gets no row, meets no matrix and adds nothing. The block is a window on that
 layout: a routing whose held copies do not fit it (every token may send all
-its ``k`` to held experts) runs the same body over the next window too, under
-one ``lax.while_loop``: one round but for an overflow, and exact for any
-routing, since no copy is dropped. The loop costs a program by its sparse
-layers, so the choice is the layer's, which knows its depth (the readings
-stand beside ``HELD_ROOM``). No code stands in for the chips that hold the
-other experts or for the exchange with them.
+its ``k`` to held experts) has one of two ways out, exact for any routing,
+since no copy is dropped, and chosen by the CALLER (``overflow=``): the same
+body over the next window too, under one ``lax.while_loop`` (one round but
+for an overflow), or all of ``expert_ffn(held=)`` as the other branch of one
+``lax.cond``. The loop costs a program by the sparse layers it executes, the
+second path by the sparse bodies it compiles, so the choice is the layer's,
+which knows its program's depth (the readings stand beside ``HELD_ROOM``).
+No code stands in for the chips that hold the other experts or for the
+exchange with them.
 
 Scopes (``jax.named_scope``; the benchmark's per-layer metrics read them):
 ``router`` (the caller wraps :func:`route` in it), and inside ``experts``:
@@ -97,12 +100,17 @@ COLUMN_TILES = {1792: 896}
 # the kernels a sparse layer) ``kimi_linear`` 56.5 -> 63.5 / 37.2 -> 40.7 and
 # ``lfm2`` 93.9 -> 106.7 / 43.6 -> 55.9: 12 s more to load 132 kernels' worth
 # of program, over the set-up's bound; as further ROUNDS of one body under a
-# `lax.while_loop` (the form here) ``kimi_linear`` **57.1 -> 64.1 / 38.9 ->
+# `lax.while_loop` (the default here) ``kimi_linear`` **57.1 -> 64.1 / 38.9 ->
 # 39.8** and ``lfm2`` 93.6 -> 96.2 / 42.6 -> 47.1. The block itself is worth
 # as much at 256 rows a group as at 64; what differs is the DEPTH: a loop a
 # sparse layer costs a program of 22 unrolled layers most of what the block
-# saves and its set-up 10%, and one of 7 nothing. So ``lfm2``'s layer calls
-# ``expert_ffn(held=)`` until its sparse layers are one scanned body
+# saves and its set-up 10%, and one of 7 nothing; a second path costs by the
+# bodies that are COMPILED. With ``lfm2``'s 22 sparse layers as 7 scanned
+# bodies (PERF.md, PR 47; 93.84 answers a second and 42.1 s as the parent
+# had it, all the copies laid out): the `cond` **112.0 / 38.1** (44 kernels
+# a program for 72), the rounds 108.6 / 39.7: a scan does not take the
+# loop's carry away, 22 EXECUTIONS a program still pay it. So ``lfm2``'s layer
+# names ``overflow="whole"`` and ``kimi_linear``'s, unrolled, keeps the rounds
 HELD_ROOM = 2
 
 
@@ -322,7 +330,7 @@ def expert_ffn(x, weights, experts, gate, up, down, n_experts=None, first_group=
     return y
 
 
-def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, counted=None):
+def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, counted=None, overflow="rounds"):
     """:func:`expert_ffn` over the share ``held`` ``(first, count, width)``
     of a router ``width`` experts wide, with only the held copies laid out:
     ``(y [T, hidden] float32, rounds)``.
@@ -343,14 +351,26 @@ def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, co
     window by window, not in the order of its ``k`` (float32: the last bit
     may differ from :func:`expert_ffn`'s). Where :func:`held_block` has no
     block (half the experts or more held) this is ``expert_ffn(held=)``, in
-    one round."""
+    one round.
+
+    ``overflow`` is the CALLER's choice of the way out, by the depth of its
+    program (the readings stand beside ``HELD_ROOM``): ``"rounds"``, the loop
+    above; ``"whole"``, the first window straight-line and, for a routing
+    that does not fit it, all of ``expert_ffn(held=)`` as the other branch of
+    one ``lax.cond`` (``rounds`` 2 then): twice the kernels where it is
+    traced, nothing where it runs, and a token's ``k`` copies summed in
+    their order whichever branch ran."""
+    if overflow not in ("rounds", "whole"):
+        raise ValueError(f"overflow={overflow!r}: 'rounds' or 'whole'")
     first, count, width = held
     block = held_block(*experts.shape, count, width)
+
+    def whole():
+        masked = weights if counted is None else jnp.where(counted[:, None], weights, 0.0)
+        return expert_ffn(x, masked, experts, gate, up, down, first_group=first_group, held=(first, count))
+
     if block is None:
-        if counted is not None:
-            weights = jnp.where(counted[:, None], weights, 0.0)
-        y = expert_ffn(x, weights, experts, gate, up, down, first_group=first_group, held=(first, count))
-        return y, jnp.int32(1)
+        return whole(), jnp.int32(1)
     (tokens, k), (rows, tile) = experts.shape, block
     with jax.named_scope("sort"):
         place, at_home, start, padded = _held_layout(experts, first, count, tile, counted)
@@ -381,5 +401,9 @@ def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, co
 
     total = jnp.sum(padded)
     y = jnp.zeros((tokens, x.shape[1]), jnp.float32)
+    if overflow == "whole":
+        fits = total <= rows
+        y = lax.cond(fits, lambda: window((jnp.int32(0), y))[1], whole)
+        return y, jnp.where(fits, 1, 2).astype(jnp.int32)
     low, y = lax.while_loop(lambda carry: carry[0] < total, window, (jnp.int32(0), y))
     return y, low // rows

@@ -80,6 +80,15 @@ def upcast(weights):
     return jax.tree.map(lambda a: a.astype(jnp.float32), weights)
 
 
+def published(weights, layers=len(TINY["layer_types"])):
+    """The tree as the reference reads it: every layer's arrays under
+    ``"<i>.<name>"``, whatever ``lfm2.scan_plan`` stacked."""
+    flat = {name: weights[name] for name in ("embed", "embedding_norm")}
+    for i in range(layers):
+        flat.update({f"{i}.{name}": a for name, a in lfm2.layer_of(weights, i).items()})
+    return flat
+
+
 @pytest.fixture(scope="module")
 def trained():
     algorithm = Lfm2Algorithm(Lfm2AlgorithmParams(**TINY, seed=5))
@@ -100,7 +109,7 @@ _jitted: dict = {}
 def reference_answer(algorithm, model, session: np.ndarray, num: int):
     config = reference_config(algorithm.params)
     if id(model) not in _jitted:
-        weights = model.weights
+        weights = published(model.weights)
         _jitted[id(model)] = jax.jit(lambda t: reference.next_item_logits(weights, config, t))
     key = (id(model), session.tobytes())
     if key not in _logits:
@@ -298,7 +307,7 @@ def test_full_logits_equal_the_references(length, seed):
     got = np.asarray(lfm2.all_logits(weights, tokens, config=config))
     assert got.shape == (2, length, 128) and 0.5 < got.std() < 2.0  # of unit order
     for row in range(2):
-        want = reference.forward(weights, reference_config(params), tokens[row])
+        want = reference.forward(published(weights), reference_config(params), tokens[row])
         np.testing.assert_allclose(got[row], want, atol=ATOL, rtol=0)
 
 
@@ -311,7 +320,7 @@ def test_the_bf16_tree_stays_within_its_own_tolerance_of_the_reference(seed):
     tokens = np.random.default_rng(seed).integers(0, N_ITEMS, (2, 96)).astype(np.int32)
     got = np.asarray(lfm2.all_logits(weights, tokens, config=config))
     for row in range(2):
-        want = np.asarray(reference.forward(weights, reference_config(params), tokens[row]))
+        want = np.asarray(reference.forward(published(weights), reference_config(params), tokens[row]))
         worst = np.abs(got[row] - want).max(axis=-1)  # by position
         assert 1e-3 < np.median(worst) < BF16_MEDIAN
         assert (worst > BF16_TIPPED[0]).mean() < BF16_TIPPED[1]
@@ -327,7 +336,7 @@ def test_the_reference_tells_a_wrong_layer_from_the_right_one(fault):
     weights = upcast(lfm2.init_weights(config, 9))
     tokens = np.random.default_rng(9).integers(0, N_ITEMS, 80).astype(np.int32)
     got = np.asarray(lfm2.all_logits(weights, tokens[None], config=config))[0]
-    wrong = reference_config(params)
+    wrong, weights = reference_config(params), published(weights)
     if fault == "experts_per_tok":
         wrong["num_experts_per_tok"] = 3
     elif fault == "no_bias":
@@ -350,7 +359,8 @@ def test_the_counts_leave_the_padding_out_and_split_held_from_absent(trained):
     config = model.config
     stream = staged(algorithm, model, [np.arange(10, dtype=np.int32)], [64], 256)
     _, counted = lfm2.session_vectors(model.weights, *stream, config=config)
-    busiest, held = (int(c) for c in counted)
+    busiest, held, overflowed = (int(c) for c in counted)
+    assert overflowed == 0  # (the third count: the sparse layers whose held copies overflowed their block)
     routed = config.routed_copies(10)
     assert routed == 5 * 10 * 4  # five sparse layers, four copies a token
     assert 0 < held < routed and held / 20 <= busiest <= min(held, 5 * 10)
@@ -374,6 +384,196 @@ def test_the_padding_around_a_session_changes_nothing_of_it(trained):
     logits = lfm2.all_logits(model.weights, jnp.asarray(session)[None], config=config)
     head = np.asarray(model.weights["embed"], np.float32)
     np.testing.assert_allclose(vectors[0] @ head.T, np.asarray(logits)[0, -1], atol=ATOL)
+
+
+# ------------------------------------------------- scanned bodies, one tree
+
+PERIOD = ("full_attention", "conv", "conv", "conv")
+# layer lists (and their dense layers): the published one, the benchmark
+# harness's tiny one, and one in which no pattern of mixers repeats
+LISTS = {
+    "published": (("conv", "conv") + PERIOD * 4 + PERIOD[:3] * 2, 2),
+    "harness": (TINY["layer_types"], 1),
+    "no repeat": (("conv", "conv", "full_attention", "conv"), 1),
+}
+PLANS = {
+    "published": ((0, 1, 1), (1, 1, 1), (2, 4, 4), (18, 3, 2)),
+    "harness": ((0, 1, 1), (1, 2, 2), (5, 1, 1)),
+    "no repeat": ((0, 1, 1), (1, 1, 1), (2, 1, 1), (3, 1, 1)),
+}
+
+
+def params_of(case, seed):
+    kinds, dense = LISTS[case]
+    return Lfm2AlgorithmParams(
+        **{**TINY, "layer_types": kinds, "num_hidden_layers": len(kinds), "num_dense_layers": dense}, seed=seed
+    )
+
+
+def parents_logits(weights, tokens, config):
+    """The program as the parent ran it: layer by layer, unrolled, every
+    sparse layer through ``expert_ffn(held=)`` over ALL the copies."""
+    x = weights["embed"][tokens].astype(jnp.float32)
+    rows, length, hidden = x.shape
+    position = jnp.broadcast_to(jnp.arange(length), tokens.shape)
+    first, count = config.experts_held
+    for i in range(config.num_hidden_layers):
+        layer = lfm2.layer_of(weights, i)
+        n = lfm2._rms(x, layer["operator_norm"], config.norm_eps)
+        if config.is_conv(i):
+            h = x + lfm2._conv_mixer(n, position, layer)
+        else:
+            h = x + lfm2._attention_mixer(n, None, position, layer, config)
+        n2 = lfm2._rms(h, layer["ffn_norm"], config.norm_eps).reshape(rows * length, hidden)
+        if config.is_dense(i):
+            y = moe.gated_mlp(n2, layer["w1"], layer["w3"], layer["w2"])
+        else:
+            chosen = moe.route_sigmoid(
+                n2, layer["router"], layer["expert_bias"], config.num_experts_per_tok,
+                config.routed_scaling_factor, eps=lfm2.ROUTER_EPS,
+            )
+            y = moe.expert_ffn(n2, *chosen, layer["gate"], layer["up"], layer["down"], held=(first, count))
+        x = h + y.reshape(rows, length, hidden)
+    out = lfm2._rms(x, weights["embedding_norm"], config.norm_eps)
+    return jnp.dot(out, weights["embed"].astype(jnp.float32).T, precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("case", list(LISTS))
+def test_the_scanned_program_equals_the_reference_and_the_parents_unrolled_layers(case):
+    params = params_of(case, 12)
+    config = params.config()
+    assert lfm2.scan_plan(config) == PLANS[case]
+    weights = upcast(lfm2.init_weights(config, 12))
+    tokens = np.random.default_rng(12).integers(0, N_ITEMS, (2, 64)).astype(np.int32)
+    got = np.asarray(lfm2.all_logits(weights, tokens, config=config))
+    layers = config.num_hidden_layers
+    np.testing.assert_allclose(got, parents_logits(weights, jnp.asarray(tokens), config), atol=ATOL, rtol=0)
+    for row in range(2):
+        want = reference.forward(published(weights, layers), reference_config(params), tokens[row])
+        np.testing.assert_allclose(got[row], want, atol=ATOL, rtol=0)
+    # one scan a repeat, and none where nothing repeats: that list compiles unrolled
+    program = str(jax.make_jaxpr(lambda w, t: lfm2.all_logits.__wrapped__(w, t, config=config))(weights, tokens))
+    assert program.count(" scan[") == sum(repeats > 1 for _, _, repeats in PLANS[case])
+    # the sparse layers' bodies: a scan's pattern once, an unrolled layer's own
+    bodies = sum(period for start, period, _ in PLANS[case] if not config.is_dense(start))
+    assert program.count(" cond[") == bodies == {"published": 7, "harness": 3, "no repeat": 3}[case]
+
+
+def parents_draw(config, seed, dtype=jnp.bfloat16):
+    """``init_weights`` as the parent had it: one key a published array, the
+    names sorted, every array alone under ``"<i>.<name>"``."""
+    from predictionio_tpu.models.sequential.olmoe import _normal
+
+    h = config.hidden_size
+    specs = {"embed": ((config.vocab_size, h), h), "embedding_norm": ((h,), None)}
+    for i in range(config.num_hidden_layers):
+        specs.update({f"{i}.{name}": spec for name, spec in lfm2._layer_shapes(config, i).items()})
+    keys = jax.random.split(jax.random.key(seed, impl="rbg"), len(specs))
+    weights = {}
+    for key, (name, (shape, fan_in)) in zip(keys, sorted(specs.items())):
+        if fan_in is not None:
+            weights[name] = _normal(key, shape, 1.0 / float(np.sqrt(fan_in)), 0.0, dtype)
+        elif name.endswith(".expert_bias"):
+            weights[name] = _normal(key, shape, 0.02, 0.0, dtype)
+        else:
+            weights[name] = _normal(key, shape, 0.1, 1.0, dtype)
+    return weights
+
+
+@pytest.mark.parametrize("case,seed", [("published", 3), ("harness", 4), ("no repeat", 2**31 - 5)])
+def test_every_layers_arrays_are_the_parents_draw_for_the_seed_however_they_are_stacked(case, seed):
+    config = params_of(case, seed).config()
+    weights = lfm2.init_weights(config, seed)
+    assert weights.keys() == lfm2.weight_shapes(config).keys()
+    assert {name: a.shape for name, a in weights.items()} == lfm2.weight_shapes(config)
+    flat = published(weights, config.num_hidden_layers)
+    want = parents_draw(config, seed)
+    assert flat.keys() == want.keys()
+    for name in want:
+        assert flat[name].dtype == want[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(flat[name], np.float32), np.asarray(want[name], np.float32), err_msg=name)
+    # a scan's arrays lie stacked in the tree, its steps in front
+    stacked = [name for name in weights if "+" in name]
+    assert bool(stacked) == any(repeats > 1 for _, _, repeats in PLANS[case])
+    for start, period, repeats in PLANS[case]:
+        if repeats > 1:
+            gate = weights[f"{start}+{period}x{repeats}.0.gate"]
+            assert gate.shape == (repeats, 4, 64, 32)
+            np.testing.assert_array_equal(
+                np.asarray(gate[1], np.float32), np.asarray(want[f"{start + period}.gate"], np.float32)
+            )
+
+
+def routed(seed, tokens=256, held=(4, 4), all_held=False, padding=0):
+    """A layer's inputs: ``tokens`` tokens of which the last ``padding`` are
+    a stream's padding, 4 copies each over 16 experts (``all_held``: every
+    copy to one of the four held; the padding's always)."""
+    x, gate, up, down, router, bias = expert_case(seed, tokens=tokens)
+    first, count = held
+    if all_held:
+        bias = bias.at[first : first + count].add(10.0)
+    weights, experts = moe.route_sigmoid(x, router, bias, 4, 1.0, eps=lfm2.ROUTER_EPS)
+    real = jnp.arange(tokens) < tokens - padding
+    experts = jnp.where(real[:, None], experts, first + jnp.arange(4))
+    block = slice(first, first + count)
+    return x, weights, experts, gate[block], up[block], down[block], real
+
+
+@pytest.mark.parametrize("overflow", ["whole", "rounds"])
+@pytest.mark.parametrize("all_held", [True, False])
+def test_a_routing_that_overflows_the_block_takes_the_way_out_and_says_so(all_held, overflow):
+    x, weights, experts, gate, up, down, _ = routed(21, all_held=all_held)
+    assert moe.held_block(256, 4, 4, 16) == (512, 64)  # half of the 1,024 copies
+    want = moe.expert_ffn(x, weights, experts, gate, up, down, held=(4, 4))
+    got, rounds = moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(4, 4, 16), overflow=overflow)
+    assert int(rounds) == (2 if all_held else 1)  # (1,024 held copies are two windows of 512 too)
+    # (the other branch IS that path; compiled here and run eagerly there, the last bit may differ)
+    np.testing.assert_allclose(got, want, atol=1e-6 if all_held and overflow == "whole" else 1e-5, rtol=0)
+    assert float(jnp.abs(want).max()) > 0.1
+    with pytest.raises(ValueError, match="overflow"):
+        moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(4, 4, 16), overflow="cond")
+
+
+@pytest.mark.parametrize("case", ["every copy to a held expert", "the router's own"])
+def test_the_program_counts_the_sparse_layers_that_overflowed(trained, case):
+    algorithm, model = trained
+    config = model.config
+    weights = dict(model.weights)
+    if case == "every copy to a held expert":
+        for name in [n for n in weights if n.endswith(".expert_bias")]:
+            weights[name] = weights[name].at[..., 4:8].add(10.0)
+    session = np.random.default_rng(3).integers(0, N_ITEMS, 250).astype(np.int32)
+    monkeypatched = dataclasses.replace(config, max_position_embeddings=512)
+    stream = staged(algorithm, model, [session], [0], 256)
+    out, counted = lfm2.session_vectors(weights, *stream, config=monkeypatched)
+    overflowed = int(counted[2])
+    assert overflowed == (config.sparse_layers if case == "every copy to a held expert" else 0)
+    assert int(counted[1]) == (5 * 250 * 4 if overflowed else int(counted[1])) and int(counted[0]) <= int(counted[1])
+    # ... and the way out is exact: the parent's layers on the same tree
+    want = parents_logits(weights, jnp.asarray(session)[None], config)[0, -1]
+    head = np.asarray(weights["embed"], np.float32)
+    np.testing.assert_allclose(np.asarray(out[0]) @ head.T, want, atol=ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("overflow", ["whole", "rounds"])
+@pytest.mark.parametrize("padding", [64, 200])
+def test_a_streams_padding_gets_no_row_and_changes_nothing_of_a_real_token(padding, overflow):
+    # the padding's copies ALL name held experts: with rows of their own they would overflow the block
+    x, weights, experts, gate, up, down, real = routed(22, padding=padding)
+    got, rounds = moe.held_expert_ffn(
+        x, weights, experts, gate, up, down, held=(4, 4, 16), counted=real, overflow=overflow
+    )
+    assert int(rounds) == 1
+    uncounted, more = moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(4, 4, 16), overflow=overflow)
+    assert int(more) == 2
+    keep = np.asarray(real)
+    np.testing.assert_allclose(np.asarray(got)[keep], np.asarray(uncounted)[keep], atol=1e-5, rtol=0)
+    assert float(jnp.abs(got[~keep]).max()) == 0.0 and float(jnp.abs(uncounted[~keep]).max()) > 0.1
+    # ... and none of the copies counted are the padding's
+    load = moe.expert_load(experts - 4, 4, real)
+    everyones = int(moe.expert_load(experts - 4, 4).sum())
+    assert int(load.sum()) == int(moe.expert_load(experts[:-padding] - 4, 4).sum()) < everyones
+
 
 
 # sessions (their lengths) of ONE stream, where each starts, the stream's
@@ -411,7 +611,7 @@ def test_a_packed_streams_session_vectors_equal_the_sessions_alone(case, monkeyp
         )
         np.testing.assert_allclose(packed[row], alone[0], atol=ATOL, rtol=0, err_msg=f"session {row}")
     # ... and the reference's answer at the session's true length
-    want = reference.next_item_logits(model.weights, reference_config(params), sessions[1])
+    want = reference.next_item_logits(published(model.weights), reference_config(params), sessions[1])
     head = np.asarray(model.weights["embed"], np.float32)
     np.testing.assert_allclose(np.asarray(packed[1]) @ head.T, want, atol=ATOL)
 
@@ -689,10 +889,14 @@ def test_the_variant_file_carries_the_published_config_and_states_the_share():
     assert 2.52e9 < parameters < 2.54e9  # 5.05 GB in bfloat16
     whole = sum(int(np.prod(s)) for s in lfm2.weight_shapes(Lfm2AlgorithmParams().config()).values())
     assert 8.3e9 < whole < 8.4e9  # the published model, every expert held
-    assert shapes["2.gate"] == (8, 2048, 1792) and shapes["2.router"] == (2048, 32)
-    assert shapes["2.q_proj"] == (2048, 2048) and shapes["2.k_proj"] == (2048, 512) and shapes["2.q_layernorm"] == (64,)
+    # layers 2 to 17 are one scan of four steps over [attention, conv, conv, conv], each array its steps' stacked
+    assert lfm2.scan_plan(config) == ((0, 1, 1), (1, 1, 1), (2, 4, 4), (18, 3, 2))
+    assert shapes["2+4x4.0.gate"] == (4, 8, 2048, 1792) and shapes["2+4x4.0.router"] == (4, 2048, 32)
+    assert shapes["2+4x4.0.q_proj"] == (4, 2048, 2048) and shapes["2+4x4.0.k_proj"] == (4, 2048, 512)
+    assert shapes["2+4x4.0.q_layernorm"] == (4, 64) and shapes["18+3x2.2.in_proj"] == (2, 2048, 6144)
     assert shapes["0.in_proj"] == (2048, 6144) and shapes["0.conv"] == (3, 2048) and shapes["0.w1"] == (2048, 7168)
-    assert shapes["embed"] == (65536, 2048) and "lm_head" not in shapes and "2.w1" not in shapes
+    assert shapes["embed"] == (65536, 2048) and "lm_head" not in shapes
+    assert not any(name.endswith(".w1") for name in shapes if name[:2] not in ("0.", "1."))
 
 
 def test_save_then_load_is_equal_bit_for_bit_and_the_manifest_names_the_backbone(tmp_path, monkeypatch):
@@ -796,7 +1000,7 @@ def test_train_then_deploy_then_one_query_through_the_variant(tmp_path, monkeypa
             rows = json.loads(resp.read())["itemScores"]
         session = data.sequences[2]
         config = reference_config(algorithm.params)
-        logits = np.asarray(reference.next_item_logits(model.weights, config, session))
+        logits = np.asarray(reference.next_item_logits(published(model.weights), config, session))
         ids = [int(r["item"][1:]) for r in rows]
         assert len(ids) == 5 and not set(ids) & set(session.tolist()) and max(ids) < N_ITEMS
         # the served tree is bfloat16: within the bf16 tree's own tolerance of the reference
