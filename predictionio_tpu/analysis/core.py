@@ -110,6 +110,8 @@ _OBS_MODULE_GLOBS = _SERVING_ENTRY_GLOBS + ("*/models/sequential/*.py",)
 # these flow through — the names stay ONLY for the roots themselves.
 _PREDICT_ENTRY_GLOBS = (
     "*/models/*/engine.py",
+    # the sequential engine's backbones answer from the module below it
+    "*/models/sequential/backbone.py",
     "*/ann/*.py",
     "*/workflow/batch_predict.py",
     "*/controller/engine.py",

@@ -1,6 +1,6 @@
 """kanana-2-30b-a3b (``model_type: deepseek_v3``) as a session continuer: the
 device side of the sequential engine's ``kanana`` algorithm
-(``engine.KananaAlgorithm``).
+(``backbone.KananaAlgorithm``).
 
 A session's items are the tokens, as in ``olmoe.py``; the ANSWER is a
 generation, as ``sdar.py``'s is, but TOKEN BY TOKEN: ``num`` items in order,
@@ -30,7 +30,7 @@ them together), and a batch runs BOTH over one weight tree:
    ``generated_slots`` a session for the positions the steps add, session by
    session (one tile of the attention kernel's keys at the shipped sizes).
    Its capacity is fixed, so a step is ONE compiled shape; a batch whose
-   streams do not fit is answered in more than one group (``engine``).
+   streams do not fit is answered in more than one group (``backbone.GroupedAlgorithm``).
 3. The first item, ``first_pick``: ``lm_head`` over the prefills' vectors and
    the choice (``ops/topk.select_top_k`` beside a log-sum-exp, under the
    session's mask: never one of its own items nor one already chosen).
@@ -70,11 +70,12 @@ from jax import lax
 from predictionio_tpu.models.sequential.olmoe import (
     LENGTH_BUCKETS, SESSION_ALIGN, TOKEN_BUDGET, _at_last, _normal, _project, _rms, _rope, stream_shapes,
 )
+from predictionio_tpu.models.sequential.records import BackboneParams
 from predictionio_tpu.ops import moe, topk
 from predictionio_tpu.ops.attention import fused_attention
 
 __all__ = [
-    "KananaConfig", "SESSIONS", "MAX_SESSION", "SESSION_ALIGN", "TOKEN_BUDGET", "weight_shapes",
+    "KananaConfig", "KananaAlgorithmParams", "SESSIONS", "MAX_SESSION", "SESSION_ALIGN", "TOKEN_BUDGET", "weight_shapes",
     "init_weights", "layer_of", "session_vectors", "new_state", "first_pick", "decode_step", "answer_of",
 ]
 
@@ -178,6 +179,68 @@ class KananaConfig:
 
 
 Config = KananaConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class KananaAlgorithmParams(BackboneParams):
+    """The published ``config.json`` of kakaocorp/kanana-2-30b-a3b-instruct-2601
+    (``model_type: deepseek_v3``). ``head_dim`` (64, the rotary width) and
+    ``qk_head_dim`` (128 + 64) restate other keys and are held to them. The
+    one answers say: no low-rank queries (``q_lora_rank`` null), a sigmoid
+    router whose group limit is the identity (``n_group`` 1, ``topk_group``
+    1), the chosen weights renormalised, interleaved RoPE without scaling."""
+
+    attention_bias: bool = False
+    first_k_dense_replace: int = 1
+    head_dim: int = 64
+    hidden_act: str = "silu"
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    kv_lora_rank: int = 512
+    max_position_embeddings: int = 32768
+    model_type: str = "deepseek_v3"
+    moe_intermediate_size: int = 768
+    moe_layer_freq: int = 1
+    n_group: int = 1
+    n_routed_experts: int = 128
+    n_shared_experts: int = 2
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 32
+    num_experts_per_tok: int = 6
+    num_hidden_layers: int = 48
+    num_key_value_heads: int = 32
+    q_lora_rank: int | None = None
+    qk_head_dim: int = 192
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    rms_norm_eps: float = 1e-6
+    rope_interleave: bool = True
+    rope_scaling: dict | None = None
+    rope_theta: float = 1000000.0
+    routed_scaling_factor: float = 2.448
+    scoring_func: str = "sigmoid"
+    tie_word_embeddings: bool = False
+    topk_group: int = 1
+    topk_method: str = "noaux_tc"
+    v_head_dim: int = 128
+    vocab_size: int = 128256
+    seed: int = 3
+
+    ONE_ANSWER = {
+        "model_type": "deepseek_v3", "hidden_act": "silu", "attention_bias": False,
+        "q_lora_rank": None, "moe_layer_freq": 1, "n_group": 1, "topk_group": 1,
+        "norm_topk_prob": True, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+        "rope_interleave": True, "rope_scaling": None, "tie_word_embeddings": False,
+        "num_key_value_heads": lambda p: p.num_attention_heads,
+        "head_dim": lambda p: p.qk_rope_head_dim,
+        "qk_head_dim": lambda p: p.qk_nope_head_dim + p.qk_rope_head_dim,
+    }
+
+    def derived(self) -> dict:
+        return {
+            "routed_scaling_factor": float(self.routed_scaling_factor),
+            "rope_theta": float(self.rope_theta),
+        }
 
 
 # ---------------------------------------------------------------------------

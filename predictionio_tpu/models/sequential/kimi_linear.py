@@ -1,5 +1,5 @@
 """Kimi-Linear-48B-A3B's block as a session encoder: the device side of the
-sequential engine's ``kimi_linear`` algorithm (``engine.KimiLinearAlgorithm``).
+sequential engine's ``kimi_linear`` algorithm (``backbone.KimiLinearAlgorithm``).
 
 As ``olmoe.py`` is for OLMoE: a session's items are the tokens, one causal
 forward pass over the session (``session_vectors``, the SAME name, arguments
@@ -55,13 +55,14 @@ from jax import lax
 from predictionio_tpu.models.sequential.olmoe import (
     LENGTH_BUCKETS, SESSION_ALIGN, _at_last, _normal, _project, _rms, bucket_of, stream_shapes,
 )
+from predictionio_tpu.models.sequential.records import BackboneParams
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
 from predictionio_tpu.ops.linear_attention import CHUNK, kda, short_conv
 
 __all__ = [
-    "KimiLinearConfig", "TOKEN_BUDGET", "MAX_SESSION", "SESSION_ALIGN", "bucket_of", "weight_shapes",
-    "init_weights", "layer_of", "session_vectors", "all_logits",
+    "KimiLinearConfig", "KimiLinearAlgorithmParams", "TOKEN_BUDGET", "MAX_SESSION", "SESSION_ALIGN", "bucket_of",
+    "weight_shapes", "init_weights", "layer_of", "session_vectors", "all_logits",
 ]
 
 # tokens a stream holds (``MAX_SESSION`` where a session is longer)
@@ -173,6 +174,82 @@ class KimiLinearConfig:
 
 
 Config = KimiLinearConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiLinearAlgorithmParams(BackboneParams):
+    """The published ``config.json`` of moonshotai/Kimi-Linear-48B-A3B-Instruct
+    and the chip's share of a stated deployment: ``experts_held`` ``[first,
+    count]`` of the router's ``num_experts`` (all of them by default) and
+    ``vocab_slice`` ``[first, count]`` of ``vocab_size`` (items are the
+    slice's tokens). ``num_hidden_layers`` may be fewer than published: layers
+    1 to that, as ``linear_attn_config`` numbers them."""
+
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    head_dim: int = 72
+    kv_lora_rank: int = 512
+    q_lora_rank: int | None = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    linear_attn_config: dict = dataclasses.field(
+        default_factory=lambda: {
+            "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+            "head_dim": 128,
+            "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26],
+            "num_heads": 32,
+            "short_conv_kernel_size": 4,
+        }
+    )
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    moe_renormalize: bool = True
+    moe_router_activation_func: str = "sigmoid"
+    routed_scaling_factor: float = 2.446
+    use_grouped_topk: bool = True
+    num_expert_group: int = 1
+    topk_group: int = 1
+    num_nextn_predict_layers: int = 0
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    rope_scaling: dict | None = None
+    tie_word_embeddings: bool = False
+    vocab_size: int = 163840
+    model_max_length: int = 1048576
+    model_type: str = "kimi_linear"
+    experts_held: tuple | None = None
+    vocab_slice: tuple | None = None
+    seed: int = 3
+
+    ONE_ANSWER = {
+        "model_type": "kimi_linear", "hidden_act": "silu", "mla_use_nope": True,
+        "q_lora_rank": None, "moe_layer_freq": 1, "moe_renormalize": True,
+        "moe_router_activation_func": "sigmoid", "num_expert_group": 1, "topk_group": 1,
+        "num_nextn_predict_layers": 0, "rope_scaling": None, "tie_word_embeddings": False,
+        "num_key_value_heads": lambda p: p.num_attention_heads,
+    }
+
+    def derived(self) -> dict:
+        linear = self.linear_attn_config
+        return {
+            "kda_num_heads": linear["num_heads"],
+            "kda_head_dim": linear["head_dim"],
+            "short_conv_kernel_size": linear["short_conv_kernel_size"],
+            "kda_layers": tuple(linear["kda_layers"]),
+            "full_attn_layers": tuple(linear["full_attn_layers"]),
+            "experts_held": tuple(self.experts_held or (0, self.num_experts)),
+            "vocab_slice": tuple(self.vocab_slice or (0, self.vocab_size)),
+        }
 
 if SESSION_ALIGN % CHUNK:
     raise ImportError(f"sessions aligned to {SESSION_ALIGN} do not start on the scan's chunks of {CHUNK}")

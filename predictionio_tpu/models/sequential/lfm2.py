@@ -1,12 +1,12 @@
 """LFM2-8B-A1B as a session encoder: the device side of the sequential
-engine's ``lfm2`` algorithm (``engine.Lfm2Algorithm``).
+engine's ``lfm2`` algorithm (``backbone.Lfm2Algorithm``).
 
 As ``olmoe.py`` and ``kimi_linear.py`` are for their backbones: a session's
 items are the tokens, one causal forward pass over the session
 (``session_vectors``, the SAME name, arguments and results, so that the
 engine's launch and the benchmark's readers serve all of them) gives the
 final-normed hidden state at its last real position, and the engine scores it
-against the embedding (the head is TIED to it: ``engine.BackboneModel.head``).
+against the embedding (the head is TIED to it: ``backbone.BackboneModel.head``).
 Layer equations: ``lfm2_reference.py``, which the tests and the benchmark hold
 this to.
 
@@ -71,12 +71,13 @@ from jax import lax
 from predictionio_tpu.models.sequential.olmoe import (
     LENGTH_BUCKETS, SESSION_ALIGN, _at_last, _normal, _project, _rms, _rope, bucket_of, stream_shapes,
 )
+from predictionio_tpu.models.sequential.records import BackboneParams
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
 from predictionio_tpu.ops.linear_attention import short_conv
 
 __all__ = [
-    "Lfm2Config", "TOKEN_BUDGET", "STACKED_ROWS", "MAX_SESSION", "SESSION_ALIGN", "ROUTER_EPS",
+    "Lfm2Config", "Lfm2AlgorithmParams", "TOKEN_BUDGET", "STACKED_ROWS", "MAX_SESSION", "SESSION_ALIGN", "ROUTER_EPS",
     "bucket_of", "weight_shapes", "init_weights", "layer_of", "gated_conv", "session_vectors",
     "all_logits",
 ]
@@ -193,6 +194,50 @@ class Lfm2Config:
 
 
 Config = Lfm2Config
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2AlgorithmParams(BackboneParams):
+    """The published ``config.json`` of LiquidAI/LFM2-8B-A1B and the chip's
+    share of a stated deployment: ``experts_held`` ``[first, count]`` of the
+    router's ``num_experts`` (all of them by default). ``layer_types`` is the
+    published LIST, a mixer's kind a layer."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_hidden_layers: int = 24
+    layer_types: tuple = ("conv", "conv", "full_attention", "conv") * 5 + (
+        "conv", "full_attention", "conv", "conv",
+    )
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    num_dense_layers: int = 2
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    vocab_size: int = 65536
+    max_position_embeddings: int = 128000
+    model_type: str = "lfm2_moe"
+    experts_held: tuple | None = None
+    seed: int = 3
+
+    ONE_ANSWER = {
+        "model_type": "lfm2_moe", "conv_bias": False, "norm_topk_prob": True, "use_expert_bias": True,
+    }
+
+    def derived(self) -> dict:
+        return {
+            "routed_scaling_factor": float(self.routed_scaling_factor),
+            "rope_theta": float(self.rope_theta),
+            "experts_held": tuple(self.experts_held or (0, self.num_experts)),
+        }
 
 
 # ---------------------------------------------------------------------------

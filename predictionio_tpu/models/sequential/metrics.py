@@ -59,7 +59,7 @@ class SeqInstruments:
 
 class BackboneInstruments:
     """What a backbone scorer (``olmoe``, ``kimi_linear``, ``lfm2``, ``sdar``, ``kanana``) launched, counted
-    where it happens (``engine.BackboneAlgorithm``). The expert counters are
+    where it happens (``backbone.BackboneAlgorithm``). The expert counters are
     over the experts the chip HOLDS. An algorithm starts with a registry of its
     own; a query server that serves it hands over its registry through
     ``register_metrics``, so two deployments in one process count apart."""

@@ -1,5 +1,5 @@
 """OLMoE-1B-7B as a session encoder: the device side of the sequential
-engine's ``olmoe`` algorithm (``engine.OlmoeAlgorithm``).
+engine's ``olmoe`` algorithm (``backbone.OlmoeAlgorithm``).
 
 A session's items are the tokens. One causal forward pass over the session
 (``session_vectors``) and the final-normed hidden state at its LAST real
@@ -34,7 +34,7 @@ The weights are drawn from a seed, not fitted: fitting the backbone is not
 this engine's work yet (ROADMAP R7).
 
 ``save_arrays`` / ``load_array`` at the end are the storage of every
-backbone's model (``engine.BackboneModel``), not OLMoE's alone.
+backbone's model (``backbone.BackboneModel``), not OLMoE's alone.
 """
 
 from __future__ import annotations
@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from predictionio_tpu.models.sequential.records import BackboneParams
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import fused_attention
 
@@ -135,6 +136,37 @@ class OlmoeConfig:
 
 
 Config = OlmoeConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoeAlgorithmParams(BackboneParams):
+    """The published ``config.json`` of allenai/OLMoE-1B-7B-0125-Instruct."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 1024
+    num_hidden_layers: int = 16
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    num_experts: int = 64
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = False
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    rope_scaling: dict | None = None
+    attention_bias: bool = False
+    clip_qkv: float | None = None
+    tie_word_embeddings: bool = False
+    vocab_size: int = 50304
+    max_position_embeddings: int = 4096
+    model_type: str = "olmoe"
+    seed: int = 3
+
+    ONE_ANSWER = {
+        "model_type": "olmoe", "hidden_act": "silu", "norm_topk_prob": False,
+        "rope_scaling": None, "attention_bias": False, "clip_qkv": None,
+        "tie_word_embeddings": False, "num_key_value_heads": lambda p: p.num_attention_heads,
+    }
 
 
 def stream_shapes(budget: int, max_session: int) -> tuple[int, ...]:

@@ -1,5 +1,5 @@
 """SDAR-30B-A3B-Chat as a session continuer: the device side of the
-sequential engine's ``sdar`` algorithm (``engine.SdarAlgorithm``).
+sequential engine's ``sdar`` algorithm (``backbone.SdarAlgorithm``).
 
 A session's items are the tokens, as in ``olmoe.py``; the ANSWER is not one
 scoring but a generation: ``num`` items in order, produced block by block by
@@ -25,7 +25,7 @@ What a batch is, on the device (the engine drives it):
    end, then ``config.most_passes`` CHUNKS of ``SESSIONS * B``: pass ``t``
    writes every session's block there, one contiguous piece. Its capacity
    is fixed, so the pass below is ONE compiled shape; a batch whose streams
-   do not fit is answered in more than one group (``engine``).
+   do not fit is answered in more than one group (``backbone.GroupedAlgorithm``).
 3. PASSES, ``denoise_pass``: all of the group's sessions in one program.
    Each session's CURRENT block goes in as its ``B`` tokens (mask id where
    masked) at its positions; a layer writes the blocks' keys and values into
@@ -59,11 +59,12 @@ from jax import lax
 from predictionio_tpu.models.sequential.olmoe import (
     LENGTH_BUCKETS, SESSION_ALIGN, TOKEN_BUDGET, _normal, _project, _rms, _rope, stream_shapes,
 )
+from predictionio_tpu.models.sequential.records import BackboneParams
 from predictionio_tpu.ops import moe, topk
 from predictionio_tpu.ops.attention import fused_attention
 
 __all__ = [
-    "SdarConfig", "SESSIONS", "MAX_SESSION", "SESSION_ALIGN", "TOKEN_BUDGET", "weight_shapes",
+    "SdarConfig", "SdarAlgorithmParams", "SESSIONS", "MAX_SESSION", "SESSION_ALIGN", "TOKEN_BUDGET", "weight_shapes",
     "init_weights", "layer_of", "session_vectors", "new_state", "denoise_pass", "answer_of",
 ]
 
@@ -182,6 +183,57 @@ class SdarConfig:
 
 
 Config = SdarConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class SdarAlgorithmParams(BackboneParams):
+    """The published ``config.json`` of JetLM/SDAR-30B-A3B-Chat, and what the
+    generation needs and ``config.json`` has no key for: ``block_length``,
+    ``denoising_steps`` (denoise passes a block) and ``mask_token_id`` (the
+    vocabulary's last id by default; never an item). ``intermediate_size``
+    names a dense feed-forward that no layer has (``mlp_only_layers`` []):
+    stated, not built."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple = ()
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    rope_scaling: dict | None = None
+    attention_bias: bool = False
+    sliding_window: int | None = None
+    use_sliding_window: bool = False
+    max_window_layers: int = 48
+    tie_word_embeddings: bool = False
+    vocab_size: int = 151936
+    max_position_embeddings: int = 32768
+    model_type: str = "sdar_moe"
+    block_length: int = 4
+    denoising_steps: int = 4
+    mask_token_id: int | None = None
+    seed: int = 3
+
+    ONE_ANSWER = {
+        "model_type": "sdar_moe", "hidden_act": "silu", "norm_topk_prob": True,
+        "decoder_sparse_step": 1, "mlp_only_layers": (), "rope_scaling": None,
+        "attention_bias": False, "sliding_window": None, "use_sliding_window": False,
+        "tie_word_embeddings": False,
+    }
+
+    def derived(self) -> dict:
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("sdar: the key/value heads do not divide the heads")
+        return {"mask_token_id": self.vocab_size - 1 if self.mask_token_id is None else self.mask_token_id}
 
 
 # ---------------------------------------------------------------------------

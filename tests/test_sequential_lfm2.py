@@ -804,7 +804,7 @@ def test_lfm2_is_an_algorithm_of_the_engine_that_shares_olmoes_serving():
     # no staging, batching or serving code of its own
     own = {name for name in vars(Lfm2Algorithm) if not name.startswith("__")}
     assert own == {"params_class", "model_class"}
-    assert {name for name in vars(Lfm2Model) if not name.startswith("__")} == {"program"}
+    assert {name for name in vars(Lfm2Model) if not name.startswith("__")} == {"module"}
     for name in ("_plan", "_stage", "_stack", "_answer", "predict_batch_dispatch", "warmup_serving", "train"):
         assert getattr(Lfm2Algorithm, name) is getattr(BackboneAlgorithm, name) is getattr(OlmoeAlgorithm, name)
     assert Lfm2Model.load.__func__ is OlmoeAlgorithm.model_class.load.__func__
