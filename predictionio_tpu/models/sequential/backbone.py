@@ -30,7 +30,7 @@ from typing import Any, Sequence
 import numpy as np
 
 from predictionio_tpu.controller import JaxAlgorithm, Params, PersistentModel, SanityCheck
-from predictionio_tpu.models.sequential import kanana, kimi_linear, lfm2, olmoe, sdar
+from predictionio_tpu.models.sequential import granite, kanana, kimi_linear, lfm2, olmoe, sdar
 from predictionio_tpu.models.sequential.metrics import BackboneInstruments
 from predictionio_tpu.models.sequential.olmoe import load_array, load_header, save_arrays
 from predictionio_tpu.models.sequential.records import ItemScore, PredictedResult, Query, TrainingData
@@ -696,12 +696,25 @@ class KananaAlgorithm(GroupedAlgorithm):
         return members, answer, counted, (routed, config.n_routed_experts), (state["reached"], offered)
 
 
+class GraniteModel(BackboneModel):
+    module = granite
+
+
+class GraniteAlgorithm(BackboneAlgorithm):
+    """``granite``: granite-4.0-h-small's block (``granite.py``)."""
+
+    params_class = granite.GraniteAlgorithmParams
+    params: granite.GraniteAlgorithmParams
+    model_class = GraniteModel
+
+
 BACKBONES = {
     "olmoe": OlmoeAlgorithm,
     "kimi_linear": KimiLinearAlgorithm,
     "sdar": SdarAlgorithm,
     "lfm2": Lfm2Algorithm,
     "kanana": KananaAlgorithm,
+    "granite": GraniteAlgorithm,
 }
 
 # a stored model names its class as ``<module>.<name>``

@@ -49,10 +49,12 @@ from predictionio_tpu.e2.markov_chain import MarkovChainModel, train_markov_chai
 # what lies below this module and is read from it, the top of the package, by
 # `__init__`, stored models' class paths, the benchmark and the tests
 from predictionio_tpu.models.sequential.backbone import (  # noqa: F401
-    BACKBONES, BackboneAlgorithm, BackboneModel, GroupedAlgorithm, KananaAlgorithm, KananaModel,
+    BACKBONES, BackboneAlgorithm, BackboneModel, GraniteAlgorithm, GraniteModel, GroupedAlgorithm, KananaAlgorithm,
+    KananaModel,
     KimiLinearAlgorithm, KimiLinearModel, Lfm2Algorithm, Lfm2Model, OlmoeAlgorithm, OlmoeModel,
     SdarAlgorithm, SdarModel, session_tails,
 )
+from predictionio_tpu.models.sequential.granite import GraniteAlgorithmParams  # noqa: F401
 from predictionio_tpu.models.sequential.kanana import KananaAlgorithmParams  # noqa: F401
 from predictionio_tpu.models.sequential.kimi_linear import KimiLinearAlgorithmParams  # noqa: F401
 from predictionio_tpu.models.sequential.lfm2 import Lfm2AlgorithmParams  # noqa: F401

@@ -1,6 +1,8 @@
-"""Linear attention by the gated delta rule with a decay a CHANNEL (Kimi
-Delta Attention, KDA), evaluated chunk by chunk, and the short causal
-convolution that feeds it.
+"""Two recurrent token mixers evaluated chunk by chunk, and the short causal
+convolution that feeds both: linear attention by the gated delta rule with a
+decay a CHANNEL (Kimi Delta Attention, ``kda``: this docstring down to "The
+state-space scan") and Mamba-2's state-space scan with a decay a HEAD and no
+delta rule (``ssd``: the last section).
 
 A head keeps a state ``S`` [keys, values] and reads it with its query::
 
@@ -47,6 +49,34 @@ padding behind its last real position follows them inside the chunk and is
 seen by none); ``short_conv(position=)`` leaves out the taps that would reach
 before a session's first position. The final state of a padded row is the
 padding's too, and of use only to a caller that passed no padding.
+
+The state-space scan (``ssd``). A head keeps a state ``S`` [values, state]
+that decays by ONE scalar a step and is read with ``C``; ``B`` and ``C`` are
+one group's, the same for every head::
+
+    S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T,   y_t = S_t C_t + d x_t
+
+with ``dt_t > 0`` a head (the caller's ``softplus``) and ``a < 0`` a head.
+Over a chunk, ``G_t`` the running sum of ``dt a`` from the chunk's start
+(position ``t`` included)::
+
+    Y[t] = exp(G_t) S_0 C_t + sum_{s <= t} (C_t . B_s) exp(G_t - G_s) dt_s x_s
+    S_C  = exp(G_C) S_0 + sum_s exp(G_C - G_s) dt_s x_s B_s^T
+
+There is no solve, so nothing but ``S_0`` ties a chunk to the one before:
+every chunk's triangle, its own sum into ``S_C`` and, once the states are
+known, what ``S_0`` adds to ``Y`` are computed for ALL chunks at once, and the
+``lax.scan`` over the chunks is the second line alone (a multiply and an add
+a state). ``C_t . B_s`` is computed once for all heads. Every exponent taken
+is at most 0 (``G`` only falls, and only ``G_t - G_s`` with ``s <= t`` is
+taken); ``G`` starts anew in every chunk, as the published kernels' does, so
+its float32 sum is over a chunk's positions and no more.
+
+Here the chunk is the CALLER's to choose (the same function at any width),
+and it may be wider than the multiple sessions start on: ``ssd(segment=)``
+takes a session id a position (the published kernels' ``seq_idx``). A pair
+``(t, s)`` of two sessions gives nothing, and ``S_0`` reaches only the
+positions of the session that was live at the end of the chunk before.
 """
 
 from __future__ import annotations
@@ -57,7 +87,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["short_conv", "kda", "CHUNK"]
+__all__ = ["short_conv", "kda", "ssd", "CHUNK"]
 
 # positions a chunk holds (a power of two): the published kernels' choice
 CHUNK = 64
@@ -69,10 +99,12 @@ PRECISION = lax.Precision.HIGH
 _dot = functools.partial(jnp.einsum, precision=PRECISION, preferred_element_type=jnp.float32)
 
 
-def short_conv(x, w, tail=None, position=None, activation=jax.nn.silu):
-    """``activation`` (``silu``, Kimi-Linear's; None for none, LFM2's, whose
-    caller gates the input and the output itself) of a causal depthwise
-    convolution over positions: ``x``
+def short_conv(x, w, tail=None, position=None, activation=jax.nn.silu, bias=None):
+    """``activation`` (``silu``, Kimi-Linear's and Granite's Mamba-2 layers';
+    None for none, LFM2's, whose caller gates the input and the output itself)
+    of a causal depthwise convolution over positions, plus ``bias`` [D]
+    INSIDE the activation where there is one (Granite's ``mamba_conv_bias``):
+    ``x``
     [B, L, D] float32, ``w`` [taps, D] (``w[-1]`` meets the position itself),
     ``tail`` [B, taps - 1, D] the inputs that came before position 0 (zeros
     when there were none). ``position`` [B, L] int32, where several sessions
@@ -93,6 +125,8 @@ def short_conv(x, w, tail=None, position=None, activation=jax.nn.silu):
         return jnp.where((position >= taps - 1 - j)[:, :, None], reached, 0.0)
 
     y = sum(w[j] * tap(j) for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     return (y if activation is None else activation(y)), padded[:, length:]
 
 
@@ -189,3 +223,78 @@ def kda(q, k, v, g, b, state=None, starts=None):
     # [n, B, heads, chunk, d_v] -> [B, L, heads, d_v]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(batch, n * chunk, heads, d_v)
     return o[:, :length], state
+
+
+def ssd(x, dt, a, b, c, d=None, state=None, segment=None, chunk: int = 256):
+    """Mamba-2's state-space scan (the module's last section) over ``x``
+    [B, L, heads, p], ``dt`` [B, L, heads] (the step, positive), ``a``
+    [heads] (negative), ``b`` and ``c`` [B, L, n] (ONE group: every head
+    reads the same) and the skip ``d`` [heads] (None: none), all float32.
+    ``state`` [B, heads, p, n] is what came before position 0 (zeros by
+    default). ``segment`` [B, L] int32, where several sessions share a row,
+    is each position's session id (a session's positions are contiguous and
+    its id its own, as ``ops/attention.fused_attention(segment=)`` takes
+    them; a negative id is padding, whose output means nothing and which no
+    session sees): wherever a session begins, inside a chunk or on its first
+    position, it begins from a zero state. ``chunk`` positions are evaluated
+    at once: any width gives the same function.
+    Returns ``(y [B, L, heads, p], the state after position L - 1)``.
+
+    ``L`` is padded to whole chunks with positions that leave the state as it
+    is (``dt`` 0)."""
+    batch, length, heads, p = x.shape
+    n = -(-length // chunk)
+    pad = n * chunk - length
+
+    def chunked(v, fill=0):
+        # [B, L, ...] -> [B, chunks, chunk, ...]
+        if pad:
+            v = jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2), constant_values=fill)
+        return v.reshape((batch, n, chunk) + v.shape[2:])
+
+    x, dt, b, c = (v.astype(jnp.float32) for v in (x, dt, b, c))
+    a = a.astype(jnp.float32)
+    xs = chunked(dt[..., None] * x)  # what a position adds, [B, n, C, heads, p]
+    bs, cs = chunked(b), chunked(c)
+    cum = jnp.cumsum(chunked(dt * a), axis=2)  # G, [B, n, C, heads]
+    at = jnp.arange(chunk)
+    if segment is None:
+        same = (at[:, None] >= at[None, :])[None, None]
+        carried = ends = None
+    else:
+        seg = chunked(segment, -1)  # [B, n, C]
+        same = (seg[..., :, None] == seg[..., None, :]) & (at[:, None] >= at[None, :])
+        # the session live at the end of the chunk before (the first chunk's: the row's first)
+        before = jnp.concatenate([seg[:, :1, 0], seg[:, :-1, -1]], axis=1)
+        carried = seg == before[..., None]  # S_0 reaches these positions
+        ends = seg == seg[..., -1:]  # ... and these reach S_C
+    # the decays inside a chunk, [B, n, heads, C(t), C(s)]: exponents at most 0
+    g = jnp.moveaxis(cum, 3, 2)
+    decay = jnp.exp(jnp.where(same[:, :, None], g[..., :, None] - g[..., None, :], -jnp.inf))
+    m = _dot("bntk,bnsk->bnts", cs, bs)[:, :, None] * decay
+    y = _dot("bnhts,bnshp->bnthp", m, xs)
+    # each chunk's own sum into the state it hands on, and what it keeps of S_0
+    out = jnp.exp(cum[:, :, -1:] - cum)  # [B, n, C, heads]
+    kept = jnp.exp(cum[:, :, -1])  # [B, n, heads]
+    if segment is not None:
+        out = jnp.where(ends[..., None], out, 0.0)
+        kept = jnp.where(carried[:, :, -1, None], kept, 0.0)
+    own = _dot("bnshp,bnsk->bnhpk", xs * out[..., None], bs)
+
+    def step(s, xs):
+        kept, own = xs
+        return kept[..., None, None] * s + own, s
+
+    if state is None:
+        state = jnp.zeros((batch, heads, p, b.shape[-1]), jnp.float32)
+    state, s_0 = lax.scan(
+        step, state.astype(jnp.float32), (jnp.moveaxis(kept, 1, 0), jnp.moveaxis(own, 1, 0))
+    )
+    reach = jnp.exp(cum)
+    if segment is not None:
+        reach = jnp.where(carried[..., None], reach, 0.0)
+    y = y + reach[..., None] * _dot("bntk,nbhpk->bnthp", cs, s_0)
+    y = y.reshape(batch, n * chunk, heads, p)[:, :length]
+    if d is not None:
+        y = y + d.astype(jnp.float32)[:, None] * x
+    return y, state

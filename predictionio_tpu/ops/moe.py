@@ -75,7 +75,8 @@ SHORT_GROUP = 64
 # 46.0 / 201.0 at 256, 45.5 / 200.3 at 1,024 (this table's default rule);
 # 1,792 whole is refused by Mosaic (16.96 MB of scoped VMEM of 16). A width
 # with no entry keeps the rule below, so OLMoE's and Kimi-Linear's 1,024 and
-# SDAR's 768 compile to the tiles they had
+# SDAR's, kanana's and Granite's 768 (whole lanes: one tile of columns, 768
+# wide) compile to the tiles they had
 COLUMN_TILES = {1792: 896}
 # The compact block of a chip that holds a SHARE of its router's experts
 # (``held_block``): room for ``HELD_ROOM`` times the copies the held experts

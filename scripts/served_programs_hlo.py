@@ -9,7 +9,9 @@ compared with every source location taken out.
 ``compile`` writes the optimised HLO of the programs that the cells serve
 whose layers call ``ops/moe`` and ``ops/attention`` (``seq-olmoe``: ``[4, 2048]``,
 ``[1, 2048]``, ``[1, 4096]``; ``seq-kimi-linear`` and ``seq-lfm2-moe``:
-``[1, 2048]``, ``[1, 4096]``; ``seq-sdar-moe``: a denoise pass and both
+``[1, 2048]``, ``[1, 4096]``, and ``seq-granite-4-h``'s where the tree has
+them (a tree from before PR 49 has no such module and the pair of trees is
+compared on the rest); ``seq-sdar-moe``: a denoise pass and both
 prefills), from the code
 under ROOT, one file a program. ``diff`` prints, a program, the lines
 that differ once locations are gone: the tables of files, functions and frames
@@ -23,6 +25,7 @@ says the edit left those cells' programs alone (PERF.md section 6: PR 33, 34,
 import base64
 import glob
 import importlib
+import importlib.util
 import json
 import os
 import re
@@ -62,11 +65,15 @@ def compile_programs(root: str, out: str) -> None:
             f.write(compiled.as_text())
         print(name, flush=True)
 
-    for backbone, cell, streams in (
+    served = [
         (olmoe, "seq-olmoe", ((4, 2048), (1, 2048), (1, 4096))),
         (kimi_linear, "seq-kimi-linear", ((1, 2048), (1, 4096))),
         (lfm2, "seq-lfm2-moe", ((1, 2048), (1, 4096))),
-    ):
+    ]
+    if importlib.util.find_spec("predictionio_tpu.models.sequential.granite"):
+        granite = importlib.import_module("predictionio_tpu.models.sequential.granite")
+        served.append((granite, "seq-granite-4-h", ((1, 2048), (1, 4096))))
+    for backbone, cell, streams in served:
         name = backbone.__name__.rsplit(".", 1)[1]
         engine = importlib.import_module(f"benchmark.engines.sequential_{name}")
         with open(os.path.join(root, "benchmark", "configs", cell + ".json")) as f:

@@ -58,6 +58,14 @@ PUBLISHED = {
         routed_scaling_factor=2.448, rms_norm_eps=1e-06, rope_theta=1000000.0, vocab_size=128256,
         max_position_embeddings=32768, cache_tokens=31744, generated_slots=32,
     )),
+    "granite": ("granite-4.0-h-small.json", "granite", dict(
+        hidden_size=4096, intermediate_size=768, shared_intermediate_size=1536, num_hidden_layers=10,
+        layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4, num_attention_heads=32,
+        num_key_value_heads=8, mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128, mamba_d_conv=4,
+        num_local_experts=72, num_experts_per_tok=10, attention_multiplier=0.0078125, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0, rms_norm_eps=1e-05, max_position_embeddings=131072,
+        experts_held=(0, 36), vocab_slice=(0, 50176),
+    )),
 }
 
 every_backbone = pytest.mark.parametrize("name", sorted(BACKBONES))
@@ -195,7 +203,7 @@ def test_nothing_above_the_table_names_a_backbone():
     only from its first backbone's class on (the model classes, the algorithm
     classes, the table): the shared host side serves whatever the table holds."""
     named = re.compile("|".join(sorted({name.split("_")[0] for name in BACKBONES})), re.IGNORECASE)
-    assert named.pattern == "kanana|kimi|lfm2|olmoe|sdar"
+    assert named.pattern == "granite|kanana|kimi|lfm2|olmoe|sdar"
     engine = without_imports((PACKAGE / "engine.py").read_text())
     assert len(engine) < 800
     assert [(at + 1, line) for at, line in enumerate(engine) if named.search(line)] == []

@@ -389,6 +389,55 @@ def test_lfm2s_whole_depth_compiles_for_v5e_beside_the_model(one_chip, monkeypat
     assert memory.temp_size_in_bytes < 2.0e9
     assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
 
+@pytest.mark.parametrize("chunk", [64, 128, 256])
+def test_the_chunked_state_space_scan_compiles_for_v5e_at_the_published_heads(one_chip, chunk):
+    """``ops/linear_attention.ssd`` at granite-4.0-h-small's 128 heads of 64
+    over a state of 128, one stream of 2,048 tokens under session ids, at the
+    three chunks the chip was asked about: the triangles grow with the chunk
+    (its temporaries stay well under a gigabyte at the widest)."""
+    from predictionio_tpu.ops.linear_attention import ssd
+
+    length, heads, p, n = 2048, 128, 64, 128
+    args = (
+        _shape(one_chip, (1, length, heads, p)), _shape(one_chip, (1, length, heads)), _shape(one_chip, (heads,)),
+        _shape(one_chip, (1, length, n)), _shape(one_chip, (1, length, n)), _shape(one_chip, (heads,)),
+    )
+    segment = _shape(one_chip, (1, length), jnp.int32)
+    compiled = jax.jit(lambda *a, segment: ssd(*a, segment=segment, chunk=chunk)).lower(*args, segment=segment).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    y, state = jax.eval_shape(lambda *a: ssd(*a, chunk=chunk), *args)
+    assert y.shape == (1, length, heads, p) and state.shape == (1, heads, p, n)
+
+
+@pytest.mark.parametrize("length", [2048, 4096])
+def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch, length):
+    """``granite.session_vectors`` at ``seq-granite-4-h``'s widths, ten
+    layers unrolled, at both lengths of its closed set: ONE attention kernel
+    at 32 query heads over 8 of 128 and 10 x 3 grouped products over 36 held
+    experts 768 wide with hidden 4,096 as the contraction (every copy laid
+    out: half the router is held, no second path); the served weights are its
+    arguments, 9.51 GB, and its temporaries leave room for a second batch
+    and the float32 table on a chip of 16.9 GB."""
+    import json
+    from pathlib import Path
+
+    from benchmark.engines import sequential_granite as engine
+    from predictionio_tpu.models.sequential import engine_factory, granite
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    file = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-granite-4-h.json").read_text())
+    config = engine_factory().engine_params_from_variant(engine.variant_of(file, 5)).algorithms[0][1].config()
+    weights = {name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in granite.weight_shapes(config).items()}
+    assert granite.STACKED_ROWS == 1 and config.stream_shapes() == (2048, 4096)
+    stream = _shape(one_chip, (1, length), jnp.int32)
+    last = _shape(one_chip, (1, granite.TOKEN_BUDGET // granite.SESSION_ALIGN), jnp.int32)
+    compiled = granite.session_vectors.lower(weights, stream, stream, stream, last, config=config).compile()
+    memory = compiled.memory_analysis()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1 + 10 * 3
+    assert memory.temp_size_in_bytes < (1.6e9 if length == 2048 else 3.0e9)
+    assert 9.5e9 < memory.argument_size_in_bytes < 9.6e9
+
+
 
 @pytest.mark.parametrize("slots", [512, 32768])
 def test_absorbed_latent_attention_compiles_for_v5e_with_the_values_cut_out_of_the_keys(one_chip, monkeypatch, slots):
