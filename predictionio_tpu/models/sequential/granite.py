@@ -391,10 +391,11 @@ def attention_mixer(n, segment, layer, config: GraniteConfig):
 def _layer(x, segment, position, layer, i: int, config: GraniteConfig):
     """Decoder layer ``i`` over ``x`` [B, L, hidden] float32: ``(x', [busiest
     held expert's copies, copies routed to a held expert, 1 if they overflowed
-    the held block (``ops/moe.held_expert_ffn``: never where half the experts
-    are held, which lays out every copy)])`` of REAL tokens (``segment`` not
-    -1; the padding's copies count for nothing). ``segment`` and ``position``
-    None: every row one session."""
+    the held block (``ops/moe.held_expert_ffn``: at half the experts held, 36
+    of 72, all the copies' rows, every held group from a tile's edge)])`` of
+    REAL tokens (``segment`` not -1; the padding's copies count for nothing
+    and get no row). ``segment`` and ``position`` None: every row one
+    session."""
     rows, length, hidden = x.shape
     eps, residual = config.rms_norm_eps, config.residual_multiplier
     if config.is_mamba(i):
@@ -413,9 +414,11 @@ def _layer(x, segment, position, layer, i: int, config: GraniteConfig):
         real = None if segment is None else (segment >= 0).reshape(-1)
         load = moe.expert_load(experts - first, count, real)
     with jax.named_scope("experts"):
+        # the way out on an overflow: ten layers unrolled take the loop, as
+        # ``kimi_linear``'s seven (the readings stand beside ``moe.HELD_ROOM``)
         y, rounds = moe.held_expert_ffn(
             n2, weights, experts, layer["gate"], layer["up"], layer["down"],
-            held=(first, count, config.num_local_experts), counted=real,
+            held=(first, count, config.num_local_experts), counted=real, overflow="rounds",
         )
     with jax.named_scope("shared"):
         y = y + moe.gated_mlp(n2, layer["shared_gate"], layer["shared_up"], layer["shared_down"])

@@ -20,10 +20,13 @@ sorts all ``T * k`` copies, the absent behind the held groups.
 holds and no others, every held group from a multiple of the grouped
 product's row tile, so that no tile of rows meets two experts, and sorts,
 gathers and multiplies a COMPACT block of ``held_block`` rows (a static part
-of the ``T * k``); a copy routed to an absent expert, or a padding token's,
-gets no row, meets no matrix and adds nothing. The block is a window on that
-layout: a routing whose held copies do not fit it (every token may send all
-its ``k`` to held experts) has one of two ways out, exact for any routing,
+of the ``T * k``: twice the copies the held experts are expected to get,
+half of all at a quarter of the experts; at half of them all the rows, and
+what the layout saves is what the rows cost); a copy routed to an absent
+expert, or a padding token's, gets no row, meets no matrix and adds nothing.
+The block is a window on that layout: a routing whose held copies do not fit
+it (every token may send all its ``k`` to held experts) has one of two ways
+out, exact for any routing,
 since no copy is dropped, and chosen by the CALLER (``overflow=``): the same
 body over the next window too, under one ``lax.while_loop`` (one round but
 for an overflow), or all of ``expert_ffn(held=)`` as the other branch of one
@@ -80,10 +83,10 @@ SHORT_GROUP = 64
 COLUMN_TILES = {1792: 896}
 # The compact block of a chip that holds a SHARE of its router's experts
 # (``held_block``): room for ``HELD_ROOM`` times the copies the held experts
-# are expected to get (half of all ``T * k`` at a quarter of the experts), in
-# row tiles of the power of two at or under the rows a held group is expected
-# to have, from 8 (a vector register's sublanes: the kernel takes no less) to
-# ``TILING``'s own. From the chip (PERF.md, PR 42).
+# are expected to get (half of all ``T * k`` at a quarter of the experts; all
+# the copies' rows at half of them or more), in row tiles of the power of two
+# at or under the rows a held group is expected to have, from 8 (a vector register's sublanes: the kernel takes no less) to
+# ``TILING``'s own. From the chip (PERF.md, PR 42; the half share PR 50).
 # The tile and the room, by the WHOLE served program at [1, 2048] / [1, 4096]
 # tokens (ms; the models' own routers, four seeded streams; "all" lays out
 # all the copies, as ``expert_ffn(held=)`` does; the block's overflow as the
@@ -95,7 +98,18 @@ COLUMN_TILES = {1792: 896}
 #   (64 groups x half a tile of padding beside 4,096 held copies): 76.3 / 161.5;
 #   ``lfm2`` (8 of 32, 4 a token, 256 / 512 rows a group, 22 sparse layers):
 #   all 45.2 / 100.8; **39.8 / 84.6** at this rule (tiles of 256, half the
-#   copies); 40.3 / 84.9 at tiles of 128.
+#   copies); 40.3 / 84.9 at tiles of 128;
+#   ``granite`` (36 of 72, 10 a token, 284 / 568 rows a group, ten sparse
+#   layers; the overflow in rounds; with the combine's re-layout still in
+#   it): all 125.6 / 258.2; three quarters of the copies in tiles of 64
+#   137.0, of 128 125.3 / 263.4, of 256 **118.7 / 252.8** (the products alone
+#   27.7 -> 38.1, 26.3, 19.7 ms a program: at 128 rows and fewer a visit is
+#   bound by the matrix it loads); the block's SIZE moves nothing the clock
+#   shows, and seven eighths of the copies overflowed in 1 layer of 10 once
+#   no token was padding (a seeded router's held half gets up to 58% of a
+#   layer's copies): a half share takes all the copies' rows. With them, in
+#   tiles of 256, and the combine gathered copy by copy: **103.2 / 223.2**
+#   (no token padding 110.7, no overflow; seven eighths again 102.9).
 # The overflow, by the cells (`answered_qps` / cached `setup_s`, parent ->
 # change, pairs sharing a seed): as the other branch of a `lax.cond` (twice
 # the kernels a sparse layer) ``kimi_linear`` 56.5 -> 63.5 / 37.2 -> 40.7 and
@@ -111,7 +125,12 @@ COLUMN_TILES = {1792: 896}
 # had it, all the copies laid out): the `cond` **112.0 / 38.1** (44 kernels
 # a program for 72), the rounds 108.6 / 39.7: a scan does not take the
 # loop's carry away, 22 EXECUTIONS a program still pay it. So ``lfm2``'s layer
-# names ``overflow="whole"`` and ``kimi_linear``'s, unrolled, keeps the rounds
+# names ``overflow="whole"`` and ``kimi_linear``'s, unrolled, keeps the rounds;
+# ``granite``'s ten unrolled layers (PERF.md, PR 50; two seeds each, the
+# parent 32.9 to 34.8 / 31.5 to 35.7): the rounds 39.92 and 39.37 / 35.8 and
+# 37.5, the `cond` 39.24 and 39.41 / 43.3 (108 s where it compiled; 34.06 and
+# 32.92 / 42.7 against the rounds' 34.06 and 33.59 / 36.4 in the block's first
+# form): as many answers and 6 s of set-up more, so it names the rounds
 HELD_ROOM = 2
 
 
@@ -231,14 +250,19 @@ def held_block(tokens: int, k: int, count: int, n_experts: int):
     from static shapes alone: the row tile is the power of two at or under
     the rows a held group is EXPECTED to have (``tokens * k / n_experts``),
     from 8 to ``TILING``'s own, and the block holds ``HELD_ROOM`` times the
-    copies the held experts are expected to get, a whole number of tiles.
-    None where the block would be no less than all the copies (the share is
-    too large for it to save a row)."""
+    copies the held experts are expected to get, or all the copies' rows
+    where that is less (more than ``1 / HELD_ROOM`` of the experts held), a
+    whole number of tiles. What the layout needs of it is the expected copies
+    and a tile a held group beside them (half a tile a group is what rounding
+    the groups up costs on average, the rest is the router's imbalance's):
+    None where that does not fit (all or nearly all the experts held: the
+    block would overflow on every routing)."""
     copies = tokens * k
     group = max(copies // n_experts, 1)
     tile = min(TILING[0], max(8, 1 << (group.bit_length() - 1)))
-    rows = -(-HELD_ROOM * copies * count // (n_experts * tile)) * tile
-    return (rows, tile) if rows < copies else None
+    expected = -(-copies * count // n_experts)
+    rows = min(-(-HELD_ROOM * copies * count // (n_experts * tile)), copies // tile) * tile
+    return (rows, tile) if expected + count * tile <= rows else None
 
 
 def _held_layout(experts, first: int, count: int, tile: int, counted=None):
@@ -351,8 +375,8 @@ def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, co
     never a tile; a token whose copies lie in two windows has them summed
     window by window, not in the order of its ``k`` (float32: the last bit
     may differ from :func:`expert_ffn`'s). Where :func:`held_block` has no
-    block (half the experts or more held) this is ``expert_ffn(held=)``, in
-    one round.
+    block (all or nearly all the experts held) this is ``expert_ffn(held=)``,
+    in one round.
 
     ``overflow`` is the CALLER's choice of the way out, by the depth of its
     program (the readings stand beside ``HELD_ROOM``): ``"rounds"``, the loop
@@ -373,6 +397,12 @@ def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, co
     if block is None:
         return whole(), jnp.int32(1)
     (tokens, k), (rows, tile) = experts.shape, block
+    # a float32 tile is 8 rows: ``[T * k, hidden]`` seen as ``[T, k, hidden]``
+    # is the same bytes where 8 divides ``k`` or ``k`` divides 8 (the tile
+    # shrinks to it) and a padded COPY of them all otherwise (10 rows lie in
+    # 16: ``granite``'s combine took 3.7 ms a layer with it and the sum over
+    # it, 2.0 without: PERF.md, PR 50), which the combine then goes around
+    padded_k = bool(k % 8 and 8 % k)
     with jax.named_scope("sort"):
         place, at_home, start, padded = _held_layout(experts, first, count, tile, counted)
         token = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32)[:, None], (tokens, k)).reshape(-1)
@@ -396,9 +426,12 @@ def held_expert_ffn(x, weights, experts, gate, up, down, held, first_group=0, co
         with jax.named_scope("combine"):
             # a copy reads its row; one that has none here counts as zero
             # (the select rides in the sum's own pass over the gathered rows)
-            out = out[jnp.where(here, local, 0).reshape(-1)]
-            out = jnp.where(here.reshape(-1)[:, None], out, 0.0).reshape(tokens, k, -1)
-            return low + rows, y + jnp.sum(out * weights[..., None], axis=1)
+            # (where ``k`` lies in no tile the rows are gathered copy by copy,
+            # [k, T, hidden], and summed over the MAJOR axis: no re-layout)
+            row, mine, weight = (a.T if padded_k else a for a in (jnp.where(here, local, 0), here, weights))
+            out = out[row.reshape(-1)]
+            out = jnp.where(mine.reshape(-1)[:, None], out, 0.0).reshape(*row.shape, -1)
+            return low + rows, y + jnp.sum(out * weight[..., None], axis=0 if padded_k else 1)
 
     total = jnp.sum(padded)
     y = jnp.zeros((tokens, x.shape[1]), jnp.float32)

@@ -236,9 +236,86 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_onc
 
 
 def test_half_the_router_held_lays_out_every_copy_and_a_quarter_a_compact_block():
-    # the cell's share and the fallback's, at a 2,048-token program
-    assert moe.held_block(2048, 10, 36, 72) is None
+    # (the name is PR 49's; since PR 50 a half share has a block too.) The
+    # cell's share at its two programs: twice the expected copies are all of
+    # them, so the block has all the copies' rows, in the tile at or under
+    # the 284 / 568 rows a group; what it saves is not rows but what the rows
+    # cost: no argsort, every group from a tile's edge, no row for padding
+    assert moe.held_block(2048, 10, 36, 72) == (20480, 256)
+    assert moe.held_block(4096, 10, 36, 72) == (40960, 256)
+    # the fallback's quarter: twice its expected copies, as Kimi-Linear's and LFM2's
     assert moe.held_block(2048, 10, 18, 72) == (10240, 256)
+    # these tests' half, third and quarter
+    assert moe.held_block(96, 3, 6, 12) == (288, 16)
+    assert moe.held_block(96, 3, 4, 12) == (192, 16) and moe.held_block(96, 3, 3, 12) == (144, 16)
+    # the expected copies and a tile a held group have to fit: three quarters
+    # of the experts held, or all, is all the copies laid out by ``expert_ffn``
+    assert moe.held_block(2048, 10, 54, 72) is None and moe.held_block(2048, 10, 72, 72) is None
+
+
+# the block at a HALF share: 96 tokens at 3 copies over 12 experts, each half
+# of the router a block of 288 rows in tiles of 16 for 288 copies (and 3
+# copies a token is no part or multiple of a tile's 8 rows: the combine sums
+# over the major axis, as at the cell's 10)
+HALF_CASES = (
+    "the router's own choice", "the router's own choice and two tokens of three padding",
+    "every copy to one half", "every copy to one half and two tokens of three padding",
+)
+
+
+@pytest.mark.parametrize("kernel", ["ragged_dot", "interpreted"])
+@pytest.mark.parametrize("overflow", ["rounds", "whole"])
+@pytest.mark.parametrize("case", HALF_CASES)
+def test_the_two_halves_add_up_to_the_uncut_layer_through_the_block(case, overflow, kernel, monkeypatch):
+    """Each half of the router through its compact block, the way out on an
+    overflow in both forms, with XLA's ``ragged_dot`` or the chip's kernel
+    interpreted at the block's tile: the halves add up to the uncut layer
+    (every copy laid out, and the reference's loop), a routing planted to
+    send EVERY copy to one half overflows that half's block and is still
+    exact, and a padding token gets no row: its ``y`` is zero and every real
+    token's is what it was."""
+    n_experts, k, count = 12, 3, 6
+    m, layer = expert_case(10 + HALF_CASES.index(case))
+    tokens = m.shape[0]
+    rows, tile = moe.held_block(tokens, k, count, n_experts)
+    weights, experts = moe.route(m, layer["router"], k, renormalise=True)
+    if case.startswith("every copy to one half"):
+        rng = np.random.default_rng(HALF_CASES.index(case))
+        experts = jnp.asarray(np.stack([6 + rng.permutation(count)[:k] for _ in range(tokens)]).astype(np.int32))
+    real = jnp.arange(tokens) % 3 == 0 if case.endswith("padding") else None
+    counted = weights if real is None else jnp.where(real[:, None], weights, 0.0)
+    if kernel == "interpreted":
+        monkeypatch.setattr(
+            moe, "grouped_matmul",
+            lambda lhs, rhs, sizes, dtype, **kw: moe.grouped_matmul_kernel(lhs, rhs, sizes, dtype, interpret=True, **kw),
+        )
+    whole = moe.expert_ffn(m, counted, experts, layer["gate"], layer["up"], layer["down"])
+    dense = jnp.zeros((tokens, n_experts)).at[jnp.arange(tokens)[:, None], experts].add(counted)
+    want = reference.experts(m, dense, layer, (0, n_experts))
+    ours, took = jnp.zeros_like(whole), []
+    for first in (0, 6):
+        part = [layer[name][first : first + count] for name in ("gate", "up", "down")]
+        y, rounds = moe.held_expert_ffn(
+            m, weights, experts, *part, held=(first, count, n_experts), counted=real, overflow=overflow
+        )
+        # the rounds that the half's groups, each from a tile's edge, take over the block
+        mine = np.asarray(experts)[slice(None) if real is None else np.asarray(real)]
+        padded = sum(-(-int((mine == e).sum()) // tile) * tile for e in range(first, first + count))
+        assert int(rounds) == (-(-padded // rows) if overflow == "rounds" else 1 + (padded > rows))
+        np.testing.assert_allclose(
+            y, moe.expert_ffn(m, counted, experts, *part, held=(first, count)), atol=2e-5, rtol=0
+        )
+        if real is not None:
+            assert not np.asarray(y)[~np.asarray(real)].any()
+            bare, _ = moe.held_expert_ffn(m, weights, experts, *part, held=(first, count, n_experts), overflow=overflow)
+            np.testing.assert_allclose(y[real], bare[real], atol=2e-5, rtol=0)
+        ours, took = ours + y, took + [int(rounds)]
+    # every copy to one half and every token real: 288 copies and their
+    # groups' rounding in a block of 288 rows
+    assert (max(took) > 1) == (case == "every copy to one half")
+    np.testing.assert_allclose(ours, whole, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(ours, want, atol=2e-5, rtol=0)
+    assert float(jnp.abs(ours).max()) > 100 * 2e-5
 
 
 # ------------------------------------------------------------ the program

@@ -345,9 +345,12 @@ def test_the_block_and_its_tile_follow_the_shapes_and_a_large_share_has_none():
     assert moe.held_block(2048, 8, 64, 256) == (8192, 64) and moe.held_block(4096, 8, 64, 256) == (16384, 128)
     # LFM2's shapes: 256 and 512 rows a held expert take the kernel's own tile and no longer one
     assert moe.held_block(2048, 4, 8, 32) == (4096, 256) and moe.held_block(4096, 4, 8, 32) == (8192, 256)
-    # half of the experts or more: a block of all the copies saves nothing,
-    # and all the copies are laid out, in one round
-    assert moe.held_block(96, 4, 8, 16) is None and moe.held_block(96, 4, 16, 16) is None
+    # half of the experts: twice the expected copies are all of them, and the
+    # block has all the copies' rows (PR 50: the held groups from a tile's
+    # edge, the absent with no row); every expert: the expected copies and a
+    # tile a group do not fit, and ``expert_ffn`` lays all the copies out, in
+    # one round
+    assert moe.held_block(96, 4, 8, 16) == (384, 16) and moe.held_block(96, 4, 16, 16) is None
     x, gate, up, down, router, _ = expert_case(5)
     weights, experts = moe.route(x, router, 4)
     named, rounds = moe.held_expert_ffn(x, weights, experts, gate, up, down, held=(0, 16, 16))

@@ -414,8 +414,12 @@ def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch
     """``granite.session_vectors`` at ``seq-granite-4-h``'s widths, ten
     layers unrolled, at both lengths of its closed set: ONE attention kernel
     at 32 query heads over 8 of 128 and 10 x 3 grouped products over 36 held
-    experts 768 wide with hidden 4,096 as the contraction (every copy laid
-    out: half the router is held, no second path); the served weights are its
+    experts 768 wide with hidden 4,096 as the contraction, over the block of
+    the held copies (half the router is held: all the copies' rows, 20,480
+    and 40,960, in tiles of 256, every held group from a tile's edge; the
+    overflow in rounds of the same body, no second path) and NO re-layout of
+    the combine's gathered rows (ten copies a token lie in no float32 tile:
+    they are gathered copy by copy); the served weights are its
     arguments, 9.51 GB, and its temporaries leave room for a second batch
     and the float32 table on a chip of 16.9 GB."""
     import json
@@ -423,8 +427,10 @@ def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch
 
     from benchmark.engines import sequential_granite as engine
     from predictionio_tpu.models.sequential import engine_factory, granite
+    from predictionio_tpu.ops import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.held_block(length, 10, 36, 72) == (10 * length, 256)
     file = json.loads((Path(engine.__file__).parents[1] / "configs" / "seq-granite-4-h.json").read_text())
     config = engine_factory().engine_params_from_variant(engine.variant_of(file, 5)).algorithms[0][1].config()
     weights = {name: _shape(one_chip, shape, jnp.bfloat16) for name, shape in granite.weight_shapes(config).items()}
@@ -432,8 +438,9 @@ def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch
     stream = _shape(one_chip, (1, length), jnp.int32)
     last = _shape(one_chip, (1, granite.TOKEN_BUDGET // granite.SESSION_ALIGN), jnp.int32)
     compiled = granite.session_vectors.lower(weights, stream, stream, stream, last, config=config).compile()
-    memory = compiled.memory_analysis()
-    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1 + 10 * 3
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 + 10 * 3
+    assert f"f32[{length},10,4096]" not in text and f"f32[10,{length},4096]" in text
     assert memory.temp_size_in_bytes < (1.6e9 if length == 2048 else 3.0e9)
     assert 9.5e9 < memory.argument_size_in_bytes < 9.6e9
 
