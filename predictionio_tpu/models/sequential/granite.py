@@ -18,8 +18,10 @@ numbered from 0 as the list numbers them). The token mixer is MAMBA-2
 causal depthwise convolution of four taps with a bias under SiLU
 (``ops/linear_attention.short_conv(bias=)``), the state-space scan
 (``ops/linear_attention.ssd``: 128 heads of 64 over a state of 128, ``B`` and
-``C`` of ONE group, the step ``softplus(dt + dt_bias)``, the skip ``D``) and an
-RMSNorm over all 8,192 channels of ``y * silu(z)``) or grouped-query
+``C`` of ONE group, the step ``softplus(dt + dt_bias)``, the skip ``D``; on
+the chip ONE Pallas kernel a layer, ``ssd_kernel``, whose grid walks the
+chunks in order with a block of heads' state in VMEM, off it the XLA form)
+and an RMSNorm over all 8,192 channels of ``y * silu(z)``) or grouped-query
 attention WITHOUT positions (``attention``: 32 query heads over 8 key/value
 heads of 128, no rotary embedding, no norm a head,
 ``ops/attention.fused_attention``). Every layer's feed-forward is sparse
@@ -46,7 +48,9 @@ as its rows, as ``olmoe.py``'s is (``segment``, ``position``): attention sees
 a key only from inside its own row and segment, the scan's state reaches a
 position only from its own session (``ssd(segment=)``: a chunk of ``SSD_CHUNK``
 positions may hold several sessions, which start on multiples of
-``SESSION_ALIGN``) and the convolution reaches no further back than a
+``SESSION_ALIGN``; the kernel takes the ids into the chunk's mask and XLA, in
+front of it, into what the state reaches and what reaches the state) and the
+convolution reaches no further back than a
 session's first item, so a session's positions come out as they would alone.
 The experts and the shared expert take the tokens of all rows at once.
 """
@@ -93,7 +97,11 @@ MAX_SESSION = 4096
 # at any width: a test holds 64, 128 and 256 to the recurrence). From the chip
 # (PERF.md, PR 49; the whole program at [1, 2048], ms): 64: 145.7, **128:
 # 126.3**, 256: 127.1 (the triangles' bytes grow with the chunk, the states
-# handed on fall with it)
+# handed on fall with it). The kernel that serves since PR 51 (PERF.md, PR
+# 51; the bare scan at [1, 2048] / [1, 4096], ms a call by the wall clock of
+# nine chained calls, a pass over ``y`` between them in it): **128: 0.45 /
+# 1.07**, 256: 0.52 / 1.20 (a head's triangle is twice the elements a token;
+# the kernel's own events in the cell's trace at 128: 0.34 / 0.65)
 SSD_CHUNK = 128
 
 MAMBA, ATTENTION = "mamba", "attention"

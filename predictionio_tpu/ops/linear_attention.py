@@ -87,7 +87,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["short_conv", "kda", "ssd", "CHUNK"]
+__all__ = ["short_conv", "kda", "ssd", "ssd_kernel", "ssd_tiles", "CHUNK"]
 
 # positions a chunk holds (a power of two): the published kernels' choice
 CHUNK = 64
@@ -241,7 +241,15 @@ def ssd(x, dt, a, b, c, d=None, state=None, segment=None, chunk: int = 256):
     Returns ``(y [B, L, heads, p], the state after position L - 1)``.
 
     ``L`` is padded to whole chunks with positions that leave the state as it
-    is (``dt`` 0)."""
+    is (``dt`` 0).
+
+    One function in two forms, chosen by what can be seen here (as
+    ``ops/moe.grouped_matmul`` chooses its): on the chip, at the shapes the
+    kernel tiles (``ssd_tiles``), ``ssd_kernel``, which keeps a chunk in
+    VMEM; off the chip and at any other shape the XLA form below, which is
+    the kernel's yardstick in the tests too."""
+    if jax.default_backend() == "tpu" and ssd_tiles(x.shape[2], x.shape[3], b.shape[-1], chunk):
+        return ssd_kernel(x, dt, a, b, c, d, state=state, segment=segment, chunk=chunk)
     batch, length, heads, p = x.shape
     n = -(-length // chunk)
     pad = n * chunk - length
@@ -298,3 +306,184 @@ def ssd(x, dt, a, b, c, d=None, state=None, segment=None, chunk: int = 256):
     if d is not None:
         y = y + d.astype(jnp.float32)[:, None] * x
     return y, state
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan as one kernel
+# ---------------------------------------------------------------------------
+
+# channels of ``x`` and ``y`` one step of the kernel's grid holds: its block
+# of heads (16 heads of 64). From the chip (PERF.md, PR 51; the bare scan at
+# [1, 2048] / [1, 4096] in chunks of 128, ms a call by the wall clock of nine
+# chained calls, a pass over ``y`` between them in every reading): 512: 0.53 /
+# 1.23, **1024: 0.45 / 1.07**, 2048: 0.42 / 1.01, which does not fit VMEM at a
+# chunk of 256 (a step of the grid costs 0.6 us beside its work; the XLA form
+# 1.83 / 5.50; the kernel's own events in the cell's trace: 0.34 / 0.65)
+SSD_WIDTH = 1024
+_TILE = 128  # lanes of a vector tile, rows and columns of the matrix unit
+
+
+def _head_block(heads: int, p: int) -> int:
+    """Heads a step of the kernel's grid holds."""
+    return min(heads, max(1, SSD_WIDTH // p))
+
+
+def ssd_tiles(heads: int, p: int, n: int, chunk: int) -> bool:
+    """Whether the chip is served these shapes by ``ssd_kernel``: the ones it
+    was compiled for (``tests/test_tpu_compile.py``) and timed at on the chip,
+    a head of 64 channels over a state of 128 (granite-4.0-h-small's), the
+    heads whole blocks of ``SSD_WIDTH`` channels, a chunk of 128 or 256. The
+    body asks for less (the chunk, the state's and a block's width whole tiles
+    of lanes, a head's channels whole tiles of rows), but what a step holds in
+    VMEM grows with all four and a shape that overflows it fails in Mosaic
+    where the XLA form runs: another shape joins when it has been compiled
+    and timed."""
+    return (p, n) == (64, 128) and chunk in (128, 256) and heads % (SSD_WIDTH // p) == 0
+
+
+def _split(v):
+    """float32 ``v`` as its bfloat16 rounding and what that leaves."""
+    high = v.astype(jnp.bfloat16)
+    return high, (v - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _dot3(a, b):
+    """``a @ b`` of two float32 matrices, each given as its ``_split``, in
+    ``PRECISION``'s three bfloat16 passes with float32 sums (Mosaic takes no
+    ``Precision.HIGH``: the passes XLA makes of it, written out)."""
+    (a_high, a_low), (b_high, b_low) = a, b
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    return dot(a_high, b_high) + (dot(a_high, b_low) + dot(a_low, b_high))
+
+
+def _ssd_chunk(
+    skip_ref, x_ref, c_ref, b_ref, g_s_ref, rows_ref, kept_ref, seg_s_ref, seg_t_ref, s0_ref,
+    y_ref, s_ref, yt_ref, xw_ref, *, block: int, p: int,
+):
+    """One chunk of one block of heads (``ssd_kernel``: the operands).
+    Inside, positions are LANES: ``x`` and ``C`` are transposed as they come
+    into VMEM and ``y`` as it leaves, so a head's channels are whole tiles of
+    rows, what a position and head have of their own (the step, ``G``) is a
+    row laid over them, and a head's triangle is built as ``[s, t]``. The
+    block's state ``[block * p, n]`` stays in ``s_ref`` from a row's first
+    chunk to its last."""
+    from jax.experimental import pallas as pl
+
+    chunk = x_ref.shape[0]
+    first = pl.program_id(1) * block
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first():
+        s_ref[...] = s0_ref[...]
+
+    s_at = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    t_at = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    same = (seg_s_ref[...] == seg_t_ref[...]) & (s_at <= t_at)
+    ct, b = _split(c_ref[...].T), _split(b_ref[...])
+    cb = jnp.where(same, _dot3(b, ct), 0.0)  # C_t . B_s at [s, t], once for the block's heads
+    from_state = _dot3(_split(s_ref[...]), ct)  # S_0 C_t, [block * p, C]
+    xt = x_ref[...].T  # [block * p, C]
+    for h in range(block):
+        at = slice(h * p, (h + 1) * p)
+        x = xt[at, :]
+        g_t, dt, reach, w = (rows_ref[j * block + h : j * block + h + 1, :] for j in range(4))
+        # the head's triangle: every exponent at most 0, and nothing where ``cb`` is masked
+        m = cb * jnp.exp(jnp.minimum(g_t - g_s_ref[:, h : h + 1], 0.0))
+        y = _dot3(_split(dt * x), _split(m))
+        yt_ref[at, :] = y + reach * from_state[at, :] + skip_ref[first + h] * x
+        xw_ref[at, :] = w * x
+        s_ref[at, :] = kept_ref[h : h + 1, :] * s_ref[at, :]  # what the block keeps of S_0
+    y_ref[...] = yt_ref[...].T
+    s_ref[...] += _dot3(_split(xw_ref[...]), b)  # the chunk's own sum into the state
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd_kernel(x, dt, a, b, c, d=None, state=None, segment=None, chunk: int = 256, interpret: bool = False):
+    """``ssd`` as ONE Pallas kernel (its arguments and results; ``interpret``
+    is how a test runs it off the chip). The grid is ``(row, block of heads,
+    chunk)``, the chunks in order, and a step holds one chunk of one block of
+    heads in VMEM: ``C_t . B_s`` once, a head's triangle ``exp(G_t - G_s)``
+    where ``s <= t`` are one session's, the three products (a head's ``dt x``
+    with its triangle; the block's state with ``C``; the block's ``x`` with
+    ``B``) each in ``PRECISION``'s three passes, and the block's state
+    ``[block * p, n]`` float32, which enters at a row's first chunk and
+    leaves after its last. HBM sees ``x``, ``B``, ``C`` in and ``y`` out once
+    and, a POSITION AND HEAD, what XLA prepares in front: the running sum
+    ``G`` of ``dt a`` inside each chunk, ``exp(G_t)`` where ``S_0`` reaches
+    (``reach``), ``dt_s exp(G_C - G_s)`` where ``s`` reaches ``S_C``
+    (``w``), and a chunk and head ``exp(G_C)`` where the state is carried
+    over (``kept``). None of the XLA form's triangles, sums or states."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, length, heads, p = x.shape
+    n = b.shape[-1]
+    block = _head_block(heads, p)
+    if heads % block:
+        raise ValueError(f"ssd_kernel: {heads} heads are no whole blocks of {block}")
+    chunks = -(-length // chunk)
+    total, blocks, width = chunks * chunk, heads // block, block * p
+
+    def padded(v, fill=0):
+        if total == length:
+            return v
+        return jnp.pad(v, [(0, 0), (0, total - length)] + [(0, 0)] * (v.ndim - 2), constant_values=fill)
+
+    x, dt, b, c = (padded(v.astype(jnp.float32)) for v in (x, dt, b, c))
+    # no ids: one session, the padding behind it too (its ``dt`` is 0)
+    seg = jnp.zeros((batch, total), jnp.int32) if segment is None else padded(segment, -1)
+    by_chunk = seg.reshape(batch, chunks, chunk)
+    # the session live at the end of the chunk before (the first chunk's: the row's first)
+    before = jnp.concatenate([by_chunk[:, :1, 0], by_chunk[:, :-1, -1]], axis=1)
+    carried = (by_chunk == before[..., None])[..., None]  # S_0 reaches these positions
+    ends = (by_chunk == by_chunk[..., -1:])[..., None]  # ... and these reach S_C
+    step = dt.reshape(batch, chunks, chunk, heads)
+    cum = jnp.cumsum(step * a.astype(jnp.float32), axis=2)  # G, anew in every chunk
+    reach = jnp.where(carried, jnp.exp(cum), 0.0)
+    w = jnp.where(ends, jnp.exp(cum[:, :, -1:] - cum), 0.0) * step
+    kept = jnp.where(carried[:, :, -1], jnp.exp(cum[:, :, -1]), 0.0)  # [B, chunks, heads]
+    # ... a row a head over the state's width, [B, blocks, chunks, block, n]
+    kept = kept.reshape(batch, chunks, blocks, block, 1).transpose(0, 2, 1, 3, 4)
+    kept = jnp.broadcast_to(kept, (batch, blocks, chunks, block, n))
+
+    def by_block(v):  # [B, chunks, C, heads] -> [B, blocks, L, block]: a column a head
+        return v.reshape(batch, total, blocks, block).transpose(0, 2, 1, 3)
+
+    # ... and [B, blocks, 4 * block, L]: a row a head of each
+    rows = jnp.concatenate([by_block(v).transpose(0, 1, 3, 2) for v in (cum, step, reach, w)], axis=2)
+    skip = jnp.zeros(heads, jnp.float32) if d is None else d.astype(jnp.float32)
+    if state is None:
+        state = jnp.zeros((batch, heads, p, n), jnp.float32)
+    y, state = pl.pallas_call(
+        functools.partial(_ssd_chunk, block=block, p=p),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # the skip: a number a head, read as a scalar
+            grid=(batch, blocks, chunks),
+            in_specs=[
+                pl.BlockSpec((None, chunk, width), lambda r, h, k, *_: (r, k, h)),
+                pl.BlockSpec((None, chunk, n), lambda r, h, k, *_: (r, k, 0)),
+                pl.BlockSpec((None, chunk, n), lambda r, h, k, *_: (r, k, 0)),
+                pl.BlockSpec((None, None, chunk, block), lambda r, h, k, *_: (r, h, k, 0)),
+                pl.BlockSpec((None, None, 4 * block, chunk), lambda r, h, k, *_: (r, h, 0, k)),
+                pl.BlockSpec((None, None, None, block, n), lambda r, h, k, *_: (r, h, k, 0, 0)),
+                pl.BlockSpec((None, chunk, 1), lambda r, h, k, *_: (r, k, 0)),
+                pl.BlockSpec((None, 1, chunk), lambda r, h, k, *_: (r, 0, k)),
+                pl.BlockSpec((None, width, n), lambda r, h, k, *_: (r, h, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, chunk, width), lambda r, h, k, *_: (r, k, h)),
+                pl.BlockSpec((None, width, n), lambda r, h, k, *_: (r, h, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((width, chunk), jnp.float32)] * 2,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, total, heads * p), jnp.float32),
+            jax.ShapeDtypeStruct((batch, heads * p, n), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(
+        skip, x.reshape(batch, total, heads * p), c, b, by_block(cum), rows, kept,
+        seg[:, :, None], seg[:, None, :], state.astype(jnp.float32).reshape(batch, heads * p, n),
+    )
+    return y.reshape(batch, total, heads, p)[:, :length], state.reshape(batch, heads, p, n)

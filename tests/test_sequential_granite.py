@@ -12,6 +12,7 @@ float32 for both sides, as ``test_sequential_olmoe.py`` does.
 """
 
 import dataclasses
+import functools
 import json
 from pathlib import Path
 
@@ -130,13 +131,28 @@ def scan_case(seed, length, heads=4, p=8, state=16):
     return x, step, a, b, c, d
 
 
+# The scan has two forms (``ops/linear_attention.ssd``): the XLA form, which is
+# what ``ssd`` is off the chip, and the kernel that serves on it, run here
+# interpreted. The kernel's cases take the published head (64 channels over a
+# state of 128: what the chip's kernel tiles) and its products' three
+# bfloat16 passes, which the CPU rounds as the chip does: 1e-5
+# of the outputs' size where the XLA form, float32 here, is within 3e-6.
+FORMS = {"xla": linear_attention.ssd, "kernel": functools.partial(linear_attention.ssd_kernel, interpret=True)}
+HEADS = {"xla": dict(heads=4, p=8, state=16), "kernel": dict(heads=4, p=64, state=128)}
+# how far a prefix and the rest from its state may lie from the whole scan: the
+# XLA form, float32 here, absolutely; the kernel's three-pass products by the
+# size of what they sum (its outputs and its state reach 10 and more)
+HANDED_ON = {"xla": lambda whole: 2e-4, "kernel": lambda whole: 2e-4 * max(1.0, float(jnp.abs(whole).max()))}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("chunk", [64, 128, 256])
 @pytest.mark.parametrize("length", [50, 256, 640])
-def test_the_chunked_scan_equals_the_recurrence_at_every_chunk(chunk, length):
-    x, step, a, b, c, d = scan_case(length, length)
+def test_the_chunked_scan_equals_the_recurrence_at_every_chunk(chunk, length, form):
+    x, step, a, b, c, d = scan_case(length, length, **HEADS[form])
     want = reference.ssd_recurrence(x, step, a, b, c, d)
-    got, _ = linear_attention.ssd(x[None], step[None], a, b[None], c[None], d, chunk=chunk)
-    assert got.shape == (1, length, 4, 8)
+    got, _ = FORMS[form](x[None], step[None], a, b[None], c[None], d, chunk=chunk)
+    assert got.shape == (1, length) + x.shape[1:]
     np.testing.assert_allclose(got[0], want, atol=1e-4 * float(jnp.abs(want).max()), rtol=0)
 
 
@@ -145,15 +161,15 @@ def test_the_chunked_scan_equals_the_recurrence_at_every_chunk(chunk, length):
 SESSIONS = ((0, 100), (128, 328), (384, 640))
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("chunk", [64, 128, 256])
-def test_a_session_that_begins_inside_a_chunk_begins_from_a_zero_state(chunk):
-    x, step, a, b, c, d = scan_case(3, 640)
+def test_a_session_that_begins_inside_a_chunk_begins_from_a_zero_state(chunk, form):
+    ssd = FORMS[form]
+    x, step, a, b, c, d = scan_case(3, 640, **HEADS[form])
     segment = np.full(640, -1, np.int32)
     for i, (start, end) in enumerate(SESSIONS):
         segment[start:end] = i
-    got, _ = linear_attention.ssd(
-        x[None], step[None], a, b[None], c[None], d, segment=jnp.asarray(segment)[None], chunk=chunk
-    )
+    got, _ = ssd(x[None], step[None], a, b[None], c[None], d, segment=jnp.asarray(segment)[None], chunk=chunk)
     assert bool(jnp.isfinite(got).all())  # the padding's output means nothing and is a number
     for start, end in SESSIONS:
         alone = (v[start:end] for v in (x, step, b, c))
@@ -161,18 +177,71 @@ def test_a_session_that_begins_inside_a_chunk_begins_from_a_zero_state(chunk):
         want = reference.ssd_recurrence(x_, step_, a, b_, c_, d)
         np.testing.assert_allclose(got[0, start:end], want, atol=1e-4 * float(jnp.abs(want).max()), rtol=0)
     # ... and WITHOUT the ids the second session reads the first one's state
-    leaked, _ = linear_attention.ssd(x[None], step[None], a, b[None], c[None], d, chunk=chunk)
+    leaked, _ = ssd(x[None], step[None], a, b[None], c[None], d, chunk=chunk)
     assert float(jnp.abs(leaked[0, 128:160] - got[0, 128:160]).max()) > 1e-2
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("cut", [64, 200, 256])
-def test_a_prefix_then_the_rest_from_its_state_equals_the_whole_scan(cut):
-    x, step, a, b, c, d = (v[None] if v.ndim > 1 else v for v in scan_case(9, 400))
-    whole, last = linear_attention.ssd(x, step, a, b, c, d, chunk=128)
-    head, state = linear_attention.ssd(x[:, :cut], step[:, :cut], a, b[:, :cut], c[:, :cut], d, chunk=128)
-    tail, end = linear_attention.ssd(x[:, cut:], step[:, cut:], a, b[:, cut:], c[:, cut:], d, state=state, chunk=128)
-    np.testing.assert_allclose(jnp.concatenate([head, tail], axis=1), whole, atol=2e-4, rtol=0)
-    np.testing.assert_allclose(end, last, atol=2e-4, rtol=0)
+def test_a_prefix_then_the_rest_from_its_state_equals_the_whole_scan(cut, form):
+    ssd = FORMS[form]
+    x, step, a, b, c, d = (v[None] if v.ndim > 1 else v for v in scan_case(9, 400, **HEADS[form]))
+    whole, last = ssd(x, step, a, b, c, d, chunk=128)
+    head, state = ssd(x[:, :cut], step[:, :cut], a, b[:, :cut], c[:, :cut], d, chunk=128)
+    tail, end = ssd(x[:, cut:], step[:, cut:], a, b[:, cut:], c[:, cut:], d, state=state, chunk=128)
+    np.testing.assert_allclose(jnp.concatenate([head, tail], axis=1), whole, atol=HANDED_ON[form](whole), rtol=0)
+    np.testing.assert_allclose(end, last, atol=HANDED_ON[form](last), rtol=0)
+
+
+def test_the_kernel_without_ids_is_the_xla_form_and_hands_on_the_same_state():
+    """``segment=None``: one session a row, the padding behind a ragged
+    length (its step is 0) part of it, so the state that comes back is the
+    state after the last real position in both forms."""
+    x, step, a, b, c, d = (v[None] if v.ndim > 1 else v for v in scan_case(11, 200, **HEADS["kernel"]))
+    want, last = FORMS["xla"](x, step, a, b, c, d, chunk=128)
+    got, state = FORMS["kernel"](x, step, a, b, c, d, chunk=128)
+    np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.abs(want).max()), rtol=0)
+    np.testing.assert_allclose(state, last, atol=1e-4 * float(jnp.abs(last).max()), rtol=0)
+    # ... and no skip where none is given
+    bare, _ = FORMS["kernel"](x, step, a, b, c, chunk=128)
+    np.testing.assert_allclose(bare, got - d[:, None] * x, atol=1e-5 * float(jnp.abs(want).max()), rtol=0)
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_a_row_of_padding_alone_hands_the_state_on_as_it_came(form):
+    """What ``ssd`` pads a row with (the id -1 and a step of 0) through two
+    whole chunks: nothing is added to the state and nothing of it decays."""
+    x, _, a, b, c, d = (v[None] if v.ndim > 1 else v for v in scan_case(5, 256, **HEADS[form]))
+    rng = np.random.default_rng(5)
+    state = jnp.asarray(rng.normal(size=(1,) + x.shape[2:] + b.shape[-1:]), jnp.float32)
+    padding = dict(segment=jnp.full((1, 256), -1, jnp.int32), state=state, chunk=128)
+    y, after = FORMS[form](x, jnp.zeros(x.shape[:3], jnp.float32), a, b, c, d, **padding)
+    assert bool(jnp.isfinite(y).all())
+    np.testing.assert_array_equal(after, state)
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_two_rows_are_scanned_each_alone(form):
+    """``B`` = 2, each row with its own sessions and its own incoming state:
+    a row comes out as it does when it is the only one."""
+    ssd = FORMS[form]
+    x, step, a, b, c, d = scan_case(13, 2 * 320, **HEADS[form])
+    x, step, b, c = (v.reshape((2, 320) + v.shape[1:]) for v in (x, step, b, c))
+    segment = np.full((2, 320), -1, np.int32)
+    segment[0, :90], segment[0, 128:320], segment[1, :200], segment[1, 256:300] = 0, 1, 0, 1
+    segment = jnp.asarray(segment)
+    rng = np.random.default_rng(13)
+    state = jnp.asarray(rng.normal(size=(2,) + x.shape[2:] + b.shape[-1:]), jnp.float32)
+    both, after = ssd(x, step, a, b, c, d, state=state, segment=segment, chunk=128)
+    for row in range(2):
+        at = slice(row, row + 1)
+        alone, its = ssd(x[at], step[at], a, b[at], c[at], d, state=state[at], segment=segment[at], chunk=128)
+        np.testing.assert_allclose(both[at], alone, atol=1e-6 * float(jnp.abs(alone).max()), rtol=0)
+        np.testing.assert_allclose(after[at], its, atol=1e-6 * float(jnp.abs(its).max()), rtol=0)
+    # the incoming state reaches the row's FIRST session and no other
+    fresh, _ = ssd(x, step, a, b, c, d, segment=segment, chunk=128)
+    assert float(jnp.abs(fresh[0, :90] - both[0, :90]).max()) > 1e-3
+    np.testing.assert_array_equal(fresh[0, 128:320], both[0, 128:320])
 
 
 def test_the_convolutions_bias_stands_inside_the_activation():

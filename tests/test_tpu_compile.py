@@ -389,31 +389,70 @@ def test_lfm2s_whole_depth_compiles_for_v5e_beside_the_model(one_chip, monkeypat
     assert memory.temp_size_in_bytes < 2.0e9
     assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
 
-@pytest.mark.parametrize("chunk", [64, 128, 256])
-def test_the_chunked_state_space_scan_compiles_for_v5e_at_the_published_heads(one_chip, chunk):
-    """``ops/linear_attention.ssd`` at granite-4.0-h-small's 128 heads of 64
-    over a state of 128, one stream of 2,048 tokens under session ids, at the
-    three chunks the chip was asked about: the triangles grow with the chunk
-    (its temporaries stay well under a gigabyte at the widest)."""
+def _compiled_scan(one_chip, length, p, chunk, heads=128, n=128):
+    """``ops/linear_attention.ssd`` as the chip would be served it, compiled
+    for v5e at granite-4.0-h-small's 128 heads over a state of 128, one
+    stream under session ids."""
     from predictionio_tpu.ops.linear_attention import ssd
 
-    length, heads, p, n = 2048, 128, 64, 128
     args = (
         _shape(one_chip, (1, length, heads, p)), _shape(one_chip, (1, length, heads)), _shape(one_chip, (heads,)),
         _shape(one_chip, (1, length, n)), _shape(one_chip, (1, length, n)), _shape(one_chip, (heads,)),
     )
     segment = _shape(one_chip, (1, length), jnp.int32)
     compiled = jax.jit(lambda *a, segment: ssd(*a, segment=segment, chunk=chunk)).lower(*args, segment=segment).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
     y, state = jax.eval_shape(lambda *a: ssd(*a, chunk=chunk), *args)
     assert y.shape == (1, length, heads, p) and state.shape == (1, heads, p, n)
+    return compiled
+
+
+def _triangle(chunk, heads=128):
+    """The XLA form's decays, a head, chunk and pair of positions."""
+    return re.compile(rf"f32\[[0-9,]*{heads},{chunk},{chunk}\]")
+
+
+@pytest.mark.parametrize(("chunk", "p"), [(64, 64), (128, 48), (256, 48)])
+def test_the_chip_is_served_the_xla_form_of_the_scan_where_the_kernel_does_not_tile(one_chip, monkeypatch, chunk, p):
+    """Shapes ``ssd_tiles`` refuses, a chunk of 64 at the published head and
+    heads of 48 channels at the chunks it takes, compile ON THE CHIP to the XLA
+    form, no Mosaic call: the triangles grow with the chunk (its temporaries
+    stay well under a gigabyte at the widest)."""
+    from predictionio_tpu.ops.linear_attention import ssd_tiles
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not ssd_tiles(128, p, 128, chunk)
+    compiled = _compiled_scan(one_chip, 2048, p, chunk)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and _triangle(chunk).search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize(("length", "chunk"), [(2048, 128), (4096, 128), (2048, 256)])
+def test_the_state_space_scan_is_one_kernel_on_the_chip_at_the_shapes_it_tiles(one_chip, monkeypatch, length, chunk):
+    """On the chip ``ops/linear_attention.ssd`` at the shapes ``ssd_tiles``
+    takes (the published head, 64 channels over a state of 128, at
+    ``granite.SSD_CHUNK`` and at ``ssd``'s own default chunk) is ONE Mosaic
+    call with none of the XLA form's triangles beside it. The predicate takes
+    what is compiled here and nothing else: not another head, state or chunk,
+    nor heads that are no whole blocks of 16."""
+    from predictionio_tpu.models.sequential.granite import SSD_CHUNK
+    from predictionio_tpu.ops.linear_attention import ssd_tiles
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert SSD_CHUNK == 128 and ssd_tiles(128, 64, 128, chunk)
+    refused = [(128, 48, 128, chunk), (128, 16, 128, chunk), (128, 128, 128, chunk), (128, 64, 64, chunk)]
+    refused += [(128, 64, 256, chunk), (128, 64, 128, 64), (128, 64, 128, 512), (8, 64, 128, chunk)]
+    assert not any(ssd_tiles(*shape) for shape in refused)
+    text = _compiled_scan(one_chip, length, 64, chunk).as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and not _triangle(chunk).search(text)
 
 
 @pytest.mark.parametrize("length", [2048, 4096])
 def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch, length):
     """``granite.session_vectors`` at ``seq-granite-4-h``'s widths, ten
     layers unrolled, at both lengths of its closed set: ONE attention kernel
-    at 32 query heads over 8 of 128 and 10 x 3 grouped products over 36 held
+    at 32 query heads over 8 of 128, nine state-space scans (one kernel a
+    Mamba-2 layer, PR 51) and 10 x 3 grouped products over 36 held
     experts 768 wide with hidden 4,096 as the contraction, over the block of
     the held copies (half the router is held: all the copies' rows, 20,480
     and 40,960, in tiles of 256, every held group from a tile's edge; the
@@ -439,7 +478,7 @@ def test_granites_period_compiles_for_v5e_beside_the_model(one_chip, monkeypatch
     last = _shape(one_chip, (1, granite.TOKEN_BUDGET // granite.SESSION_ALIGN), jnp.int32)
     compiled = granite.session_vectors.lower(weights, stream, stream, stream, last, config=config).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 + 10 * 3
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 + 10 * 3 + 9
     assert f"f32[{length},10,4096]" not in text and f"f32[10,{length},4096]" in text
     assert memory.temp_size_in_bytes < (1.6e9 if length == 2048 else 3.0e9)
     assert 9.5e9 < memory.argument_size_in_bytes < 9.6e9
